@@ -11,8 +11,33 @@ from __future__ import annotations
 
 import glob
 import os
+import sys
 
 _GKE_TPU_ENV = "TPU_WORKER_ID"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def ensure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache for this process and every
+    process it spawns; returns the directory.
+
+    `JAX_COMPILATION_CACHE_DIR` set from outside wins and nothing is set
+    in code. Otherwise the cache lives at ONE fixed path inside the
+    checkout: the path is part of the cache key's environment, so a
+    directory made from a temporary name, a pid or a time never hits.
+    Thresholds stay at JAX's defaults. Workers get the variable through
+    `build_worker_env`; a process that compiles on its own (bench_tpu.py)
+    calls this before its first jit."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+    jax = sys.modules.get("jax")
+    if jax is not None:  # imported before the variable existed
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def detect_tpus() -> int:

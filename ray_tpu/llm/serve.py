@@ -387,6 +387,15 @@ class _LLMServerImpl:
     def model_ids(self) -> list:
         return [self.cfg.model_id, *self._adapters]
 
+    def device(self) -> dict:
+        """What this replica's engine really runs on, as JAX reports it
+        (a replica deployed with num_tpus_per_replica=0 says "cpu")."""
+        import jax
+        dev = jax.devices()[0]
+        return {"platform": dev.platform, "kind": dev.device_kind,
+                "count": jax.device_count(),
+                "memory": dev.memory_stats()}  # None on the CPU backend
+
     def __del__(self):
         self._stop = True
 
@@ -410,7 +419,11 @@ def _logprob_fields(tokenizer, text: str, stopped: bool, generated,
             decoded_len += len(tokenizer.decode([t]))
             if decoded_len >= len(text):
                 break
+    # token_ids: the byte tokenizer renders every id >= 256 as "", so at a
+    # real vocabulary the ids are the only way to tell which token each
+    # log-probability belongs to.
     return {"tokens": [tokenizer.decode([t]) for t in kept],
+            "token_ids": [int(t) for t in kept],
             "token_logprobs": list(token_logprobs[:len(kept)])}
 
 
@@ -545,7 +558,27 @@ class _OpenAiRouterImpl:
         return 404, {"error": f"no route {path}"}
 
 
+def _warn_if_cpu_deployment(llm_config: LLMConfig) -> None:
+    """A replica that reserves no chip boots on the CPU backend like every
+    pooled worker, where the paged kernels run interpreted or as plain XLA
+    and flash attention as the dense reference. On a cluster that HAS
+    chips that is almost never meant: say so once."""
+    import ray_tpu
+    if llm_config.num_tpus_per_replica or not ray_tpu.is_initialized():
+        return
+    chips = ray_tpu.cluster_resources().get("TPU", 0)
+    if chips:
+        import warnings
+        warnings.warn(
+            f"LLMConfig(model_id={llm_config.model_id!r}) has "
+            f"num_tpus_per_replica=0 on a cluster with {chips:g} TPU "
+            "chip(s): its replicas will serve from the CPU backend. Pass "
+            "num_tpus_per_replica=1 (or more) to run them on the chips.",
+            RuntimeWarning, stacklevel=3)
+
+
 def build_llm_deployment(llm_config: LLMConfig):
+    _warn_if_cpu_deployment(llm_config)
     d = serve.deployment(
         _LLMServerImpl, name=f"LLMServer:{llm_config.model_id}")
     return d.options(
@@ -1305,6 +1338,7 @@ def build_disagg_deployment(llm_config: LLMConfig,
     """The disaggregated serving plane as an Application rooted at the
     coordinator: a prefill pool + a decode pool + the coordinator wiring
     them (admission, prefix routing, handoff, recovery)."""
+    _warn_if_cpu_deployment(llm_config)
     d = disagg or DisaggConfig()
     mid = llm_config.model_id
     prefill = serve.deployment(

@@ -5,35 +5,40 @@ from __future__ import annotations
 from ray_tpu.models.transformer import ModelConfig
 
 
+def _preset(kw: dict, **fields) -> ModelConfig:
+    """A preset's fields with the caller's overrides winning, so depth can
+    be cut without touching a width: `configs.qwen2_7b(n_layers=2)`."""
+    return ModelConfig(**(fields | kw))
+
+
 def tiny(**kw) -> ModelConfig:
     """CPU-test scale."""
-    return ModelConfig(vocab=256, d_model=64, n_layers=2, n_heads=4,
-                       n_kv_heads=2, d_ff=128, **kw)
+    return _preset(kw, vocab=256, d_model=64, n_layers=2, n_heads=4,
+                   n_kv_heads=2, d_ff=128)
 
 
 def tiny_moe(**kw) -> ModelConfig:
-    return ModelConfig(vocab=256, d_model=64, n_layers=2, n_heads=4,
-                       n_kv_heads=4, d_ff=128, moe_experts=4, moe_top_k=2,
-                       **kw)
+    return _preset(kw, vocab=256, d_model=64, n_layers=2, n_heads=4,
+                   n_kv_heads=4, d_ff=128, moe_experts=4, moe_top_k=2)
 
 
 def llama3_8b(**kw) -> ModelConfig:
     """Llama-3-8B geometry (BASELINE north-star FSDP config)."""
-    return ModelConfig(vocab=128256, d_model=4096, n_layers=32, n_heads=32,
-                       n_kv_heads=8, d_ff=14336, rope_theta=500000.0,
-                       dtype="bfloat16", remat=True, **kw)
+    return _preset(kw, vocab=128256, d_model=4096, n_layers=32, n_heads=32,
+                   n_kv_heads=8, d_ff=14336, rope_theta=500000.0,
+                   dtype="bfloat16", remat=True)
 
 
 def llama3_1b(**kw) -> ModelConfig:
-    return ModelConfig(vocab=128256, d_model=2048, n_layers=16, n_heads=32,
-                       n_kv_heads=8, d_ff=8192, rope_theta=500000.0,
-                       dtype="bfloat16", **kw)
+    return _preset(kw, vocab=128256, d_model=2048, n_layers=16, n_heads=32,
+                   n_kv_heads=8, d_ff=8192, rope_theta=500000.0,
+                   dtype="bfloat16")
 
 
 def bench_125m(**kw) -> ModelConfig:
     """Single-chip bench scale (GPT-small geometry)."""
-    return ModelConfig(vocab=32000, d_model=768, n_layers=12, n_heads=12,
-                       n_kv_heads=12, d_ff=3072, dtype="bfloat16", **kw)
+    return _preset(kw, vocab=32000, d_model=768, n_layers=12, n_heads=12,
+                   n_kv_heads=12, d_ff=3072, dtype="bfloat16")
 
 
 def llama_125m(**kw) -> ModelConfig:
@@ -43,23 +48,22 @@ def llama_125m(**kw) -> ModelConfig:
 
 def llama3_70b(**kw) -> ModelConfig:
     """Llama-3-70B geometry (multi-slice FSDP+TP target)."""
-    return ModelConfig(vocab=128256, d_model=8192, n_layers=80, n_heads=64,
-                       n_kv_heads=8, d_ff=28672, rope_theta=500000.0,
-                       dtype="bfloat16", remat=True, **kw)
+    return _preset(kw, vocab=128256, d_model=8192, n_layers=80, n_heads=64,
+                   n_kv_heads=8, d_ff=28672, rope_theta=500000.0,
+                   dtype="bfloat16", remat=True)
 
 
 def mixtral_8x7b(**kw) -> ModelConfig:
     """Mixtral-8x7B geometry: 8-expert top-2 MoE (the EP mesh-axis
     flagship)."""
-    return ModelConfig(vocab=32000, d_model=4096, n_layers=32, n_heads=32,
-                       n_kv_heads=8, d_ff=14336, rope_theta=1e6,
-                       moe_experts=8, moe_top_k=2,
-                       dtype="bfloat16", remat=True, **kw)
+    return _preset(kw, vocab=32000, d_model=4096, n_layers=32, n_heads=32,
+                   n_kv_heads=8, d_ff=14336, rope_theta=1e6,
+                   moe_experts=8, moe_top_k=2,
+                   dtype="bfloat16", remat=True)
 
 
 def qwen2_7b(**kw) -> ModelConfig:
     """Qwen-2-7B-class geometry (GQA, untied head)."""
-    return ModelConfig(vocab=152064, d_model=3584, n_layers=28, n_heads=28,
-                       n_kv_heads=4, d_ff=18944, rope_theta=1e6,
-                       dtype="bfloat16", remat=True, tie_embeddings=False,
-                       **kw)
+    return _preset(kw, vocab=152064, d_model=3584, n_layers=28, n_heads=28,
+                   n_kv_heads=4, d_ff=18944, rope_theta=1e6,
+                   dtype="bfloat16", remat=True, tie_embeddings=False)

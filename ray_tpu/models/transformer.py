@@ -157,7 +157,8 @@ def _attention(x, lp, c: ModelConfig, sin, cos, mesh):
             from ray_tpu.parallel.ulysses import ulysses_attention
             o = ulysses_attention(q, k, v, mesh, causal=True)
     else:
-        o = flash_attention(q, k, v, causal=True, impl=c.attn_impl)
+        o = flash_attention(q, k, v, causal=True, impl=c.attn_impl,
+                            mesh=mesh)
     o = o.reshape(b, s, h * hd)
     return jnp.einsum("bsk,kd->bsd", o, lp["wo"])
 

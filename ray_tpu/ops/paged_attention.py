@@ -30,6 +30,7 @@ Returns [B, n_heads, head_dim].
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -37,11 +38,22 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax 0.4.x names it TPUCompilerParams; same fields.
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 _NEG = -0.7 * float(np.finfo(np.float32).max)
+
+
+def _mosaic_tiles(page: int, hd: int) -> bool:
+    """Mosaic can only DMA page slices whose trailing dims tile to
+    (8, 128). Off-size pages (toy/test configs) take the XLA
+    gather-attend formulation — slower, always correct. Only the compiled
+    (on-chip) path asks, and there no dispatcher leaves the kernel
+    without saying so."""
+    if page % 128 == 0 and hd % 8 == 0:
+        return True
+    warnings.warn(
+        f"paged attention: page_size={page}, head_dim={hd} do not tile to "
+        "(8, 128); running the XLA gather formulation instead of the "
+        "Pallas kernel", RuntimeWarning, stacklevel=3)
+    return False
 
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
@@ -60,10 +72,7 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     page, hd = k_pages.shape[3], k_pages.shape[2]
-    if not interpret and (page % 128 or hd % 8):
-        # Mosaic can only DMA page slices whose trailing dims tile to
-        # (8, 128); off-size pages (toy/test configs) fall back to the
-        # XLA gather-attend formulation — slower, always correct.
+    if not interpret and not _mosaic_tiles(page, hd):
         return _paged_decode_xla(q, k_pages, v_pages, lengths, page_tables)
     return _paged_decode_dma(q, k_pages, v_pages, lengths,
                              page_tables, interpret=interpret)
@@ -356,9 +365,8 @@ def paged_verify_insert_attention(q, pool_k, pool_v, knew, vnew,
     # Interpret mode does not propagate the kernel's in-place HBM
     # writebacks through the input/output aliasing (verified empirically:
     # the aliased outputs come back unmodified), so CPU paths — tests and
-    # the multichip dryrun — take the XLA insert+attend fallback. The
-    # Mosaic path also needs (8, 128)-tileable page slices.
-    if interpret or page % 128 or hd % 8:
+    # the multichip dryrun — take the XLA insert+attend fallback.
+    if interpret or not _mosaic_tiles(page, hd):
         return _verify_insert_xla(q, pool_k, pool_v, knew, vnew,
                                   lengths, page_tables, layer)
     return _verify_insert_dma(q, pool_k, pool_v, knew, vnew, lengths,
@@ -475,7 +483,7 @@ def paged_verify_attention(q, k_pages, v_pages, lengths, page_tables, *,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     page, hd = k_pages.shape[3], k_pages.shape[2]
-    if not interpret and (page % 128 or hd % 8):
+    if not interpret and not _mosaic_tiles(page, hd):
         return _paged_verify_xla(q, k_pages, v_pages, lengths, page_tables)
     return _paged_verify_dma(q, k_pages, v_pages, lengths, page_tables,
                              interpret=interpret)

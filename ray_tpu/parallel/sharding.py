@@ -101,19 +101,11 @@ def with_sharding(x, mesh: Mesh, spec: P):
 
 
 def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions: `jax.shard_map(..., check_vma=)` on
-    current jax, `jax.experimental.shard_map.shard_map(..., check_rep=)`
-    on 0.4.x — same semantics (replication checking off; the wrappers
-    here all psum/permute explicitly). Every sp/pp entry point routes
-    through this so one jax upgrade path touches one function."""
-    try:
-        from jax import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """`jax.shard_map` with replication checking off (the wrappers here
+    all psum/permute explicitly). Every sp/pp entry point routes through
+    this, so that choice is made in one place."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def data_axes(mesh: Mesh) -> tuple[str, ...]:

@@ -83,7 +83,7 @@ class Learner:
     @staticmethod
     def _finalize_metrics(loss, aux) -> dict:
         # ONE device fetch for every metric — per-scalar float() costs a
-        # blocking round trip each (painful on remote/tunneled devices).
+        # blocking host sync each.
         loss, aux = jax.device_get((loss, aux))
         out = {"total_loss": float(loss)}
         out.update({k: float(v) for k, v in aux.items()})

@@ -36,6 +36,8 @@ class _ReplicaState:
         self.handle = handle
         self.version = version
         self.healthy = False
+        self.started = False       # has answered once: constructor done
+        self.checking = False      # one health check in flight at a time
         self.last_health_check = 0.0
         self.health_check_failures = 0
 
@@ -314,9 +316,11 @@ class ServeController:
         # 1) health-check running replicas.
         now = time.monotonic()
         for r in list(ds.replicas):
-            if now - r.last_health_check < cfg.health_check_period_s:
+            if (r.checking
+                    or now - r.last_health_check < cfg.health_check_period_s):
                 continue
             r.last_health_check = now
+            r.checking = True
             asyncio.ensure_future(self._check_replica(ds, r))
         # 2) cull replicas that failed health checks or are from old versions
         #    once enough new-version replicas are healthy (rolling update).
@@ -342,10 +346,20 @@ class ServeController:
                 await self._stop_replica(ds, r, graceful=True)
 
     async def _check_replica(self, ds, r):
+        # A replica that has never answered is still in its constructor
+        # (an LLM replica initialises its weights and KV pool there:
+        # minutes at real widths), where the call simply queues. Holding
+        # it to the health-check deadline killed and restarted every
+        # replica that took longer than three timeouts to construct. It
+        # is waited for as long as its actor lives — a constructor that
+        # raises or a process that dies fails this call at once — and only
+        # a replica that HAS answered is held to the deadline.
         try:
             await asyncio.wait_for(
                 _await_ref(r.handle.check_health.remote()),
-                timeout=ds.config.health_check_timeout_s)
+                timeout=(ds.config.health_check_timeout_s if r.started
+                         else None))
+            r.started = True
             if not r.healthy:
                 ds.snapshot_version += 1
             r.healthy = True
@@ -355,6 +369,8 @@ class ServeController:
             if r.healthy:
                 r.healthy = False
                 ds.snapshot_version += 1
+        finally:
+            r.checking = False
 
     def _start_replica(self, ds: _DeploymentState):
         import cloudpickle

@@ -52,6 +52,10 @@ class ProxyActor:
         self._routes = {}          # prefix -> (app_name, ingress_deployment)
         self._handles = {}         # app_name -> DeploymentHandle
         self._last_refresh = 0.0
+        # Concurrent requests wait for an in-flight refresh instead of
+        # matching against the table it has not filled yet (the first
+        # burst after a deploy otherwise 404s all but one request).
+        self._refresh_lock = asyncio.Lock()
         self._server = None
         self._num_requests = 0
 
@@ -67,18 +71,19 @@ class ProxyActor:
         return self._num_requests
 
     async def _refresh_routes(self):
-        now = time.monotonic()
-        if now - self._last_refresh < self.ROUTE_REFRESH_S:
-            return
-        self._last_refresh = now
-        try:
-            controller = ray_tpu.get_actor(CONTROLLER_NAME)
-            ref = controller.get_http_routes.remote()
-            loop = asyncio.get_running_loop()
-            self._routes = await loop.run_in_executor(
-                None, lambda: ray_tpu.get(ref, timeout=5))
-        except (RayTpuError, ValueError):
-            pass
+        async with self._refresh_lock:
+            now = time.monotonic()
+            if now - self._last_refresh < self.ROUTE_REFRESH_S:
+                return
+            self._last_refresh = now
+            try:
+                controller = ray_tpu.get_actor(CONTROLLER_NAME)
+                ref = controller.get_http_routes.remote()
+                loop = asyncio.get_running_loop()
+                self._routes = await loop.run_in_executor(
+                    None, lambda: ray_tpu.get(ref, timeout=5))
+            except (RayTpuError, ValueError):
+                pass
 
     def _match(self, path: str):
         best = None

@@ -51,6 +51,9 @@ class ScalingConfig:
     """Parity: ray.train.ScalingConfig (air/config.py)."""
 
     num_workers: int = 1          # = number of hosts in the mesh
+    # True reserves chips for every worker (never zero: fit() raises on a
+    # cluster without TPUs), so the worker leaves the CPU backend pooled
+    # workers boot on and owns its host's chips.
     use_tpu: bool = False
     resources_per_worker: dict | None = None
     chips_per_worker: int = 0     # 0 = all chips on the host
@@ -300,11 +303,32 @@ class JaxTrainer:
         req["CPU"] = res.get("CPU", 1)
         tpus = res.get("TPU", self.scaling.chips_per_worker
                        if self.scaling.use_tpu else 0)
+        if self.scaling.use_tpu and not tpus:
+            tpus = self._chips_per_host()
         if tpus:
             req["TPU"] = tpus
         else:
             req.pop("TPU", None)
         return req
+
+    @staticmethod
+    def _chips_per_host() -> float:
+        """use_tpu with chips_per_worker=0: every chip of a host (one
+        worker per host owns its chips). The largest live host's count —
+        slices are homogeneous, and on a mixed cluster a request too big
+        for the small hosts waits loudly where a smaller one would put
+        two owners on one host. Zero chips anywhere is an error: resolving
+        to no TPU would train on the CPU and say nothing."""
+        chips = max((row["resources"].get("TPU", 0.0)
+                     for row in ray_tpu.nodes() if row["alive"]),
+                    default=0.0)
+        if not chips:
+            from ray_tpu.core.status import ResourceError
+            raise ResourceError(
+                "ScalingConfig(use_tpu=True) but no live node of the "
+                "cluster has TPU chips (ray_tpu.init() found none; pass "
+                "num_tpus= only to declare chips the host really has)")
+        return chips
 
     def _fit_now(self) -> int:
         """Workers placeable RIGHT NOW, summed per node (aggregate totals
@@ -449,6 +473,7 @@ class JaxTrainer:
     def fit(self) -> Result:
         import cloudpickle
         from ray_tpu.train import checkpoint as ckpt_mod
+        self._per_worker_req()  # misconfigured from the start: raise raw
         storage_dir = self._storage_dir()
         _register_run(self)
         loop_bytes = cloudpickle.dumps(self.train_loop)

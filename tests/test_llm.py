@@ -522,6 +522,9 @@ def test_openai_logprobs_surface(openai_llm_app):
     lp = out["choices"][0]["logprobs"]
     assert len(lp["token_logprobs"]) == 3
     assert len(lp["tokens"]) == 3
+    # ids alongside the (lossy, byte-tokenizer) strings
+    assert len(lp["token_ids"]) == 3
+    assert all(isinstance(t, int) for t in lp["token_ids"])
     assert all(x <= 0.0 for x in lp["token_logprobs"])
 
 

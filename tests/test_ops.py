@@ -68,6 +68,41 @@ def test_flash_attention_gqa():
     assert out.shape == q.shape
 
 
+@pytest.mark.parametrize("axes", [dict(fsdp=4), dict(fsdp=2, tp=2),
+                                  dict(fsdp=1, tp=4)])
+def test_flash_attention_per_shard_on_a_mesh(axes):
+    """Inside a jit over several devices the kernel runs under shard_map
+    (GSPMD cannot partition a Mosaic call): batch over the data axes,
+    heads over tp — also when tp exceeds the KV heads (GQA broadcast
+    first). Forward and gradients match the unsharded reference."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+    mesh = make_mesh(MeshConfig(**axes), devices=jax.devices()[:4])
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (4, 128, 8, 16))
+    k = jax.random.normal(ks[1], (4, 128, 2, 16))
+    v = jax.random.normal(ks[2], (4, 128, 2, 16))
+
+    def loss(impl, mesh, q, k, v):
+        out = flash_attention(q, k, v, impl=impl, mesh=mesh)
+        return jnp.sum(out ** 2), out
+
+    sh = NamedSharding(mesh, P(("dp", "fsdp")))
+    args = [jax.device_put(x, sh) for x in (q, k, v)]
+    (_, got), g_got = jax.jit(jax.value_and_grad(
+        lambda *a: loss("interpret", mesh, *a), argnums=(0, 1, 2),
+        has_aux=True))(*args)
+    (_, ref), g_ref = jax.value_and_grad(
+        lambda *a: loss("reference", None, *a), argnums=(0, 1, 2),
+        has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(g_got, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-2, atol=2e-2)
+
+
 def test_flash_attention_grads():
     q, k, v = _qkv(jax.random.PRNGKey(3), s=32, d=16)
 

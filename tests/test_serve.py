@@ -262,6 +262,32 @@ def test_replica_recovery(serve_instance):
     serve.delete("fragile")
 
 
+def test_slow_constructor_is_waited_for_not_restarted(serve_instance,
+                                                       tmp_path):
+    """A replica still in its constructor (a model replica loads weights
+    there for minutes) is not held to the health-check deadline: it comes
+    up once, instead of being killed and restarted every three timeouts."""
+    births = tmp_path / "births"
+
+    @serve.deployment(health_check_period_s=0.2)
+    class SlowStart:
+        def __init__(self, log):
+            with open(log, "a") as f:
+                f.write("born\n")
+            time.sleep(4.0)
+
+        def __call__(self, x):
+            return x
+
+    SlowStart.config.health_check_timeout_s = 0.5  # 3 x 0.5 s << 4 s
+    h = serve.run(SlowStart.bind(str(births)), name="slowstart",
+                  route_prefix=None, http_port=HTTP_PORT,
+                  blocking_timeout_s=30)
+    assert h.remote(7).result() == 7
+    assert births.read_text() == "born\n"
+    serve.delete("slowstart")
+
+
 def test_batching(serve_instance):
     @serve.deployment(max_ongoing_requests=32)
     class Batched:

@@ -1,8 +1,8 @@
 """AOT lowering of registered graphs under simulated meshes.
 
 No execution, no TPU: `jax.jit(fn, ...).lower(*avals)` traces and lowers
-on CPU (the jax-0.4.37 seam — `.lower()` on the jit wrapper, StableHLO
-via `.as_text()`), `.compile()` runs the XLA pipeline far enough to
+on CPU (`.lower()` on the jit wrapper, StableHLO via `.as_text()`),
+`.compile()` runs the XLA pipeline far enough to
 expose the partitioned module (collectives, input shardings, memory and
 cost analyses) without ever dispatching. Meshes are carved out of the
 virtual CPU device set (`--xla_force_host_platform_device_count`), the
@@ -101,20 +101,17 @@ def lower_graph(spec: GraphSpec) -> LoweredGraph:
     # cache warmth (and graphcheck's own compiles would pollute the cache
     # the test suite shares). Hermetic: cache off for the compile, restored
     # after.
+    from jax.experimental.compilation_cache import compilation_cache as _cc
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    # The flag alone is not enough: compilation_cache.is_cache_used()
-    # latches its answer on the FIRST jitted computation in the process
-    # (jax 0.4.37 `_cache_checked`), so if anything jax ran before
-    # graphcheck in this process with the cache on, compiles here still
-    # read warm entries and report cache-loaded memory estimates.
-    # reset_cache() drops the latch so the disable takes effect; a second
-    # reset in the finally re-latches with the restored flag.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — private seam, best-effort
-        _cc = None
+    # The flag alone is not enough: whether the cache is used is latched
+    # on the FIRST jitted computation in the process, so if anything jax
+    # ran before graphcheck in this process with the cache on, compiles
+    # here still read warm entries and report cache-loaded memory
+    # estimates. reset_cache() drops the latch so the disable takes
+    # effect; a second reset in the finally re-latches with the restored
+    # flag.
+    _cc.reset_cache()
     try:
         with warnings.catch_warnings(record=True) as wlog:
             warnings.simplefilter("always")
@@ -131,11 +128,7 @@ def lower_graph(spec: GraphSpec) -> LoweredGraph:
                 error = f"{type(e).__name__}: {e}"
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
-        if _cc is not None:
-            try:
-                _cc.reset_cache()
-            except Exception:  # noqa: BLE001 — private seam, best-effort
-                pass
+        _cc.reset_cache()
     for w in wlog:
         msg = str(w.message)
         if _DONATION_REJECT.search(msg):
