@@ -117,12 +117,22 @@ class Request:
 # ---------------- pure model steps ----------------
 
 
-def _qkv(x, lp, c: ModelConfig):
+def _qkv(x, lp, c: ModelConfig, fence: bool = False):
+    """x [b, s, d] -> q [b, s, h, hd], k, v [b, s, hkv, hd]; each weight
+    is read by its matmul where it lies in `params["layers"]`.
+
+    `fence` (the paged decode / verify programs) keeps the head split
+    out of the projections. At a handful of tokens XLA otherwise folds
+    the reshape and the transposes after it into each projection as a
+    convolution over a heads axis, which wants the weight transposed —
+    and re-lays-out wq, wk, wv of every layer on every step (1.09 of
+    qwen2_7b's 20.2 ms decode step, chip trace, PR 26)."""
     b, s, _ = x.shape
     h, hkv, hd = c.n_heads, c.n_kv_heads, c.head_dim
-    q = jnp.einsum("bsd,dq->bsq", x, lp["wq"]).reshape(b, s, h, hd)
-    k = jnp.einsum("bsd,dk->bsk", x, lp["wk"]).reshape(b, s, hkv, hd)
-    v = jnp.einsum("bsd,dk->bsk", x, lp["wv"]).reshape(b, s, hkv, hd)
+    hold = jax.lax.optimization_barrier if fence else (lambda a: a)
+    q = hold(jnp.einsum("bsd,dq->bsq", x, lp["wq"])).reshape(b, s, h, hd)
+    k = hold(jnp.einsum("bsd,dk->bsk", x, lp["wk"])).reshape(b, s, hkv, hd)
+    v = hold(jnp.einsum("bsd,dk->bsk", x, lp["wv"])).reshape(b, s, hkv, hd)
     return q, k, v
 
 
@@ -367,21 +377,10 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     w_off = lengths % page
     hkv_idx = jnp.arange(c.n_kv_heads)[:, None]
 
-    h_dim, kv_dim = c.n_heads * c.head_dim, c.n_kv_heads * c.head_dim
     for li in range(c.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
         normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        # Fused QKV: one [B, d] x [d, (h+2hkv)*hd] matmul instead of
-        # three — the weight concat is loop-invariant, so XLA hoists it
-        # out of the decode window's scan; at B=32 the per-matmul fixed
-        # cost dominates these tiny GEMMs.
-        wqkv = jnp.concatenate([lp["wq"], lp["wk"], lp["wv"]], axis=1)
-        qkv = jnp.einsum("bsd,dq->bsq", normed, wqkv)
-        q = qkv[..., :h_dim].reshape(B, 1, c.n_heads, c.head_dim)
-        k = qkv[..., h_dim:h_dim + kv_dim].reshape(
-            B, 1, c.n_kv_heads, c.head_dim)
-        v = qkv[..., h_dim + kv_dim:].reshape(
-            B, 1, c.n_kv_heads, c.head_dim)
+        q, k, v = _qkv(normed, lp, c, fence=True)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
         # token KV -> (page, offset) per slot; [B,1,hkv,hd] -> [hkv,B,hd]
@@ -397,16 +396,7 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
             q[:, 0], pool_k[li], pool_v[li], lengths + 1, page_tables)
         attn = attn.reshape(B, 1, c.n_heads * c.head_dim).astype(x.dtype)
         h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        if c.moe_experts:
-            x = _mlp_block(h, lp, c)
-        else:
-            # Fused gate+up (same loop-invariant-concat rationale).
-            normed2 = rmsnorm(h, lp["mlp_norm"], c.norm_eps)
-            wgu = jnp.concatenate([lp["wg"], lp["wu"]], axis=1)
-            gu = jnp.einsum("bsd,df->bsf", normed2, wgu)
-            f = gu.shape[-1] // 2
-            act = jax.nn.silu(gu[..., :f]) * gu[..., f:]
-            x = h + jnp.einsum("bsf,fd->bsd", act, lp["wd"])
+        x = _mlp_block(h, lp, c)
 
     x = rmsnorm(x, params["final_norm"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
@@ -435,17 +425,10 @@ def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
     positions = lengths[:, None] + jnp.arange(S)[None]     # [B, S]
     sin, cos = rope(positions, c.head_dim, c.rope_theta)
 
-    h_dim, kv_dim = c.n_heads * c.head_dim, c.n_kv_heads * c.head_dim
     for li in range(c.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
         normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        wqkv = jnp.concatenate([lp["wq"], lp["wk"], lp["wv"]], axis=1)
-        qkv = jnp.einsum("bsd,dq->bsq", normed, wqkv)
-        q = qkv[..., :h_dim].reshape(B, S, c.n_heads, c.head_dim)
-        k = qkv[..., h_dim:h_dim + kv_dim].reshape(
-            B, S, c.n_kv_heads, c.head_dim)
-        v = qkv[..., h_dim + kv_dim:].reshape(
-            B, S, c.n_kv_heads, c.head_dim)
+        q, k, v = _qkv(normed, lp, c, fence=True)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
         # Insert is FUSED into the attention kernel: the new tokens'
@@ -457,15 +440,7 @@ def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
             q, pool_k, pool_v, k, v, lengths + 1, page_tables, li)
         attn = attn.reshape(B, S, c.n_heads * c.head_dim).astype(x.dtype)
         h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        if c.moe_experts:
-            x = _mlp_block(h, lp, c)
-        else:
-            normed2 = rmsnorm(h, lp["mlp_norm"], c.norm_eps)
-            wgu = jnp.concatenate([lp["wg"], lp["wu"]], axis=1)
-            gu = jnp.einsum("bsd,df->bsf", normed2, wgu)
-            f = gu.shape[-1] // 2
-            act = jax.nn.silu(gu[..., :f]) * gu[..., f:]
-            x = h + jnp.einsum("bsf,fd->bsd", act, lp["wd"])
+        x = _mlp_block(h, lp, c)
 
     x = rmsnorm(x, params["final_norm"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])

@@ -5,14 +5,17 @@ Parity: reference llm tests (`python/ray/llm/tests/`) — engine behavior,
 router contract, multiplexing."""
 
 import json
+from functools import partial
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_tpu.llm import EngineConfig, InferenceEngine, LLMConfig
-from ray_tpu.llm.engine import sample
+from ray_tpu.llm.engine import (decode_paged, prefill_batch, sample,
+                                verify_paged)
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models import ModelConfig, forward, init_params
 
@@ -707,3 +710,109 @@ def test_decode_steady_state_no_recompiles(tiny_params):
         eng.step()
     assert diagnostics.jit_misses() == base, \
         "steady-state decode recompiled"
+
+
+# ---- the paged decode / verify programs, as pure functions ----
+
+_PAGED_CONFIGS = {
+    "dense": TINY,
+    "moe": ModelConfig(vocab=200, d_model=64, n_layers=2, n_heads=4,
+                       n_kv_heads=2, d_ff=96, moe_experts=4, moe_top_k=2,
+                       dtype="float32"),
+}
+_PAGE, _SLOTS, _TABLE = 16, 2, 2
+
+
+def _paged_args(c):
+    """(params, pool_k, pool_v, page_tables) for `_SLOTS` slots of
+    `_TABLE` pages each; page 0 is the engine's scratch page."""
+    params = init_params(c, jax.random.PRNGKey(7))
+    pool = jnp.zeros((c.n_layers, c.n_kv_heads, 1 + _SLOTS * _TABLE,
+                      c.head_dim, _PAGE), jnp.float32)
+    tables = 1 + jnp.arange(_SLOTS * _TABLE, dtype=jnp.int32).reshape(
+        _SLOTS, _TABLE)
+    return params, pool, pool, tables
+
+
+@pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
+def test_paged_decode_and_verify_reproduce_prefill_logits(family):
+    """Token-by-token `decode_paged`, then one 3-token `verify_paged`,
+    against `prefill_batch` at the same positions: the three programs
+    spell the layer separately and must stay one function of the same
+    `params["layers"]` leaves."""
+    c = _PAGED_CONFIGS[family]
+    params, pool_k, pool_v, tables = _paged_args(c)
+    n_decode, n_verify = 5, 3
+    tokens = jax.random.randint(jax.random.PRNGKey(11),
+                                (_SLOTS, n_decode + n_verify), 1, c.vocab)
+    want, _, _ = jax.jit(partial(prefill_batch, config=c))(params, tokens)
+    decode = jax.jit(partial(decode_paged, config=c))
+    verify = jax.jit(partial(verify_paged, config=c))
+    active = jnp.ones((_SLOTS,), jnp.bool_)
+    for t in range(n_decode):
+        got, pool_k, pool_v = decode(
+            params, pool_k, pool_v, tokens[:, t],
+            jnp.full((_SLOTS,), t, jnp.int32), active, tables)
+        np.testing.assert_allclose(got, want[:, t], atol=1e-5, rtol=0)
+    got, _, _ = verify(params, pool_k, pool_v, tokens[:, n_decode:],
+                       jnp.full((_SLOTS,), n_decode, jnp.int32), active,
+                       tables)
+    np.testing.assert_allclose(got, want[:, n_decode:], atol=1e-5, rtol=0)
+
+
+# What may touch a layer's weight matrix besides the matmul that reads it
+# (`dot_general`): picking the layer out of the stack, a view of the leaf.
+_WEIGHT_VIEWS = {"slice", "squeeze", "dynamic_slice", "reshape"}
+
+
+def _weight_copies(jaxpr, tainted):
+    """Equations of `jaxpr` that build an array out of a weight matrix
+    rather than read it: [(primitive, output shapes)]. `tainted` is the
+    set of jaxpr vars that are (views of) `params["layers"]` matrices."""
+    found = []
+    for eqn in jaxpr.eqns:
+        hit = [i for i, v in enumerate(eqn.invars)
+               if isinstance(v, jex_core.Var) and v in tainted]
+        if not hit:
+            continue
+        name = eqn.primitive.name
+        inner = [p for p in eqn.params.values()
+                 if isinstance(p, (jex_core.Jaxpr, jex_core.ClosedJaxpr))]
+        if name in _WEIGHT_VIEWS:
+            tainted.update(eqn.outvars)
+        elif len(inner) == 1 and name in ("pjit", "jit", "closed_call",
+                                          "custom_jvp_call"):
+            sub = getattr(inner[0], "jaxpr", inner[0])
+            found += _weight_copies(
+                sub, tainted | {sub.invars[i] for i in hit})
+        elif name != "dot_general":
+            found.append((name, [v.aval.shape for v in eqn.outvars]))
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "verify"])
+@pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
+def test_paged_programs_read_weights_in_place(family, program):
+    """Neither program builds a weight-sized array: every matrix of
+    `params["layers"]` goes from its per-layer view straight into a
+    matmul. A `concatenate` of `wq|wk|wv` or `wg|wu` inside the jit is a
+    read and a write of those weights on EVERY token — the replica's pump
+    runs one `decode_paged` per token, so nothing hoists it (it was 10.8
+    of qwen2_7b's 30.2 ms decode step on the chip, ledger PR 23)."""
+    c = _PAGED_CONFIGS[family]
+    params, pool_k, pool_v, tables = _paged_args(c)
+    lengths = jnp.zeros((_SLOTS,), jnp.int32)
+    active = jnp.ones((_SLOTS,), jnp.bool_)
+    if program == "decode":
+        fn, tokens = decode_paged, jnp.ones((_SLOTS,), jnp.int32)
+    else:
+        fn, tokens = verify_paged, jnp.ones((_SLOTS, 3), jnp.int32)
+    args = (params, pool_k, pool_v, tokens, lengths, active, tables)
+    closed = jax.make_jaxpr(partial(fn, config=c))(*args)
+    leaves = jax.tree_util.tree_flatten_with_path(args)[0]
+    weights = {
+        var for (path, leaf), var in zip(leaves, closed.jaxpr.invars)
+        if jax.tree_util.keystr(path).startswith("[0]['layers']")
+        and leaf.ndim >= 3}                    # [L, in, out]: a matrix
+    assert len(weights) == (8 if c.moe_experts else 7)
+    assert _weight_copies(closed.jaxpr, weights) == []
