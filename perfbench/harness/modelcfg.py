@@ -1,8 +1,13 @@
 """From a configuration file (configs/<name>.json, the source's own key
-names) to the program's ModelConfig / EngineConfig. Nothing about a model
-is written in code: a new configuration is a new file."""
+names) to the program's ModelConfig / EngineConfig, by a table of fields
+that the file may extend, and the decoders' count of training operations.
+What is written here about a model is the default table and that count,
+both for the two decoders the benchmark began with; a configuration of
+another architecture brings its own as files (perfbench/README.md)."""
 
 from __future__ import annotations
+
+import dataclasses
 
 # Log-probabilities of two independent programs over the same bf16 weights
 # (the engine: dense fp32-softmax prefill, Pallas paged decode, bf16 KV
@@ -14,6 +19,29 @@ from __future__ import annotations
 LOGPROB_TOL = {"bfloat16": 0.1, "float32": 1e-2}
 
 
+# Field of ray_tpu.models.ModelConfig -> where its value comes from: a key
+# of the configuration's file (the source's own key name) with its cast,
+# and a default where the source may leave the key out. The table of the
+# two decoders the benchmark began with; a configuration's own
+# "model_fields" is laid over it (perfbench/README.md).
+DEFAULT_MODEL_FIELDS = {
+    "vocab": {"key": "vocab_size", "cast": "int"},
+    "d_model": {"key": "hidden_size", "cast": "int"},
+    "n_layers": {"key": "num_hidden_layers", "cast": "int"},
+    "n_heads": {"key": "num_attention_heads", "cast": "int"},
+    "n_kv_heads": {"key": "num_key_value_heads", "cast": "int"},
+    "d_ff": {"key": "intermediate_size", "cast": "int"},
+    "rope_theta": {"key": "rope_theta", "cast": "float"},
+    "norm_eps": {"key": "rms_norm_eps", "cast": "float"},
+    "moe_experts": {"key": "num_local_experts", "cast": "int", "default": 0},
+    "moe_top_k": {"key": "num_experts_per_tok", "cast": "int", "default": 2},
+    "dtype": {"key": "torch_dtype", "cast": "str"},
+    "tie_embeddings": {"key": "tie_word_embeddings", "cast": "bool"},
+    "remat": {"key": "remat", "cast": "bool", "default": False},
+}
+CASTS = {"int": int, "float": float, "str": str, "bool": bool}
+
+
 def _merged(cfg: dict, kind: str, rehearsal: bool) -> dict:
     out = dict(cfg)
     out.update(cfg.get("by_kind", {}).get(kind, {}))
@@ -22,36 +50,70 @@ def _merged(cfg: dict, kind: str, rehearsal: bool) -> dict:
     return out
 
 
+def _hashable(v):
+    """JSON lists as tuples, objects (a rope_scaling) as tuples of (key,
+    value) sorted by key: the program's configs are frozen dataclasses
+    used as static arguments. dict(value) gives an object back."""
+    if isinstance(v, list):
+        return tuple(_hashable(x) for x in v)
+    if isinstance(v, dict):
+        return tuple(sorted((k, _hashable(x)) for k, x in v.items()))
+    return v
+
+
 def model_config(cfg: dict, kind: str, rehearsal: bool = False):
+    """ModelConfig by the field table. An entry is {"key": <key of the
+    file>, "cast": int|float|str|bool, "default": <literal>} (cast and
+    default optional), {"value": <literal>}, or null, which drops a default
+    line so that the dataclass's own default holds. Keys are looked up
+    after by_kind and the rehearsal's sizes were laid over the file."""
     from ray_tpu.models import ModelConfig
     c = _merged(cfg, kind, rehearsal)
-    return ModelConfig(
-        vocab=int(c["vocab_size"]), d_model=int(c["hidden_size"]),
-        n_layers=int(c["num_hidden_layers"]),
-        n_heads=int(c["num_attention_heads"]),
-        n_kv_heads=int(c["num_key_value_heads"]),
-        d_ff=int(c["intermediate_size"]),
-        rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        moe_experts=int(c.get("num_local_experts", 0)),
-        moe_top_k=int(c.get("num_experts_per_tok", 2)),
-        dtype=str(c["torch_dtype"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        remat=bool(c.get("remat", False)))
+    file = cfg.get("_file", "the configuration")
+    known = {f.name for f in dataclasses.fields(ModelConfig)}
+    kw = {}
+    for field, how in {**DEFAULT_MODEL_FIELDS,
+                       **c.get("model_fields", {})}.items():
+        if field not in known:
+            raise ValueError(f"{file}: model_fields names `{field}`, which "
+                             f"ray_tpu.models.ModelConfig does not have")
+        if how is None:
+            continue
+        if (not isinstance(how, dict) or ("key" in how) == ("value" in how)
+                or ("cast" in how and how["cast"] not in CASTS)):
+            raise ValueError(f"{file}: model_fields[`{field}`] is {how!r}; "
+                             f"want a key (with a cast of {sorted(CASTS)}, "
+                             f"a default) or a value")
+        if "value" in how:
+            v = how["value"]
+        elif how["key"] in c:
+            v = c[how["key"]]
+        elif "default" in how:
+            v = how["default"]
+        else:
+            raise KeyError(f"{file}: ModelConfig.{field} is read from key "
+                           f"`{how['key']}`, which the file does not have")
+        kw[field] = CASTS[how["cast"]](v) if "cast" in how else _hashable(v)
+    return ModelConfig(**kw)
 
 
 def engine_config(cfg: dict, cellp: dict, rehearsal: bool = False):
+    """EngineConfig from every key of the file's `engine` object, then the
+    rehearsal's, then the cell's own shape, which wins. `reckoning` is
+    prose; a key EngineConfig does not have is an error."""
     from ray_tpu.llm import EngineConfig
-    e = dict(cfg["engine"])
+    e = {"kv_layout": "paged", **cfg["engine"]}
     if rehearsal:
         e.update(cfg["rehearsal"].get("engine", {}))
-    e.update(cellp.get("engine", {}))   # the cell's own shape wins
-    return EngineConfig(
-        max_slots=int(e["max_slots"]), max_len=int(e["max_len"]),
-        prompt_buckets=tuple(e["prompt_buckets"]),
-        page_size=int(e["page_size"]),
-        prefix_cache=bool(e["prefix_cache"]),
-        eos_token=int(e["eos_token"]), kv_layout="paged")
+    e.update(cellp.get("engine", {}))
+    e.pop("reckoning", None)
+    known = {f.name for f in dataclasses.fields(EngineConfig)}
+    unknown = sorted(set(e) - known)
+    if unknown:
+        raise ValueError(
+            f"{cfg.get('_file', 'the configuration')}: `engine` has "
+            f"{unknown}, which ray_tpu.llm.EngineConfig does not have")
+    return EngineConfig(**{k: _hashable(v) for k, v in e.items()})
 
 
 def matmul_params(m) -> int:

@@ -13,12 +13,11 @@ the metric out of the line.
 
 from __future__ import annotations
 
-import importlib
 import json
 import math
 import os
 
-from perfbench.harness import tracered
+from perfbench.harness import cells, tracered
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 METRICS_DIR = os.path.join(os.path.dirname(_HERE), "metrics")
@@ -71,14 +70,16 @@ def _collective_exposed(spec, rec):
 def _roofline(spec, rec):
     """Least time the chip could take for what the kernel's events did in
     the traced window (operations and bytes from shapes, by the cost
-    function kernels/<kernel>.py) over the time they took."""
+    function kernels/<kernel>.py of the benchmark root the cell was loaded
+    from) over the time they took."""
     t = rec.trace
     if not t:
         return None
     secs, n = tracered.seconds_matching(t, spec["op_pattern"])
     if secs <= 0 or n == 0:
         return None
-    mod = importlib.import_module("perfbench.kernels." + spec["kernel"])
+    mod = cells.load_module(os.path.join(spec["kernels_dir"],
+                                         spec["kernel"] + ".py"))
     cost = mod.cost({**rec.context, "op_count": t["op_count"],
                      "n_events": n, "op_pattern": spec["op_pattern"]})
     if cost is None:
@@ -103,7 +104,10 @@ def load_metric(name: str, directory: str = METRICS_DIR) -> dict:
 
 
 def read_metric(name: str, rec, directory: str = METRICS_DIR):
-    spec = load_metric(name, directory)
+    # a kernel's cost function lies beside the metrics, under the same root
+    spec = {"kernels_dir": os.path.join(os.path.dirname(directory),
+                                        "kernels"),
+            **load_metric(name, directory)}
     out = REDUCERS[spec["reducer"]](spec, rec)
     if out is None or (isinstance(out, float) and not math.isfinite(out)):
         return None

@@ -251,15 +251,15 @@ def run_waves(rep: Replica, waves: list, seed: int, vocab: int) -> int:
 # ------------------------------------------------------------ correctness
 
 
-def reference_diffs(rep: Replica, model_cfg, seed: int, index: int = 10**6,
-                    n_prompt: int = 200, n_new: int = 16,
+def reference_diffs(rep: Replica, reference, model_cfg, seed: int,
+                    index: int = 10**6, n_prompt: int = 200, n_new: int = 16,
                     fault=None) -> dict:
     """One seeded sequence: prefill, then `n_new` greedy tokens through the
     paged cache; |log-probability - the plain reference's| for every
     generated token, with the reference's router margin at its position.
+    `reference` is the configuration's own (cells.load_reference).
     `fault(model_cfg, ids) -> (model_cfg, ids)` changes what the REFERENCE
     is given (tools/checkdist.py: what a wrong program would read)."""
-    from perfbench.reference import decoder
     ids = traffic_mod.prompt_ids(seed, index, n_prompt, model_cfg.vocab)
     req = traffic_mod.Req(-1, 0.0, n_prompt, n_new, False)
     done = threading.Event()
@@ -272,8 +272,8 @@ def reference_diffs(rep: Replica, model_cfg, seed: int, index: int = 10**6,
     gen = list(r.generated)
     got = [float(x) for x in r.token_logprobs]
     ref_cfg, ref_ids = fault(model_cfg, ids) if fault else (model_cfg, ids)
-    want, margin = decoder.logprobs_of(rep.engine.params, ref_cfg, ref_ids,
-                                       gen)
+    want, margin = reference.logprobs_of(rep.engine.params, ref_cfg, ref_ids,
+                                         gen)
     return {"diffs": [abs(a - b) for a, b in zip(got, want)],
             "margins": [m if math.isfinite(m) else None for m in margin],
             "finite": all(math.isfinite(x) for x in got + want),
@@ -318,11 +318,11 @@ def judge(d: dict, tol: float, rule: dict | None = None) -> dict:
     return {"ok": bool(ok), **out}
 
 
-def check_against_reference(rep: Replica, model_cfg, seed: int, tol: float,
-                            rule: dict | None = None) -> dict:
+def check_against_reference(rep: Replica, reference, model_cfg, seed: int,
+                            tol: float, rule: dict | None = None) -> dict:
     n_new = int(rule["new_tokens"]) if rule else 16
-    return judge(reference_diffs(rep, model_cfg, seed, n_new=n_new), tol,
-                 rule)
+    return judge(reference_diffs(rep, reference, model_cfg, seed,
+                                 n_new=n_new), tol, rule)
 
 
 # ------------------------------------------------------------------ loops
@@ -552,23 +552,26 @@ def run(cell: dict, cfg: dict, traffic: dict, cellp: dict, args, rec,
         proc_start_wall: float, trace_dir: str | None) -> dict:
     import jax
 
-    from perfbench.harness import modelcfg
+    from perfbench.harness import cells, modelcfg
     model_cfg = modelcfg.model_config(cfg, traffic["kind"], args.rehearsal)
     engine_cfg = modelcfg.engine_config(cfg, cellp, args.rehearsal)
+    reference = cells.load_reference(args.benchmark_root, cfg)
     jseed = int(args.seed) % (2**31 - 5)
     marks = [("start_to_replica", time.time())]   # where set-up goes
     rep = Replica(model_cfg, engine_cfg, jseed, rec)
     jax.block_until_ready(rep.engine.params)
     marks.append(("weights", time.time()))
-    rec.context.update(model=model_cfg, engine=engine_cfg)
+    # what a kernel's cost function may read: the program's two configs,
+    # and the configuration's file (the chip's share it states)
+    rec.context.update(model=model_cfg, engine=engine_cfg, cfg=cfg)
     try:
         waves = warm_waves(traffic, engine_cfg,
                            int(cellp.get("warm_admit_together", 4)))
         n_warm = run_waves(rep, waves, jseed, model_cfg.vocab)
         marks.append(("warm_up", time.time()))
         tol = modelcfg.LOGPROB_TOL[model_cfg.dtype]
-        check = check_against_reference(rep, model_cfg, jseed, tol,
-                                        cfg.get("check"))
+        check = check_against_reference(rep, reference, model_cfg, jseed,
+                                        tol, cfg.get("check"))
         marks.append(("check", time.time()))
         if traffic["kind"] == "open_loop":
             out = run_open_loop(rep, rec, traffic, cellp, args.seed,

@@ -29,8 +29,7 @@ def worker_loop(config: dict):
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from perfbench.harness import modelcfg, tracered
-    from perfbench.reference import decoder
+    from perfbench.harness import cells, modelcfg, tracered
     from ray_tpu import diagnostics
     from ray_tpu.models.transformer import (init_params, loss_fn,
                                             param_logical_axes)
@@ -41,6 +40,8 @@ def worker_loop(config: dict):
     traffic, seconds = config["traffic"], float(config["seconds"])
     cfg = modelcfg.model_config(config["cfg"], "train_job",
                                 config["rehearsal"])
+    reference = cells.load_reference(config["root"], config["cfg"])
+    flops_per_token = cells.load_flops(config["root"], config["cfg"])
     dev = jax.devices()[0]
     n = jax.device_count()
     if n < config["chips"] or (dev.platform != "tpu"
@@ -70,7 +71,7 @@ def worker_loop(config: dict):
         for i in range(n_batches)]
     # The reference's loss on step 0's parameters and batch, before the
     # step donates them.
-    ref_loss = decoder.mean_loss(state.params, cfg, batches[0]["tokens"])
+    ref_loss = reference.mean_loss(state.params, cfg, batches[0]["tokens"])
     step = compile_for(state, batches[0]).lower(state, batches[0]).compile()
     losses = []
     for i in range(int(traffic["warmup_steps"])):
@@ -141,7 +142,7 @@ def worker_loop(config: dict):
         "trace_steps": trace_steps if traced else 0,
         "compiles_in_window": (diagnostics.jit_misses()
                                + diagnostics.jit_traces() - misses0),
-        "flops_per_token": modelcfg.train_flops_per_token(cfg, seq),
+        "flops_per_token": flops_per_token(cfg, seq),
         "n_params": sum(x.size for x in jax.tree.leaves(state.params)),
     })
 
@@ -179,7 +180,8 @@ def run(cell: dict, cfg: dict, traffic: dict, cellp: dict, args, rec,
         result = JaxTrainer(
             worker_loop,
             train_loop_config={
-                "cfg": cfg, "traffic": traffic, "chips": cell["chips"],
+                "cfg": cfg, "root": os.path.abspath(args.benchmark_root),
+                "traffic": traffic, "chips": cell["chips"],
                 "seed": args.seed, "seconds": args.seconds,
                 "rehearsal": args.rehearsal, "trace_dir": trace_dir,
                 "debug_dir": args.debug_dir},
@@ -203,8 +205,8 @@ def run(cell: dict, cfg: dict, traffic: dict, cellp: dict, args, rec,
         rec.values["hbm_peak_gib"] = m["memory_peak_bytes"] / 2**30
     rec.trace = m["trace"]
     model_cfg = modelcfg.model_config(cfg, "train_job", args.rehearsal)
-    rec.context.update(model=model_cfg, traffic=traffic, chips=chips,
-                       seq=m["seq"], batch=m["batch"],
+    rec.context.update(model=model_cfg, cfg=cfg, traffic=traffic,
+                       chips=chips, seq=m["seq"], batch=m["batch"],
                        trace_steps=m["trace_steps"])
     if m["device"]["platform"] == "tpu":
         rec.context["peaks"] = peaks_for(m["device"]["kind"])

@@ -77,13 +77,7 @@ def test_train_cell_rehearses_on_four_virtual_devices():
 def test_a_cell_is_added_with_files_and_one_entry(tmp_path):
     """A throw-away cell, traffic mix and per-layer metric: new files and
     new entries only; nothing that exists is edited."""
-    root = str(tmp_path)
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    for d in ("configs", "traffic", "cells", "metrics"):
-        shutil.copytree(os.path.join(ROOT, "perfbench", d),
-                        os.path.join(root, "perfbench", d))
-    before = {p: open(p).read() for p in _files(root)}
-    pb = os.path.join(root, "perfbench")
+    root, pb, before = _copy_of_the_benchmark(tmp_path)
     with open(os.path.join(pb, "traffic", "serve-tiny-burst.json"), "w") as f:
         json.dump({"kind": "open_loop", "max_total_tokens": 512,
                    "prompt_tokens": {"dist": "uniform_grid", "min": 10,
@@ -117,9 +111,81 @@ def test_a_cell_is_added_with_files_and_one_entry(tmp_path):
     out = json.loads(last)
     assert out["correct"] and out["attempted"] == 10
     assert "itl_p90_ms" in out["rehearsal_only_not_device_numbers"]
-    after = {p: open(p).read() for p in before
-             if not p.endswith("BENCHMARK.json")}
-    assert all(before[p] == s for p, s in after.items())
+    _nothing_that_existed_was_edited(before)
+
+
+@pytest.mark.parametrize("shift,correct", [(0.0, True), (1.0, False)])
+def test_a_configuration_is_added_with_files_and_entries(tmp_path, shift,
+                                                         correct):
+    """A throw-away configuration of its own field table and its own
+    reference: new files and new entries only. Its table sets a ModelConfig
+    field outside the default thirteen, which its reference insists on
+    seeing; with its log-probabilities shifted by 1.0 the cell is not
+    correct, so the named file decided, not reference/decoder.py."""
+    root, pb, before = _copy_of_the_benchmark(tmp_path)
+    with open(os.path.join(pb, "configs", "qwen2_7b.json")) as f:
+        cfg = json.load(f)
+    cfg.update(model_fields={"attn_impl": {"value": "reference"},
+                             "unroll_layers": {"key": "unroll"},
+                             "remat": None},
+               unroll=True, reference="perfbench/reference/throwaway.py")
+    with open(os.path.join(pb, "configs", "throwaway.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(pb, "reference", "decoder.py")) as f:
+        plain = f.read()
+    with open(os.path.join(pb, "reference", "throwaway.py"), "w") as f:
+        f.write(plain + f"""
+
+_plain_logprobs_of = logprobs_of
+
+
+def logprobs_of(params, c, prompt, generated):
+    assert c.attn_impl == "reference" and c.unroll_layers is True, c
+    logp, margin = _plain_logprobs_of(params, c, prompt, generated)
+    return [x - {shift} for x in logp], margin
+""")
+    shutil.copy(os.path.join(pb, "cells", "qwen2_7b-serve-chat.json"),
+                os.path.join(pb, "cells", "throwaway-serve-chat.json"))
+    bench = _bench()
+    bench["configs"].append({
+        "name": "throwaway", "source": cfg["source"],
+        "file": "perfbench/configs/throwaway.json",
+        "reduced": ["num_hidden_layers"], "why": "test"})
+    bench["workloads"].append({
+        "name": "throwaway-serve-chat", "config": "throwaway",
+        "traffic": "serve-chat", "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:
+        if "workloads" in m and m["name"].startswith("itl"):
+            m["workloads"].append("throwaway-serve-chat")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    rc, last, err = _run("--workload", "throwaway-serve-chat", "--seed", "11",
+                         "--seconds", "2", "--trace", "0", "--rehearsal",
+                         root=root)
+    assert rc == 0, err[-2000:]
+    out = json.loads(last)
+    assert out["correct"] is correct and out["failed"] == 0
+    assert abs(out["check"]["max_abs_diff"] - shift) < 1e-3
+    _nothing_that_existed_was_edited(before)
+
+
+def _copy_of_the_benchmark(tmp_path):
+    """BENCHMARK.json and the directories of data files and of modules found
+    by name, copied: (root, its perfbench/, every file's text)."""
+    root = str(tmp_path)
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    for d in ("configs", "traffic", "cells", "metrics", "reference",
+              "kernels"):
+        shutil.copytree(os.path.join(ROOT, "perfbench", d),
+                        os.path.join(root, "perfbench", d),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    return (root, os.path.join(root, "perfbench"),
+            {p: open(p).read() for p in _files(root)})
+
+
+def _nothing_that_existed_was_edited(before):
+    assert all(open(p).read() == s for p, s in before.items()
+               if not p.endswith("BENCHMARK.json"))
 
 
 def _files(root):

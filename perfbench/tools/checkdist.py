@@ -76,6 +76,7 @@ def main() -> int:
         return 3
     model_cfg = modelcfg.model_config(cfg, traffic["kind"], args.rehearsal)
     engine_cfg = modelcfg.engine_config(cfg, cellp, args.rehearsal)
+    reference = cells.load_reference(args.benchmark_root, cfg)
     jseed = args.seed % (2**31 - 5)
     tol = modelcfg.LOGPROB_TOL[model_cfg.dtype]
     rep = serve_cell.Replica(model_cfg, engine_cfg, jseed,
@@ -84,9 +85,9 @@ def main() -> int:
         for fault in args.fault.split(","):
             for k in range(args.sequences):
                 d = serve_cell.reference_diffs(
-                    rep, model_cfg, jseed, 10**6 + k, args.prompt_tokens,
-                    args.new_tokens, None if fault == "none"
-                    else FAULTS[fault])
+                    rep, reference, model_cfg, jseed, 10**6 + k,
+                    args.prompt_tokens, args.new_tokens,
+                    None if fault == "none" else FAULTS[fault])
                 verdict = serve_cell.judge(d, tol, cfg.get("check"))
                 print(json.dumps({"seed": args.seed, "sequence": k,
                                   "fault": fault, "verdict": verdict,
