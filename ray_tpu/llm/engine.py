@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import ModelConfig, init_params
+from ray_tpu.models import ModelConfig, init_params, model_module
 from ray_tpu.ops.layers import apply_rope, rmsnorm, rope
 
 # ---- shared compiled-step cache -------------------------------------
@@ -112,6 +112,12 @@ class Request:
     # into the prefix cache right before this request's admission, so the
     # suffix prefill only covers what the handoff does not.
     kv_handoff: tuple | None = None
+    # Tokens of `prompt` that came with the request; preemption appends
+    # the generated tokens the model has seen after them.
+    n_prompt: int = 0
+
+    def __post_init__(self):
+        self.n_prompt = len(self.prompt)
 
 
 # ---------------- pure model steps ----------------
@@ -707,6 +713,16 @@ def _resolve_params(model_config: ModelConfig, params, mesh, rules,
     return params
 
 
+def _refuse_latent(c: ModelConfig, what: str):
+    """What a latent-cache model does not run with: each is a program this
+    file spells for per-head K and V only (ROADMAP D1)."""
+    raise ValueError(
+        f"ModelConfig.attention={c.attention!r} keeps a latent KV cache "
+        f"(ModelConfig.kv_cache == \"latent\"), which does not run with "
+        f"{what}: only the paged single-device programs (prefill_batch, "
+        f"prefill_with_prefix_batch, insert, decode_paged) exist for it")
+
+
 def _prompt_bucket(e: EngineConfig, n: int) -> int:
     """The prefill compile bucket for an n-token prompt. Buckets above
     max_len are unusable: their prefill KV could not be spliced into the
@@ -736,6 +752,22 @@ class InferenceEngine:
         self.c = model_config
         self.e = engine_config or EngineConfig()
         self.mesh = mesh
+        # A latent-cache model (ModelConfig.kv_cache) brings its own four
+        # programs (models/deepseek_v2.py) over ONE pool [L, N, latent,
+        # page], held in `cache_k` (`cache_v` is None); page accounting,
+        # prefix hashing, chunked prefill and preemption below are shared.
+        self.latent = model_config.kv_cache == "latent"
+        if self.latent:
+            e = self.e
+            if e.kv_layout != "paged":
+                _refuse_latent(model_config,
+                               f"EngineConfig.kv_layout={e.kv_layout!r}")
+            if e.speculation is not None:
+                _refuse_latent(model_config,
+                               f"EngineConfig.speculation={e.speculation!r}")
+            if mesh is not None and mesh.devices.size > 1:
+                _refuse_latent(model_config, f"a mesh of {dict(mesh.shape)} "
+                                             f"(tensor parallelism)")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         c, e = self.c, self.e
@@ -762,10 +794,18 @@ class InferenceEngine:
             # head_dim BEFORE page so the Pallas decode kernel can DMA
             # per-page blocks [hkv, hd, page] whose trailing dims
             # (hd, 128) satisfy Mosaic's (8, 128) tiling.
-            kv_shape = (c.n_layers, c.n_kv_heads, self.num_pages,
-                        c.head_dim, page)
-            self.cache_k = jnp.zeros(kv_shape, c.jdtype)
-            self.cache_v = jnp.zeros(kv_shape, c.jdtype)
+            if self.latent:
+                model = model_module(c)
+                self.cache_k = jnp.zeros(
+                    model.pool_shape(c, self.num_pages, page), c.jdtype)
+                self.cache_v = None
+                self._moe_acc = model.stats_zero(c)   # device; moe_stats()
+                self._moe_total = np.zeros(self._moe_acc.shape, np.int64)
+            else:
+                kv_shape = (c.n_layers, c.n_kv_heads, self.num_pages,
+                            c.head_dim, page)
+                self.cache_k = jnp.zeros(kv_shape, c.jdtype)
+                self.cache_v = jnp.zeros(kv_shape, c.jdtype)
             # page bookkeeping (host side)
             self.free_pages: list[int] = list(range(1, self.num_pages))
             self.page_refs: dict[int, int] = {}
@@ -802,9 +842,16 @@ class InferenceEngine:
             self._guide_fp = None
             # Donate the pool/cache: without donation every step round-trips
             # the full KV through a fresh HBM allocation (~GBs/step).
-            self._insert_batch = _shared_jit(
-                ("insert_pages_batch",),
-                lambda: jax.jit(insert_pages_batch, donate_argnums=(0, 1)))
+            if self.latent:
+                self._insert_batch = _shared_jit(
+                    ("insert_latent_pages_batch",),
+                    lambda: jax.jit(model.insert_latent_pages_batch,
+                                    donate_argnums=(0,)))
+            else:
+                self._insert_batch = _shared_jit(
+                    ("insert_pages_batch",),
+                    lambda: jax.jit(insert_pages_batch,
+                                    donate_argnums=(0, 1)))
             self._prefill_batches: dict[tuple, object] = {}
         else:
             kv_shape = (c.n_layers, e.max_slots, e.max_len, c.n_kv_heads,
@@ -895,6 +942,8 @@ class InferenceEngine:
                 and not self.paged):
             raise ValueError("decode-state resume / KV handoff require "
                              "the paged KV layout")
+        if kv_handoff is not None and self.latent:
+            _refuse_latent(self.c, "a per-head KV handoff")
         if guide is not None:
             if guide.table.shape[1] != self.c.vocab:
                 raise ValueError(
@@ -1060,6 +1109,8 @@ class InferenceEngine:
         engine queue's kv_handoff field routes a handoff there)."""
         if not (self.paged and self.e.prefix_cache):
             return 0
+        if self.latent:
+            _refuse_latent(self.c, "a per-head KV handoff")
         page = self.e.page_size
         prompt = list(map(int, prompt_tokens))
         full = len(prompt) // page
@@ -1121,7 +1172,9 @@ class InferenceEngine:
         # Re-prefill everything the model has SEEN (prompt + all fed-back
         # tokens); the final sampled-but-never-fed token resumes decoding
         # exactly where it stopped, without re-sampling its position.
-        req.prompt = req.prompt + req.generated[:-1]
+        # (from the prompt as it came: a request preempted before holds
+        # its earlier generated tokens in `prompt` already)
+        req.prompt = req.prompt[:req.n_prompt] + req.generated[:-1]
         req.resume_token = req.generated[-1]
         self.queue.appendleft(req)
         self.preemptions += 1
@@ -1240,23 +1293,8 @@ class InferenceEngine:
                 plens[j] = p["hit"] * page
                 lens[j] = p["ns"]
                 tabs[j, :len(p["new_pages"])] = p["new_pages"]
-            key = (n_pad, bucket, pre_bucket)
-            fn = self._prefill_pre.get(key)
-            if fn is None:
-                fn = _shared_jit(
-                    ("prefill_with_prefix_batch", self.c),
-                    lambda: jax.jit(partial(prefill_with_prefix_batch,
-                                            config=self.c)))
-                self._prefill_pre[key] = fn
-            logits, ks, vs = fn(
-                self.params, jnp.asarray(toks), self.cache_k,
-                self.cache_v, jnp.asarray(pres), jnp.asarray(plens))
-            self.cache_k, self.cache_v = self._insert_batch(
-                self.cache_k, self.cache_v, ks, vs, jnp.asarray(tabs),
-                jnp.asarray(lens))
-            for j, p in enumerate(group):
-                if p["slot"] is not None:
-                    logits_of[p["slot"]] = logits[j, p["ns"] - 1]
+            self._prefill_group(group, logits_of, toks, lens, tabs, pres,
+                                plens)
         for bucket, group in nohit_by_bucket.items():
             n_real = len(group)
             # Pad the batch to a power of two: bounded compile variants.
@@ -1271,20 +1309,7 @@ class InferenceEngine:
                 toks[j, :p["ns"]] = p["suffix"]
                 lens[j] = p["ns"]
                 tabs[j, :len(p["new_pages"])] = p["new_pages"]
-            key = (n_pad, bucket)
-            fn = self._prefill_batches.get(key)
-            if fn is None:
-                fn = _shared_jit(
-                    ("prefill_batch", self.c),
-                    lambda: jax.jit(partial(prefill_batch, config=self.c)))
-                self._prefill_batches[key] = fn
-            logits, ks, vs = fn(self.params, jnp.asarray(toks))
-            self.cache_k, self.cache_v = self._insert_batch(
-                self.cache_k, self.cache_v, ks, vs, jnp.asarray(tabs),
-                jnp.asarray(lens))
-            for j, p in enumerate(group):
-                if p["slot"] is not None:
-                    logits_of[p["slot"]] = logits[j, p["ns"] - 1]
+            self._prefill_group(group, logits_of, toks, lens, tabs)
 
         # Phase 3 — host-side registration.
         for p in planned:
@@ -1359,6 +1384,44 @@ class InferenceEngine:
                 self._maybe_finish(slot, first)
         return admitted
 
+    def _prefill_group(self, group: list, logits_of: dict, toks, lens, tabs,
+                       pres=None, plens=None):
+        """ONE prefill dispatch and ONE page-insert dispatch for a group of
+        planned admissions (over cached prefix pages `pres` of `plens`
+        tokens where the group hit the prefix cache); the last-token
+        logits row of every request that takes a slot goes into
+        `logits_of`. A latent-cache model's programs return that row alone
+        (the head runs at the sampled position only)."""
+        hit = pres is not None
+        name = "prefill_with_prefix_batch" if hit else "prefill_batch"
+        cache = self._prefill_pre if hit else self._prefill_batches
+        key = toks.shape + ((pres.shape[1],) if hit else ())
+        fn = cache.get(key)
+        if fn is None:
+            own = prefill_with_prefix_batch if hit else prefill_batch
+            program = (getattr(model_module(self.c), name) if self.latent
+                       else own)
+            fn = cache[key] = _shared_jit(
+                (name, self.c),
+                lambda: jax.jit(partial(program, config=self.c)))
+        toks, lens, tabs = (jnp.asarray(a) for a in (toks, lens, tabs))
+        if self.latent:
+            prefix = ((self.cache_k, jnp.asarray(pres), jnp.asarray(plens))
+                      if hit else ())
+            last, lat, self._moe_acc = fn(self.params, toks, lens, *prefix,
+                                          self._moe_acc)
+            self.cache_k = self._insert_batch(self.cache_k, lat, tabs, lens)
+        else:
+            prefix = ((self.cache_k, self.cache_v, jnp.asarray(pres),
+                       jnp.asarray(plens)) if hit else ())
+            logits, ks, vs = fn(self.params, toks, *prefix)
+            self.cache_k, self.cache_v = self._insert_batch(
+                self.cache_k, self.cache_v, ks, vs, tabs, lens)
+        for j, p in enumerate(group):
+            if p["slot"] is not None:
+                logits_of[p["slot"]] = (last[j] if self.latent
+                                        else logits[j, p["ns"] - 1])
+
     def _sample_first(self, req: Request, logits, last_idx: int) -> int:
         self._key, sub = jax.random.split(self._key)
         if req.top_k == 0 and req.top_p >= 1.0:
@@ -1385,6 +1448,25 @@ class InferenceEngine:
             "preemptions": self.preemptions,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
+        }
+
+    def moe_stats(self) -> dict:
+        """What the expert layers of a latent-cache model routed since the
+        engine began: counted on the device inside the programs and
+        fetched only here (never a host fence in step())."""
+        if not self.latent:
+            return {}
+        N_STATS = model_module(self.c).N_STATS
+        fresh, self._moe_acc = self._moe_acc, jnp.zeros_like(self._moe_acc)
+        self._moe_total += np.asarray(fresh)
+        tokens, pairs, none_held, calls = map(int, self._moe_total[:N_STATS])
+        load = self._moe_total[N_STATS:]
+        return {
+            "expert_layer_calls": calls, "routed_tokens": tokens,
+            "held_pairs": pairs, "tokens_without_held_expert": none_held,
+            "held_expert_load": load.tolist(),
+            "load_max_over_mean": (float(load.max() / load.mean())
+                                   if pairs else 0.0),
         }
 
     def _admit_dense(self) -> dict[int, int]:
@@ -1540,15 +1622,22 @@ class InferenceEngine:
         p_bucket = tables.shape[1]
         fn = self._decode_paged.get(p_bucket)
         if fn is None:
+            program = (model_module(self.c).decode_paged if self.latent
+                       else decode_paged)
             fn = _shared_jit(
                 ("decode_paged", self.c),
-                lambda: jax.jit(partial(decode_paged, config=self.c),
-                                donate_argnums=(1, 2)))
+                lambda: jax.jit(partial(program, config=self.c),
+                                donate_argnums=(1,) if self.latent
+                                else (1, 2)))
             self._decode_paged[p_bucket] = fn
-        logits, self.cache_k, self.cache_v = fn(
-            self.params, self.cache_k, self.cache_v,
-            jnp.asarray(self.last_tokens), jnp.asarray(self.lengths),
-            jnp.asarray(self.active), jnp.asarray(tables))
+        state = (jnp.asarray(self.last_tokens), jnp.asarray(self.lengths),
+                 jnp.asarray(self.active), jnp.asarray(tables))
+        if self.latent:
+            logits, self.cache_k, self._moe_acc = fn(
+                self.params, self.cache_k, *state, self._moe_acc)
+        else:
+            logits, self.cache_k, self.cache_v = fn(
+                self.params, self.cache_k, self.cache_v, *state)
         return logits
 
     def _build_tables(self) -> np.ndarray:
@@ -1868,6 +1957,9 @@ class InferenceEngine:
         only; falls back to single-step elsewhere)."""
         if not self.paged:
             return self.step()
+        if self.latent:
+            _refuse_latent(self.c, "step_window() (decode_window); call "
+                                   "step()")
         emitted = self._admit()
         if self.active.any():
             upd = (self._run_window_spec() if self._spec_applicable()
@@ -1886,8 +1978,9 @@ class InferenceEngine:
         stream through)."""
         ids = [self.add_request(p, max_new_tokens, temperature)
                for p in prompts]
+        step = self.step if self.latent else self.step_window
         while self.has_work():
-            self.step_window()
+            step()
         out = []
         for rid in ids:
             req = self.finished.pop(rid)
@@ -1912,6 +2005,9 @@ class PrefillEngine:
         self.c = model_config
         self.e = engine_config or EngineConfig()
         self.mesh = mesh
+        if model_config.kv_cache == "latent":
+            _refuse_latent(model_config, "the prefill pool, which exports "
+                                         "per-head K and V")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         self._prefill = _shared_jit(

@@ -67,3 +67,41 @@ def qwen2_7b(**kw) -> ModelConfig:
     return _preset(kw, vocab=152064, d_model=3584, n_layers=28, n_heads=28,
                    n_kv_heads=4, d_ff=18944, rope_theta=1e6,
                    dtype="bfloat16", remat=True, tie_embeddings=False)
+
+
+def deepseek_v2(**kw) -> ModelConfig:
+    """DeepSeek-V2 (236B-A21B) at its published sizes: latent attention,
+    one dense layer, then 160 routed experts (6 a token from 3 of 8
+    groups) beside 2 shared experts; YaRN over 4096 positions. Every
+    expert held: a serving replica names its share through `moe_experts`,
+    `moe_router_experts` and `moe_held_group`."""
+    return _preset(
+        kw, vocab=102400, d_model=5120, n_layers=60, n_heads=128,
+        n_kv_heads=128, d_ff=12288, rope_theta=10000.0, norm_eps=1e-6,
+        dtype="bfloat16", tie_embeddings=False, attention="mla",
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling=(("beta_fast", 32), ("beta_slow", 1), ("factor", 40),
+                      ("mscale", 0.707), ("mscale_all_dim", 0.707),
+                      ("original_max_position_embeddings", 4096),
+                      ("type", "yarn")),
+        first_k_dense=1, moe_experts=160, moe_top_k=6, moe_d_ff=1536,
+        moe_shared_experts=2, moe_router_experts=160, moe_n_group=8,
+        moe_topk_group=3, moe_routed_scale=16.0, moe_norm_topk=False)
+
+
+def tiny_mla(**kw) -> ModelConfig:
+    """CPU-test scale of deepseek_v2's structure: 1 dense layer + 1 expert
+    layer, 8 routed experts in 4 groups (2 of them a token), 1 shared."""
+    return _preset(
+        kw, vocab=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
+        d_ff=128, tie_embeddings=False, attention="mla", q_lora_rank=16,
+        kv_lora_rank=8, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_scaling=(("beta_fast", 32), ("beta_slow", 1), ("factor", 40),
+                      ("mscale", 0.707), ("mscale_all_dim", 0.707),
+                      ("original_max_position_embeddings", 64),
+                      ("type", "yarn")),
+        first_k_dense=1, moe_experts=8, moe_top_k=3, moe_d_ff=32,
+        moe_shared_experts=1, moe_router_experts=8, moe_n_group=4,
+        moe_topk_group=2, moe_routed_scale=4.0, moe_norm_topk=False)

@@ -45,10 +45,41 @@ class ModelConfig:
     # a GPT-small train step; at billion-param scale the copies amortize
     # and scan keeps compiles fast). True/False forces it.
     unroll_layers: bool | None = None
+    # --- what models/deepseek_v2.py reads (attention == "mla") ---
+    # "gqa": this file. "mla": latent attention, a dense layer before the
+    # expert layers, shared experts, a group-limited router; the serving
+    # engine then keeps a latent page pool (`kv_cache`).
+    attention: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The source's `rope_scaling` object as a tuple of (key, value) pairs
+    # (a frozen dataclass stays hashable); None = plain rotary embedding.
+    rope_scaling: tuple | None = None
+    first_k_dense: int = 0          # leading layers with the dense d_ff MLP
+    moe_d_ff: int = 0               # width of one routed expert
+    moe_shared_experts: int = 0     # always-on experts, each moe_d_ff wide
+    # The router's published width; `moe_experts` of them are held here:
+    # experts [moe_held_group * moe_experts, (moe_held_group + 1) *
+    # moe_experts). 0 = every expert is held (moe_experts wide).
+    moe_router_experts: int = 0
+    moe_held_group: int = 0
+    moe_n_group: int = 1            # group-limited routing: groups,
+    moe_topk_group: int = 1         # and how many of them a token may use
+    moe_routed_scale: float = 1.0   # routed_scaling_factor
+    moe_norm_topk: bool = True      # renormalise the top-k weights
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def kv_cache(self) -> str:
+        """What the serving engine pages: "per_head" K and V, or the
+        "latent" (normed c_kv | rotated k_pe) of latent attention."""
+        return "latent" if self.attention == "mla" else "per_head"
 
     @property
     def jdtype(self):

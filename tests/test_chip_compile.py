@@ -111,6 +111,30 @@ def _paged(kind):
     return build
 
 
+def _latent(kind):
+    """DeepSeek-V2's widths: 128 heads over a 512 + 64 latent, a 192-wide
+    q.k and a 128-wide p.v; the longdoc cell's pool and chunk."""
+    from ray_tpu.ops import latent_attention as la
+
+    def build(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        if kind == "decode":
+            return (lambda q, pool, ln, tb: la.paged_latent_decode_attention(
+                q, pool, ln, tb, layer=3, rank=512, scale=0.1,
+                interpret=False)), (
+                sds((8, 128, 576)), sds((8, 704, 576, 128)),
+                sds((8,), jnp.int32), sds((8, 68), jnp.int32))
+        return (lambda q, k, v, pl: la.mla_prefill_attention(
+            q, k, v, pl, pre_t=4096, scale=0.1, interpret=False)), (
+            sds((1, 128, 4096, 192)), sds((1, 128, 8192, 192)),
+            sds((1, 128, 8192, 128)), sds((1,), jnp.int32))
+    return build
+
+
 CASES = {
     "flash_fwd_2x2048": _flash((2, 2048), grad=False),
     "flash_bwd_2x2048": _flash((2, 2048), grad=True),
@@ -119,6 +143,8 @@ CASES = {
     "paged_decode": _paged("decode"),
     "paged_verify": _paged("verify"),
     "paged_verify_insert": _paged("verify_insert"),
+    "latent_decode": _latent("decode"),
+    "mla_prefill_over_prefix": _latent("prefill"),
 }
 
 
