@@ -354,15 +354,24 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
                  page_tables, config: ModelConfig):
     """One token for every slot against the paged pool. page_tables
     [B, P] page ids in position order (0 = unused -> scratch page, whose
-    garbage the position mask hides). The new token's KV scatters into
+    garbage the position mask hides). The new token's KV is written at
     (write_page, lengths % page); compute scales with the bucketed P,
     not the model's max context. Pool layout [L, hkv, N, hd, page].
 
-    TPU-shaped (the two costs that matter on this hardware):
+    TPU-shaped (the three costs that matter on this hardware):
     - the layer loop is UNROLLED python, not lax.scan with the pools as
       scan xs/ys — scan materializes a fresh stacked pool output every
       step (a full-pool HBM copy per token: measured ~30ms/step for a
-      0.6GB pool), while unrolled donated in-place updates don't;
+      0.6GB pool);
+    - the pools are touched only where they lie: one
+      `dynamic_update_slice` column a slot for the write, and the kernel
+      reads the stacked pool at the layer it is handed. A scatter
+      `pool.at[li, heads, w_page, :, w_off].set(...)` indexes the pool's
+      minor (page) axis, so XLA moved the whole donated pool into an
+      hd-minor layout, scattered there, sliced each `pool[li]` out and
+      re-tiled it for the kernel, and moved the pool back: 6.8 ms of
+      qwen2_7b's 18.9 ms step on the v5e, 0.9 ms as it is now (PERF.md
+      section 5, PR 29);
     - attention runs the Pallas paged-decode kernel
       (ops/paged_attention.py), which DMAs exactly the pages each slot
       owns — XLA lowers the gather-then-attend formulation at ~10% of
@@ -381,7 +390,30 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     w_page = jnp.where(lengths // page >= P, 0, w_page)
     w_page = jnp.where(active, w_page, 0)  # inactive -> scratch page
     w_off = lengths % page
-    hkv_idx = jnp.arange(c.n_kv_heads)[:, None]
+
+    # per slot the (page, offset) scalars, taken once: every layer's K
+    # and V column of a slot lands at the same place
+    w_at = [(w_page[b], w_off[b]) for b in range(B)]
+    zero = jnp.zeros((), jnp.int32)
+
+    def write(pool, new, li):
+        # token KV [B,1,hkv,hd] -> per slot a column [1,hkv,1,hd,1] at
+        # (li, 0, w_page, 0, w_off). The columns are laid out once as the
+        # pool has them (hd down the sublanes, one slot a lane), so an
+        # update is a lane slice and not a re-laid-out copy of its own;
+        # every index is an int32 scalar in range by construction, so no
+        # wrap-around arithmetic is staged. Both keep the program small:
+        # qwen2_7b makes 384 updates a step, and what each drags along
+        # is paid at every trace, compile and cache look-up of warm-up.
+        cols = new.astype(pool.dtype).reshape(
+            B, c.n_kv_heads, c.head_dim).transpose(1, 2, 0).reshape(
+            1, c.n_kv_heads, 1, c.head_dim, B)
+        layer = jnp.full((), li, jnp.int32)
+        for b, (pg, off) in enumerate(w_at):
+            pool = jax.lax.dynamic_update_slice(
+                pool, jax.lax.slice_in_dim(cols, b, b + 1, axis=4),
+                (layer, zero, pg, zero, off), allow_negative_indices=False)
+        return pool
 
     for li in range(c.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
@@ -389,17 +421,11 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
         q, k, v = _qkv(normed, lp, c, fence=True)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
-        # token KV -> (page, offset) per slot; [B,1,hkv,hd] -> [hkv,B,hd]
-        # (advanced indices around the hd slice put the adv dims first:
-        # the update shape is [hkv, B, hd])
-        pool_k = pool_k.at[li, hkv_idx, w_page[None], :, w_off[None]].set(
-            k[:, 0].transpose(1, 0, 2).astype(pool_k.dtype))
-        pool_v = pool_v.at[li, hkv_idx, w_page[None], :, w_off[None]].set(
-            v[:, 0].transpose(1, 0, 2).astype(pool_v.dtype))
+        pool_k, pool_v = write(pool_k, k, li), write(pool_v, v, li)
         # attend INCLUSIVE of the just-written token: positions
         # < lengths+1 == positions <= lengths
         attn = paged_decode_attention(
-            q[:, 0], pool_k[li], pool_v[li], lengths + 1, page_tables)
+            q[:, 0], pool_k, pool_v, lengths + 1, page_tables, layer=li)
         attn = attn.reshape(B, 1, c.n_heads * c.head_dim).astype(x.dtype)
         h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
         x = _mlp_block(h, lp, c)

@@ -16,13 +16,22 @@ page DMA).
 
 Layouts:
   q            [B, n_heads, head_dim]
-  k_pages, v_pages [n_kv_heads, num_pages, head_dim, page_size]
+  pool_k, pool_v   [n_layers, n_kv_heads, num_pages, head_dim, page_size]
+      the engine's STACKED pools, with the `layer` to read: the kernels
+      index `pool.at[layer, :, page_id]` themselves. A caller that hands
+      over `pool[layer]` makes XLA slice 1/L of the pool out and re-tile
+      it for the call on every layer of every token (1.0 ms of slices in
+      qwen2_7b's 18.9 ms decode step on the v5e, beside the whole-pool
+      copies of the scatter that fed it; PERF.md section 5, PR 29).
       (head_dim BEFORE page: a page's DMA slice then has trailing dims
       (head_dim, page) = (64|128, 128), which Mosaic can tile — with page
       last-minor the 64-wide head_dim would land on the 128-lane axis and
       the per-page slice fails to lower)
   lengths      [B]  number of valid tokens (attend positions < lengths)
   page_tables  [B, P]  page ids in position order (entry 0 = scratch page)
+
+The `*_reference` functions and `paged_verify_attention` take ONE
+layer's pages [n_kv_heads, num_pages, head_dim, page_size].
 
 Returns [B, n_heads, head_dim].
 """
@@ -56,9 +65,10 @@ def _mosaic_tiles(page: int, hd: int) -> bool:
     return False
 
 
-def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
-                           interpret: bool | None = None):
-    """Flash decode over paged KV; see module docstring for layouts.
+def paged_decode_attention(q, pool_k, pool_v, lengths, page_tables, *,
+                           layer: int, interpret: bool | None = None):
+    """Flash decode over layer `layer` of the stacked paged pools; see
+    module docstring for layouts.
 
     interpret=None auto-selects: the Mosaic lowering needs a real TPU
     backend; everywhere else (CPU tests, multichip dryrun) the kernel
@@ -68,27 +78,37 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
     mode is also ~100x slower than XLA on CPU)."""
     import os
     if os.environ.get("RAY_TPU_PAGED_ATTN_IMPL") == "xla":
-        return _paged_decode_xla(q, k_pages, v_pages, lengths, page_tables)
+        return _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables,
+                                 layer=layer)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    page, hd = k_pages.shape[3], k_pages.shape[2]
+    page, hd = pool_k.shape[4], pool_k.shape[3]
     if not interpret and not _mosaic_tiles(page, hd):
-        return _paged_decode_xla(q, k_pages, v_pages, lengths, page_tables)
-    return _paged_decode_dma(q, k_pages, v_pages, lengths,
-                             page_tables, interpret=interpret)
+        return _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables,
+                                 layer=layer)
+    return _paged_decode_dma(q, pool_k, pool_v, lengths, page_tables, layer,
+                             interpret=interpret)
 
 
-@jax.jit
-def _paged_decode_xla(q, k_pages, v_pages, lengths, page_tables):
-    return paged_decode_attention_reference(q, k_pages, v_pages, lengths,
-                                            page_tables)
+@functools.partial(jax.jit, static_argnames=("layer",))
+def _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables, *,
+                      layer: int):
+    return paged_decode_attention_reference(
+        q, pool_k[layer], pool_v[layer], lengths, page_tables)
 
 
-def _dma_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
+def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
                 q_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, m_ref, l_ref, acc_ref, sem, *, page: int,
                 scale: float, pages_per_seq: int, n_q: int = 1):
-    """One grid step per slot; the slot's pages stream HBM->VMEM through
+    """k_hbm / v_hbm are the stacked pools [L, hkv, N, hd, page], read at
+    layer `layer_ref[0]`: a prefetched scalar, not a static, so that the
+    L calls of a decode program share ONE traced, lowered and compiled
+    kernel (a static layer made twelve of each on qwen2_7b: 0.5 s more
+    of tracing and lowering whenever a decode program is looked up, 7 s
+    of the chat cell's warm-up on the chip machine; PERF.md section 6).
+
+    One grid step per slot; the slot's pages stream HBM->VMEM through
     a two-deep manual DMA pipeline (page i+1 in flight while page i is in
     the flash update). One grid step per slot keeps grid overhead off the
     hot path — a BlockSpec-per-page variant spends more time stepping the
@@ -101,6 +121,7 @@ def _dma_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     lengths-1+j, so its causal limit is lengths+j. The flash accumulators
     simply widen by n_q rows."""
     b = pl.program_id(0)
+    layer = layer_ref[0]
     length = lengths_ref[b]
     npg = jnp.minimum(
         jax.lax.div(length + (n_q - 1) + page - 1, page), pages_per_seq)
@@ -108,15 +129,15 @@ def _dma_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     def start_copy(i, slot):
         pid = tables_ref[b, i]
         pltpu.make_async_copy(
-            k_hbm.at[:, pid], kbuf.at[slot], sem.at[slot, 0]).start()
+            k_hbm.at[layer, :, pid], kbuf.at[slot], sem.at[slot, 0]).start()
         pltpu.make_async_copy(
-            v_hbm.at[:, pid], vbuf.at[slot], sem.at[slot, 1]).start()
+            v_hbm.at[layer, :, pid], vbuf.at[slot], sem.at[slot, 1]).start()
 
     def wait_copy(slot):
         pltpu.make_async_copy(
-            k_hbm.at[:, 0], kbuf.at[slot], sem.at[slot, 0]).wait()
+            k_hbm.at[layer, :, 0], kbuf.at[slot], sem.at[slot, 0]).wait()
         pltpu.make_async_copy(
-            v_hbm.at[:, 0], vbuf.at[slot], sem.at[slot, 1]).wait()
+            v_hbm.at[layer, :, 0], vbuf.at[slot], sem.at[slot, 1]).wait()
 
     m_ref[...] = jnp.full_like(m_ref, _NEG)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -176,10 +197,10 @@ def _dma_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, *,
+def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, layer, *,
                       interpret: bool = False):
     B, h, hd = q.shape
-    hkv, N, _, page = k_pages.shape
+    _, hkv, N, _, page = k_pages.shape
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     P = page_tables.shape[1]
@@ -190,16 +211,16 @@ def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, hkv, g, hd),
-                             lambda b, lens, tbl: (b, 0, 0, 0)),
+                             lambda b, lyr, lens, tbl: (b, 0, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),   # k_pages in HBM
                 pl.BlockSpec(memory_space=pl.ANY),   # v_pages in HBM
             ],
             out_specs=pl.BlockSpec((1, hkv, g, hd),
-                                   lambda b, lens, tbl: (b, 0, 0, 0)),
+                                   lambda b, lyr, lens, tbl: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, hkv, hd, page), k_pages.dtype),  # kbuf
                 pltpu.VMEM((2, hkv, hd, page), v_pages.dtype),  # vbuf
@@ -213,7 +234,8 @@ def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths, page_tables, q4, k_pages, v_pages)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths, page_tables, q4,
+      k_pages, v_pages)
     return out.reshape(B, h, hd)
 
 
@@ -512,16 +534,16 @@ def _paged_verify_dma(q, k_pages, v_pages, lengths, page_tables, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, hkv, g * S, hd),
-                             lambda b, lens, tbl: (b, 0, 0, 0)),
+                             lambda b, lyr, lens, tbl: (b, 0, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),      # k_pages in HBM
                 pl.BlockSpec(memory_space=pl.ANY),      # v_pages in HBM
             ],
             out_specs=pl.BlockSpec((1, hkv, g * S, hd),
-                                   lambda b, lens, tbl: (b, 0, 0, 0)),
+                                   lambda b, lyr, lens, tbl: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, hkv, hd, page), k_pages.dtype),  # kbuf
                 pltpu.VMEM((2, hkv, hd, page), v_pages.dtype),  # vbuf
@@ -535,7 +557,8 @@ def _paged_verify_dma(q, k_pages, v_pages, lengths, page_tables, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths, page_tables, q4, k_pages, v_pages)
+    )(jnp.zeros((1,), jnp.int32), lengths, page_tables, q4, k_pages[None],
+      v_pages[None])   # one layer's pages as a stack of one
     return out.reshape(B, hkv, g, S, hd).transpose(0, 3, 1, 2, 4).reshape(
         B, S, h, hd)
 

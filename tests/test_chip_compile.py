@@ -11,6 +11,8 @@ a pass here says nothing about results or times.
 """
 
 import os
+import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -93,16 +95,17 @@ def _paged(kind):
         lengths = sds((SLOTS,), jnp.int32)
         tables = sds((SLOTS, P_SEQ), jnp.int32)
         pages = sds((HKV, N_PAGES, HD, PAGE), jnp.bfloat16)
+        pool = sds((2, HKV, N_PAGES, HD, PAGE), jnp.bfloat16)
         if kind == "decode":
             q = sds((SLOTS, H, HD), jnp.bfloat16)
             return (lambda *a: pa.paged_decode_attention(
-                *a, interpret=False)), (q, pages, pages, lengths, tables)
+                *a, layer=1, interpret=False)), (
+                q, pool, pool, lengths, tables)
         S = 5  # spec_k=4 drafts + the token they follow
         q = sds((SLOTS, S, H, HD), jnp.bfloat16)
         if kind == "verify":
             return (lambda *a: pa.paged_verify_attention(
                 *a, interpret=False)), (q, pages, pages, lengths, tables)
-        pool = sds((2, HKV, N_PAGES, HD, PAGE), jnp.bfloat16)
         new = sds((SLOTS, S, HKV, HD), jnp.bfloat16)
         return (lambda q, pk, pv, kn, vn, ln, tb:
                 pa.paged_verify_insert_attention(
@@ -155,3 +158,45 @@ def test_kernel_compiles_for_v5e(topo, case):
     assert "tpu_custom_call" in text, (
         f"{case}: compiled for the described chip without the Pallas "
         "kernel (an XLA fallback took its place)")
+
+
+def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
+    """The whole `decode_paged` program at the qwen2_7b chat cell's
+    geometry (2 of its layers, 16 slots, 257 pages), pools donated: the
+    optimized HLO holds no `copy`, `scatter` or `slice` whose result is a
+    pool or one layer of it, and the program's temporaries do not hold a
+    pool. With a scatter over the page axis and the kernel called on
+    `pool[li]` this compile held 2 + 2 whole-pool copies, 4 scatters and
+    4 per-layer slices, and 98.5 MiB of temporaries against a 64.25 MiB
+    pool."""
+    from ray_tpu.llm.engine import decode_paged
+    from ray_tpu.models import ModelConfig, init_params
+    # the dispatcher asks the backend whether to interpret the kernel;
+    # the process is on the CPU, the compile is for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, slots, n_pages = 2, 16, 257
+    c = ModelConfig(vocab=152064, d_model=3584, n_layers=layers, n_heads=H,
+                    n_kv_heads=HKV, d_ff=18944, rope_theta=1e6,
+                    tie_embeddings=False, dtype="bfloat16")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0))))
+    pool = sds((layers, HKV, n_pages, HD, PAGE), jnp.bfloat16)
+    compiled = jax.jit(partial(decode_paged, config=c),
+                       donate_argnums=(1, 2)).lower(
+        params, pool, pool, sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
+        sds((slots, P_SEQ), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= layers
+    pool_sized = re.compile(
+        r"= bf16\[(?:%d|1),%d,%d,%d,%d\]\S* (copy|scatter|slice)[-(]"
+        % (layers, HKV, n_pages, HD, PAGE))
+    assert pool_sized.findall(text) == []
+    pool_bytes = layers * HKV * n_pages * HD * PAGE * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4

@@ -760,15 +760,12 @@ def test_paged_decode_and_verify_reproduce_prefill_logits(family):
     np.testing.assert_allclose(got, want[:, n_decode:], atol=1e-5, rtol=0)
 
 
-# What may touch a layer's weight matrix besides the matmul that reads it
-# (`dot_general`): picking the layer out of the stack, a view of the leaf.
-_WEIGHT_VIEWS = {"slice", "squeeze", "dynamic_slice", "reshape"}
-
-
-def _weight_copies(jaxpr, tainted):
-    """Equations of `jaxpr` that build an array out of a weight matrix
-    rather than read it: [(primitive, output shapes)]. `tainted` is the
-    set of jaxpr vars that are (views of) `params["layers"]` matrices."""
+def _offences(jaxpr, tainted, is_view, offends):
+    """Equations of `jaxpr` that read a tainted var and that
+    `offends(primitive, output shapes)` names: [(primitive, shapes)].
+    `tainted` is a set of jaxpr vars; the outputs of an equation that
+    `is_view(primitive, positions of its tainted inputs)` accepts join
+    it, and a call's inner jaxpr is walked with the taint carried in."""
     found = []
     for eqn in jaxpr.eqns:
         hit = [i for i, v in enumerate(eqn.invars)
@@ -776,18 +773,32 @@ def _weight_copies(jaxpr, tainted):
         if not hit:
             continue
         name = eqn.primitive.name
+        shapes = [v.aval.shape for v in eqn.outvars]
         inner = [p for p in eqn.params.values()
                  if isinstance(p, (jex_core.Jaxpr, jex_core.ClosedJaxpr))]
-        if name in _WEIGHT_VIEWS:
+        if is_view(name, hit):
             tainted.update(eqn.outvars)
         elif len(inner) == 1 and name in ("pjit", "jit", "closed_call",
                                           "custom_jvp_call"):
             sub = getattr(inner[0], "jaxpr", inner[0])
-            found += _weight_copies(
-                sub, tainted | {sub.invars[i] for i in hit})
-        elif name != "dot_general":
-            found.append((name, [v.aval.shape for v in eqn.outvars]))
+            found += _offences(sub, tainted | {sub.invars[i] for i in hit},
+                               is_view, offends)
+        elif offends(name, shapes):
+            found.append((name, shapes))
     return found
+
+
+# What may touch a layer's weight matrix besides the matmul that reads it
+# (`dot_general`): picking the layer out of the stack, a view of the leaf.
+_WEIGHT_VIEWS = {"slice", "squeeze", "dynamic_slice", "reshape"}
+
+
+def _weight_copies(jaxpr, weights):
+    """Equations that build an array out of a weight matrix rather than
+    read it. `weights`: the jaxpr vars that are `params["layers"]`
+    matrices."""
+    return _offences(jaxpr, weights, lambda name, _: name in _WEIGHT_VIEWS,
+                     lambda name, _: name != "dot_general")
 
 
 @pytest.mark.parametrize("program", ["decode", "verify"])
@@ -816,3 +827,134 @@ def test_paged_programs_read_weights_in_place(family, program):
         and leaf.ndim >= 3}                    # [L, in, out]: a matrix
     assert len(weights) == (8 if c.moe_experts else 7)
     assert _weight_copies(closed.jaxpr, weights) == []
+
+
+def _pool_touches(jaxpr, pools, per_layer):
+    """Equations that scatter into a KV pool or build a `per_layer`-shaped
+    array out of one (`pool[li]`, with or without its unit axis). `pools`:
+    the jaxpr vars that are a pool; a `dynamic_update_slice` INTO a pool
+    is a pool."""
+    size = int(np.prod(per_layer))
+    return _offences(
+        jaxpr, pools,
+        lambda name, hit: name == "dynamic_update_slice" and hit == [0],
+        lambda name, shapes: name.startswith("scatter") or any(
+            s[-len(per_layer):] == per_layer and int(np.prod(s)) == size
+            for s in shapes))
+
+
+@pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
+def test_decode_paged_touches_its_pools_in_place(family):
+    """`decode_paged` writes the new token's K and V columns into the
+    stacked pools where they lie and hands the kernel the stacked pools:
+    no `scatter` over a pool and no per-layer view `pool[li]`. The
+    scatter indexed the pool's minor (page) axis, so on the chip XLA
+    re-laid the whole pool out and back and re-tiled a `pool[li]` slice
+    for every layer's kernel call, on every token (ledger PR 28:
+    `copy.*` of `bf16[12,4,257,128,128]`, 23 % of qwen2_7b's busy time)."""
+    c = _PAGED_CONFIGS[family]
+    params, pool_k, pool_v, tables = _paged_args(c)
+    args = (params, pool_k, pool_v, jnp.ones((_SLOTS,), jnp.int32),
+            jnp.zeros((_SLOTS,), jnp.int32), jnp.ones((_SLOTS,), jnp.bool_),
+            tables)
+    closed = jax.make_jaxpr(partial(decode_paged, config=c))(*args)
+    leaves = jax.tree_util.tree_flatten_with_path(args)[0]
+    pools = {var for (path, _), var in zip(leaves, closed.jaxpr.invars)
+             if jax.tree_util.keystr(path) in ("[1]", "[2]")}
+    assert len(pools) == 2
+    assert _pool_touches(closed.jaxpr, pools, pool_k.shape[1:]) == []
+    # the guard sees what it guards against: the parent's two expressions
+    old = jax.make_jaxpr(lambda p: (
+        p.at[0, jnp.arange(2)[:, None], jnp.array([[1, 2]]), :,
+             jnp.array([[0, 3]])].set(1.0), p[1]))(pool_k)
+    assert sorted(n for n, _ in _pool_touches(
+        old.jaxpr, set(old.jaxpr.invars), pool_k.shape[1:])) == [
+            "scatter", "slice"]
+
+
+def _decode_paged_scatter(params, pool_k, pool_v, tokens, lengths, active,
+                          page_tables, c):
+    """`decode_paged` as it stood before PR 29, kept as the reference of
+    the write path: one scatter over (head, page, offset) per pool and
+    layer, and the kernel over the per-layer view `pool[li]`."""
+    from ray_tpu.llm.engine import _mlp_block, _qkv
+    from ray_tpu.ops.layers import apply_rope, rmsnorm, rope
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+    B, P = page_tables.shape
+    page = pool_k.shape[4]
+    x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]
+    sin, cos = rope(lengths[:, None], c.head_dim, c.rope_theta)
+    w_idx = jnp.clip(lengths // page, 0, P - 1)
+    w_page = jnp.take_along_axis(page_tables, w_idx[:, None], 1)[:, 0]
+    w_page = jnp.where(lengths // page >= P, 0, w_page)
+    w_page = jnp.where(active, w_page, 0)
+    w_off = lengths % page
+    hkv_idx = jnp.arange(c.n_kv_heads)[:, None]
+    for li in range(c.n_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+        q, k, v = _qkv(rmsnorm(x, lp["attn_norm"], c.norm_eps), lp, c,
+                       fence=True)
+        q, k = apply_rope(q, sin, cos), apply_rope(k, sin, cos)
+        pool_k = pool_k.at[li, hkv_idx, w_page[None], :, w_off[None]].set(
+            k[:, 0].transpose(1, 0, 2).astype(pool_k.dtype))
+        pool_v = pool_v.at[li, hkv_idx, w_page[None], :, w_off[None]].set(
+            v[:, 0].transpose(1, 0, 2).astype(pool_v.dtype))
+        attn = paged_decode_attention(
+            q[:, 0], pool_k[li][None], pool_v[li][None], lengths + 1,
+            page_tables, layer=0)
+        attn = attn.reshape(B, 1, c.n_heads * c.head_dim).astype(x.dtype)
+        x = _mlp_block(x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"]), lp, c)
+    x = rmsnorm(x, params["final_norm"], c.norm_eps)
+    head = params["embed"].T if c.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bd,dv->bv", x[:, 0].astype(jnp.float32),
+                        head.astype(jnp.float32))
+    neg = jnp.full_like(logits, -1e30).at[:, 0].set(0.0)
+    return jnp.where(active[:, None], logits, neg), pool_k, pool_v
+
+
+# lengths, active, page tables of three slots over two-page tables
+# (`_PAGE` 16): where each slot's new column lands.
+_WRITE_CASES = {
+    "offset_0": ([16, 0, 5], [1, 1, 1], [[1, 2], [3, 4], [5, 6]]),
+    "offset_last": ([15, 31, 5], [1, 1, 1], [[1, 2], [3, 4], [5, 6]]),
+    "inactive_slot": ([7, 20, 5], [1, 0, 1], [[1, 2], [3, 4], [5, 6]]),
+    "past_table_bucket": ([32, 3, 5], [1, 1, 1], [[1, 2], [3, 4], [5, 6]]),
+    "shared_prefix_pages": ([20, 18, 5], [1, 1, 1],
+                            [[1, 2], [1, 3], [5, 6]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_decode_paged_writes_what_the_scatter_wrote(case):
+    """The in-place column writes leave the pools BITWISE as the parent's
+    scatter left them, in the pool's own bf16 (greedy outputs must not
+    move), and the logits with them. Scratch page 0 alone may differ, and
+    only where a slot was sent there (inactive, or past its table
+    bucket); every column no slot owns keeps its bytes — prefix pages
+    that two slots share among them."""
+    c = ModelConfig(vocab=300, d_model=64, n_layers=2, n_heads=4,
+                    n_kv_heads=2, d_ff=128, dtype="bfloat16")
+    lengths, active, tables = _WRITE_CASES[case]
+    params = init_params(c, jax.random.PRNGKey(7))
+    shape = (c.n_layers, c.n_kv_heads, 7, c.head_dim, _PAGE)
+    pool_k = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.bfloat16)
+    pool_v = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.bfloat16)
+    args = (params, pool_k, pool_v, jnp.asarray([11, 12, 13], jnp.int32),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(active, jnp.bool_),
+            jnp.asarray(tables, jnp.int32))
+    got = jax.jit(partial(decode_paged, config=c))(*args)
+    want = jax.jit(partial(_decode_paged_scatter, c=c))(*args)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    to_scratch = case in ("inactive_slot", "past_table_bucket")
+    written = np.zeros(shape, bool)
+    for n, a, tab in zip(lengths, active, tables):
+        if a and n // _PAGE < len(tab):
+            written[:, :, tab[n // _PAGE], :, n % _PAGE] = True
+    assert written.sum() == (3 - to_scratch) * shape[0] * shape[1] * shape[3]
+    for new, ref, before in zip(got[1:], want[1:], (pool_k, pool_v)):
+        new, ref, before = (np.asarray(a.astype(jnp.float32))[
+            :, :, int(to_scratch):] for a in (new, ref, before))
+        np.testing.assert_array_equal(new, ref)
+        own = written[:, :, int(to_scratch):]
+        np.testing.assert_array_equal(new[~own], before[~own])
+        assert (new[own] != before[own]).mean() > 0.9
