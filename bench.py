@@ -6,7 +6,7 @@ FULL 21-metric regression-gate set in BASELINE.md, same workload semantics
 (nested submission for multi-client, Client fan-out actors, threaded /
 async actors, 10k-ref objects, wait loops, PG churn, client-mode RPCs).
 Prints ONE JSON line:
-  {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, "tpu": {...}}
+  {"metric": ..., "value": N, "unit": ..., "vs_baseline": N}
 where vs_baseline is the geometric mean of (ours / reference) across all
 metrics. Detail per-metric numbers go to stderr.
 
@@ -76,7 +76,6 @@ _REPO = os.path.dirname(os.path.abspath(__file__))
 
 RESULTS: dict[str, float] = {}
 SKIPPED: list[str] = []
-TPU: dict = {}
 EXTRAS: dict = {}
 _FINAL_PRINTED = False
 
@@ -124,8 +123,6 @@ def final_line(status: str = "complete"):
         ratios.append(r)
         (par_r if key in PARALLEL else single_r).append(r)
     geomean = _gm(ratios)
-    mfu = max((c["mfu_pct"] for c in TPU.get("configs", [])
-               if isinstance(c, dict) and "mfu_pct" in c), default=None)
     detail_path = os.environ.get(
         "BENCH_OUT", os.path.join(_REPO, "bench_out.json"))
     full = {
@@ -150,8 +147,6 @@ def final_line(status: str = "complete"):
         "elastic_train": EXTRAS.get("elastic_train", {}),
         "multi_tenant": EXTRAS.get("multi_tenant", {}),
         "serve_storm": EXTRAS.get("serve_storm", {}),
-        "tpu_mfu_pct": mfu,
-        "tpu": TPU,
         "detail": {k: round(v, 1) for k, v in RESULTS.items()},
     }
     if missing:
@@ -235,7 +230,6 @@ def final_line(status: str = "complete"):
         "tev_ovh_pct": EXTRAS.get("task_events", {}).get("overhead_pct"),
         "xlang_s": EXTRAS.get("cross_language", {}).get(
             "cpp_tasks_async_s"),
-        "tpu_mfu_pct": mfu,
         "host": {k: EXTRAS.get("host", {}).get(k)
                  for k in ("cpu_count", "memcpy_gbps")},
         "top": {k: round(RESULTS[k], 1) for k in (
@@ -256,7 +250,7 @@ def final_line(status: str = "complete"):
     # window, full stop. An assert here would EAT the headline on the
     # oversize path — trim to the irreducible core instead of dying.
     if len(line) >= 2048:
-        for key in ("host", "tpu_mfu_pct", "xlang_s", "tev_ovh_pct",
+        for key in ("host", "xlang_s", "tev_ovh_pct",
                     "adag_x", "data_x", "chaos_x", "train_bit",
                     "train_rec_s",
                     "serve_p50_ms", "serve_dvd_x", "serve_kill_p99_ms",
@@ -416,8 +410,6 @@ def main():
                           "error": f"{type(e).__name__}: {str(e)[:200]}"}),
               file=sys.stderr, flush=True)
         final_line(status=f"degraded: {type(e).__name__}: {str(e)[:100]}")
-    if "error" in TPU:
-        sys.exit(1)  # the TPU section was asked for and did not run clean
 
 
 def _main_inner():
@@ -426,37 +418,6 @@ def _main_inner():
     import ray_tpu
     from ray_tpu.core.session import gc_stale_sessions
     gc_stale_sessions()
-
-    # TPU bench first, in a CHILD that exits before ray_tpu.init() below
-    # spawns workers: a chip belongs to one process at a time, and a
-    # parent that had run JAX would keep it from every worker that
-    # reserves it. Gets at most half the budget; must leave >=600s for
-    # the core suite. Asked for unless RAY_TPU_SKIP_TPU_BENCH is set, and
-    # when asked for, no chip or a crash is an error (exit code 1 after
-    # the core suite has still landed its headline), never "skipped".
-    global TPU
-    if os.environ.get("RAY_TPU_SKIP_TPU_BENCH"):
-        TPU = {"skipped": "RAY_TPU_SKIP_TPU_BENCH set"}
-    else:
-        tpu_budget = min(_remaining() - 600, _BUDGET / 2)
-        try:
-            # bench_tpu honors its budget cooperatively; the +60 kill
-            # covers one wedged XLA compile (the r04 failure shape).
-            out = run_sub(
-                "import runpy, sys; "
-                f"sys.argv = ['bench_tpu.py', '{tpu_budget:.0f}']; "
-                "runpy.run_module('bench_tpu', run_name='__main__')",
-                timeout=max(tpu_budget + 60, 30), tag="bench_tpu")
-            TPU = json.loads(out.strip().splitlines()[-1])
-        except Exception as e:  # noqa: BLE001 — the core suite still runs
-            TPU = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-        for c in TPU.get("configs", []):
-            for key in ("decode_tokens_per_sec", "tokens_per_sec",
-                        "tokens_per_sec_per_chip", "env_steps_per_sec",
-                        "mfu_pct"):
-                if key in c:
-                    emit(f"tpu_{c['config']}_{key}", float(c[key]))
-                    break
 
     ncpu = os.cpu_count() or 1
     EXTRAS["host"] = {"cpu_count": ncpu,
@@ -1599,8 +1560,7 @@ ray_tpu.shutdown()
         ray_tpu.shutdown()
     except Exception:
         pass
-    final_line("degraded: tpu section failed" if "error" in TPU
-               else "complete" if not SKIPPED else "partial")
+    final_line("complete" if not SKIPPED else "partial")
 
 
 def _memcpy_ceiling_gbps() -> float:

@@ -27,8 +27,8 @@ def ensure_compile_cache() -> str:
     checkout: the path is part of the cache key's environment, so a
     directory made from a temporary name, a pid or a time never hits.
     Thresholds stay at JAX's defaults. Workers get the variable through
-    `build_worker_env`; a process that compiles on its own (bench_tpu.py)
-    calls this before its first jit."""
+    `build_worker_env`; a process that compiles on its own calls this
+    before its first jit."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path

@@ -6,18 +6,16 @@ batching, paged KV, TP sizing consumed for placement). TPU-native redesign
 (JetStream-shaped rather than a vLLM port):
 
 - **Static shapes everywhere.** The decode batch is a fixed array of
-  `max_slots` sequence slots over a preallocated KV cache
-  [layers, slots, max_len, kv_heads, head_dim]; admission/eviction mutate
-  slot state, never array shapes, so XLA compiles prefill (per prompt-length
-  bucket) and decode exactly once.
+  `max_slots` sequence slots over ONE preallocated pool of KV pages
+  [layers, kv_heads, pages, head_dim, page]; admission, growth and
+  eviction mutate slot state and page tables, never array shapes, so XLA
+  compiles prefill once a prompt-length bucket and decode once a
+  page-table bucket.
 - **Decode is one jit for ALL slots** — a [slots, 1] batched step keeps the
   MXU busy and lets GSPMD shard heads over the "tp" mesh axis; per-slot
-  positions/masks are data, not shapes.
+  positions, masks and page ids are data, not shapes.
 - **Prefill/decode disaggregation is a host-side policy**: prefill runs as
-  its own jit per bucket and its KV is spliced into the cache with
-  dynamic_update_slice.
-- Paged-attention bookkeeping collapses: on TPU a contiguous per-slot ring
-  of max_len beats page tables (sequential HBM streams; no gather).
+  its own jit per bucket and its KV is written into the slot's pages.
 """
 
 from __future__ import annotations
@@ -68,10 +66,13 @@ class EngineConfig:
     # --- KV layout (parity: vLLM's paged KV under the reference's llm
     # stack, vllm_models.py:123-137; TPU-shaped: static page pool +
     # bucketed gathers instead of CUDA page kernels) ---
-    kv_layout: str = "paged"       # "paged" | "dense" (legacy fixed slots)
+    # One layout, "paged": InferenceEngine refuses any other value. The
+    # field is here only because the benchmark's harness passes it
+    # (perfbench/harness/modelcfg.py) and refuses keys EngineConfig lacks.
+    kv_layout: str = "paged"
     page_size: int = 128           # tokens per KV page (TPU lane-friendly)
     num_pages: int | None = None   # pool size; None = slots*ceil(max_len/
-    #                                page)+1 (capacity parity with dense)
+    #                                page)+1 (every slot can reach max_len)
     prefix_cache: bool = True      # reuse full prompt pages across requests
     # --- speculative decoding (parity: vLLM ngram speculation under the
     # reference's llm stack; greedy windows only — sampled slots fall back
@@ -148,11 +149,57 @@ def _mlp_block(x, lp, c: ModelConfig):
     return x + (_moe(normed, lp, c) if c.moe_experts else _mlp(normed, lp))
 
 
-def _gqa_scores(q, k, n_rep):
-    # q [b,1,h,hd]; k [b,T,hkv,hd] -> scores [b,h,T]
+def _embed(params, tokens):
+    return jnp.take(params["embed"], tokens, axis=0)
+
+
+def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False):
+    """One layer of a per-head program, x [b, s, d] -> (x, kept). What a
+    program brings: `turn(t)` rotates q and k to its positions, and
+    `attend(q, k, v)` -> (attention output [b, s, h, hd] in any grouping
+    of its axes, what the program keeps of this layer: its K and V, or
+    the pools it wrote them to). `fence` as in `_qkv`."""
+    b, s, _ = x.shape
+    normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
+    q, k, v = _qkv(normed, lp, c, fence)
+    attn, kept = attend(turn(q), turn(k), v)
+    attn = attn.reshape(b, s, c.n_heads * c.head_dim).astype(x.dtype)
+    h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
+    return _mlp_block(h, lp, c), kept
+
+
+def _softmax_attention(q, k, v, mask, c: ModelConfig):
+    """q [b, s, h, hd] over keys and values [b, t, hkv, hd], all of them
+    in reach at once (the prefill programs); mask [b, s, t], or [s, t]
+    where one serves every request."""
+    n_rep = c.n_heads // c.n_kv_heads
     if n_rep > 1:
         k = jnp.repeat(k, n_rep, axis=2)
-    return jnp.einsum("bqhd,bthd->bhqt", q, k)[:, :, 0, :]
+        v = jnp.repeat(v, n_rep, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(c.head_dim)
+    # a heads axis, and a batch axis where the mask has none
+    mask = jnp.expand_dims(mask, tuple(range(mask.ndim - 2, 2)))
+    scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _head(x, params, c: ModelConfig, active=None, at=None):
+    """Final norm and the fp32 head: x [b, s, d] -> logits [b, s, vocab],
+    or [b, vocab] at the one position `at` of every sequence. An inactive
+    slot (`active` [b]) reads -1e30 except token 0: it must not corrupt
+    metrics downstream, and argmax / categorical stay defined."""
+    x = rmsnorm(x, params["final_norm"], c.norm_eps)
+    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
+    if at is not None:
+        x = x[:, at]
+    logits = jnp.einsum("...d,dv->...v", x.astype(jnp.float32),
+                        head.astype(jnp.float32))
+    if active is None:
+        return logits
+    neg = jnp.full_like(logits, -1e30).at[..., 0].set(0.0)
+    return jnp.where(jnp.expand_dims(active, tuple(range(1, logits.ndim))),
+                     logits, neg)
 
 
 def prefill_batch(params, tokens, config: ModelConfig):
@@ -162,107 +209,28 @@ def prefill_batch(params, tokens, config: ModelConfig):
     Batched so an admission burst pays ONE dispatch, not one per prompt
     (the vLLM-style batched prefill role)."""
     c = config
-    x = jnp.take(params["embed"], tokens, axis=0)
-    n, s = tokens.shape
-    positions = jnp.arange(s)
-    sin, cos = rope(positions, c.head_dim, c.rope_theta)
+    x = _embed(params, tokens)
+    s = tokens.shape[1]
+    sin, cos = rope(jnp.arange(s), c.head_dim, c.rope_theta)
     causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
 
-    def layer(x, lp):
-        normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        q, k, v = _qkv(normed, lp, c)
-        q = apply_rope(q, sin[None], cos[None])
-        k = apply_rope(k, sin[None], cos[None])
-        n_rep = c.n_heads // c.n_kv_heads
-        kk = jnp.repeat(k, n_rep, axis=2) if n_rep > 1 else k
-        vv = jnp.repeat(v, n_rep, axis=2) if n_rep > 1 else v
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / np.sqrt(c.head_dim)
-        scores = jnp.where(causal[None, None], scores.astype(jnp.float32),
-                           -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        attn = attn.reshape(n, s, c.n_heads * c.head_dim)
-        h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        return _mlp_block(h, lp, c), (k, v)
+    def turn(t):  # [None] stays in here, staged once a use inside the
+        # scan: the lowered text keys this program's compile-cache entry
+        return apply_rope(t, sin[None], cos[None])
 
-    x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("nsd,dv->nsv", x.astype(jnp.float32),
-                        head.astype(jnp.float32))
-    return logits, ks, vs
+    def attend(q, k, v):
+        return _softmax_attention(q, k, v, causal, c), (k, v)
+
+    x, (ks, vs) = jax.lax.scan(
+        lambda x, lp: _block(x, lp, c, turn, attend), x, params["layers"])
+    return _head(x, params, c), ks, vs
 
 
 def prefill(params, tokens, config: ModelConfig):
     """tokens [1, S] -> (logits [S, vocab], k/v [L, S, hkv, hd]); the
-    single-prompt view of prefill_batch (dense-layout + prefix paths)."""
+    single-prompt view of prefill_batch (PrefillEngine's program)."""
     logits, ks, vs = prefill_batch(params, tokens, config)
     return logits[0], ks[:, 0], vs[:, 0]
-
-
-def insert_kv(cache_k, cache_v, ks, vs, slot, length):
-    """Splice a prefill's KV into a slot. ks/vs [L, S, hkv, hd]; zero the
-    padded tail so stale garbage can't alias later positions."""
-    S = ks.shape[1]
-    mask = (jnp.arange(S) < length)[None, :, None, None]
-    ks = jnp.where(mask, ks, 0)
-    vs = jnp.where(mask, vs, 0)
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, ks[:, None].astype(cache_k.dtype), (0, slot, 0, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, vs[:, None].astype(cache_v.dtype), (0, slot, 0, 0, 0))
-    return cache_k, cache_v
-
-
-def decode_step(params, cache_k, cache_v, tokens, lengths, active,
-                config: ModelConfig):
-    """One token for every slot. tokens [B] (last sampled), lengths [B]
-    (cache fill = position of the new token), active [B] bool.
-    Returns (logits [B, vocab] fp32, cache_k, cache_v)."""
-    c = config
-    B, T = cache_k.shape[1], cache_k.shape[2]
-    x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]  # [B,1,d]
-    sin, cos = rope(lengths[:, None], c.head_dim, c.rope_theta)  # [B,1,half]
-    n_rep = c.n_heads // c.n_kv_heads
-    pos_mask = jnp.arange(T)[None] <= lengths[:, None]  # [B,T] inclusive
-
-    def write(cache_l, kv_b):
-        # cache_l [B,T,hkv,hd], kv_b [B,1,hkv,hd]: per-slot positional write
-        return jax.vmap(
-            lambda cb, kb, p: jax.lax.dynamic_update_slice(
-                cb, kb.astype(cb.dtype), (p, 0, 0))
-        )(cache_l, kv_b, lengths)
-
-    def layer(x, scan_in):
-        lp, ck, cv = scan_in
-        normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        q, k, v = _qkv(normed, lp, c)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
-        ck = write(ck, k)
-        cv = write(cv, v)
-        scores = _gqa_scores(q, ck, n_rep) / np.sqrt(c.head_dim)  # [B,h,T]
-        scores = jnp.where(pos_mask[:, None], scores.astype(jnp.float32),
-                           -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        cvv = jnp.repeat(cv, n_rep, axis=2) if n_rep > 1 else cv
-        attn = jnp.einsum("bht,bthd->bhd", probs, cvv)
-        attn = attn.reshape(B, 1, c.n_heads * c.head_dim)
-        h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        return _mlp_block(h, lp, c), (ck, cv)
-
-    x, (cache_k, cache_v) = jax.lax.scan(
-        layer, x, (params["layers"], cache_k, cache_v))
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bd,dv->bv", x[:, 0].astype(jnp.float32),
-                        head.astype(jnp.float32))
-    # Inactive slots must not corrupt metrics downstream; mask to -inf
-    # except token 0 so argmax/categorical stay defined.
-    neg = jnp.full_like(logits, -1e30)
-    neg = neg.at[:, 0].set(0.0)
-    logits = jnp.where(active[:, None], logits, neg)
-    return logits, cache_k, cache_v
 
 
 def prefill_with_prefix_batch(params, tokens, pool_k, pool_v,
@@ -276,7 +244,7 @@ def prefill_with_prefix_batch(params, tokens, pool_k, pool_v,
     suffix positions offset by prefix_len. Returns (suffix logits
     [n, S, vocab] f32, suffix k/v caches [L, n, S, hkv, hd])."""
     c = config
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = _embed(params, tokens)
     n, s = tokens.shape
     page = pool_k.shape[4]
     pre_t = prefix_pages.shape[1] * page
@@ -290,12 +258,7 @@ def prefill_with_prefix_batch(params, tokens, pool_k, pool_v,
         [pre_mask, jnp.broadcast_to(causal[None], (n, s, s))],
         axis=2)                                               # [n,S,preT+S]
 
-    def layer(x, scan_in):
-        lp, pk, pv = scan_in  # pk/pv [hkv, pages, hd, page]
-        normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        q, k, v = _qkv(normed, lp, c)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+    def attend(pk, pv, q, k, v):  # pk/pv [hkv, pages, hd, page]
         # [hkv, n, Pp, hd, page] -> [n, Pp, page, hkv, hd]
         #                        -> [n, preT, hkv, hd]
         prek = pk[:, prefix_pages].transpose(1, 2, 4, 0, 3).reshape(
@@ -304,25 +267,15 @@ def prefill_with_prefix_batch(params, tokens, pool_k, pool_v,
             n, pre_t, pv.shape[0], -1)
         kk = jnp.concatenate([prek.astype(k.dtype), k], axis=1)
         vv = jnp.concatenate([prev.astype(v.dtype), v], axis=1)
-        n_rep = c.n_heads // c.n_kv_heads
-        if n_rep > 1:
-            kk = jnp.repeat(kk, n_rep, axis=2)
-            vv = jnp.repeat(vv, n_rep, axis=2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / np.sqrt(c.head_dim)
-        scores = jnp.where(full_mask[:, None],
-                           scores.astype(jnp.float32), -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        attn = attn.reshape(n, s, c.n_heads * c.head_dim)
-        h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        return _mlp_block(h, lp, c), (k, v)
+        return _softmax_attention(q, kk, vv, full_mask, c), (k, v)
+
+    def layer(x, scan_in):
+        lp, pk, pv = scan_in
+        return _block(x, lp, c, partial(apply_rope, sin=sin, cos=cos),
+                      partial(attend, pk, pv))
 
     x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], pool_k, pool_v))
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("nsd,dv->nsv", x.astype(jnp.float32),
-                        head.astype(jnp.float32))
-    return logits, ks, vs
+    return _head(x, params, c), ks, vs
 
 
 def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
@@ -381,7 +334,7 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     c = config
     B, P = page_tables.shape
     page = pool_k.shape[4]
-    x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]  # [B,1,d]
+    x = _embed(params, tokens)[:, None, :]  # [B,1,d]
     sin, cos = rope(lengths[:, None], c.head_dim, c.rope_theta)
     w_idx = jnp.clip(lengths // page, 0, P - 1)
     w_page = jnp.take_along_axis(page_tables, w_idx[:, None], 1)[:, 0]
@@ -415,29 +368,20 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
                 (layer, zero, pg, zero, off), allow_negative_indices=False)
         return pool
 
-    for li in range(c.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        q, k, v = _qkv(normed, lp, c, fence=True)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+    def attend(li, pool_k, pool_v, q, k, v):
         pool_k, pool_v = write(pool_k, k, li), write(pool_v, v, li)
         # attend INCLUSIVE of the just-written token: positions
         # < lengths+1 == positions <= lengths
         attn = paged_decode_attention(
             q[:, 0], pool_k, pool_v, lengths + 1, page_tables, layer=li)
-        attn = attn.reshape(B, 1, c.n_heads * c.head_dim).astype(x.dtype)
-        h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        x = _mlp_block(h, lp, c)
+        return attn, (pool_k, pool_v)
 
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bd,dv->bv", x[:, 0].astype(jnp.float32),
-                        head.astype(jnp.float32))
-    neg = jnp.full_like(logits, -1e30)
-    neg = neg.at[:, 0].set(0.0)
-    logits = jnp.where(active[:, None], logits, neg)
-    return logits, pool_k, pool_v
+    for li in range(c.n_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+        x, (pool_k, pool_v) = _block(
+            x, lp, c, partial(apply_rope, sin=sin, cos=cos),
+            partial(attend, li, pool_k, pool_v), fence=True)
+    return _head(x, params, c, active, at=0), pool_k, pool_v
 
 
 def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
@@ -452,17 +396,11 @@ def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
     all S queries)."""
     from ray_tpu.ops.paged_attention import paged_verify_insert_attention
     c = config
-    B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)          # [B, S, d]
-    positions = lengths[:, None] + jnp.arange(S)[None]     # [B, S]
+    x = _embed(params, tokens)                             # [B, S, d]
+    positions = lengths[:, None] + jnp.arange(tokens.shape[1])[None]
     sin, cos = rope(positions, c.head_dim, c.rope_theta)
 
-    for li in range(c.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-        q, k, v = _qkv(normed, lp, c, fence=True)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+    def attend(li, pool_k, pool_v, q, k, v):
         # Insert is FUSED into the attention kernel: the new tokens'
         # K/V merge into the page already streaming through VMEM and the
         # merged page DMAs back to the aliased pool — token-granular XLA
@@ -470,18 +408,14 @@ def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
         # forward (measured; see ops/paged_attention.py).
         attn, pool_k, pool_v = paged_verify_insert_attention(
             q, pool_k, pool_v, k, v, lengths + 1, page_tables, li)
-        attn = attn.reshape(B, S, c.n_heads * c.head_dim).astype(x.dtype)
-        h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-        x = _mlp_block(h, lp, c)
+        return attn, (pool_k, pool_v)
 
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x.astype(jnp.float32),
-                        head.astype(jnp.float32))
-    neg = jnp.full_like(logits, -1e30)
-    neg = neg.at[:, :, 0].set(0.0)
-    logits = jnp.where(active[:, None, None], logits, neg)
-    return logits, pool_k, pool_v
+    for li in range(c.n_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+        x, (pool_k, pool_v) = _block(
+            x, lp, c, partial(apply_rope, sin=sin, cos=cos),
+            partial(attend, li, pool_k, pool_v), fence=True)
+    return _head(x, params, c, active), pool_k, pool_v
 
 
 def ngram_draft(hist, lengths, last_tokens, k: int):
@@ -724,6 +658,18 @@ def sample(logits, temperature, key, top_p=None, top_k=None, mask=None):
 # ---------------- the engine ----------------
 
 
+def _samplers():
+    """Two compiled samplers: the plain one (no sorts) serves the default
+    top_k=0/top_p=1 case on the hot decode loop; the truncating one
+    compiles the top-k/top-p masking only when some request asks for it."""
+    return (_shared_jit(("sample",), lambda: jax.jit(sample)),
+            _shared_jit(
+                ("sample_trunc",),
+                lambda: jax.jit(
+                    lambda lg, t, k, p, tk, m=None: sample(
+                        lg, t, k, top_p=p, top_k=tk, mask=m))))
+
+
 def _resolve_params(model_config: ModelConfig, params, mesh, rules,
                     seed: int):
     """Init (or accept) params and shard them over the replica mesh —
@@ -747,6 +693,14 @@ def _refuse_latent(c: ModelConfig, what: str):
         f"(ModelConfig.kv_cache == \"latent\"), which does not run with "
         f"{what}: only the paged single-device programs (prefill_batch, "
         f"prefill_with_prefix_batch, insert, decode_paged) exist for it")
+
+
+def _sampling_of(reqs) -> tuple:
+    """(temperatures, top_ps, top_ks), a row a request, on the host; None
+    (an empty slot) reads greedy and untruncated."""
+    return (np.array([r.temperature if r else 0.0 for r in reqs], np.float32),
+            np.array([r.top_p if r else 1.0 for r in reqs], np.float32),
+            np.array([r.top_k if r else 0 for r in reqs], np.int32))
 
 
 def _prompt_bucket(e: EngineConfig, n: int) -> int:
@@ -778,127 +732,108 @@ class InferenceEngine:
         self.c = model_config
         self.e = engine_config or EngineConfig()
         self.mesh = mesh
+        if self.e.kv_layout != "paged":
+            raise ValueError(
+                f"EngineConfig.kv_layout={self.e.kv_layout!r}: the engine "
+                f"keeps one KV layout, \"paged\" (a pool of pages; what a "
+                f"page holds follows ModelConfig.attention="
+                f"{model_config.attention!r})")
         # A latent-cache model (ModelConfig.kv_cache) brings its own four
         # programs (models/deepseek_v2.py) over ONE pool [L, N, latent,
         # page], held in `cache_k` (`cache_v` is None); page accounting,
         # prefix hashing, chunked prefill and preemption below are shared.
         self.latent = model_config.kv_cache == "latent"
         if self.latent:
-            e = self.e
-            if e.kv_layout != "paged":
-                _refuse_latent(model_config,
-                               f"EngineConfig.kv_layout={e.kv_layout!r}")
-            if e.speculation is not None:
-                _refuse_latent(model_config,
-                               f"EngineConfig.speculation={e.speculation!r}")
+            if self.e.speculation is not None:
+                _refuse_latent(model_config, "EngineConfig.speculation="
+                                             f"{self.e.speculation!r}")
             if mesh is not None and mesh.devices.size > 1:
                 _refuse_latent(model_config, f"a mesh of {dict(mesh.shape)} "
                                              f"(tensor parallelism)")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         c, e = self.c, self.e
-        self.paged = e.kv_layout == "paged"
-        kv_sharding = None
-        if mesh is not None and "tp" in mesh.axis_names:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            # kv-head axis: position 1 in the paged layout
-            # [L, hkv, N, page, hd], position 3 in the dense layout.
-            kv_sharding = NamedSharding(
-                mesh, P(None, "tp") if e.kv_layout == "paged"
-                else P(None, None, None, "tp", None))
-        if self.paged:
-            # Paged pool (parity: vLLM paged KV, vllm_models.py:123-137):
-            # HBM tracks the pool size — actual token load — not
-            # slots x max_len; sequences grow page by page and shared
-            # prompt prefixes share pages. Page 0 is reserved scratch
-            # (unused page-table entries point at it).
-            page = e.page_size
-            self.pages_per_slot = -(-e.max_len // page)
-            self.num_pages = (e.num_pages
-                              or e.max_slots * self.pages_per_slot + 1)
-            # [L, hkv, N, hd, page] — kv-heads outermost after layers and
-            # head_dim BEFORE page so the Pallas decode kernel can DMA
-            # per-page blocks [hkv, hd, page] whose trailing dims
-            # (hd, 128) satisfy Mosaic's (8, 128) tiling.
-            if self.latent:
-                model = model_module(c)
-                self.cache_k = jnp.zeros(
-                    model.pool_shape(c, self.num_pages, page), c.jdtype)
-                self.cache_v = None
-                self._moe_acc = model.stats_zero(c)   # device; moe_stats()
-                self._moe_total = np.zeros(self._moe_acc.shape, np.int64)
-            else:
-                kv_shape = (c.n_layers, c.n_kv_heads, self.num_pages,
-                            c.head_dim, page)
-                self.cache_k = jnp.zeros(kv_shape, c.jdtype)
-                self.cache_v = jnp.zeros(kv_shape, c.jdtype)
-            # page bookkeeping (host side)
-            self.free_pages: list[int] = list(range(1, self.num_pages))
-            self.page_refs: dict[int, int] = {}
-            self.page_hash: dict = {}          # prefix-hash -> page id
-            self.hash_of_page: dict[int, object] = {}
-            self.cached_lru: "collections.OrderedDict[int, object]" = (
-                collections.OrderedDict())     # ref-0 cached pages (LRU)
-            self.slot_pages: list[list[int]] = [[] for _ in
-                                                range(e.max_slots)]
-            self.slot_borrowed = [0] * e.max_slots
-            self.prefix_hits = 0
-            self.preemptions = 0
-            # decode compile buckets over pages-in-use: powers of two up
-            # to the per-slot page bound
-            pb, b = [], 1
-            while b < self.pages_per_slot:
-                pb.append(b)
-                b *= 2
-            pb.append(self.pages_per_slot)
-            self._page_buckets = pb
-            self._decode_paged: dict[int, object] = {}
-            self._prefill_pre: dict[tuple, object] = {}
-            self._window_fns: dict[tuple, object] = {}
-            self._win_buckets = (1, 2, 4, 8, 16, 32, 64)
-            # Device-resident decode state (uploaded only when the host
-            # view changed): a per-step upload is a host sync like a
-            # download.
-            self._dev = None           # (tokens, lengths, active) on device
-            self._dev_dirty = True
-            self._dev_key = jax.random.PRNGKey(seed + 2)
-            self._dev_sampling = None  # (temps, top_ps, top_ks) device
-            self._dev_sampling_fp = None
-            self._dev_gtables = None   # stacked guide tables [B, S, V]
-            self._guide_fp = None
-            # Donate the pool/cache: without donation every step round-trips
-            # the full KV through a fresh HBM allocation (~GBs/step).
-            if self.latent:
-                self._insert_batch = _shared_jit(
-                    ("insert_latent_pages_batch",),
-                    lambda: jax.jit(model.insert_latent_pages_batch,
-                                    donate_argnums=(0,)))
-            else:
-                self._insert_batch = _shared_jit(
-                    ("insert_pages_batch",),
-                    lambda: jax.jit(insert_pages_batch,
-                                    donate_argnums=(0, 1)))
-            self._prefill_batches: dict[tuple, object] = {}
+        # Paged pool (parity: vLLM paged KV, vllm_models.py:123-137):
+        # HBM tracks the pool size — actual token load — not
+        # slots x max_len; sequences grow page by page and shared
+        # prompt prefixes share pages. Page 0 is reserved scratch
+        # (unused page-table entries point at it).
+        page = e.page_size
+        self.pages_per_slot = -(-e.max_len // page)
+        self.num_pages = (e.num_pages
+                          or e.max_slots * self.pages_per_slot + 1)
+        # [L, hkv, N, hd, page] — kv-heads outermost after layers and
+        # head_dim BEFORE page so the Pallas decode kernel can DMA
+        # per-page blocks [hkv, hd, page] whose trailing dims
+        # (hd, 128) satisfy Mosaic's (8, 128) tiling.
+        if self.latent:
+            model = model_module(c)
+            self.cache_k = jnp.zeros(
+                model.pool_shape(c, self.num_pages, page), c.jdtype)
+            self.cache_v = None
+            self._moe_acc = model.stats_zero(c)   # device; moe_stats()
+            self._moe_total = np.zeros(self._moe_acc.shape, np.int64)
         else:
-            kv_shape = (c.n_layers, e.max_slots, e.max_len, c.n_kv_heads,
-                        c.head_dim)
+            kv_shape = (c.n_layers, c.n_kv_heads, self.num_pages,
+                        c.head_dim, page)
             self.cache_k = jnp.zeros(kv_shape, c.jdtype)
             self.cache_v = jnp.zeros(kv_shape, c.jdtype)
-            self._insert = _shared_jit(
-                ("insert_kv",),
-                lambda: jax.jit(insert_kv, donate_argnums=(0, 1)))
-            self._decode = _shared_jit(
-                ("decode_step", c),
-                lambda: jax.jit(partial(decode_step, config=c),
-                                donate_argnums=(1, 2)))
-        if kv_sharding is not None:
+        # page bookkeeping (host side)
+        self.free_pages: list[int] = list(range(1, self.num_pages))
+        self.page_refs: dict[int, int] = {}
+        self.page_hash: dict = {}          # prefix-hash -> page id
+        self.hash_of_page: dict[int, object] = {}
+        self.cached_lru: "collections.OrderedDict[int, object]" = (
+            collections.OrderedDict())     # ref-0 cached pages (LRU)
+        self.slot_pages: list[list[int]] = [[] for _ in
+                                            range(e.max_slots)]
+        self.prefix_hits = 0
+        self.preemptions = 0
+        # decode compile buckets over pages-in-use: powers of two up
+        # to the per-slot page bound
+        pb, b = [], 1
+        while b < self.pages_per_slot:
+            pb.append(b)
+            b *= 2
+        pb.append(self.pages_per_slot)
+        self._page_buckets = pb
+        self._decode_paged: dict[int, object] = {}
+        self._prefill_pre: dict[tuple, object] = {}
+        self._window_fns: dict[tuple, object] = {}
+        self._win_buckets = (1, 2, 4, 8, 16, 32, 64)
+        # Device-resident decode state (uploaded only when the host
+        # view changed): a per-step upload is a host sync like a
+        # download.
+        self._dev = None           # (tokens, lengths, active) on device
+        self._dev_dirty = True
+        self._dev_key = jax.random.PRNGKey(seed + 2)
+        self._dev_sampling = None  # (temps, top_ps, top_ks) device
+        self._dev_sampling_fp = None
+        self._dev_gtables = None   # stacked guide tables [B, S, V]
+        self._guide_fp = None
+        # Donate the pool/cache: without donation every step round-trips
+        # the full KV through a fresh HBM allocation (~GBs/step).
+        if self.latent:
+            self._insert_batch = _shared_jit(
+                ("insert_latent_pages_batch",),
+                lambda: jax.jit(model.insert_latent_pages_batch,
+                                donate_argnums=(0,)))
+        else:
+            self._insert_batch = _shared_jit(
+                ("insert_pages_batch",),
+                lambda: jax.jit(insert_pages_batch,
+                                donate_argnums=(0, 1)))
+        self._prefill_batches: dict[tuple, object] = {}
+        if mesh is not None and "tp" in mesh.axis_names:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            # kv-head axis: position 1 of [L, hkv, N, hd, page]
+            kv_sharding = NamedSharding(mesh, P(None, "tp"))
             self.cache_k = jax.device_put(self.cache_k, kv_sharding)
             self.cache_v = jax.device_put(self.cache_v, kv_sharding)
 
-        # Speculative decoding state (both layouts keep the host history
-        # mirror — step()/_admit write it unconditionally; the device twin
-        # and window machinery are paged-only).
-        self._spec = self.paged and e.speculation == "ngram"
+        # Speculative decoding state (step()/_admit write the host history
+        # mirror unconditionally; the device twin is the spec window's).
+        self._spec = e.speculation == "ngram"
         if self._spec and e.spec_k + 1 > e.page_size:
             # verify writes span at most 2 pages per slot
             raise ValueError(
@@ -911,18 +846,7 @@ class InferenceEngine:
         self.spec_accepted = 0
         self._spec_alpha = 0.0  # acceptance-rate EMA (window sizing)
 
-        self._prefill = _shared_jit(
-            ("prefill", c), lambda: jax.jit(partial(prefill, config=c)))
-        # Two compiled samplers: the plain one (no sorts) serves the
-        # default top_k=0/top_p=1 case on the hot decode loop; the
-        # truncating one compiles the top-k/top-p masking only when some
-        # request asks for it.
-        self._sample = _shared_jit(("sample",), lambda: jax.jit(sample))
-        self._sample_trunc = _shared_jit(
-            ("sample_trunc",),
-            lambda: jax.jit(
-                lambda lg, t, k, p, tk, m=None: sample(lg, t, k, top_p=p,
-                                                       top_k=tk, mask=m)))
+        self._sample, self._sample_trunc = _samplers()
         self._key = jax.random.PRNGKey(seed + 1)
 
         # host-side slot state
@@ -957,17 +881,9 @@ class InferenceEngine:
         suffix re-prefills."""
         # Validate at submission, in the CALLER's thread: an invalid prompt
         # must fail its own request, not blow up the shared engine pump.
-        if self._chunk_size() and len(prompt_tokens) < self.e.max_len:
-            pass  # chunked prefill admits any prompt under max_len
-        else:
+        # chunked prefill admits any prompt under max_len
+        if not (self._chunk_size() and len(prompt_tokens) < self.e.max_len):
             self._bucket(len(prompt_tokens))
-        if (guide is not None or logprobs) and not self.paged:
-            raise ValueError("guided decoding / logprobs require the "
-                             "paged KV layout")
-        if ((resume_token is not None or kv_handoff is not None)
-                and not self.paged):
-            raise ValueError("decode-state resume / KV handoff require "
-                             "the paged KV layout")
         if kv_handoff is not None and self.latent:
             _refuse_latent(self.c, "a per-head KV handoff")
         if guide is not None:
@@ -1034,8 +950,7 @@ class InferenceEngine:
             self.finished[req.request_id] = req
             self.active[i] = False
             self.slot_req[i] = None
-            if self.paged:
-                self._release_slot(i)
+            self._release_slot(i)
             self._dev_dirty = True
 
     def has_work(self) -> bool:
@@ -1050,7 +965,7 @@ class InferenceEngine:
         NEXT admission resumes where this one stopped — long-prompt
         admission interleaves with decode instead of stalling it (parity:
         vLLM chunked prefill, `llm/_internal/serve/.../vllm/`)."""
-        if not (self.paged and self.e.prefix_cache):
+        if not self.e.prefix_cache:
             return 0
         page = self.e.page_size
         usable = [b for b in self.e.prompt_buckets if b <= self.e.max_len]
@@ -1061,7 +976,7 @@ class InferenceEngine:
     def _bucket(self, n: int) -> int:
         return _prompt_bucket(self.e, n)
 
-    # ---- page pool (paged layout only) ----
+    # ---- page pool ----
 
     def _alloc_page(self) -> int | None:
         """A free page, else evict the LRU ref-0 cached page, else None."""
@@ -1096,7 +1011,6 @@ class InferenceEngine:
         for pid in self.slot_pages[slot]:
             self._decref_page(pid)
         self.slot_pages[slot] = []
-        self.slot_borrowed[slot] = 0
 
     @staticmethod
     def _prefix_hash(tokens: list) -> bytes:
@@ -1107,7 +1021,7 @@ class InferenceEngine:
     def _find_prefix(self, prompt: list) -> list[int]:
         """Longest run of already-cached full prompt pages (at least one
         token is always left to prefill — its logits seed sampling)."""
-        if not (self.paged and self.e.prefix_cache):
+        if not self.e.prefix_cache:
             return []
         page = self.e.page_size
         full = len(prompt) // page
@@ -1133,7 +1047,7 @@ class InferenceEngine:
 
         NOT thread-safe against step(): call from the pump thread (the
         engine queue's kv_handoff field routes a handoff there)."""
-        if not (self.paged and self.e.prefix_cache):
+        if not self.e.prefix_cache:
             return 0
         if self.latent:
             _refuse_latent(self.c, "a per-head KV handoff")
@@ -1208,9 +1122,6 @@ class InferenceEngine:
 
     def _admit(self) -> dict[int, int]:
         self._apply_cancels()
-        return self._admit_paged() if self.paged else self._admit_dense()
-
-    def _admit_paged(self) -> dict[int, int]:
         admitted: dict[int, int] = {}
         pending: list[tuple] = []  # (slot, req, last-logits row) to sample
         e = self.e
@@ -1291,28 +1202,24 @@ class InferenceEngine:
         # (suffix bucket, prefix-page bucket), the rest by suffix bucket —
         # each group pays ONE prefill dispatch + ONE page-insert dispatch.
         logits_of: dict[int, object] = {}  # slot -> last-logits row
-        nohit_by_bucket: dict[int, list[dict]] = {}
-        hit_by_key: dict[tuple, list[dict]] = {}
+        groups: dict[tuple, list[dict]] = {}
         for p in planned:
+            pre_bucket = 0  # no prefix hit: the plain prefill program
             if p["hit"]:
                 pre_bucket = 1
                 while pre_bucket < p["hit"]:
                     pre_bucket *= 2
-                hit_by_key.setdefault(
-                    (p["bucket"], pre_bucket), []).append(p)
-            else:
-                nohit_by_bucket.setdefault(p["bucket"], []).append(p)
-        for (bucket, pre_bucket), group in hit_by_key.items():
-            n_real = len(group)
+            groups.setdefault((p["bucket"], pre_bucket), []).append(p)
+        for (bucket, pre_bucket), group in groups.items():
+            # Pad the batch to a power of two: bounded compile variants.
             n_pad = 1
-            while n_pad < n_real:
+            while n_pad < len(group):
                 n_pad *= 2
             toks = np.zeros((n_pad, bucket), np.int32)
             pres = np.zeros((n_pad, pre_bucket), np.int32)
             plens = np.zeros((n_pad,), np.int32)
             lens = np.zeros((n_pad,), np.int32)
-            n_tab = -(-bucket // page)
-            tabs = np.zeros((n_pad, n_tab), np.int32)
+            tabs = np.zeros((n_pad, -(-bucket // page)), np.int32)
             for j, p in enumerate(group):
                 toks[j, :p["ns"]] = p["suffix"]
                 pres[j, :p["hit"]] = p["pre_pages"]
@@ -1321,21 +1228,6 @@ class InferenceEngine:
                 tabs[j, :len(p["new_pages"])] = p["new_pages"]
             self._prefill_group(group, logits_of, toks, lens, tabs, pres,
                                 plens)
-        for bucket, group in nohit_by_bucket.items():
-            n_real = len(group)
-            # Pad the batch to a power of two: bounded compile variants.
-            n_pad = 1
-            while n_pad < n_real:
-                n_pad *= 2
-            toks = np.zeros((n_pad, bucket), np.int32)
-            lens = np.zeros((n_pad,), np.int32)
-            n_tab = -(-bucket // page)
-            tabs = np.zeros((n_pad, n_tab), np.int32)
-            for j, p in enumerate(group):
-                toks[j, :p["ns"]] = p["suffix"]
-                lens[j] = p["ns"]
-                tabs[j, :len(p["new_pages"])] = p["new_pages"]
-            self._prefill_group(group, logits_of, toks, lens, tabs)
 
         # Phase 3 — host-side registration.
         for p in planned:
@@ -1359,7 +1251,6 @@ class InferenceEngine:
                 self.queue.appendleft(req)
                 continue
             self.slot_pages[slot] = p["pre_pages"] + new_pages
-            self.slot_borrowed[slot] = hit
             self.slot_req[slot] = req
             self.lengths[slot] = n
             self.active[slot] = True
@@ -1375,33 +1266,14 @@ class InferenceEngine:
                 # the whole admission burst instead of a fence per prompt.
                 pending.append((slot, req, logits_of[slot]))
             self._dev_dirty = True  # slot state changed by this admission
-        if pending:
-            stacked = jnp.stack([row for _s, _r, row in pending])
-            temps = jnp.asarray([r.temperature for _s, r, _l in pending],
-                                jnp.float32)
-            self._key, sub = jax.random.split(self._key)
-            mask = self._host_guide_mask(
-                [(r, r.guide_state) for _s, r, _l in pending])
-            if all(r.top_k == 0 and r.top_p >= 1.0
-                   for _s, r, _l in pending):
-                toks = self._sample(stacked, temps, sub, mask=mask)
-            else:
-                toks = self._sample_trunc(
-                    stacked, temps, sub,
-                    jnp.asarray([r.top_p for _s, r, _l in pending],
-                                jnp.float32),
-                    jnp.asarray([r.top_k for _s, r, _l in pending],
-                                jnp.int32), mask)
-            toks = np.asarray(toks)  # one fence for the burst
-            p_logps = None
-            if any(r.logprobs for _s, r, _l in pending):
-                p_logps = np.asarray(jnp.take_along_axis(
-                    jax.nn.log_softmax(stacked, axis=-1),
-                    jnp.asarray(toks)[:, None], 1)[:, 0])
-            for j, ((slot, req, _l), tok) in enumerate(zip(pending, toks)):
-                first = int(tok)
-                if req.logprobs and p_logps is not None:
-                    req.token_logprobs.append(float(p_logps[j]))
+        if pending:  # one fence for the burst
+            toks, logps = self._sample_rows(
+                jnp.stack([row for _s, _r, row in pending]),
+                [r for _s, r, _l in pending])
+            for j, (slot, req, _l) in enumerate(pending):
+                first = int(toks[j])
+                if req.logprobs:
+                    req.token_logprobs.append(float(logps[j]))
                 req.generated.append(first)
                 admitted[req.request_id] = first
                 self.last_tokens[slot] = first
@@ -1411,14 +1283,14 @@ class InferenceEngine:
         return admitted
 
     def _prefill_group(self, group: list, logits_of: dict, toks, lens, tabs,
-                       pres=None, plens=None):
+                       pres, plens):
         """ONE prefill dispatch and ONE page-insert dispatch for a group of
-        planned admissions (over cached prefix pages `pres` of `plens`
-        tokens where the group hit the prefix cache); the last-token
-        logits row of every request that takes a slot goes into
+        planned admissions (over cached prefix pages `pres` [n, Pp] of
+        `plens` tokens; Pp is 0 where the group hit no cached prefix); the
+        last-token logits row of every request that takes a slot goes into
         `logits_of`. A latent-cache model's programs return that row alone
         (the head runs at the sampled position only)."""
-        hit = pres is not None
+        hit = pres.shape[1] > 0
         name = "prefill_with_prefix_batch" if hit else "prefill_batch"
         cache = self._prefill_pre if hit else self._prefill_batches
         key = toks.shape + ((pres.shape[1],) if hit else ())
@@ -1448,22 +1320,8 @@ class InferenceEngine:
                 logits_of[p["slot"]] = (last[j] if self.latent
                                         else logits[j, p["ns"] - 1])
 
-    def _sample_first(self, req: Request, logits, last_idx: int) -> int:
-        self._key, sub = jax.random.split(self._key)
-        if req.top_k == 0 and req.top_p >= 1.0:
-            return int(self._sample(
-                logits[last_idx - 1][None],
-                jnp.asarray([req.temperature], jnp.float32), sub)[0])
-        return int(self._sample_trunc(
-            logits[last_idx - 1][None],
-            jnp.asarray([req.temperature], jnp.float32), sub,
-            jnp.asarray([req.top_p], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32))[0])
-
     def kv_stats(self) -> dict:
         """Pool/HBM accounting for tests, the dashboard, and the bench."""
-        if not self.paged:
-            return {"layout": "dense"}
         return {
             "layout": "paged", "num_pages": self.num_pages,
             "free_pages": len(self.free_pages),
@@ -1495,31 +1353,6 @@ class InferenceEngine:
                                    if pairs else 0.0),
         }
 
-    def _admit_dense(self) -> dict[int, int]:
-        admitted: dict[int, int] = {}
-        free = [i for i in range(self.e.max_slots) if not self.active[i]]
-        while free and self.queue:
-            req = self.queue.popleft()
-            slot = free.pop(0)
-            n = len(req.prompt)
-            bucket = self._bucket(n)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :n] = req.prompt
-            logits, ks, vs = self._prefill(self.params, jnp.asarray(toks))
-            first = self._sample_first(req, logits, n)
-            self.cache_k, self.cache_v = self._insert(
-                self.cache_k, self.cache_v, ks, vs, slot, n)
-            req.generated.append(first)
-            admitted[req.request_id] = first
-            self.slot_req[slot] = req
-            self.lengths[slot] = n
-            self.active[slot] = True
-            self.last_tokens[slot] = first
-            self.hist[slot, :n] = req.prompt
-            self.hist[slot, n] = first
-            self._maybe_finish(slot, first)
-        return admitted
-
     def _maybe_finish(self, slot: int, token: int):
         req = self.slot_req[slot]
         total = self.lengths[slot] + 1  # +1: the just-sampled token
@@ -1530,8 +1363,7 @@ class InferenceEngine:
             self.finished[req.request_id] = req
             self.active[slot] = False
             self.slot_req[slot] = None
-            if self.paged:
-                self._release_slot(slot)
+            self._release_slot(slot)
 
     def step(self) -> dict[int, int]:
         """Admit queued prompts, run one decode step; returns
@@ -1540,51 +1372,16 @@ class InferenceEngine:
         emitted = self._admit()
         if not self.active.any():
             return emitted
-        temps = np.array(
-            [self.slot_req[i].temperature if self.slot_req[i] else 0.0
-             for i in range(self.e.max_slots)], np.float32)
-        top_ps = np.array(
-            [self.slot_req[i].top_p if self.slot_req[i] else 1.0
-             for i in range(self.e.max_slots)], np.float32)
-        top_ks = np.array(
-            [self.slot_req[i].top_k if self.slot_req[i] else 0
-             for i in range(self.e.max_slots)], np.int32)
-        if self.paged:
-            logits = self._decode_paged_step()
-            if logits is None:  # every active slot was preempted
-                return emitted
-        else:
-            logits, self.cache_k, self.cache_v = self._decode(
-                self.params, self.cache_k, self.cache_v,
-                jnp.asarray(self.last_tokens), jnp.asarray(self.lengths),
-                jnp.asarray(self.active))
-        self._key, sub = jax.random.split(self._key)
-        mask = None
-        if any(r is not None and r.guide is not None
-               for r in self.slot_req):
-            m = np.ones((self.e.max_slots, self.c.vocab), bool)
-            for i, r in enumerate(self.slot_req):
-                if r is not None and r.guide is not None:
-                    m[i] = r.guide.table[r.guide_state] >= 0
-            mask = jnp.asarray(m)
-        if (top_ks == 0).all() and (top_ps >= 1.0).all():
-            tokens = np.asarray(self._sample(logits, jnp.asarray(temps),
-                                             sub, mask=mask))
-        else:
-            tokens = np.asarray(self._sample_trunc(
-                logits, jnp.asarray(temps), sub,
-                jnp.asarray(top_ps), jnp.asarray(top_ks), mask))
-        logps = None
-        if any(r is not None and r.logprobs for r in self.slot_req):
-            logps = np.asarray(jnp.take_along_axis(
-                jax.nn.log_softmax(logits, axis=-1),
-                jnp.asarray(tokens)[:, None], 1)[:, 0])
+        logits = self._decode_paged_step()
+        if logits is None:  # every active slot was preempted
+            return emitted
+        tokens, logps = self._sample_rows(logits, self.slot_req)
         for i in range(self.e.max_slots):
             if not self.active[i]:
                 continue
             tok = int(tokens[i])
             req = self.slot_req[i]
-            if req.logprobs and logps is not None:
+            if req.logprobs:
                 req.token_logprobs.append(float(logps[i]))
             req.generated.append(tok)
             emitted[req.request_id] = tok
@@ -1725,16 +1522,33 @@ class InferenceEngine:
              else 0 for r in reqs], jnp.int32)
         return True, self._dev_gtables, states
 
-    def _host_guide_mask(self, rows) -> object | None:
-        """numpy mask [len(rows), vocab] for a host-side sample call, or
-        None when no row is guided. rows = list of (req, state)."""
-        if not any(r.guide is not None for r, _s in rows):
-            return None
-        m = np.ones((len(rows), self.c.vocab), bool)
-        for j, (r, s) in enumerate(rows):
-            if r.guide is not None:
-                m[j] = r.guide.table[s] >= 0
-        return jnp.asarray(m)
+    def _sample_rows(self, logits, reqs) -> tuple:
+        """One token a row of `logits` [len(reqs), vocab] by that row's
+        request (None = an empty slot: greedy, untruncated, unguided) ->
+        (tokens, log p(token) a row, or None where no request asks for
+        it), on the host after ONE fence (a second for the logprobs)."""
+        temps, top_ps, top_ks = _sampling_of(reqs)
+        self._key, sub = jax.random.split(self._key)
+        mask = None
+        if any(r is not None and r.guide is not None for r in reqs):
+            m = np.ones((len(reqs), self.c.vocab), bool)
+            for j, r in enumerate(reqs):
+                if r is not None and r.guide is not None:
+                    m[j] = r.guide.table[r.guide_state] >= 0
+            mask = jnp.asarray(m)
+        if (top_ks == 0).all() and (top_ps >= 1.0).all():
+            toks = self._sample(logits, jnp.asarray(temps), sub, mask=mask)
+        else:
+            toks = self._sample_trunc(
+                logits, jnp.asarray(temps), sub, jnp.asarray(top_ps),
+                jnp.asarray(top_ks), mask)
+        toks = np.asarray(toks)
+        logps = None
+        if any(r is not None and r.logprobs for r in reqs):
+            logps = np.asarray(jnp.take_along_axis(
+                jax.nn.log_softmax(logits, axis=-1),
+                jnp.asarray(toks)[:, None], 1)[:, 0])
+        return toks, logps
 
     @staticmethod
     def _advance_guide(req: Request, tok: int):
@@ -1743,16 +1557,7 @@ class InferenceEngine:
                                                       tok]), 0)
 
     def _sync_sampling(self):
-        e = self.e
-        temps = np.array(
-            [self.slot_req[i].temperature if self.slot_req[i] else 0.0
-             for i in range(e.max_slots)], np.float32)
-        top_ps = np.array(
-            [self.slot_req[i].top_p if self.slot_req[i] else 1.0
-             for i in range(e.max_slots)], np.float32)
-        top_ks = np.array(
-            [self.slot_req[i].top_k if self.slot_req[i] else 0
-             for i in range(e.max_slots)], np.int32)
+        temps, top_ps, top_ks = _sampling_of(self.slot_req)
         fp = (temps.tobytes(), top_ps.tobytes(), top_ks.tobytes())
         if fp != self._dev_sampling_fp:
             self._dev_sampling = (jnp.asarray(temps), jnp.asarray(top_ps),
@@ -1979,10 +1784,7 @@ class InferenceEngine:
         return emitted
 
     def step_window(self) -> dict[int, int]:
-        """Admit queued prompts, then decode a whole window (paged layout
-        only; falls back to single-step elsewhere)."""
-        if not self.paged:
-            return self.step()
+        """Admit queued prompts, then decode a whole window."""
         if self.latent:
             _refuse_latent(self.c, "step_window() (decode_window); call "
                                    "step()")
@@ -2039,12 +1841,7 @@ class PrefillEngine:
         self._prefill = _shared_jit(
             ("prefill", self.c),
             lambda: jax.jit(partial(prefill, config=self.c)))
-        self._sample = _shared_jit(("sample",), lambda: jax.jit(sample))
-        self._sample_trunc = _shared_jit(
-            ("sample_trunc",),
-            lambda: jax.jit(
-                lambda lg, t, k, p, tk, m=None: sample(lg, t, k, top_p=p,
-                                                       top_k=tk, mask=m)))
+        self._sample, self._sample_trunc = _samplers()
         self._key = jax.random.PRNGKey(seed + 1)
 
     def prefill_export(self, prompt_tokens, temperature=None,

@@ -26,7 +26,6 @@ def test_hanging_and_crashing_sections_still_emit_headline(tmp_path):
         "PYTHONPATH": REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
         "JAX_PLATFORMS": "cpu",
         "BENCH_OUT": str(out_path),
-        "RAY_TPU_SKIP_TPU_BENCH": "1",
         # Shield the test harness's own clusters from the preflight
         # sweep (it kills every ray_tpu daemon on the box otherwise).
         "RAY_TPU_BENCH_NO_PREFLIGHT": "1",
@@ -69,7 +68,6 @@ def test_boot_crash_still_emits_degraded_headline(tmp_path):
         "PYTHONPATH": REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
         "JAX_PLATFORMS": "cpu",
         "BENCH_OUT": str(out_path),
-        "RAY_TPU_SKIP_TPU_BENCH": "1",
         "RAY_TPU_BENCH_NO_PREFLIGHT": "1",
         "RAY_TPU_BENCH_SECTIONS": "tasks",
         # Budget already burned: the section must skip, not run.
@@ -103,7 +101,6 @@ def test_final_line_stays_under_2048_with_bloated_extras(tmp_path,
                  "junk": "y" * 3000},
         "adag_pipeline": {"tensor_speedup_x": "z" * 2000},
     })
-    monkeypatch.setattr(bench, "TPU", {"configs": []})
     bench.final_line("partial")
     out = capsys.readouterr().out.strip().splitlines()[-1]
     assert len(out) < 2048

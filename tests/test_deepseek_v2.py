@@ -228,7 +228,6 @@ def _tp_mesh():
 
 @pytest.mark.parametrize("what,build", [
     ("speculation", lambda: _engine(speculation="ngram")),
-    ("kv_layout", lambda: _engine(kv_layout="dense")),
     ("tensor parallelism", lambda: InferenceEngine(
         SHARE, EngineConfig(max_slots=2, max_len=64), mesh=_tp_mesh())),
     ("step_window", lambda: _engine().step_window()),
@@ -240,6 +239,18 @@ def test_what_a_latent_cache_does_not_run_with_names_the_field(what, build):
     with pytest.raises(ValueError, match=what) as e:
         build()
     assert "attention='mla'" in str(e.value)
+
+
+@pytest.mark.parametrize("model", [configs.tiny(), SHARE],
+                         ids=["gqa", "mla"])
+def test_engine_has_one_kv_layout(model):
+    """The field still constructs (the benchmark's harness passes it); an
+    engine takes "paged" alone, whatever its model's attention."""
+    e = EngineConfig(max_slots=2, max_len=64, kv_layout="dense")
+    assert e.kv_layout == "dense"
+    with pytest.raises(ValueError, match="EngineConfig.kv_layout") as err:
+        InferenceEngine(model, e)
+    assert f"ModelConfig.attention={model.attention!r}" in str(err.value)
 
 
 def test_moe_stats_add_up():

@@ -14,8 +14,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import EngineConfig, InferenceEngine, LLMConfig
-from ray_tpu.llm.engine import (decode_paged, prefill_batch, sample,
-                                verify_paged)
+from ray_tpu.llm.engine import (decode_paged, insert_pages_batch,
+                                prefill_batch, prefill_with_prefix_batch,
+                                sample, verify_paged)
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models import ModelConfig, forward, init_params
 
@@ -758,6 +759,38 @@ def test_paged_decode_and_verify_reproduce_prefill_logits(family):
                        jnp.full((_SLOTS,), n_decode, jnp.int32), active,
                        tables)
     np.testing.assert_allclose(got, want[:, n_decode:], atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
+def test_prefix_prefill_reproduces_prefill_logits(family):
+    """A prompt whose page-aligned prefix lies in the pools
+    (`insert_pages_batch`) and whose suffix goes through
+    `prefill_with_prefix_batch`, against `prefill_batch` over the whole
+    prompt: the suffix rows of its logits, and the same suffix K and V.
+    The two requests cache one page and two."""
+    c = _PAGED_CONFIGS[family]
+    params, pool_k, pool_v, tables = _paged_args(c)
+    n_suffix = 8
+    cached = jnp.asarray([_PAGE, _TABLE * _PAGE], jnp.int32)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(13), (_SLOTS, _TABLE * _PAGE + n_suffix), 1,
+        c.vocab)
+    want, ks, vs = jax.jit(partial(prefill_batch, config=c))(params, tokens)
+    pool_k, pool_v = jax.jit(insert_pages_batch)(
+        pool_k, pool_v, ks[:, :, :_TABLE * _PAGE], vs[:, :, :_TABLE * _PAGE],
+        tables, cached)
+    rows = cached[:, None] + jnp.arange(n_suffix)[None]       # [n, S]
+    got, got_k, got_v = jax.jit(
+        partial(prefill_with_prefix_batch, config=c))(
+        params, jnp.take_along_axis(tokens, rows, 1), pool_k, pool_v,
+        tables, cached)
+    np.testing.assert_allclose(
+        got, jnp.take_along_axis(want, rows[:, :, None], 1), atol=1e-5,
+        rtol=0)
+    for new, whole in ((got_k, ks), (got_v, vs)):
+        np.testing.assert_allclose(
+            new, jnp.take_along_axis(whole, rows[None, :, :, None, None], 2),
+            atol=1e-5, rtol=0)
 
 
 def _offences(jaxpr, tainted, is_view, offends):
