@@ -56,7 +56,7 @@ HTTP_PORT = 18473  # not serve's default: nothing else on the host owns it
 MODEL_ID = "qwen2-7b-smoke"
 
 # Log-probabilities from two independent programs over the same bf16
-# weights: the engine (dense fp32-softmax prefill, Pallas paged decode, KV
+# weights: the engine (Pallas flash prefill, Pallas paged decode, KV
 # pool in bf16) against models.transformer.forward (Pallas flash, one
 # pass). Both accumulate in fp32; what differs is where activations round
 # to bf16 (2^-8 relative) across the layers, which moves a unit-variance

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import ModelConfig, init_params, model_module
-from ray_tpu.ops.layers import apply_rope, rmsnorm, rope
+from ray_tpu.ops.attention import prefill_attention
+from ray_tpu.ops.layers import apply_rope, last_rows, rmsnorm, rope
 
 # ---- shared compiled-step cache -------------------------------------
 # Engines used to create their own jax.jit wrappers, so two engines with
@@ -168,25 +169,23 @@ def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False):
     return _mlp_block(h, lp, c), kept
 
 
-def _softmax_attention(q, k, v, mask, c: ModelConfig):
-    """q [b, s, h, hd] over keys and values [b, t, hkv, hd], all of them
-    in reach at once (the prefill programs); mask [b, s, t], or [s, t]
-    where one serves every request."""
-    n_rep = c.n_heads // c.n_kv_heads
-    if n_rep > 1:
-        k = jnp.repeat(k, n_rep, axis=2)
-        v = jnp.repeat(v, n_rep, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(c.head_dim)
-    # a heads axis, and a batch axis where the mask has none
-    mask = jnp.expand_dims(mask, tuple(range(mask.ndim - 2, 2)))
-    scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+def _prefill_attention(q, keys, values, prefix_len, pre_t: int,
+                       c: ModelConfig):
+    """q [n, s, h, hd] over keys and values [n, hkv, pre_t + s, hd] = a
+    cached prefix of which request i has prefix_len[i] positions | the
+    chunk itself, causal; -> [n, s, h, hd]. One flash kernel for both
+    prefill programs (the jnp reference where no chip is): no score array
+    in HBM, K and V read by head // (h // hkv), never repeated."""
+    out = prefill_attention(
+        q.transpose(0, 2, 1, 3), keys, values, prefix_len, pre_t=pre_t,
+        scale=c.head_dim ** -0.5, name="gqa_prefill_attention")
+    return out.transpose(0, 2, 1, 3)
 
 
 def _head(x, params, c: ModelConfig, active=None, at=None):
     """Final norm and the fp32 head: x [b, s, d] -> logits [b, s, vocab],
-    or [b, vocab] at the one position `at` of every sequence. An inactive
+    or [b, vocab] at the one position `at` of every sequence (or from
+    x [b, d], the rows a prefill program picked itself). An inactive
     slot (`active` [b]) reads -1e30 except token 0: it must not corrupt
     metrics downstream, and argmax / categorical stay defined."""
     x = rmsnorm(x, params["final_norm"], c.norm_eps)
@@ -202,72 +201,70 @@ def _head(x, params, c: ModelConfig, active=None, at=None):
                      logits, neg)
 
 
-def prefill_batch(params, tokens, config: ModelConfig):
-    """tokens [n, S] (right-padded) -> (logits [n, S, vocab] fp32,
+def prefill_batch(params, tokens, lengths, config: ModelConfig):
+    """tokens [n, S] (right-padded), lengths [n] -> (logits [n, vocab]
+    fp32 at each request's last token, the one row that is sampled from,
     k,v caches [L, n, S, hkv, hd]). Causal; padding contributes garbage
     KV beyond each true length, which insert never reads (length mask).
     Batched so an admission burst pays ONE dispatch, not one per prompt
     (the vLLM-style batched prefill role)."""
     c = config
     x = _embed(params, tokens)
-    s = tokens.shape[1]
+    n, s = tokens.shape
     sin, cos = rope(jnp.arange(s), c.head_dim, c.rope_theta)
-    causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
+    no_prefix = jnp.zeros((n,), jnp.int32)
 
     def turn(t):  # [None] stays in here, staged once a use inside the
         # scan: the lowered text keys this program's compile-cache entry
         return apply_rope(t, sin[None], cos[None])
 
     def attend(q, k, v):
-        return _softmax_attention(q, k, v, causal, c), (k, v)
+        return _prefill_attention(q, k.transpose(0, 2, 1, 3),
+                                  v.transpose(0, 2, 1, 3), no_prefix, 0,
+                                  c), (k, v)
 
     x, (ks, vs) = jax.lax.scan(
         lambda x, lp: _block(x, lp, c, turn, attend), x, params["layers"])
-    return _head(x, params, c), ks, vs
+    return _head(last_rows(x, lengths), params, c), ks, vs
 
 
-def prefill(params, tokens, config: ModelConfig):
-    """tokens [1, S] -> (logits [S, vocab], k/v [L, S, hkv, hd]); the
-    single-prompt view of prefill_batch (PrefillEngine's program)."""
-    logits, ks, vs = prefill_batch(params, tokens, config)
-    return logits[0], ks[:, 0], vs[:, 0]
+def prefill(params, tokens, lengths, config: ModelConfig):
+    """tokens [1, S], lengths [1] -> (logits [vocab] at the last token,
+    k/v [L, S, hkv, hd]); the single-prompt view of prefill_batch
+    (PrefillEngine's program)."""
+    last, ks, vs = prefill_batch(params, tokens, lengths, config)
+    return last[0], ks[:, 0], vs[:, 0]
 
 
-def prefill_with_prefix_batch(params, tokens, pool_k, pool_v,
+def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
                               prefix_pages, prefix_len,
                               config: ModelConfig):
     """Prefill only the SUFFIX of prompts whose prefix pages are already
     cached (prefix caching), a whole burst per dispatch. tokens [n, S] =
-    suffixes (right-padded); prefix_pages [n, Pp] page ids into the pool
-    (0-padded); prefix_len [n] true prefix token counts. Cached K is
-    stored post-RoPE at absolute positions, so it is reused as-is;
-    suffix positions offset by prefix_len. Returns (suffix logits
-    [n, S, vocab] f32, suffix k/v caches [L, n, S, hkv, hd])."""
+    suffixes (right-padded) of lengths [n]; prefix_pages [n, Pp] page ids
+    into the pool (0-padded); prefix_len [n] true prefix token counts.
+    Cached K is stored post-RoPE at absolute positions, so it is reused
+    as-is; suffix positions offset by prefix_len. Returns (logits
+    [n, vocab] f32 at each suffix's last token, suffix k/v caches
+    [L, n, S, hkv, hd])."""
     c = config
     x = _embed(params, tokens)
     n, s = tokens.shape
-    page = pool_k.shape[4]
-    pre_t = prefix_pages.shape[1] * page
+    pre_t = prefix_pages.shape[1] * pool_k.shape[4]
     positions = prefix_len[:, None] + jnp.arange(s)[None]      # [n, S]
     sin, cos = rope(positions, c.head_dim, c.rope_theta)
-    causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
-    pre_mask = jnp.broadcast_to(
-        (jnp.arange(pre_t)[None, None] < prefix_len[:, None, None]),
-        (n, s, pre_t))
-    full_mask = jnp.concatenate(
-        [pre_mask, jnp.broadcast_to(causal[None], (n, s, s))],
-        axis=2)                                               # [n,S,preT+S]
 
-    def attend(pk, pv, q, k, v):  # pk/pv [hkv, pages, hd, page]
-        # [hkv, n, Pp, hd, page] -> [n, Pp, page, hkv, hd]
-        #                        -> [n, preT, hkv, hd]
-        prek = pk[:, prefix_pages].transpose(1, 2, 4, 0, 3).reshape(
-            n, pre_t, pk.shape[0], -1)
-        prev = pv[:, prefix_pages].transpose(1, 2, 4, 0, 3).reshape(
-            n, pre_t, pv.shape[0], -1)
-        kk = jnp.concatenate([prek.astype(k.dtype), k], axis=1)
-        vv = jnp.concatenate([prev.astype(v.dtype), v], axis=1)
-        return _softmax_attention(q, kk, vv, full_mask, c), (k, v)
+    def behind(pages, new):  # pages [hkv, N, hd, page], new [n, S, hkv, hd]
+        # [hkv, n, Pp, hd, page] -> [n, hkv, Pp, page, hd]
+        #                        -> [n, hkv, preT | S, hd]
+        cached = pages[:, prefix_pages].transpose(1, 0, 2, 4, 3).reshape(
+            n, pages.shape[0], pre_t, -1)
+        return jnp.concatenate(
+            [cached.astype(new.dtype), new.transpose(0, 2, 1, 3)], axis=2)
+
+    def attend(pk, pv, q, k, v):
+        return _prefill_attention(q, behind(pk, k), behind(pv, v),
+                                  prefix_len, pre_t, c), (k, v)
 
     def layer(x, scan_in):
         lp, pk, pv = scan_in
@@ -275,7 +272,7 @@ def prefill_with_prefix_batch(params, tokens, pool_k, pool_v,
                       partial(attend, pk, pv))
 
     x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], pool_k, pool_v))
-    return _head(x, params, c), ks, vs
+    return _head(last_rows(x, lengths), params, c), ks, vs
 
 
 def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
@@ -1288,8 +1285,7 @@ class InferenceEngine:
         planned admissions (over cached prefix pages `pres` [n, Pp] of
         `plens` tokens; Pp is 0 where the group hit no cached prefix); the
         last-token logits row of every request that takes a slot goes into
-        `logits_of`. A latent-cache model's programs return that row alone
-        (the head runs at the sampled position only)."""
+        `logits_of` (the programs run the head at that position only)."""
         hit = pres.shape[1] > 0
         name = "prefill_with_prefix_batch" if hit else "prefill_batch"
         cache = self._prefill_pre if hit else self._prefill_batches
@@ -1303,22 +1299,20 @@ class InferenceEngine:
                 (name, self.c),
                 lambda: jax.jit(partial(program, config=self.c)))
         toks, lens, tabs = (jnp.asarray(a) for a in (toks, lens, tabs))
+        pools = ((self.cache_k,) if self.latent
+                 else (self.cache_k, self.cache_v))
+        prefix = (*pools, jnp.asarray(pres), jnp.asarray(plens)) if hit else ()
         if self.latent:
-            prefix = ((self.cache_k, jnp.asarray(pres), jnp.asarray(plens))
-                      if hit else ())
             last, lat, self._moe_acc = fn(self.params, toks, lens, *prefix,
                                           self._moe_acc)
             self.cache_k = self._insert_batch(self.cache_k, lat, tabs, lens)
         else:
-            prefix = ((self.cache_k, self.cache_v, jnp.asarray(pres),
-                       jnp.asarray(plens)) if hit else ())
-            logits, ks, vs = fn(self.params, toks, *prefix)
+            last, ks, vs = fn(self.params, toks, lens, *prefix)
             self.cache_k, self.cache_v = self._insert_batch(
                 self.cache_k, self.cache_v, ks, vs, tabs, lens)
         for j, p in enumerate(group):
             if p["slot"] is not None:
-                logits_of[p["slot"]] = (last[j] if self.latent
-                                        else logits[j, p["ns"] - 1])
+                logits_of[p["slot"]] = last[j]
 
     def kv_stats(self) -> dict:
         """Pool/HBM accounting for tests, the dashboard, and the bench."""
@@ -1861,11 +1855,12 @@ class PrefillEngine:
         bucket = _prompt_bucket(self.e, n)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :n] = ids
-        logits, ks, vs = self._prefill(self.params, jnp.asarray(toks))
+        last, ks, vs = self._prefill(self.params, jnp.asarray(toks),
+                                     jnp.asarray([n], jnp.int32))
         temp = (self.e.default_temperature if temperature is None
                 else temperature)
         self._key, sub = jax.random.split(self._key)
-        row = logits[n - 1][None]
+        row = last[None]
         if top_k == 0 and top_p >= 1.0:
             first = int(self._sample(
                 row, jnp.asarray([temp], jnp.float32), sub)[0])
@@ -1912,8 +1907,9 @@ def __graphcheck__(gc):
     def build_prefill(mesh):
         return gc.GraphSpec(
             name="llm.prefill", fn=partial(prefill_batch, config=c),
-            args=(_params(), _sds((2, 32), jnp.int32)),
-            arg_names=("params", "tokens"))
+            args=(_params(), _sds((2, 32), jnp.int32),
+                  _sds((2,), jnp.int32)),
+            arg_names=("params", "tokens", "lengths"))
 
     def build_decode(mesh):
         return gc.GraphSpec(
@@ -1959,8 +1955,9 @@ def __graphcheck__(gc):
     def build_prefill_pool(mesh):
         return gc.GraphSpec(
             name="llm.prefill_pool", fn=partial(prefill, config=c),
-            args=(_params(), _sds((1, 32), jnp.int32)),
-            arg_names=("params", "tokens"))
+            args=(_params(), _sds((1, 32), jnp.int32),
+                  _sds((1,), jnp.int32)),
+            arg_names=("params", "tokens", "lengths"))
 
     def build_decode_window(mesh):
         return gc.GraphSpec(

@@ -57,7 +57,8 @@ import jax.numpy as jnp
 from ray_tpu.models.transformer import ModelConfig
 from ray_tpu.ops.latent_attention import (mla_prefill_attention,
                                           paged_latent_decode_attention)
-from ray_tpu.ops.layers import apply_rope, rmsnorm, rope, swiglu, yarn_mscale
+from ray_tpu.ops.layers import (apply_rope, last_rows, rmsnorm, rope, swiglu,
+                                yarn_mscale)
 
 # Rows of the grouped product a dispatch pass may fill. A token sends at
 # most top-k pairs to the held experts and 1/n_group of that on average:
@@ -387,18 +388,11 @@ def forward(params, tokens, config: ModelConfig, mesh=None):
 # ---------------------------------------- the serving engine's programs
 
 
-def _last_logits(x, lengths, params):
-    """The head at the one position of each request that is sampled from."""
-    last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _head(last, params)
-
-
 def prefill_batch(params, tokens, lengths, stats, config: ModelConfig):
     """tokens [n, S] right-padded, lengths [n] -> (logits [n, vocab] at
     each request's last token, latents [L, n, S, latent], stats)."""
     x, latents, stats = _prefill(params, tokens, lengths, stats, config)
-    return _last_logits(x, lengths, params), latents, stats
+    return _head(last_rows(x, lengths), params), latents, stats
 
 
 def prefill_with_prefix_batch(params, tokens, lengths, pool, prefix_pages,
@@ -411,7 +405,7 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool, prefix_pages,
     tokens, and one flash kernel then serves both prefill programs."""
     x, latents, stats = _prefill(params, tokens, lengths, stats, config,
                                  (pool, prefix_pages, prefix_len))
-    return _last_logits(x, lengths, params), latents, stats
+    return _head(last_rows(x, lengths), params), latents, stats
 
 
 def insert_latent_pages_batch(pool, latents, page_ids, lengths):

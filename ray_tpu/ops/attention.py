@@ -13,6 +13,13 @@ inputs with fp32 MXU accumulation. The forward saves the per-row logsumexp
 (replicated along a 128-lane minor dim so both backward kernels read it in
 their natural layout without in-kernel relayouts). A jnp recompute backward
 (`impl="reference"`) remains as the numerics oracle.
+
+Serving prefill (`prefill_attention`, at the end): a forward-only kernel of
+its own over [cached prefix | chunk] keys with per-request prefix lengths,
+general in the q.k and p.v widths and in the number of query heads that
+share a K/V head. llm/engine.py's two prefill programs call it as
+`gqa_prefill_attention`, models/deepseek_v2.py's as
+`mla_prefill_attention` (ops/latent_attention.py).
 """
 
 from __future__ import annotations
@@ -417,3 +424,165 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
     out = _flash(qt, kt, vt, scale, causal, impl, kv_len, blk)
     out = out.reshape(b, h, s_pad, d).transpose(0, 2, 1, 3)
     return out[:, :s] if s_pad != s else out
+
+
+# ------------------------------------------- serving prefill (forward only)
+
+
+def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths
+                    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                    scale: float, bq: int, bk: int, nk: int, pre_t: int,
+                    heads: int):
+    """Keys are [pre_t cached-prefix positions | the chunk]: prefix key j
+    counts where j < plen of the request, chunk key c where c <= the query
+    row. Blocks with nothing to count are predicated out."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    plen = plen_ref[jax.lax.div(pl.program_id(0), heads)]
+    k0 = ki * bk
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    has_prefix = k0 < jnp.minimum(plen, pre_t)
+    has_chunk = (k0 + bk > pre_t) & (
+        jnp.maximum(k0, pre_t) - pre_t <= qi * bq + bq - 1)
+
+    @pl.when(has_prefix | has_chunk)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [bq, bk]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + qi * bq
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k0
+        counts = (cols < jnp.minimum(plen, pre_t)) | (
+            (cols >= pre_t) & (cols - pre_t <= rows))
+        s = jnp.where(counts, s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
+            l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0],
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(
+            o_ref.dtype)
+
+
+# Query rows and key rows a grid cell: min(1024, S) and min(1024, keys).
+# Kernel benches on the chip (v5e), ms a call, query/key rows:
+# - PR 28, 128 heads x 4096 queries, 192/128 wide: 512/512 16.4, 1024/512
+#   15.8, 512/1024 11.9, 1024/1024 10.0 (68 TFLOP/s of causal work; 95 over
+#   a 4096-token prefix); the [1024, 1024] float32 scores still fit the
+#   16 MB of scoped VMEM.
+# - PR 31, 28 query / 4 KV heads of 128 (32 / 8 in brackets), n 8 x S 1024:
+#   256/256 3.38, 512/512 1.84, 1024/512 2.05, 512/1024 1.26, 1024/1024 1.16
+#   (1.41); over a 1024-token prefix 512/512 3.84, 512/1024 2.36, 1024/1024
+#   2.05 (2.42); n 1: 0.19 and 0.30 (0.21, 0.35). 1024/1024 was first at
+#   every n, S and prefix tried.
+# - Key blocks do not shrink with the chunk: n 8 x S 64 over a 1024-token
+#   prefix takes 2.11 ms with 64-key blocks (17 steps a head), 0.42 with one
+#   of 1024; n 8 x S 256: 1.39 against 0.83.
+# - Below 1024 rows a cell is one head's whole [S, S] block and the grid
+#   step is what costs: n 8 x S 256, no prefix, 0.35 ms (n 1: 0.09; S 64:
+#   0.18 and 0.07) where XLA's fused softmax over the small score array
+#   took 0.13 (0.06; 0.06 and 0.04). Several heads a cell would mend that.
+_PREFILL_BQ = 1024
+_PREFILL_BK = 1024
+
+
+@functools.partial(jax.jit, static_argnames=("pre_t", "scale", "name", "bq",
+                                             "bk", "interpret"))
+def _prefill_flash(q, k, v, prefix_len, *, pre_t: int, scale: float,
+                   name: str, bq: int, bk: int, interpret: bool):
+    n, h, s, dq = q.shape
+    hkv, t, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = h // hkv    # query heads that read one K/V head: the index
+    #                     maps hand a grid cell its head's K and V, so a
+    #                     grouped-query model's K and V are never repeated
+    bq, bk = min(bq, s), min(bk, t)
+    s_pad, t_pad = -(-s // bq) * bq, -(-t // bk) * bk
+    if s_pad != s:      # padded query rows: garbage the caller slices off
+        q = jnp.pad(q, [(0, 0), (0, 0), (0, s_pad - s), (0, 0)])
+    if t_pad != t:      # padded keys sit past every row's diagonal
+        k = jnp.pad(k, [(0, 0), (0, 0), (0, t_pad - t), (0, 0)])
+        v = jnp.pad(v, [(0, 0), (0, 0), (0, t_pad - t), (0, 0)])
+    nk = t_pad // bk
+    kernel = functools.partial(_prefill_kernel, scale=scale, bq=bq, bk=bk,
+                               nk=nk, pre_t=pre_t, heads=h)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n * h, s_pad // bq, nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, dq), lambda b, i, j, pl_: (b, i, 0)),
+                pl.BlockSpec((1, bk, dq),
+                             lambda b, i, j, pl_: (b // group, j, 0)),
+                pl.BlockSpec((1, bk, dv),
+                             lambda b, i, j, pl_: (b // group, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, dv),
+                                   lambda b, i, j, pl_: (b, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),   # running max
+                pltpu.VMEM((bq, 128), jnp.float32),   # running sum
+                pltpu.VMEM((bq, dv), jnp.float32),    # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n * h, s_pad, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(prefix_len, q.reshape(n * h, s_pad, dq),
+      k.reshape(n * hkv, t_pad, dq), v.reshape(n * hkv, t_pad, dv))
+    return out.reshape(n, h, s_pad, dv)[:, :, :s]
+
+
+def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
+                      name: str, impl: str = "auto"):
+    """The serving prefill programs' attention, forward only, no score
+    tensor in HBM. q [n, h, S, dq]; k [n, hkv, pre_t + S, dq], v [n, hkv,
+    pre_t + S, dv], h a multiple of hkv: the first pre_t keys are a cached
+    prefix of which request i has prefix_len[i] (the rest is padding), the
+    last S the chunk itself, causal. Query row r sits at position
+    prefix_len + r. -> [n, h, S, dv]. bf16 goes into the MXU as it is,
+    scores and accumulators are float32.
+
+    `name` is the kernel's in a device trace. impl: "auto" (the kernel on
+    the TPU, the jnp reference elsewhere), "pallas", "interpret" (the
+    kernel's interpreter), "reference"."""
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "reference"
+    if impl == "reference":
+        return prefill_attention_reference(q, k, v, prefix_len, pre_t=pre_t,
+                                           scale=scale)
+    return _prefill_flash(q, k, v, prefix_len, pre_t=pre_t, scale=scale,
+                          name=name, bq=_PREFILL_BQ, bk=_PREFILL_BK,
+                          interpret=(impl == "interpret"))
+
+
+def prefill_attention_reference(q, k, v, prefix_len, *, pre_t: int,
+                                scale: float):
+    """The same function with the whole score tensor: the tests' oracle,
+    and what the prefill programs run where no chip is."""
+    n, h, s, _ = q.shape
+    hkv, t = k.shape[1], k.shape[2]
+    qg = q.astype(jnp.float32).reshape(n, hkv, h // hkv, s, -1)
+    sc = jnp.einsum("ngrqd,ngkd->ngrqk", qg, k.astype(jnp.float32)) * scale
+    cols, rows = jnp.arange(t)[None, None, :], jnp.arange(s)[None, :, None]
+    ok = jnp.where(cols < pre_t, cols < prefix_len[:, None, None],
+                   cols - pre_t <= rows)                     # [n, s, t]
+    p = jax.nn.softmax(jnp.where(ok[:, None, None], sc, -jnp.inf), axis=-1)
+    out = jnp.einsum("ngrqk,ngkd->ngrqd", p, v.astype(jnp.float32))
+    return out.reshape(n, h, s, -1).astype(q.dtype)

@@ -9,9 +9,10 @@ Two kernels, one for each form of the same function
   in, so a page is read once for all heads. The DMA scheme is
   ops/paged_attention.py's: one grid step a slot, the slot's pages
   streamed HBM -> VMEM two deep, flash accumulation on the way.
-- `mla_prefill_attention`: the UP-PROJECTED form for prefill, a forward
-  flash kernel with a 192-wide q.k and a 128-wide p.v, over
-  [cached prefix | this chunk] keys: no score tensor ever lives in HBM.
+- `mla_prefill_attention`: the UP-PROJECTED form for prefill: the
+  serving prefill kernel of ops/attention.py (`prefill_attention`, a
+  forward flash kernel over [cached prefix | this chunk] keys, no score
+  tensor in HBM) with a 192-wide q.k and a 128-wide p.v.
 
 Layouts:
   pool         [L, num_pages, 576, page]   (latent BEFORE page: a page's
@@ -30,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import prefill_attention
 
 _NEG = -0.7 * float(np.finfo(np.float32).max)
 
@@ -161,125 +164,15 @@ def paged_latent_decode_reference(q_lat, pool, lengths, page_tables, *,
 # --------------------------------------------------------------- prefill
 
 
-def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths
-                    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                    scale: float, bq: int, bk: int, nk: int, pre_t: int,
-                    heads: int):
-    """Keys are [pre_t cached-prefix positions | the chunk]: prefix key j
-    counts where j < plen of the request, chunk key c where c <= the query
-    row. Blocks with nothing to count are predicated out."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    plen = plen_ref[jax.lax.div(pl.program_id(0), heads)]
-    k0 = ki * bk
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    has_prefix = k0 < jnp.minimum(plen, pre_t)
-    has_chunk = (k0 + bk > pre_t) & (
-        jnp.maximum(k0, pre_t) - pre_t <= qi * bq + bq - 1)
-
-    @pl.when(has_prefix | has_chunk)
-    def _compute():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [bq, bk]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + qi * bq
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k0
-        counts = (cols < jnp.minimum(plen, pre_t)) | (
-            (cols >= pre_t) & (cols - pre_t <= rows))
-        s = jnp.where(counts, s, _NEG)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0],
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(
-            o_ref.dtype)
-
-
-_BLOCK_Q = 1024   # query and key rows a grid cell. On the chip, 128 heads x
-_BLOCK_K = 1024   # 4096 queries (PR 28): 512/512 16.4 ms, 1024/512 15.8,
-#                   512/1024 11.9, 1024/1024 10.0 (68 TFLOP/s of causal
-#                   work; 95 over a 4096-token prefix); the [1024, 1024]
-#                   float32 scores still fit the 16 MB of scoped VMEM
-
-
-@functools.partial(jax.jit, static_argnames=("pre_t", "scale", "interpret"))
-def _mla_prefill(q, k, v, prefix_len, *, pre_t: int, scale: float,
-                 interpret: bool):
-    n, h, s, dq = q.shape
-    t, dv = k.shape[2], v.shape[3]
-    bq, bk = min(_BLOCK_Q, s), min(_BLOCK_K, s)
-    s_pad, t_pad = -(-s // bq) * bq, -(-t // bk) * bk
-    if s_pad != s:      # padded query rows: garbage the caller slices off
-        q = jnp.pad(q, [(0, 0), (0, 0), (0, s_pad - s), (0, 0)])
-    if t_pad != t:      # padded keys sit past every row's diagonal
-        k = jnp.pad(k, [(0, 0), (0, 0), (0, t_pad - t), (0, 0)])
-        v = jnp.pad(v, [(0, 0), (0, 0), (0, t_pad - t), (0, 0)])
-    nk = t_pad // bk
-    kernel = functools.partial(_prefill_kernel, scale=scale, bq=bq, bk=bk,
-                               nk=nk, pre_t=pre_t, heads=h)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n * h, s_pad // bq, nk),
-            in_specs=[
-                pl.BlockSpec((1, bq, dq), lambda b, i, j, pl_: (b, i, 0)),
-                pl.BlockSpec((1, bk, dq), lambda b, i, j, pl_: (b, j, 0)),
-                pl.BlockSpec((1, bk, dv), lambda b, i, j, pl_: (b, j, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, dv),
-                                   lambda b, i, j, pl_: (b, i, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bq, 128), jnp.float32),   # running max
-                pltpu.VMEM((bq, 128), jnp.float32),   # running sum
-                pltpu.VMEM((bq, dv), jnp.float32),    # accumulator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n * h, s_pad, dv), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="mla_prefill_attention",
-    )(prefix_len, q.reshape(n * h, s_pad, dq), k.reshape(n * h, t_pad, dq),
-      v.reshape(n * h, t_pad, dv))
-    return out.reshape(n, h, s_pad, dv)[:, :, :s]
-
-
 def mla_prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
                           interpret: bool | None = None):
-    """q [n, h, S, dq]; k [n, h, pre_t + S, dq], v [n, h, pre_t + S, dv]:
-    the first pre_t keys are a cached prefix of which request i has
-    prefix_len[i] (the rest is padding), the last S the chunk itself,
-    causal. Query row r sits at position prefix_len + r. -> [n, h, S, dv]."""
+    """ops/attention.prefill_attention at the up-projected form's widths:
+    every head has a K and V of its own (q, k [n, h, ., 192], v [n, h, .,
+    128] at DeepSeek-V2's). interpret=None: the kernel's interpreter off
+    the chip."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    return _mla_prefill(q, k, v, prefix_len, pre_t=pre_t, scale=scale,
-                        interpret=interpret)
-
-
-def mla_prefill_reference(q, k, v, prefix_len, *, pre_t: int, scale: float):
-    """The same function with the whole score tensor (tests)."""
-    s, t = q.shape[2], k.shape[2]
-    sc = jnp.einsum("nhqd,nhkd->nhqk", q.astype(jnp.float32),
-                    k.astype(jnp.float32)) * scale
-    cols, rows = jnp.arange(t)[None, None, :], jnp.arange(s)[None, :, None]
-    ok = jnp.where(cols < pre_t, cols < prefix_len[:, None, None],
-                   cols - pre_t <= rows)
-    p = jax.nn.softmax(jnp.where(ok[:, None], sc, -jnp.inf), axis=-1)
-    return jnp.einsum("nhqk,nhkd->nhqd", p, v.astype(jnp.float32)).astype(
-        q.dtype)
+    return prefill_attention(
+        q, k, v, prefix_len, pre_t=pre_t, scale=scale,
+        name="mla_prefill_attention",
+        impl="interpret" if interpret else "pallas")

@@ -22,6 +22,13 @@ def rmsnorm(x, weight, eps: float = 1e-6):
     return (out * weight.astype(jnp.float32)).astype(dtype)
 
 
+def last_rows(x, lengths):
+    """x [n, S, d] right-padded, lengths [n] -> [n, d]: each request's last
+    token, the one position a prefill program's head is read at."""
+    return jnp.take_along_axis(
+        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+
+
 def yarn_mscale(factor: float, mscale: float) -> float:
     """YaRN's attention temperature term, 0.1 * mscale * ln(factor) + 1."""
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0

@@ -138,6 +138,26 @@ def _latent(kind):
     return build
 
 
+def _gqa_prefill(h, hkv, n, s, pre_t):
+    """The per-head prefill programs' attention at a configuration's head
+    geometry: `prefill_batch`'s call (pre_t 0) and
+    `prefill_with_prefix_batch`'s over a cached prefix."""
+    from ray_tpu.ops.attention import prefill_attention
+
+    def build(topo):
+        one = SingleDeviceSharding(topo.devices[0])
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        return (lambda q, k, v, pl: prefill_attention(
+            q, k, v, pl, pre_t=pre_t, scale=HD ** -0.5,
+            name="gqa_prefill_attention", impl="pallas")), (
+            sds((n, h, s, HD)), sds((n, hkv, pre_t + s, HD)),
+            sds((n, hkv, pre_t + s, HD)), sds((n,), jnp.int32))
+    return build
+
+
 CASES = {
     "flash_fwd_2x2048": _flash((2, 2048), grad=False),
     "flash_bwd_2x2048": _flash((2, 2048), grad=True),
@@ -148,6 +168,13 @@ CASES = {
     "paged_verify_insert": _paged("verify_insert"),
     "latent_decode": _latent("decode"),
     "mla_prefill_over_prefix": _latent("prefill"),
+    "gqa_prefill_qwen2_7b_1x1024": _gqa_prefill(28, 4, 1, 1024, 0),
+    "gqa_prefill_qwen2_7b_8x1024_over_prefix": _gqa_prefill(28, 4, 8, 1024,
+                                                            1024),
+    "gqa_prefill_qwen2_7b_4x64": _gqa_prefill(28, 4, 4, 64, 0),
+    "gqa_prefill_mixtral_8x7b_1x1024": _gqa_prefill(32, 8, 1, 1024, 0),
+    "gqa_prefill_mixtral_8x7b_4x256_over_prefix": _gqa_prefill(32, 8, 4, 256,
+                                                               1024),
 }
 
 
@@ -160,6 +187,19 @@ def test_kernel_compiles_for_v5e(topo, case):
         "kernel (an XLA fallback took its place)")
 
 
+def _qwen2_7b(layers, one):
+    """(config, parameter shapes on device `one`) of `layers` layers at
+    qwen2_7b's published widths."""
+    from ray_tpu.models import ModelConfig, init_params
+    c = ModelConfig(vocab=152064, d_model=3584, n_layers=layers,
+                    n_heads=H, n_kv_heads=HKV, d_ff=18944, rope_theta=1e6,
+                    tie_embeddings=False, dtype="bfloat16")
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0))))
+    return c, params
+
+
 def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
     """The whole `decode_paged` program at the qwen2_7b chat cell's
     geometry (2 of its layers, 16 slots, 257 pages), pools donated: the
@@ -170,22 +210,16 @@ def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
     4 per-layer slices, and 98.5 MiB of temporaries against a 64.25 MiB
     pool."""
     from ray_tpu.llm.engine import decode_paged
-    from ray_tpu.models import ModelConfig, init_params
     # the dispatcher asks the backend whether to interpret the kernel;
     # the process is on the CPU, the compile is for the chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     layers, slots, n_pages = 2, 16, 257
-    c = ModelConfig(vocab=152064, d_model=3584, n_layers=layers, n_heads=H,
-                    n_kv_heads=HKV, d_ff=18944, rope_theta=1e6,
-                    tie_embeddings=False, dtype="bfloat16")
     one = SingleDeviceSharding(topo.devices[0])
+    c, params = _qwen2_7b(layers, one)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-    params = jax.tree_util.tree_map(
-        lambda a: sds(a.shape, a.dtype),
-        jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0))))
     pool = sds((layers, HKV, n_pages, HD, PAGE), jnp.bfloat16)
     compiled = jax.jit(partial(decode_paged, config=c),
                        donate_argnums=(1, 2)).lower(
@@ -200,3 +234,40 @@ def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
     assert pool_sized.findall(text) == []
     pool_bytes = layers * HKV * n_pages * HD * PAGE * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+@pytest.mark.parametrize("program", ["prefill_batch",
+                                     "prefill_with_prefix_batch"])
+def test_prefill_programs_hold_no_scores_and_no_prompt_logits(
+        topo, monkeypatch, program):
+    """One 1024-token admission at the qwen2_7b chat cell's geometry (2 of
+    its layers): the program attends through the kernel (an XLA fallback
+    fails here), and no buffer of its optimized HLO is a [.., 1024, keys]
+    score array or the logits of every prompt position. With
+    `_softmax_attention` and `_head` over [n, S, vocab] this compile held
+    f32[1,28,1024,1024] scores and f32[1,1024,152064] logits (623 MB)."""
+    from ray_tpu.llm import engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, s, pre_pages, n_pages = 2, 1024, 8, 257
+    one = SingleDeviceSharding(topo.devices[0])
+    c, params = _qwen2_7b(layers, one)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    args = (params, sds((1, s)), sds((1,)))
+    if program == "prefill_with_prefix_batch":
+        pool = sds((layers, HKV, n_pages, HD, PAGE), jnp.bfloat16)
+        args += (pool, pool, sds((1, pre_pages)), sds((1,)))
+    text = jax.jit(partial(getattr(engine, program), config=c)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text and "gqa_prefill_attention" in text
+    keys = (s, s + pre_pages * PAGE)
+
+    def offends(dims):   # a score array, or logits of every position
+        return ((len(dims) >= 3 and dims[-2] == s and dims[-1] in keys)
+                or (s in dims and c.vocab in dims))
+
+    arrays = set(re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert sorted(a for a in arrays
+                  if offends([int(d) for d in a.split(",")])) == []

@@ -17,6 +17,7 @@ from ray_tpu.llm import EngineConfig, InferenceEngine
 from ray_tpu.llm.engine import PrefillEngine
 from ray_tpu.models import configs, deepseek_v2 as ds, forward, init_params
 from ray_tpu.ops import latent_attention as la
+from ray_tpu.ops.attention import prefill_attention_reference
 from ray_tpu.ops.layers import rope, yarn_frequencies
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -201,7 +202,7 @@ def test_mla_prefill_kernel_matches_jnp(n, s, pre_t, plen):
     plen = jnp.array(plen, jnp.int32)
     np.testing.assert_allclose(
         la.mla_prefill_attention(q, k, v, plen, **kw),
-        la.mla_prefill_reference(q, k, v, plen, **kw), atol=TOL)
+        prefill_attention_reference(q, k, v, plen, **kw), atol=TOL)
 
 
 def test_init_params_makes_no_float32_leaf():

@@ -735,58 +735,75 @@ def _paged_args(c):
     return params, pool, pool, tables
 
 
+# The prefill programs run the head at each request's last token only, so
+# a row of "the logits prefill would give" is one call at that length:
+# ragged inside a batch, and over the cases every row the decode and
+# verify steps below produce (positions 0..7) for both slots.
+_LAST = [(1, 8), (2, 7), (3, 6), (4, 5), (5, 4), (6, 3), (7, 2), (8, 1)]
+
+
+@pytest.mark.parametrize("lengths", _LAST, ids=lambda l: "len%d_%d" % l)
 @pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
-def test_paged_decode_and_verify_reproduce_prefill_logits(family):
+def test_paged_decode_and_verify_reproduce_prefill_logits(family, lengths):
     """Token-by-token `decode_paged`, then one 3-token `verify_paged`,
-    against `prefill_batch` at the same positions: the three programs
-    spell the layer separately and must stay one function of the same
-    `params["layers"]` leaves."""
+    against `prefill_batch` read at the same positions: the three
+    programs spell the layer separately and must stay one function of
+    the same `params["layers"]` leaves."""
     c = _PAGED_CONFIGS[family]
     params, pool_k, pool_v, tables = _paged_args(c)
     n_decode, n_verify = 5, 3
     tokens = jax.random.randint(jax.random.PRNGKey(11),
                                 (_SLOTS, n_decode + n_verify), 1, c.vocab)
-    want, _, _ = jax.jit(partial(prefill_batch, config=c))(params, tokens)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    want, _, _ = jax.jit(partial(prefill_batch, config=c))(
+        params, tokens, lengths)
+    assert want.shape == (_SLOTS, c.vocab)
     decode = jax.jit(partial(decode_paged, config=c))
     verify = jax.jit(partial(verify_paged, config=c))
     active = jnp.ones((_SLOTS,), jnp.bool_)
+    rows = []                                       # [position][slot, vocab]
     for t in range(n_decode):
         got, pool_k, pool_v = decode(
             params, pool_k, pool_v, tokens[:, t],
             jnp.full((_SLOTS,), t, jnp.int32), active, tables)
-        np.testing.assert_allclose(got, want[:, t], atol=1e-5, rtol=0)
+        rows.append(got)
     got, _, _ = verify(params, pool_k, pool_v, tokens[:, n_decode:],
                        jnp.full((_SLOTS,), n_decode, jnp.int32), active,
                        tables)
-    np.testing.assert_allclose(got, want[:, n_decode:], atol=1e-5, rtol=0)
+    rows.extend(got[:, j] for j in range(n_verify))
+    got = jnp.stack(rows, 1)                        # [slot, position, vocab]
+    np.testing.assert_allclose(
+        jnp.take_along_axis(got, (lengths - 1)[:, None, None], 1)[:, 0],
+        want, atol=1e-5, rtol=0)
 
 
+@pytest.mark.parametrize("lengths", _LAST, ids=lambda l: "len%d_%d" % l)
 @pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
-def test_prefix_prefill_reproduces_prefill_logits(family):
+def test_prefix_prefill_reproduces_prefill_logits(family, lengths):
     """A prompt whose page-aligned prefix lies in the pools
     (`insert_pages_batch`) and whose suffix goes through
     `prefill_with_prefix_batch`, against `prefill_batch` over the whole
-    prompt: the suffix rows of its logits, and the same suffix K and V.
-    The two requests cache one page and two."""
+    prompt: the logits at the suffix's last token, and the same suffix K
+    and V. The two requests cache one page and two."""
     c = _PAGED_CONFIGS[family]
     params, pool_k, pool_v, tables = _paged_args(c)
     n_suffix = 8
     cached = jnp.asarray([_PAGE, _TABLE * _PAGE], jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     tokens = jax.random.randint(
         jax.random.PRNGKey(13), (_SLOTS, _TABLE * _PAGE + n_suffix), 1,
         c.vocab)
-    want, ks, vs = jax.jit(partial(prefill_batch, config=c))(params, tokens)
+    want, ks, vs = jax.jit(partial(prefill_batch, config=c))(
+        params, tokens, cached + lengths)
     pool_k, pool_v = jax.jit(insert_pages_batch)(
         pool_k, pool_v, ks[:, :, :_TABLE * _PAGE], vs[:, :, :_TABLE * _PAGE],
         tables, cached)
     rows = cached[:, None] + jnp.arange(n_suffix)[None]       # [n, S]
     got, got_k, got_v = jax.jit(
         partial(prefill_with_prefix_batch, config=c))(
-        params, jnp.take_along_axis(tokens, rows, 1), pool_k, pool_v,
-        tables, cached)
-    np.testing.assert_allclose(
-        got, jnp.take_along_axis(want, rows[:, :, None], 1), atol=1e-5,
-        rtol=0)
+        params, jnp.take_along_axis(tokens, rows, 1), lengths, pool_k,
+        pool_v, tables, cached)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
     for new, whole in ((got_k, ks), (got_v, vs)):
         np.testing.assert_allclose(
             new, jnp.take_along_axis(whole, rows[None, :, :, None, None], 2),
