@@ -46,6 +46,11 @@ from ray_tpu.ops.layers import apply_rope, last_rows, rmsnorm, rope
 # OUT of the key: the wrapper's own aval-keyed cache handles those.
 _JIT_CACHE: dict[tuple, object] = {}
 _JIT_CACHE_LOCK = threading.Lock()
+# Rows of recurrent state the prefix cache keeps beside the slots' own (a
+# model with ModelConfig.kv_cache == "recurrent"): snapshots of the state
+# at a prefill chunk's end, where a continuation, or a later request with
+# the same prefix, resumes.
+SNAPSHOT_ROWS = 16
 
 
 def _shared_jit(key: tuple, factory):
@@ -117,6 +122,9 @@ class Request:
     # Tokens of `prompt` that came with the request; preemption appends
     # the generated tokens the model has seen after them.
     n_prompt: int = 0
+    # The slot it decodes in, or decoded in last (its row of the row
+    # pools: InferenceEngine.state_rows).
+    slot: int | None = None
 
     def __post_init__(self):
         self.n_prompt = len(self.prompt)
@@ -682,14 +690,66 @@ def _resolve_params(model_config: ModelConfig, params, mesh, rules,
     return params
 
 
-def _refuse_latent(c: ModelConfig, what: str):
-    """What a latent-cache model does not run with: each is a program this
-    file spells for per-head K and V only (ROADMAP D1)."""
+def _refuse(c: ModelConfig, what: str):
+    """What a model that brings its own serving programs does not run
+    with: each is a program this file spells for per-head K and V alone
+    (ROADMAP D1)."""
+    keeps = (f"ModelConfig.attention={c.attention!r} keeps a latent KV cache"
+             if c.kv_cache == "latent" else
+             f"ModelConfig.layer_pattern={c.layer_pattern!r} keeps recurrent "
+             f"state beside its KV pages")
     raise ValueError(
-        f"ModelConfig.attention={c.attention!r} keeps a latent KV cache "
-        f"(ModelConfig.kv_cache == \"latent\"), which does not run with "
-        f"{what}: only the paged single-device programs (prefill_batch, "
-        f"prefill_with_prefix_batch, insert, decode_paged) exist for it")
+        f"{keeps} (ModelConfig.kv_cache == \"{c.kv_cache}\"), which does "
+        f"not run with {what}: only the paged single-device programs "
+        f"(prefill_batch, prefill_with_prefix_batch, insert, decode_paged) "
+        f"exist for it")
+
+
+@dataclasses.dataclass(frozen=True)
+class _Serving:
+    """What the engine asks of a model for serving (ROADMAP D18): what a
+    sequence keeps, and the four programs over it. One calling convention
+    for every kind, `pools` the page pools, `rows` the row pools of
+    recurrent state (none for most), `stats` the expert layers' counters
+    (none for most), `[...]` only over a cached prefix:
+
+      prefill(params, tokens, lengths, [*pools, prefix_pages, prefix_len],
+              [*rows, src_rows, dst_rows], [stats])
+          -> (last-token logits, *what insert takes, [*rows], [stats])
+      insert(*pools, *what prefill gave, page_ids, lengths) -> pools
+      decode(params, *pools, *rows, tokens, lengths, active, page_tables,
+             [stats]) -> (logits, *pools, *rows, [stats])
+
+    The per-head programs are this file's; a model with another kind of
+    cache brings its own in its module (models.model_module)."""
+    page_pools: object   # (c, num_pages, page) -> ShapeDtypeStructs
+    row_pools: object    # (c, rows) -> ShapeDtypeStructs, or None
+    stats_zero: object   # (c) -> counters, or None
+    prefill_batch: object
+    prefill_with_prefix_batch: object
+    insert_batch: object
+    decode_paged: object
+
+
+def _per_head_pools(c: ModelConfig, num_pages: int, page: int) -> tuple:
+    # [L, hkv, N, hd, page] — kv-heads outermost after layers and
+    # head_dim BEFORE page so the Pallas decode kernel can DMA
+    # per-page blocks [hkv, hd, page] whose trailing dims
+    # (hd, 128) satisfy Mosaic's (8, 128) tiling.
+    shape = (c.n_layers, c.n_kv_heads, num_pages, c.head_dim, page)
+    return (jax.ShapeDtypeStruct(shape, c.jdtype),) * 2
+
+
+def _serving_of(c: ModelConfig) -> _Serving:
+    if c.kv_cache == "per_head":
+        return _Serving(_per_head_pools, None, None, prefill_batch,
+                        prefill_with_prefix_batch, insert_pages_batch,
+                        decode_paged)
+    m = model_module(c)
+    return _Serving(
+        m.page_pools, getattr(m, "row_pools", None), m.stats_zero,
+        m.prefill_batch, m.prefill_with_prefix_batch,
+        getattr(m, "insert_pages_batch", insert_pages_batch), m.decode_paged)
 
 
 def _sampling_of(reqs) -> tuple:
@@ -735,18 +795,19 @@ class InferenceEngine:
                 f"keeps one KV layout, \"paged\" (a pool of pages; what a "
                 f"page holds follows ModelConfig.attention="
                 f"{model_config.attention!r})")
-        # A latent-cache model (ModelConfig.kv_cache) brings its own four
-        # programs (models/deepseek_v2.py) over ONE pool [L, N, latent,
-        # page], held in `cache_k` (`cache_v` is None); page accounting,
+        # A model with another kind of cache than per-head K and V
+        # (ModelConfig.kv_cache) brings its own four programs in its
+        # module, over pools of its own shapes (_Serving); page accounting,
         # prefix hashing, chunked prefill and preemption below are shared.
-        self.latent = model_config.kv_cache == "latent"
-        if self.latent:
+        self.serving = _serving_of(model_config)
+        self._own = model_config.kv_cache != "per_head"
+        if self._own:
             if self.e.speculation is not None:
-                _refuse_latent(model_config, "EngineConfig.speculation="
-                                             f"{self.e.speculation!r}")
+                _refuse(model_config, "EngineConfig.speculation="
+                                      f"{self.e.speculation!r}")
             if mesh is not None and mesh.devices.size > 1:
-                _refuse_latent(model_config, f"a mesh of {dict(mesh.shape)} "
-                                             f"(tensor parallelism)")
+                _refuse(model_config, f"a mesh of {dict(mesh.shape)} "
+                                      f"(tensor parallelism)")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         c, e = self.c, self.e
@@ -759,22 +820,36 @@ class InferenceEngine:
         self.pages_per_slot = -(-e.max_len // page)
         self.num_pages = (e.num_pages
                           or e.max_slots * self.pages_per_slot + 1)
-        # [L, hkv, N, hd, page] — kv-heads outermost after layers and
-        # head_dim BEFORE page so the Pallas decode kernel can DMA
-        # per-page blocks [hkv, hd, page] whose trailing dims
-        # (hd, 128) satisfy Mosaic's (8, 128) tiling.
-        if self.latent:
-            model = model_module(c)
-            self.cache_k = jnp.zeros(
-                model.pool_shape(c, self.num_pages, page), c.jdtype)
-            self.cache_v = None
-            self._moe_acc = model.stats_zero(c)   # device; moe_stats()
+
+        def zeros(shapes):
+            return tuple(jnp.zeros(s.shape, s.dtype) for s in shapes)
+
+        # the page pools: K and V a head, or one latent pool (`cache_v`
+        # is None then)
+        self._set_pools(zeros(self.serving.page_pools(c, self.num_pages,
+                                                      page)))
+        # Row pools of recurrent state: row b is slot b's, then
+        # SNAPSHOT_ROWS rows the prefix cache keeps (the state at a
+        # chunk's end, keyed as the page that ends there), then one
+        # scratch row for the padding of a prefill batch.
+        self.rows: tuple = ()
+        self._scratch_row = e.max_slots + SNAPSHOT_ROWS
+        self.free_snaps: list[int] = []
+        if self.serving.row_pools is not None:
+            self.rows = zeros(self.serving.row_pools(
+                c, self._scratch_row + 1))
+            self.free_snaps = list(range(e.max_slots, self._scratch_row))
+        self.snap_of_hash: dict = {}       # prefix-hash -> snapshot row
+        self.hash_of_snap: dict[int, object] = {}
+        self.snap_lru: "collections.OrderedDict[int, object]" = (
+            collections.OrderedDict())     # unpinned snapshot rows (LRU)
+        self.snap_pins: dict[int, int] = {}  # rows an admission is using
+        self.snapshot_hits = 0
+        self.snapshot_evictions = 0
+        self._moe_acc = None               # device; moe_stats()
+        if self.serving.stats_zero is not None:
+            self._moe_acc = self.serving.stats_zero(c)
             self._moe_total = np.zeros(self._moe_acc.shape, np.int64)
-        else:
-            kv_shape = (c.n_layers, c.n_kv_heads, self.num_pages,
-                        c.head_dim, page)
-            self.cache_k = jnp.zeros(kv_shape, c.jdtype)
-            self.cache_v = jnp.zeros(kv_shape, c.jdtype)
         # page bookkeeping (host side)
         self.free_pages: list[int] = list(range(1, self.num_pages))
         self.page_refs: dict[int, int] = {}
@@ -810,16 +885,11 @@ class InferenceEngine:
         self._guide_fp = None
         # Donate the pool/cache: without donation every step round-trips
         # the full KV through a fresh HBM allocation (~GBs/step).
-        if self.latent:
-            self._insert_batch = _shared_jit(
-                ("insert_latent_pages_batch",),
-                lambda: jax.jit(model.insert_latent_pages_batch,
-                                donate_argnums=(0,)))
-        else:
-            self._insert_batch = _shared_jit(
-                ("insert_pages_batch",),
-                lambda: jax.jit(insert_pages_batch,
-                                donate_argnums=(0, 1)))
+        insert = self.serving.insert_batch
+        self._insert_batch = _shared_jit(
+            (insert.__name__,),
+            lambda: jax.jit(insert, donate_argnums=tuple(
+                range(len(self._pools())))))
         self._prefill_batches: dict[tuple, object] = {}
         if mesh is not None and "tp" in mesh.axis_names:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -862,6 +932,13 @@ class InferenceEngine:
         self._lock = threading.Lock()
         self._cancel_rids: set[int] = set()
 
+    def _pools(self) -> tuple:
+        return tuple(p for p in (self.cache_k, self.cache_v)
+                     if p is not None)
+
+    def _set_pools(self, pools):
+        self.cache_k, self.cache_v = (tuple(pools) + (None,))[:2]
+
     # ---- request API ----
 
     def add_request(self, prompt_tokens, max_new_tokens=None,
@@ -881,8 +958,8 @@ class InferenceEngine:
         # chunked prefill admits any prompt under max_len
         if not (self._chunk_size() and len(prompt_tokens) < self.e.max_len):
             self._bucket(len(prompt_tokens))
-        if kv_handoff is not None and self.latent:
-            _refuse_latent(self.c, "a per-head KV handoff")
+        if kv_handoff is not None and self._own:
+            _refuse(self.c, "a per-head KV handoff")
         if guide is not None:
             if guide.table.shape[1] != self.c.vocab:
                 raise ValueError(
@@ -984,8 +1061,48 @@ class InferenceEngine:
             self.page_hash.pop(h, None)
             self.hash_of_page.pop(pid, None)
             self.page_refs.pop(pid, None)
+            # a snapshot goes with, or before, the pages it stands on: a
+            # key is the prefix's own bytes, so every snapshot at or past
+            # this page's boundary begins with the page's key
+            for sh, row in list(self.snap_of_hash.items()):
+                if sh[:len(h)] == h:
+                    self._drop_snap(row)
             return pid
         return None
+
+    # ---- snapshot rows of recurrent state ----
+
+    def _drop_snap(self, row: int):
+        """Forget the snapshot in `row` (never a pinned one: its pages are
+        pinned with it, so nothing evicts them) and free the row."""
+        self.snap_of_hash.pop(self.hash_of_snap.pop(row), None)
+        self.snap_lru.pop(row, None)
+        self.free_snaps.append(row)
+        self.snapshot_evictions += 1
+
+    def _alloc_snap(self) -> int | None:
+        """A free snapshot row, pinned, else the least recently used
+        unpinned one, else None."""
+        if not self.free_snaps and self.snap_lru:
+            self._drop_snap(next(iter(self.snap_lru)))
+        if not self.free_snaps:
+            return None
+        row = self.free_snaps.pop()
+        self.snap_pins[row] = 1
+        return row
+
+    def _pin_snap(self, row: int):
+        self.snap_pins[row] = self.snap_pins.get(row, 0) + 1
+        self.snap_lru.pop(row, None)
+
+    def _unpin_snap(self, row: int):
+        n = self.snap_pins.pop(row) - 1
+        if n > 0:
+            self.snap_pins[row] = n
+        elif row in self.hash_of_snap:
+            self.snap_lru[row] = self.hash_of_snap[row]   # most recent
+        else:
+            self.free_snaps.append(row)    # never registered: rolled back
 
     def _incref_page(self, pid: int):
         self.page_refs[pid] = self.page_refs.get(pid, 0) + 1
@@ -1017,7 +1134,10 @@ class InferenceEngine:
 
     def _find_prefix(self, prompt: list) -> list[int]:
         """Longest run of already-cached full prompt pages (at least one
-        token is always left to prefill — its logits seed sampling)."""
+        token is always left to prefill — its logits seed sampling). With
+        recurrent state: the longest such run that ends at a boundary
+        whose state is held (`snap_of_hash`, same key) — never pages
+        without their state."""
         if not self.e.prefix_cache:
             return []
         page = self.e.page_size
@@ -1031,6 +1151,10 @@ class InferenceEngine:
             if pid is None:
                 break
             pages.append(pid)
+        if self.rows:
+            while pages and self._prefix_hash(
+                    prompt[:len(pages) * page]) not in self.snap_of_hash:
+                pages.pop()
         return pages
 
     def import_kv(self, prompt_tokens, ks, vs) -> int:
@@ -1046,8 +1170,8 @@ class InferenceEngine:
         engine queue's kv_handoff field routes a handoff there)."""
         if not self.e.prefix_cache:
             return 0
-        if self.latent:
-            _refuse_latent(self.c, "a per-head KV handoff")
+        if self._own:
+            _refuse(self.c, "a per-head KV handoff")
         page = self.e.page_size
         prompt = list(map(int, prompt_tokens))
         full = len(prompt) // page
@@ -1162,26 +1286,43 @@ class InferenceEngine:
             # be able to evict and reuse them.
             for pid in pre_pages:
                 self._incref_page(pid)
+            # Recurrent state: resume from the snapshot at the prefix's
+            # end (pinned like its pages), end in the slot's own row, or,
+            # for a partial chunk, in a snapshot row keyed as the page
+            # that ends there.
+            src_row = dst_row = None
+            if self.rows:
+                if hit:
+                    src_row = self.snap_of_hash[
+                        self._prefix_hash(req.prompt[:hit * page])]
+                    self._pin_snap(src_row)
+                dst_row = self._alloc_snap() if is_partial else slot
+            no_row = bool(self.rows) and dst_row is None
             # Pages covering [hit*page, n): allocated up front; growth
             # pages come later, one decode page at a time.
             need = -(-n // page) - hit
             new_pages = []
-            for _ in range(need):
+            while len(new_pages) < need and not no_row:
                 pid = self._alloc_page()
                 if pid is None:
                     break
                 new_pages.append(pid)
             if len(new_pages) < need:
-                # Pool exhausted: put everything back and stop admitting.
+                # Pool exhausted (pages, or every snapshot row pinned):
+                # put everything back and stop admitting.
                 self.free_pages.extend(new_pages)
                 for pid in pre_pages:
                     self._decref_page(pid)
+                for row in (src_row, dst_row if is_partial else None):
+                    if row is not None:
+                        self._unpin_snap(row)
                 self.queue.appendleft(req)
                 break
             for pid in new_pages:
                 self.page_refs[pid] = 1
             if hit:
                 self.prefix_hits += 1
+                self.snapshot_hits += src_row is not None
             if is_partial:
                 # A partial chunk never occupies the slot — and must not
                 # reuse its id either: a later full admission in this same
@@ -1193,7 +1334,8 @@ class InferenceEngine:
             planned.append(dict(slot=slot, req=req, n=n, ns=ns,
                                 bucket=bucket, hit=hit, partial=is_partial,
                                 suffix=suffix, pre_pages=pre_pages,
-                                new_pages=new_pages))
+                                new_pages=new_pages, src_row=src_row,
+                                dst_row=dst_row))
 
         # Phase 2 — device work, grouped: prefix-hit prompts batch by
         # (suffix bucket, prefix-page bucket), the rest by suffix bucket —
@@ -1217,14 +1359,22 @@ class InferenceEngine:
             plens = np.zeros((n_pad,), np.int32)
             lens = np.zeros((n_pad,), np.int32)
             tabs = np.zeros((n_pad, -(-bucket // page)), np.int32)
+            # rows of recurrent state to start from and to end in; the
+            # batch's padding reads and writes the scratch row
+            srcs = np.full((n_pad,), self._scratch_row, np.int32)
+            dsts = np.full((n_pad,), self._scratch_row, np.int32)
             for j, p in enumerate(group):
                 toks[j, :p["ns"]] = p["suffix"]
                 pres[j, :p["hit"]] = p["pre_pages"]
                 plens[j] = p["hit"] * page
                 lens[j] = p["ns"]
                 tabs[j, :len(p["new_pages"])] = p["new_pages"]
+                if self.rows:
+                    dsts[j] = p["dst_row"]
+                    if p["hit"]:
+                        srcs[j] = p["src_row"]
             self._prefill_group(group, logits_of, toks, lens, tabs, pres,
-                                plens)
+                                plens, srcs, dsts)
 
         # Phase 3 — host-side registration.
         for p in planned:
@@ -1238,6 +1388,16 @@ class InferenceEngine:
                     if h not in self.page_hash:
                         self.page_hash[h] = pid
                         self.hash_of_page[pid] = h
+            if p["src_row"] is not None:
+                self._unpin_snap(p["src_row"])
+            if p["partial"] and self.rows:
+                # the state at this chunk's end, under its last page's key
+                h = self._prefix_hash(req.prompt[:n])
+                if h in self.snap_of_hash:     # an older copy of the same
+                    self._drop_snap(self.snap_of_hash[h])
+                self.snap_of_hash[h] = p["dst_row"]
+                self.hash_of_snap[p["dst_row"]] = h
+                self._unpin_snap(p["dst_row"])
             if p["partial"]:
                 # Chunk prefilled and registered; hand the pages to the
                 # prefix cache (ref 0 -> protected in the LRU until the
@@ -1249,6 +1409,7 @@ class InferenceEngine:
                 continue
             self.slot_pages[slot] = p["pre_pages"] + new_pages
             self.slot_req[slot] = req
+            req.slot = slot
             self.lengths[slot] = n
             self.active[slot] = True
             self.hist[slot, :n] = req.prompt
@@ -1280,36 +1441,44 @@ class InferenceEngine:
         return admitted
 
     def _prefill_group(self, group: list, logits_of: dict, toks, lens, tabs,
-                       pres, plens):
+                       pres, plens, srcs, dsts):
         """ONE prefill dispatch and ONE page-insert dispatch for a group of
         planned admissions (over cached prefix pages `pres` [n, Pp] of
-        `plens` tokens; Pp is 0 where the group hit no cached prefix); the
-        last-token logits row of every request that takes a slot goes into
-        `logits_of` (the programs run the head at that position only)."""
+        `plens` tokens; Pp is 0 where the group hit no cached prefix; from
+        rows `srcs` of recurrent state into rows `dsts`, where the model
+        has any); the last-token logits row of every request that takes a
+        slot goes into `logits_of` (the programs run the head at that
+        position only). `_Serving` has the calling convention."""
         hit = pres.shape[1] > 0
         name = "prefill_with_prefix_batch" if hit else "prefill_batch"
         cache = self._prefill_pre if hit else self._prefill_batches
         key = toks.shape + ((pres.shape[1],) if hit else ())
+        pools = self._pools()
+        n_before = 3 + (len(pools) + 2 if hit else 0)
         fn = cache.get(key)
         if fn is None:
-            own = prefill_with_prefix_batch if hit else prefill_batch
-            program = (getattr(model_module(self.c), name) if self.latent
-                       else own)
+            program = getattr(self.serving, name)
+            donate = tuple(range(n_before, n_before + len(self.rows)))
             fn = cache[key] = _shared_jit(
                 (name, self.c),
-                lambda: jax.jit(partial(program, config=self.c)))
+                lambda: jax.jit(partial(program, config=self.c),
+                                donate_argnums=donate))
         toks, lens, tabs = (jnp.asarray(a) for a in (toks, lens, tabs))
-        pools = ((self.cache_k,) if self.latent
-                 else (self.cache_k, self.cache_v))
-        prefix = (*pools, jnp.asarray(pres), jnp.asarray(plens)) if hit else ()
-        if self.latent:
-            last, lat, self._moe_acc = fn(self.params, toks, lens, *prefix,
-                                          self._moe_acc)
-            self.cache_k = self._insert_batch(self.cache_k, lat, tabs, lens)
-        else:
-            last, ks, vs = fn(self.params, toks, lens, *prefix)
-            self.cache_k, self.cache_v = self._insert_batch(
-                self.cache_k, self.cache_v, ks, vs, tabs, lens)
+        args = (self.params, toks, lens)
+        if hit:
+            args += (*pools, jnp.asarray(pres), jnp.asarray(plens))
+        if self.rows:
+            args += (*self.rows, jnp.asarray(srcs), jnp.asarray(dsts))
+        if self._moe_acc is not None:
+            args += (self._moe_acc,)
+        last, *out = fn(*args)
+        if self._moe_acc is not None:
+            self._moe_acc = out.pop()
+        if self.rows:
+            out, self.rows = out[:-len(self.rows)], tuple(
+                out[-len(self.rows):])
+        pools = self._insert_batch(*pools, *out, tabs, lens)
+        self._set_pools(pools if isinstance(pools, tuple) else (pools,))
         for j, p in enumerate(group):
             if p["slot"] is not None:
                 logits_of[p["slot"]] = last[j]
@@ -1326,13 +1495,39 @@ class InferenceEngine:
             "preemptions": self.preemptions,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
+            # recurrent state (zeros for a model that keeps none): a row a
+            # running sequence; snapshots held, of them pinned by an
+            # admission under way; prefix hits that resumed from one
+            "state_rows_in_use": int(self.active.sum()) if self.rows else 0,
+            "snapshot_rows": len(self.hash_of_snap),
+            "snapshot_rows_in_use": len(self.snap_pins),
+            "snapshot_hits": self.snapshot_hits,
+            "snapshot_evictions": self.snapshot_evictions,
         }
 
+    def state_rows(self, rows: list) -> tuple:
+        """Rows of the two row pools, on the host: (state [n, LM, H, P,
+        N], window [n, LM, W - 1, channels]). A slot's row is its number
+        (`Request.slot`; it keeps the state after the last token fed until
+        the slot is taken again), a snapshot's is `snapshot_row`'s. For
+        tests and perfbench/tools/checkstate.py, which compare the state
+        itself with the plain recurrence."""
+        ssm, conv = self.rows      # a slice a row: never a gather here
+        return (np.stack([np.asarray(ssm[:, int(r)]) for r in rows]),
+                np.stack([np.asarray(conv[:, :, int(r)].astype(jnp.float32))
+                          for r in rows]))
+
+    def snapshot_row(self, prefix: list) -> int | None:
+        """The row that holds the state at the end of `prefix` (a whole
+        number of pages), if the prefix cache keeps it."""
+        return self.snap_of_hash.get(self._prefix_hash(prefix))
+
     def moe_stats(self) -> dict:
-        """What the expert layers of a latent-cache model routed since the
-        engine began: counted on the device inside the programs and
-        fetched only here (never a host fence in step())."""
-        if not self.latent:
+        """What the expert layers of a model that holds a share of its
+        experts (models/experts.py) routed since the engine began: counted
+        on the device inside the programs and fetched only here (never a
+        host fence in step())."""
+        if self._moe_acc is None:
             return {}
         N_STATS = model_module(self.c).N_STATS
         fresh, self._moe_acc = self._moe_acc, jnp.zeros_like(self._moe_acc)
@@ -1437,24 +1632,25 @@ class InferenceEngine:
             return None
         tables = self._build_tables()
         p_bucket = tables.shape[1]
+        pools = self._pools()
+        n_donated = len(pools) + len(self.rows)
         fn = self._decode_paged.get(p_bucket)
         if fn is None:
-            program = (model_module(self.c).decode_paged if self.latent
-                       else decode_paged)
             fn = _shared_jit(
                 ("decode_paged", self.c),
-                lambda: jax.jit(partial(program, config=self.c),
-                                donate_argnums=(1,) if self.latent
-                                else (1, 2)))
+                lambda: jax.jit(
+                    partial(self.serving.decode_paged, config=self.c),
+                    donate_argnums=tuple(range(1, 1 + n_donated))))
             self._decode_paged[p_bucket] = fn
-        state = (jnp.asarray(self.last_tokens), jnp.asarray(self.lengths),
-                 jnp.asarray(self.active), jnp.asarray(tables))
-        if self.latent:
-            logits, self.cache_k, self._moe_acc = fn(
-                self.params, self.cache_k, *state, self._moe_acc)
-        else:
-            logits, self.cache_k, self.cache_v = fn(
-                self.params, self.cache_k, self.cache_v, *state)
+        stats = () if self._moe_acc is None else (self._moe_acc,)
+        logits, *out = fn(
+            self.params, *pools, *self.rows, jnp.asarray(self.last_tokens),
+            jnp.asarray(self.lengths), jnp.asarray(self.active),
+            jnp.asarray(tables), *stats)
+        if stats:
+            self._moe_acc = out.pop()
+        self._set_pools(out[:len(pools)])
+        self.rows = tuple(out[len(pools):])
         return logits
 
     def _build_tables(self) -> np.ndarray:
@@ -1779,9 +1975,8 @@ class InferenceEngine:
 
     def step_window(self) -> dict[int, int]:
         """Admit queued prompts, then decode a whole window."""
-        if self.latent:
-            _refuse_latent(self.c, "step_window() (decode_window); call "
-                                   "step()")
+        if self._own:
+            _refuse(self.c, "step_window() (decode_window); call step()")
         emitted = self._admit()
         if self.active.any():
             upd = (self._run_window_spec() if self._spec_applicable()
@@ -1800,7 +1995,7 @@ class InferenceEngine:
         stream through)."""
         ids = [self.add_request(p, max_new_tokens, temperature)
                for p in prompts]
-        step = self.step if self.latent else self.step_window
+        step = self.step if self._own else self.step_window
         while self.has_work():
             step()
         out = []
@@ -1827,9 +2022,9 @@ class PrefillEngine:
         self.c = model_config
         self.e = engine_config or EngineConfig()
         self.mesh = mesh
-        if model_config.kv_cache == "latent":
-            _refuse_latent(model_config, "the prefill pool, which exports "
-                                         "per-head K and V")
+        if model_config.kv_cache != "per_head":
+            _refuse(model_config, "the prefill pool, which exports "
+                                  "per-head K and V")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         self._prefill = _shared_jit(

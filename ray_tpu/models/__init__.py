@@ -15,8 +15,12 @@ from ray_tpu.models import configs
 
 
 def model_module(config: ModelConfig):
-    """The module that implements `config`, chosen by its `attention`
-    field: this package's transformer ("gqa") or deepseek_v2 ("mla")."""
+    """The module that implements `config`: nemotron_h where it has a
+    `layer_pattern`, else by its `attention` field this package's
+    transformer ("gqa") or deepseek_v2 ("mla")."""
+    if config.layer_pattern:
+        from ray_tpu.models import nemotron_h
+        return nemotron_h
     if config.attention == "mla":
         from ray_tpu.models import deepseek_v2
         return deepseek_v2

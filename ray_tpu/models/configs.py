@@ -105,3 +105,37 @@ def tiny_mla(**kw) -> ModelConfig:
         first_k_dense=1, moe_experts=8, moe_top_k=3, moe_d_ff=32,
         moe_shared_experts=1, moe_router_experts=8, moe_n_group=4,
         moe_topk_group=2, moe_routed_scale=4.0, moe_norm_topk=False)
+
+
+def nemotron3_nano_30b(**kw) -> ModelConfig:
+    """NVIDIA Nemotron-3-Nano-30B-A3B at its published sizes: 52 blocks of
+    one mixer each (23 Mamba-2, 23 expert, 6 attention), 128 routed relu^2
+    experts (6 a token, sigmoid scores) beside one shared expert. Every
+    expert held: a serving replica names its share through `moe_experts`,
+    `moe_router_experts` and `moe_held_group`."""
+    return _preset(
+        kw, vocab=131072, d_model=2688, n_layers=52, n_heads=32,
+        n_kv_heads=2, head_size=128, d_ff=1856, norm_eps=1e-5,
+        dtype="bfloat16", tie_embeddings=False, rotary=False,
+        layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        ssm_conv_width=4, ssm_chunk=128, mlp_act="relu2",
+        moe_score="sigmoid", moe_scale_normed=True, moe_experts=128,
+        moe_router_experts=128, moe_top_k=6, moe_d_ff=1856,
+        moe_shared_experts=1, moe_shared_d_ff=3712, moe_routed_scale=2.5,
+        moe_norm_topk=True, moe_grouped="tiles")
+
+
+def tiny_hybrid(**kw) -> ModelConfig:
+    """CPU-test scale of nemotron3_nano_30b's structure: 7 blocks (3
+    Mamba-2 of 8 heads x 8 over a state of 16 in 2 groups, 3 expert, 1
+    attention), 8 routed experts (3 a token) beside one shared expert."""
+    return _preset(
+        kw, vocab=256, d_model=64, n_layers=7, n_heads=4, n_kv_heads=2,
+        head_size=16, d_ff=32, norm_eps=1e-5, tie_embeddings=False,
+        rotary=False, layer_pattern="MEM*EME", ssm_heads=8, ssm_head_dim=8,
+        ssm_state=16, ssm_groups=2, ssm_conv_width=4, ssm_chunk=8,
+        mlp_act="relu2", moe_score="sigmoid", moe_scale_normed=True,
+        moe_experts=8, moe_router_experts=8, moe_top_k=3, moe_d_ff=32,
+        moe_shared_experts=1, moe_shared_d_ff=48, moe_routed_scale=2.5,
+        moe_norm_topk=True, moe_grouped="tiles")

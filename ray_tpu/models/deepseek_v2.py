@@ -35,13 +35,9 @@ The equations (x after the layer's input RMSNorm; h heads):
     times moe_routed_scale (the source scales only in that branch)
     y = sum_k w_k SwiGLU^(e_k)(x) + SwiGLU^shared(x)
 
-The expert layer is told which experts it HOLDS: `moe_experts` of the
-`moe_router_experts` the router scores, group `moe_held_group`. It routes
-over all of them at the published width, dispatches the (token, expert)
-pairs that land on held experts (sorted by expert, one grouped matrix
-product: no capacity, no token dropped, no [tokens, experts, width]
-intermediate), adds the shared experts for every token, and leaves out
-what the absent experts would add. Nothing stands in for the absent chips.
+The expert layer (models/experts.py, shared with models/nemotron_h.py) is
+told which experts it HOLDS and leaves out what the absent experts would
+add: nothing stands in for the absent chips.
 
 Parameters: {"embed" [V, d], "layers": [one tree a layer; kinds differ],
 "final_norm" [d], "lm_head" [d, V]}.
@@ -54,18 +50,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.experts import (N_STATS, expert_layer,  # noqa: F401
+                                    init_expert_weights, route, stats_zero)
 from ray_tpu.models.transformer import ModelConfig
 from ray_tpu.ops.latent_attention import (mla_prefill_attention,
                                           paged_latent_decode_attention)
 from ray_tpu.ops.layers import (apply_rope, last_rows, rmsnorm, rope, swiglu,
                                 yarn_mscale)
-
-# Rows of the grouped product a dispatch pass may fill. A token sends at
-# most top-k pairs to the held experts and 1/n_group of that on average:
-# sizing the gathers for the worst case would cost top-k times the memory
-# and the row gathers of a typical step, so long batches take the pairs in
-# passes of this many rows (one pass unless routing is badly skewed).
-_MIN_PASS_ROWS = 4096
 
 
 def latent_width(c: ModelConfig) -> int:
@@ -83,10 +74,6 @@ def softmax_scale(c: ModelConfig) -> float:
 
 def _is_dense(c: ModelConfig, li: int) -> bool:
     return li < c.first_k_dense or not c.moe_experts
-
-
-def router_width(c: ModelConfig) -> int:
-    return c.moe_router_experts or c.moe_experts
 
 
 # ---------------------------------------------------------------- params
@@ -128,13 +115,8 @@ def _init_layer(key, config: ModelConfig, dense: bool) -> dict:
         lp.update(wg=w((d, c.d_ff), d), wu=w((d, c.d_ff), d),
                   wd=w((c.d_ff, d), c.d_ff))
         return lp
-    E, f = c.moe_experts, c.moe_d_ff
-    lp.update(router=w((d, router_width(c)), d),
-              wg=w((E, d, f), d), wu=w((E, d, f), d), wd=w((E, f, d), f))
-    if c.moe_shared_experts:
-        fs = c.moe_shared_experts * f
-        lp.update(shared_wg=w((d, fs), d), shared_wu=w((d, fs), d),
-                  shared_wd=w((fs, d), fs))
+    lp.update(init_expert_weights(
+        w, c, lambda shape, scale: _seeded(next(ks), shape, scale, c.dtype)))
     return lp
 
 
@@ -231,94 +213,6 @@ def attend_absorbed_dense(q_nope, q_pe, keys, lp, c: ModelConfig):
 
 
 # ----------------------------------------------------------- feed-forward
-
-
-N_STATS = 4   # routed tokens, pairs on held experts, tokens with no held
-#               expert, expert-layer calls; then one load count an expert
-
-
-def stats_zero(c: ModelConfig):
-    return jnp.zeros((N_STATS + c.moe_experts,), jnp.int32)
-
-
-def route(x, lp, c: ModelConfig):
-    """x [T, d] -> (weights [T, k] float32, expert ids [T, k] over the
-    router's published width)."""
-    k = c.moe_top_k
-    g = jax.nn.softmax(jnp.einsum("td,dx->tx", x, lp["router"],
-                                  preferred_element_type=jnp.float32), -1)
-    if c.moe_n_group > 1:
-        T, X = g.shape
-        per = X // c.moe_n_group
-        _, best = jax.lax.top_k(g.reshape(T, c.moe_n_group, per).max(-1),
-                                c.moe_topk_group)
-        keep = jax.nn.one_hot(best, c.moe_n_group, dtype=jnp.bool_).any(1)
-        g = jnp.where(jnp.repeat(keep, per, axis=1), g, 0.0)
-    w, idx = jax.lax.top_k(g, k)
-    if k > 1 and c.moe_norm_topk:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
-    else:
-        w = w * c.moe_routed_scale
-    return w, idx
-
-
-def _grouped_swiglu(rows, lp, sizes):
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
-    act = jax.nn.silu(dot(rows, lp["wg"])) * dot(rows, lp["wu"])
-    return dot(act, lp["wd"])
-
-
-def expert_layer(x, lp, c: ModelConfig, valid):
-    """x [T, d] (normed), valid [T] bool (padding routes nowhere) ->
-    (held experts' part + shared experts [T, d], stats [N_STATS + E])."""
-    T, d = x.shape
-    E, k = c.moe_experts, c.moe_top_k
-    with jax.named_scope("expert_layer"):
-        w, idx = route(x, lp, c)
-        local = idx - c.moe_held_group * E
-        held = (local >= 0) & (local < E) & valid[:, None]      # [T, k]
-        # pairs sorted by held expert; pairs of absent experts sort last
-        key = jnp.where(held, local, E).reshape(-1)
-        order = jnp.argsort(key, stable=True)       # sorted row -> pair
-        where_sorted = jnp.argsort(order).reshape(T, k)  # pair -> sorted row
-        counts = jnp.sum(key[:, None] == jnp.arange(E)[None], axis=0,
-                         dtype=jnp.int32)
-        ends = jnp.cumsum(counts)
-        n_held = ends[-1]
-        rows_a_pass = min(T * k, max(T, _MIN_PASS_ROWS))
-
-        def one_pass(start, y):
-            take = jnp.minimum(start + jnp.arange(rows_a_pass), T * k - 1)
-            rows = jnp.take(x, order[take] // k, axis=0)
-            sizes = (jnp.clip(ends, start, start + rows_a_pass)
-                     - jnp.clip(ends - counts, start, start + rows_a_pass))
-            out = _grouped_swiglu(rows, lp, sizes)
-            rel = where_sorted - start
-            here = held & (rel >= 0) & (rel < rows_a_pass)
-            for j in range(k):      # a row gather a choice; no scatter
-                got = jnp.take(out, jnp.clip(rel[:, j], 0, rows_a_pass - 1),
-                               axis=0)
-                y = y + jnp.where(here[:, j, None],
-                                  got.astype(jnp.float32) * w[:, j, None], 0)
-            return y
-
-        y = jnp.zeros((T, d), jnp.float32)
-        if rows_a_pass == T * k:
-            y = one_pass(0, y)
-        else:
-            _, y = jax.lax.while_loop(
-                lambda sy: sy[0] < n_held,
-                lambda sy: (sy[0] + rows_a_pass, one_pass(sy[0], sy[1])),
-                (jnp.int32(0), y))
-        y = y.astype(x.dtype)
-        if c.moe_shared_experts:
-            y = y + swiglu(x[None], lp["shared_wg"], lp["shared_wu"],
-                           lp["shared_wd"])[0]
-        stats = jnp.concatenate([jnp.stack([
-            jnp.sum(valid, dtype=jnp.int32), n_held,
-            jnp.sum(valid & ~held.any(1), dtype=jnp.int32),
-            jnp.int32(1)]), counts])
-    return y, stats
 
 
 def _mlp_block(h, lp, c: ModelConfig, li: int, valid, stats):
@@ -465,3 +359,13 @@ def decode_paged(params, pool, tokens, lengths, active, page_tables, stats,
 
 def pool_shape(c: ModelConfig, num_pages: int, page: int) -> tuple:
     return (c.n_layers, num_pages, latent_width(c), page)
+
+
+# ---- what the engine asks for by name (llm/engine._serving_of) ----
+
+
+def page_pools(c: ModelConfig, num_pages: int, page: int) -> tuple:
+    return (jax.ShapeDtypeStruct(pool_shape(c, num_pages, page), c.jdtype),)
+
+
+insert_pages_batch = insert_latent_pages_batch

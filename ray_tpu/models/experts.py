@@ -1,0 +1,350 @@
+"""The expert layer of a chip that HOLDS a share of the routed experts,
+for the models that have one (models/deepseek_v2.py, models/nemotron_h.py).
+
+The layer is told which experts it holds: `moe_experts` of the
+`moe_router_experts` the router scores, group `moe_held_group`. It routes
+over all of them at the published width, dispatches the (token, expert)
+pairs that land on held experts (ordered by expert, one grouped matrix
+product: no capacity, no token dropped, no [tokens, experts, width]
+intermediate), adds the shared experts for every token,
+and leaves out what the absent experts would add. Nothing stands in for
+the absent chips.
+
+What a configuration chooses (`ModelConfig`):
+  moe_score         "softmax": g = softmax_fp32(x W_g).
+                    "sigmoid": g = sigmoid_fp32(x W_g), and the learned
+                    `router_bias` joins g in the CHOICE only; the weights
+                    are g without it.
+  moe_n_group, moe_topk_group   group-limited choice: group score = max of
+                    g in each group, the best groups stay, the rest is
+                    zeroed; then the top-k of what is left.
+  moe_norm_topk, moe_routed_scale, moe_scale_normed   weights renormalised
+                    (sum + 1e-20) or not; times the scale where they were
+                    not renormalised, and after renormalising too where
+                    moe_scale_normed.
+  mlp_act           the form of an expert: "swiglu" W_d (silu(W_g x) *
+                    W_u x), or "relu2" W_d relu(W_u x)^2 (no W_g).
+  moe_shared_experts, moe_shared_d_ff   the always-on expert of the same
+                    form, moe_shared_d_ff wide (0: moe_shared_experts *
+                    moe_d_ff).
+  moe_grouped       how the order and the grouped product are computed:
+                    "ragged_dot" (a stable sort, XLA's ragged-dot kernel:
+                    deepseek_v2's, as its cell has always run it) or
+                    "tiles" (counted order, plain products over tiles, a
+                    dense batched product where tokens are few: the one
+                    form nemotron_h's programs run with on the chip; the
+                    comment above `_TILE_ROWS` has the runs).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.transformer import ModelConfig
+from ray_tpu.ops.layers import swiglu
+
+# Rows of the grouped product a dispatch pass may fill. A token sends at
+# most top-k pairs to the held experts and 1/n_group of that on average:
+# sizing the gathers for the worst case would cost top-k times the memory
+# and the row gathers of a typical step, so long batches take the pairs in
+# passes of this many rows (one pass unless routing is badly skewed).
+_MIN_PASS_ROWS = 4096
+
+N_STATS = 4   # routed tokens, pairs on held experts, tokens with no held
+#               expert, expert-layer calls; then one load count an expert
+
+
+def stats_zero(c: ModelConfig):
+    return jnp.zeros((N_STATS + c.moe_experts,), jnp.int32)
+
+
+def router_width(c: ModelConfig) -> int:
+    return c.moe_router_experts or c.moe_experts
+
+
+def shared_width(c: ModelConfig) -> int:
+    return c.moe_shared_d_ff or c.moe_shared_experts * c.moe_d_ff
+
+
+def relu2_mlp(x, w_up, w_down):
+    """W_down relu(x W_up)^2, outputs in x.dtype (as ops/layers.swiglu)."""
+    u = jax.nn.relu(jnp.einsum("...e,ef->...f", x, w_up))
+    return jnp.einsum("...f,fe->...e", u * u, w_down)
+
+
+def init_expert_weights(w, c: ModelConfig, seeded) -> dict:
+    """The expert layer's leaves. `w(shape, fan_in)` draws a weight;
+    `seeded(shape, scale)` one of a given spread (the router's bias)."""
+    d, E, f = c.d_model, c.moe_experts, c.moe_d_ff
+    gated = c.mlp_act == "swiglu"
+    lp = {"router": w((d, router_width(c)), d)}
+    if c.moe_score == "sigmoid":
+        # small and non-zero, so that the choice differs from the weights'
+        lp["router_bias"] = seeded((router_width(c),), 0.05)
+    if gated:
+        lp["wg"] = w((E, d, f), d)
+    lp.update(wu=w((E, d, f), d), wd=w((E, f, d), f))
+    if c.moe_shared_experts:
+        fs = shared_width(c)
+        if gated:
+            lp["shared_wg"] = w((d, fs), d)
+        lp.update(shared_wu=w((d, fs), d), shared_wd=w((fs, d), fs))
+    return lp
+
+
+def route(x, lp, c: ModelConfig):
+    """x [T, d] -> (weights [T, k] float32, expert ids [T, k] over the
+    router's published width)."""
+    k = c.moe_top_k
+    logits = jnp.einsum("td,dx->tx", x, lp["router"],
+                        preferred_element_type=jnp.float32)
+    biased = c.moe_score == "sigmoid"
+    if biased:
+        g = jax.nn.sigmoid(logits)
+        pick = g + lp["router_bias"].astype(jnp.float32)
+    else:
+        g = pick = jax.nn.softmax(logits, -1)
+    if c.moe_n_group > 1:
+        T, X = pick.shape
+        per = X // c.moe_n_group
+        _, best = jax.lax.top_k(pick.reshape(T, c.moe_n_group, per).max(-1),
+                                c.moe_topk_group)
+        keep = jax.nn.one_hot(best, c.moe_n_group, dtype=jnp.bool_).any(1)
+        pick = jnp.where(jnp.repeat(keep, per, axis=1), pick, 0.0)
+    w, idx = jax.lax.top_k(pick, k)
+    if biased:
+        w = jnp.take_along_axis(g, idx, axis=1)
+    if k > 1 and c.moe_norm_topk:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+        if c.moe_scale_normed:
+            w = w * c.moe_routed_scale
+    else:
+        w = w * c.moe_routed_scale
+    return w, idx
+
+
+def _grouped_mlp(rows, lp, sizes, c: ModelConfig):
+    if c.moe_grouped == "tiles":
+        return _grouped_mlp_tiles(rows, lp, sizes, c)
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
+    if c.mlp_act == "relu2":
+        u = jax.nn.relu(dot(rows, lp["wu"]))
+        return dot(u * u, lp["wd"])
+    act = jax.nn.silu(dot(rows, lp["wg"])) * dot(rows, lp["wu"])
+    return dot(act, lp["wd"])
+
+
+# `moe_grouped == "tiles"` (nemotron3_nano_30b's): no sort and no
+# ragged-dot. It is the only form that RUNS that model on the chip. With the
+# ragged-dot kernel in its 27-block programs the cell never got through
+# warm-up, twice (chip calls 23 and 24 of PR 33, exit 124): with the sort,
+# nothing in 1000 s; with the counted order below, ten programs compiled and
+# ran, then no sign of life for 8 minutes after the tenth had compiled -
+# where four chunked prompts continue together, the program that had hung
+# before over a gather of state rows (models/nemotron_h._load_rows). Cause
+# not established; each part runs alone. Two costs it also avoids, both
+# measured for PR 33. (1) Compile time: XLA's sort network over a
+# 4096-token program's 24,576 pairs took 18 s of its 25 s of described-chip
+# compile, in each of a cell's 12 prefill programs; the counted order
+# (`_order_by_count`) takes under 2 s. On `deepseek_v2`'s shapes the layer
+# alone runs 7.39 ms sorted and 7.76 ms counted at 4096 tokens, 0.53 both
+# at 8 (chip microbench, PR 33), so that model keeps the sort its cell was
+# measured with. (2) A decode step has few tokens: every held expert over
+# every token in one batched product reads each expert's weights once,
+# which is the step's whole cost, with no dispatch around it.
+# - many rows: the sorted rows in blocks of `_TILE_ROWS`; a block holds rows
+#   of a few experts, and the (block, expert) pairs that exist are walked in
+#   order (at most blocks + experts - 1 of them), each one product of the
+#   block with that expert's weights, kept where the row is the expert's;
+# - few tokens (decode): no dispatch at all, `held_dense`.
+_TILE_ROWS = 256      # rows a tile at 2048 rows a pass or more, else
+_SMALL_TILE_ROWS = 64
+_DENSE_ROWS = 4096    # tokens x held experts up to which every held expert
+#                       runs over every token (decode: 64 x 16)
+
+
+def _one_expert(x, lp, e, c: ModelConfig):
+    """Expert e's feed-forward of x [rows, d], its weights read where they
+    lie (a dynamic slice that XLA fuses into the product)."""
+    def w(name):
+        return jax.lax.dynamic_index_in_dim(lp[name], e, 0, keepdims=False)
+
+    if c.mlp_act == "relu2":
+        u = jax.nn.relu(jnp.dot(x, w("wu")))
+        return jnp.dot(u * u, w("wd"))
+    return jnp.dot(jax.nn.silu(jnp.dot(x, w("wg"))) * jnp.dot(x, w("wu")),
+                   w("wd"))
+
+
+def _grouped_mlp_tiles(rows, lp, sizes, c: ModelConfig):
+    """rows [R, d] sorted by expert, sizes [E] rows an expert (their sum at
+    most R) -> [R, d]; rows past the sum are left zero."""
+    R, d = rows.shape
+    tm = _TILE_ROWS if R >= 2048 else min(_SMALL_TILE_ROWS, R)
+    pad = -R % tm
+    if pad:
+        rows = jnp.pad(rows, [(0, pad), (0, 0)])
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm                                 # an expert's blocks
+    tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    tile_ends = jnp.cumsum(tiles)
+    E = sizes.shape[0]
+
+    def one_tile(t_out):
+        t, out = t_out
+        e = jnp.minimum(jnp.sum(t >= tile_ends), E - 1)
+        at = (first[e] + t - (tile_ends[e] - tiles[e])) * tm
+        y = _one_expert(jax.lax.dynamic_slice(rows, (at, 0), (tm, d)), lp, e,
+                        c)
+        r = at + jnp.arange(tm)
+        mine = (r >= starts[e]) & (r < ends[e])
+        old = jax.lax.dynamic_slice(out, (at, 0), (tm, d))
+        return t + 1, jax.lax.dynamic_update_slice(
+            out, jnp.where(mine[:, None], y.astype(out.dtype), old), (at, 0))
+
+    _, out = jax.lax.while_loop(
+        lambda t_out: t_out[0] < tile_ends[-1], one_tile,
+        (jnp.int32(0), jnp.zeros_like(rows)))
+    return out[:R]
+
+
+def held_dense(x, lp, c: ModelConfig, w, local, held):
+    """x [T, d], few tokens: every held expert over every token, weighted
+    by the router's weight where the token chose it (w, local, held
+    [T, k]) -> [T, d] float32. One batched product over the experts, the
+    stacked weights read once where they lie (transformer._moe's form):
+    all a decode step costs; no sort, no gather."""
+    if c.mlp_act == "relu2":
+        u = jax.nn.relu(jnp.einsum("td,edf->etf", x, lp["wu"]))
+        act = u * u
+    else:
+        act = (jax.nn.silu(jnp.einsum("td,edf->etf", x, lp["wg"]))
+               * jnp.einsum("td,edf->etf", x, lp["wu"]))
+    y = jnp.einsum("etf,efd->etd", act, lp["wd"])
+    gate = jnp.sum(
+        jnp.where(held[..., None] & (local[..., None]
+                                     == jnp.arange(c.moe_experts)),
+                  w[..., None], 0.0), axis=1)                     # [T, E]
+    return jnp.einsum("etd,te->td", y.astype(jnp.float32), gate)
+
+
+def shared_expert(x, lp, c: ModelConfig):
+    """x [T, d] -> the always-on expert's output [T, d]."""
+    if c.mlp_act == "relu2":
+        return relu2_mlp(x, lp["shared_wu"], lp["shared_wd"])
+    return swiglu(x[None], lp["shared_wg"], lp["shared_wu"],
+                  lp["shared_wd"])[0]
+
+
+def _order_by_sort(held, local, E: int):
+    """The held pairs sorted by expert (absent experts' pairs last), by a
+    stable sort of the T * k pairs -> (token_of(rows) for sorted rows,
+    where_sorted [T, k]: pair -> sorted row, counts [E], ends [E])."""
+    T, k = held.shape
+    key = jnp.where(held, local, E).reshape(-1)
+    order = jnp.argsort(key, stable=True)       # sorted row -> pair
+    where_sorted = jnp.argsort(order).reshape(T, k)  # pair -> sorted row
+    counts = jnp.sum(key[:, None] == jnp.arange(E)[None], axis=0,
+                     dtype=jnp.int32)
+    return (lambda rows: order[jnp.minimum(rows, T * k - 1)] // k,
+            where_sorted, counts, jnp.cumsum(counts))
+
+
+def _order_by_count(held, local, E: int):
+    """The same order without a sort: an expert's pairs are counted down
+    the tokens, so
+    a pair's row is its expert's start plus the count above it, and a
+    row's token is found back by a binary search down its expert's column
+    of counts."""
+    T = held.shape[0]
+    onehot = held[..., None] & (local[..., None] == jnp.arange(E))
+    above = jnp.cumsum(onehot.any(1), axis=0, dtype=jnp.int32)  # [T, E]
+    counts = above[-1]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    where_sorted = jnp.sum(
+        jnp.where(onehot, (starts + above - 1)[:, None, :], 0), axis=2)
+    flat_above = above.reshape(-1)
+
+    def token_of(rows):
+        col = jnp.minimum(
+            jnp.sum(rows[:, None] >= ends[None], axis=1), E - 1)
+        nth = rows - jnp.take(starts, col) + 1   # its expert's nth pair
+
+        def halve(_, lo_hi):
+            lo, hi = lo_hi
+            mid = (lo + hi) // 2
+            right = jnp.take(flat_above,
+                             jnp.minimum(mid, T - 1) * E + col) < nth
+            return jnp.where(right, mid + 1, lo), jnp.where(right, hi, mid)
+
+        lo, _ = jax.lax.fori_loop(
+            0, T.bit_length(), halve,
+            (jnp.zeros_like(rows), jnp.full_like(rows, T)))
+        return jnp.minimum(lo, T - 1)   # past the held pairs: any row
+
+    return token_of, where_sorted, counts, ends
+
+
+def expert_layer(x, lp, c: ModelConfig, valid):
+    """x [T, d] (normed), valid [T] bool (padding routes nowhere) ->
+    (held experts' part + shared experts [T, d], stats [N_STATS + E])."""
+    T, d = x.shape
+    E, k = c.moe_experts, c.moe_top_k
+    with jax.named_scope("expert_layer"):
+        w, idx = route(x, lp, c)
+        local = idx - c.moe_held_group * E
+        held = (local >= 0) & (local < E) & valid[:, None]      # [T, k]
+        dense = c.moe_grouped == "tiles" and T * E <= _DENSE_ROWS
+        if dense:
+            counts = jnp.sum(
+                held[..., None] & (local[..., None] == jnp.arange(E)),
+                axis=(0, 1), dtype=jnp.int32)
+            ends = jnp.cumsum(counts)
+        elif c.moe_grouped == "tiles":
+            token_of, where_sorted, counts, ends = _order_by_count(
+                held, local, E)
+        else:
+            # pairs sorted by held expert; pairs of absent experts sort last
+            token_of, where_sorted, counts, ends = _order_by_sort(
+                held, local, E)
+        n_held = ends[-1]
+        rows_a_pass = min(T * k, max(T, _MIN_PASS_ROWS))
+
+        def one_pass(start, y):
+            rows = jnp.take(x, token_of(start + jnp.arange(rows_a_pass)),
+                            axis=0)
+            sizes = (jnp.clip(ends, start, start + rows_a_pass)
+                     - jnp.clip(ends - counts, start, start + rows_a_pass))
+            out = _grouped_mlp(rows, lp, sizes, c)
+            rel = where_sorted - start
+            here = held & (rel >= 0) & (rel < rows_a_pass)
+            for j in range(k):      # a row gather a choice; no scatter
+                got = jnp.take(out, jnp.clip(rel[:, j], 0, rows_a_pass - 1),
+                               axis=0)
+                y = y + jnp.where(here[:, j, None],
+                                  got.astype(jnp.float32) * w[:, j, None], 0)
+            return y
+
+        y = jnp.zeros((T, d), jnp.float32)
+        if dense:
+            y = held_dense(x, lp, c, w, local, held)
+        elif rows_a_pass == T * k:
+            y = one_pass(0, y)
+        else:
+            _, y = jax.lax.while_loop(
+                lambda sy: sy[0] < n_held,
+                lambda sy: (sy[0] + rows_a_pass, one_pass(sy[0], sy[1])),
+                (jnp.int32(0), y))
+        y = y.astype(x.dtype)
+        if c.moe_shared_experts:
+            y = y + shared_expert(x, lp, c)
+        stats = jnp.concatenate([jnp.stack([
+            jnp.sum(valid, dtype=jnp.int32), n_held,
+            jnp.sum(valid & ~held.any(1), dtype=jnp.int32),
+            jnp.int32(1)]), counts])
+    return y, stats

@@ -70,15 +70,50 @@ class ModelConfig:
     moe_topk_group: int = 1         # and how many of them a token may use
     moe_routed_scale: float = 1.0   # routed_scaling_factor
     moe_norm_topk: bool = True      # renormalise the top-k weights
+    # --- what models/nemotron_h.py reads besides (layer_pattern != "") ---
+    # One mixer a block, a letter a block: "M" a Mamba-2 mixer, "E" the
+    # expert layer, "*" attention (`n_layers` letters; "" = every layer
+    # is attention then feed-forward, the two modules above).
+    layer_pattern: str = ""
+    head_size: int = 0              # stated head size; 0 = d_model / heads
+    rotary: bool = True             # False: attention rotates nothing
+    ssm_heads: int = 0              # Mamba-2: heads, each ssm_head_dim
+    ssm_head_dim: int = 0           # wide, over a state of ssm_state
+    ssm_state: int = 0              # numbers a head and channel;
+    ssm_groups: int = 1             # B and C are shared by heads in groups
+    ssm_conv_width: int = 4         # taps of the causal convolution
+    ssm_chunk: int = 128            # rows a step of the prefill scan
+    ssm_state_dtype: str = "float32"   # of the recurrent state a sequence
+    #                                    keeps between tokens
+    mlp_act: str = "swiglu"         # "relu2": W_down relu(W_up x)^2, no gate
+    moe_score: str = "softmax"      # "sigmoid": scores are sigmoids, and a
+    #                                 learned bias joins them in the CHOICE
+    moe_scale_normed: bool = False  # x moe_routed_scale after renormalising
+    #                                 too (False: only where not renormalised)
+    moe_shared_d_ff: int = 0        # the shared expert's width; 0 =
+    #                                 moe_shared_experts * moe_d_ff
+    # The held experts' grouped product: "ragged_dot" (a stable sort, XLA's
+    # kernel: deepseek_v2's), or "tiles" (counted order, plain products
+    # over [block of rows, expert] tiles, every held expert over every
+    # token where tokens are few). Not a choice of taste: with "ragged_dot"
+    # the 27-block hybrid programs of models/nemotron_h.py HANG the v5e in
+    # warm-up (models/experts.py has the chip runs), so "tiles" is the one
+    # form that runs that model, and deepseek_v2's cell was only ever
+    # measured on the other.
+    moe_grouped: str = "ragged_dot"
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
 
     @property
     def kv_cache(self) -> str:
-        """What the serving engine pages: "per_head" K and V, or the
-        "latent" (normed c_kv | rotated k_pe) of latent attention."""
+        """What a sequence keeps in the serving engine: "per_head" K and V
+        pages, the "latent" (normed c_kv | rotated k_pe) pages of latent
+        attention, or "recurrent": per-head pages for the attention layers
+        beside one fixed-size row of state for the Mamba layers."""
+        if self.layer_pattern:
+            return "recurrent"
         return "latent" if self.attention == "mla" else "per_head"
 
     @property

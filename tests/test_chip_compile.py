@@ -158,6 +158,23 @@ def _gqa_prefill(h, hkv, n, s, pre_t):
     return build
 
 
+def _ssm_update(topo):
+    """The decode state update at nemotron3_nano_30b's widths and the
+    reasoning cell's pool: 64 heads of [64, 128] float32 a row, 64 slots
+    of 81 rows, 8 groups."""
+    from ray_tpu.ops import ssm
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    return (lambda pool, x, dt, a, b, c, act: ssm.ssm_state_update(
+        pool, x, dt, a, b, c, act, layer=3, impl="pallas")), (
+        sds((12, 81, 64, 64, 128)), sds((64, 64, 64), jnp.bfloat16),
+        sds((64, 64)), sds((64,)), sds((64, 8, 128), jnp.bfloat16),
+        sds((64, 8, 128), jnp.bfloat16), sds((64,), jnp.bool_))
+
+
 CASES = {
     "flash_fwd_2x2048": _flash((2, 2048), grad=False),
     "flash_bwd_2x2048": _flash((2, 2048), grad=True),
@@ -175,6 +192,7 @@ CASES = {
     "gqa_prefill_mixtral_8x7b_1x1024": _gqa_prefill(32, 8, 1, 1024, 0),
     "gqa_prefill_mixtral_8x7b_4x256_over_prefix": _gqa_prefill(32, 8, 4, 256,
                                                                1024),
+    "ssm_state_update_nemotron3_nano_30b": _ssm_update,
 }
 
 
@@ -234,6 +252,50 @@ def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
     assert pool_sized.findall(text) == []
     pool_bytes = layers * HKV * n_pages * HD * PAGE * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+def test_hybrid_decode_leaves_its_pools_where_they_lie(topo, monkeypatch):
+    """`models/nemotron_h.decode_paged` over both kinds of pool at
+    nemotron3_nano_30b's widths (its first four blocks, "MEM*", this
+    chip's 16 experts, 64 slots, 81 rows, the cell's 2048 pages), every
+    pool donated:
+    the state pool is touched by the named kernel alone, and no `copy`,
+    `scatter` or `slice` yields it, a K/V pool or a layer of one. (The
+    window pool is 6 MB here and the compiler parks so small a buffer in
+    faster memory; at the cell's 12 layers it stays put, PERF.md section
+    5.)"""
+    from ray_tpu.models import configs, init_params, nemotron_h as nh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    c = configs.nemotron3_nano_30b(
+        n_layers=4, layer_pattern="MEM*", vocab=16384, moe_experts=16)
+    slots, rows, n_pages = 64, 81, 2048
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    params = placed(jax.eval_shape(
+        lambda: init_params(c, jax.random.PRNGKey(0))))
+    pools = placed(nh.page_pools(c, n_pages, PAGE) + nh.row_pools(c, rows))
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    text = jax.jit(partial(nh.decode_paged, config=c),
+                   donate_argnums=(1, 2, 3, 4)).lower(
+        params, *pools, sds((slots,)), sds((slots,)),
+        sds((slots,), jnp.bool_), sds((slots, P_SEQ)),
+        sds((nh.N_STATS + 16,))).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+    state = r"f32\[(?:2,)?%d,64,64,128\]" % rows
+    made_by = set(re.findall(r"= \(?%s\S* ([a-z\-]+)\(" % state, text))
+    assert made_by <= {"parameter", "custom-call", "get-tuple-element"}
+    moved = re.compile(
+        r"= (?:%s|bf16\[(?:1,)?2,%d,128,128\])"
+        r"\S* (copy|scatter|slice)[-(]" % (state, n_pages))
+    assert moved.findall(text) == []
 
 
 @pytest.mark.parametrize("program", ["prefill_batch",

@@ -15,7 +15,8 @@ import pytest
 
 from ray_tpu.llm import EngineConfig, InferenceEngine
 from ray_tpu.llm.engine import PrefillEngine
-from ray_tpu.models import configs, deepseek_v2 as ds, forward, init_params
+from ray_tpu.models import (configs, deepseek_v2 as ds, experts, forward,
+                            init_params)
 from ray_tpu.ops import latent_attention as la
 from ray_tpu.ops.attention import prefill_attention_reference
 from ray_tpu.ops.layers import rope, yarn_frequencies
@@ -126,7 +127,7 @@ def test_dispatch_in_passes_equals_one_pass(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(2), (48, c.d_model))
     valid = jnp.arange(48) < 40
     one, s1 = ds.expert_layer(x, lp, c, valid)
-    monkeypatch.setattr(ds, "_MIN_PASS_ROWS", 8)     # 48 rows a pass of 144
+    monkeypatch.setattr(experts, "_MIN_PASS_ROWS", 8)    # 48 rows a pass of 144
     many, s2 = ds.expert_layer(x, lp, c, valid)
     np.testing.assert_allclose(many, one, atol=TOL)
     np.testing.assert_array_equal(s1, s2)
