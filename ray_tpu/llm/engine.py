@@ -130,6 +130,21 @@ class Request:
         self.n_prompt = len(self.prompt)
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A decode step that was dispatched and not fetched yet
+    (InferenceEngine.step). The host's view (`lengths`, `active`,
+    `last_tokens`, each request's `generated`) is the one BEFORE it until
+    `_land` fetches the tokens."""
+    tokens: object      # [B] int32 on the device: the token every slot drew
+    logps: object       # [B] log p(token) on the device; None if none asked
+    active: np.ndarray  # [B] bool: the slots that decoded in it
+    reqs: list          # [B] whose slots they were when it was dispatched
+    ends: np.ndarray    # [B] bool: its token is the slot's last, by
+    #                     max_new_tokens or max_len (eos_token is known only
+    #                     once the token is fetched)
+
+
 # ---------------- pure model steps ----------------
 
 
@@ -873,8 +888,11 @@ class InferenceEngine:
         self._prefill_pre: dict[tuple, object] = {}
         self._window_fns: dict[tuple, object] = {}
         self._win_buckets = (1, 2, 4, 8, 16, 32, 64)
-        # Device-resident decode state (uploaded only when the host
-        # view changed): a per-step upload is a host sync like a
+        self._flight: _Flight | None = None  # step()'s step in the air
+        self.decode_steps = 0        # dispatched by step()
+        self.decode_steps_ahead = 0  # ... before the step before was fetched
+        # Device-resident decode state of the windows (uploaded only when
+        # the host view changed): a per-step upload is a host sync like a
         # download.
         self._dev = None           # (tokens, lengths, active) on device
         self._dev_dirty = True
@@ -1028,7 +1046,13 @@ class InferenceEngine:
             self._dev_dirty = True
 
     def has_work(self) -> bool:
-        return bool(self.queue) or bool(self.active.any())
+        """Whether step() has anything left to do or to hand over. True
+        while a decode step is in flight (its slots stay `active` until
+        step() has fetched their tokens; the last one may have ended on
+        eos_token under a step that led it), so a loop over has_work()
+        ends with nothing in the air."""
+        return (bool(self.queue) or bool(self.active.any())
+                or self._flight is not None)
 
     # ---- scheduling ----
 
@@ -1247,7 +1271,11 @@ class InferenceEngine:
         pending: list[tuple] = []  # (slot, req, last-logits row) to sample
         e = self.e
         page = e.page_size
-        free = [i for i in range(e.max_slots) if not self.active[i]]
+        # No slot is given out under a decode step in flight (step() lands
+        # it first when a request could be admitted): the slot state below
+        # is that step's until its tokens are fetched.
+        free = [] if self._flight is not None else [
+            i for i in range(e.max_slots) if not self.active[i]]
         # Phase 1 — host-side planning: pop requests, match prefixes,
         # allocate pages. No device work yet, so a whole admission burst
         # can share one batched prefill dispatch below (one dispatch and
@@ -1495,6 +1523,10 @@ class InferenceEngine:
             "preemptions": self.preemptions,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
+            # step()'s decode steps, and those of them dispatched before
+            # the step before was fetched (the rest waited for the host)
+            "decode_steps": self.decode_steps,
+            "decode_steps_ahead": self.decode_steps_ahead,
             # recurrent state (zeros for a model that keeps none): a row a
             # running sequence; snapshots held, of them pinned by an
             # admission under way; prefix hits that resumed from one
@@ -1555,21 +1587,86 @@ class InferenceEngine:
             self._release_slot(slot)
 
     def step(self) -> dict[int, int]:
-        """Admit queued prompts, run one decode step; returns
-        {request_id: token} for tokens emitted this step (prefill's first
-        token included)."""
-        emitted = self._admit()
-        if not self.active.any():
-            return emitted
-        logits = self._decode_paged_step()
-        if logits is None:  # every active slot was preempted
-            return emitted
-        tokens, logps = self._sample_rows(logits, self.slot_req)
-        for i in range(self.e.max_slots):
-            if not self.active[i]:
+        """Admit queued prompts, dispatch one decode step, fetch the one
+        before; returns {request_id: token}, one token a streaming request.
+
+        The decode loop runs one step ahead of the host: the step this
+        call dispatches stays IN FLIGHT when it returns, and a token it
+        returns was drawn by the step the call before dispatched (a
+        caller that counts tokens a call sees them one call later; one
+        that loops over has_work() misses none). Step N + 1 is dispatched
+        from the tokens N left on the device before N's are fetched, so
+        the fence on N, the loop over the slots, the caller's fan-out and
+        the next call's page tables all pass while the device works. What
+        N + 1 needs of N without its tokens the host knows: every length
+        is one more, and a slot that N ends by max_new_tokens or max_len
+        sits out. An end on eos_token shows only at the fetch: N + 1 then
+        ran that row once too often, and `_land` throws its token away.
+
+        The step before is fetched FIRST, and the next one dispatched from
+        the host's view (in step, the device waiting as long), where
+        `_may_lead` says why. A prompt's first token is returned by the
+        call that admits it only if no decode step follows it there: the
+        stream has never carried it otherwise (ROADMAP D11)."""
+        emitted = {} if self._may_lead() else self._land()
+        admitted = self._admit()
+        flight = self._decode_paged_step()
+        emitted.update(self._land())
+        self._flight = flight
+        led = set() if flight is None else {
+            r.request_id for r in flight.reqs if r is not None}
+        emitted.update({rid: tok for rid, tok in admitted.items()
+                        if rid not in led})
+        return emitted
+
+    def _may_lead(self) -> bool:
+        """Whether the next decode step may be dispatched before the one
+        in flight is fetched (True too with nothing in flight). Not when
+        the host needs that step's tokens, or its slots, first: a cancel
+        takes a slot; a request is queued and a slot is free or about to
+        be (an admission moves slots and draws the new slot's first token
+        on the host); a guide's mask for the next token follows from this
+        one; speculation keeps the token history whole on the host; or
+        the pool cannot grow every slot its next page, and a victim of
+        preemption is requeued with all its tokens."""
+        f = self._flight
+        if f is None:
+            return True
+        if self._cancel_rids or self._spec or (
+                self.queue and (f.ends.any() or not self.active.all())):
+            return False
+        if any(r is not None and r.guide is not None for r in self.slot_req):
+            return False
+        short = self._pages_short(self.lengths + f.active,
+                                  self.active & ~f.ends)
+        return len(short) <= len(self.free_pages) + len(self.cached_lru)
+
+    def _land(self) -> dict[int, int]:
+        """Fetch the tokens of the step in flight (the one host fence a
+        token) and move the host's view past it; {} with nothing in
+        flight."""
+        f, self._flight = self._flight, None
+        if f is None:
+            return {}
+        tokens = np.asarray(f.tokens)
+        logps = None if f.logps is None else np.asarray(f.logps)
+        emitted: dict[int, int] = {}
+        for i in np.flatnonzero(f.active):
+            req = f.reqs[i]
+            if self.slot_req[i] is not req:
+                # The slot ended on eos_token (or was cancelled) at the
+                # step before, which this one led: its row ran once too
+                # often, and the token is no part of the request. What the
+                # row wrote, a K/V column in a page that was the slot's
+                # own (never a shared prompt page: those lie below the
+                # first generated position) and the slot's own row of
+                # state, nobody else sees: pages and rows are handed out
+                # on the host only after this fetch's step was dispatched,
+                # so every program that writes them for their next owner
+                # runs after it on the device, and a reader is masked to
+                # the positions its owner wrote.
                 continue
             tok = int(tokens[i])
-            req = self.slot_req[i]
             if req.logprobs:
                 req.token_logprobs.append(float(logps[i]))
             req.generated.append(tok)
@@ -1623,14 +1720,47 @@ class InferenceEngine:
             self._dev_dirty = True
         return bool(self.active.any())
 
-    def _decode_paged_step(self):
-        """Grow pages for slots whose next token starts a fresh page
-        (preempting if the pool is dry), build the bucketed page tables,
-        and run the decode jit for that bucket. Returns logits or None if
-        preemption drained every active slot."""
-        if not self._grow_pages(1):
-            return None
-        tables = self._build_tables()
+    def _pages_short(self, lengths, active) -> list[int]:
+        """The slots of `active` whose token at `lengths` starts a page
+        they do not hold yet."""
+        page, last = self.e.page_size, self.e.max_len - 1
+        return [i for i in np.flatnonzero(active)
+                if min(int(lengths[i]), last) // page
+                >= len(self.slot_pages[i])]
+
+    def _decode_paged_step(self) -> _Flight | None:
+        """Dispatch one decode step and its sampler and fetch nothing: ->
+        the step in flight, or None if no slot decodes.
+
+        With nothing in flight the step runs IN STEP with the host: pages
+        grow for slots whose next token starts a fresh page (preempting
+        if the pool is dry) and the tokens are the host's. With a step in
+        flight (`_may_lead` allowed it) this one runs AHEAD of it: its
+        tokens are that step's, where the sampler left them on the device
+        (the same shape and dtype as the host's upload: one program
+        either way), its lengths that step's plus one, and the pool has
+        the pages (`_may_lead` counted them). Every upload is an array of
+        its own: the program may run after the host has moved on, and on
+        the CPU backend jnp.asarray can alias the host's buffer."""
+        e, prev = self.e, self._flight
+        if prev is None:
+            if not self._grow_pages(1):
+                return None
+            lengths, active = self.lengths.copy(), self.active.copy()
+            tokens = jnp.asarray(self.last_tokens.copy())
+        else:
+            lengths = self.lengths + prev.active
+            active = self.active & ~prev.ends
+            if not active.any():
+                return None
+            for i in self._pages_short(lengths, active):
+                pid = self._alloc_page()
+                self.page_refs[pid] = 1
+                self.slot_pages[i].append(pid)
+            tokens = prev.tokens
+            self.decode_steps_ahead += 1
+        self.decode_steps += 1
+        tables = self._build_tables(active)
         p_bucket = tables.shape[1]
         pools = self._pools()
         n_donated = len(pools) + len(self.rows)
@@ -1644,24 +1774,37 @@ class InferenceEngine:
             self._decode_paged[p_bucket] = fn
         stats = () if self._moe_acc is None else (self._moe_acc,)
         logits, *out = fn(
-            self.params, *pools, *self.rows, jnp.asarray(self.last_tokens),
-            jnp.asarray(self.lengths), jnp.asarray(self.active),
-            jnp.asarray(tables), *stats)
+            self.params, *pools, *self.rows, tokens, jnp.asarray(lengths),
+            jnp.asarray(active), jnp.asarray(tables), *stats)
         if stats:
             self._moe_acc = out.pop()
         self._set_pools(out[:len(pools)])
         self.rows = tuple(out[len(pools):])
-        return logits
+        reqs = [r if active[i] else None
+                for i, r in enumerate(self.slot_req)]
+        # what the host knows of this step's end without its tokens: a
+        # slot's count of tokens after it (one more is in flight when
+        # this step leads), and the length its sequence reaches
+        ends = np.array([
+            r is not None and (
+                len(r.generated) + (prev is not None) + 1 >= r.max_new_tokens
+                or lengths[i] + 2 >= e.max_len)
+            for i, r in enumerate(reqs)])
+        return _Flight(*self._sample_dispatch(logits, reqs), active, reqs,
+                       ends)
 
-    def _build_tables(self) -> np.ndarray:
+    def _build_tables(self, active=None) -> np.ndarray:
+        """Page tables [B, bucket] of the `active` slots (the host's view
+        of them unless given)."""
         e = self.e
+        active = self.active if active is None else active
         p_need = max(
             (len(self.slot_pages[i]) for i in range(e.max_slots)
-             if self.active[i]), default=1)
+             if active[i]), default=1)
         p_bucket = next(b for b in self._page_buckets if b >= p_need)
         tables = np.zeros((e.max_slots, p_bucket), np.int32)
         for i in range(e.max_slots):
-            if self.active[i]:
+            if active[i]:
                 row = self.slot_pages[i][:p_bucket]
                 tables[i, :len(row)] = row
         return tables
@@ -1712,11 +1855,11 @@ class InferenceEngine:
              else 0 for r in reqs], jnp.int32)
         return True, self._dev_gtables, states
 
-    def _sample_rows(self, logits, reqs) -> tuple:
+    def _sample_dispatch(self, logits, reqs) -> tuple:
         """One token a row of `logits` [len(reqs), vocab] by that row's
         request (None = an empty slot: greedy, untruncated, unguided) ->
         (tokens, log p(token) a row, or None where no request asks for
-        it), on the host after ONE fence (a second for the logprobs)."""
+        it), both left on the device: nothing is fetched."""
         temps, top_ps, top_ks = _sampling_of(reqs)
         self._key, sub = jax.random.split(self._key)
         mask = None
@@ -1732,13 +1875,17 @@ class InferenceEngine:
             toks = self._sample_trunc(
                 logits, jnp.asarray(temps), sub, jnp.asarray(top_ps),
                 jnp.asarray(top_ks), mask)
-        toks = np.asarray(toks)
         logps = None
         if any(r is not None and r.logprobs for r in reqs):
-            logps = np.asarray(jnp.take_along_axis(
-                jax.nn.log_softmax(logits, axis=-1),
-                jnp.asarray(toks)[:, None], 1)[:, 0])
+            logps = jnp.take_along_axis(
+                jax.nn.log_softmax(logits, axis=-1), toks[:, None], 1)[:, 0]
         return toks, logps
+
+    def _sample_rows(self, logits, reqs) -> tuple:
+        """`_sample_dispatch`, fetched: on the host after ONE fence (a
+        second for the logprobs)."""
+        toks, logps = self._sample_dispatch(logits, reqs)
+        return np.asarray(toks), None if logps is None else np.asarray(logps)
 
     @staticmethod
     def _advance_guide(req: Request, tok: int):
@@ -1977,7 +2124,8 @@ class InferenceEngine:
         """Admit queued prompts, then decode a whole window."""
         if self._own:
             _refuse(self.c, "step_window() (decode_window); call step()")
-        emitted = self._admit()
+        emitted = self._land()   # a caller may mix it with step()
+        emitted.update(self._admit())
         if self.active.any():
             upd = (self._run_window_spec() if self._spec_applicable()
                    else None)

@@ -1,0 +1,380 @@
+"""InferenceEngine.step() runs its decode loop one step ahead of the host
+(llm/engine.py `_Flight`, `_may_lead`, `_land`): every request's tokens and
+log-probabilities against an engine that fetches a step before it
+dispatches the next, for a per-head, a latent and a hybrid model, on the
+CPU at tiny sizes; and the lowered text of the serving programs, which the
+change of schedule must not touch.
+"""
+
+import functools
+import hashlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import EngineConfig, InferenceEngine
+from ray_tpu.llm.guided import TokenGuide
+from ray_tpu.models import configs
+
+pytestmark = pytest.mark.heavy
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MODELS = {
+    "per_head": configs.tiny(),
+    "latent": configs.tiny_mla(moe_experts=2, moe_held_group=1),
+    "hybrid": configs.tiny_hybrid(moe_experts=2, moe_held_group=1),
+}
+KINDS = sorted(MODELS)
+# float32 on both sides; a request that was preempted at another token
+# re-prefills another length, and sums in another order
+TOL = 2e-5
+
+
+@functools.lru_cache(maxsize=None)
+def _params(kind):
+    return InferenceEngine(MODELS[kind], EngineConfig(
+        max_slots=1, max_len=32, page_size=16, prompt_buckets=(16,)),
+        seed=3).params
+
+
+def _engine(kind, ahead=True, **kw):
+    e = dict(max_slots=3, max_len=160, page_size=16, prompt_buckets=(16, 32),
+             eos_token=-1)
+    eng = InferenceEngine(MODELS[kind], EngineConfig(**{**e, **kw}),
+                          params=_params(kind))
+    if not ahead:
+        # the reference loop: every step is fetched before the next is
+        # dispatched, from the tokens the host holds
+        eng._may_lead = lambda: False
+    return eng
+
+
+def _ids(n, seed):
+    return [int(t) for t in np.random.RandomState(seed).randint(0, 256, n)]
+
+
+def _drive(eng, script, watch=None):
+    """Call step() until nothing is left; `script` {call: [fn(eng)]} runs
+    before that call (an `add` keeps its Request in `reqs`); `watch(eng)`
+    after every call. -> the requests, in the order they came."""
+    reqs, k = [], 0
+    while eng.has_work() or any(c >= k for c in script):
+        for fn in script.get(k, ()):
+            r = fn(eng)
+            if r is not None:
+                reqs.append(eng.request(r))
+        eng.step()
+        if watch is not None:
+            watch(eng)
+        k += 1
+        assert k < 2000
+    assert eng._flight is None
+    return reqs
+
+
+def add(n_prompt, new, seed, **kw):
+    return lambda eng: eng.add_request(_ids(n_prompt, seed), new, 0.0,
+                                       logprobs=True, **kw)
+
+
+def _same(got, want, exact=True):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.done and w.done
+        assert g.generated == w.generated
+        assert len(g.token_logprobs) == len(g.generated)
+        if exact:
+            assert g.token_logprobs == w.token_logprobs
+        else:
+            np.testing.assert_allclose(g.token_logprobs, w.token_logprobs,
+                                       atol=TOL)
+
+
+# ---------------------------------------------- ahead = fetched-then-dispatched
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_admissions_mid_stream_and_ends_by_count(kind):
+    """Requests join a running batch (one into a free slot, one that waits
+    for a slot to end by max_new_tokens), and one runs into max_len."""
+    script = {0: [add(10, 12, 1), add(20, 5, 2)], 4: [add(27, 9, 3)],
+              6: [add(12, 20, 4), add(30, 200, 5)]}
+    got, want = (_drive(_engine(kind, ahead), script)
+                 for ahead in (True, False))
+    _same(got, want)
+    assert [len(r.generated) for r in got] == [12, 5, 9, 20, 160 - 30]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_plain_greedy_traffic_runs_ahead(kind):
+    """One burst, long outputs: all but the step after the admission are
+    dispatched before the step before is fetched."""
+    eng = _engine(kind)
+    reqs = _drive(eng, {0: [add(10, 40, 1), add(14, 40, 2), add(9, 40, 3)]})
+    assert [len(r.generated) for r in reqs] == [40, 40, 40]
+    st = eng.kv_stats()
+    assert st["decode_steps"] == 39
+    assert st["decode_steps_ahead"] / st["decode_steps"] >= 0.9
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_has_work_while_a_token_is_in_flight(kind):
+    eng = _engine(kind)
+    req = eng.request(eng.add_request(_ids(10, 1), 3, 0.0))
+    assert eng.step() == {}        # the first token is not streamed (D11)
+    assert eng._flight is not None and len(req.generated) == 1
+    calls = 1
+    while eng.has_work():
+        assert eng._flight is not None
+        assert len(eng.step()) == 1  # a call later than it was dispatched
+        calls += 1
+    assert eng._flight is None and req.done
+    assert calls == 3 == len(req.generated)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_an_end_on_eos_with_a_step_in_flight(kind):
+    """A request ends on eos_token while the step after is in the air: that
+    step's token for it is thrown away, the request that keeps running and
+    the one that takes over the slot and its pages read nothing of it."""
+    script = {0: [add(10, 30, 1), add(22, 25, 2)], 2: [add(12, 10, 3)]}
+    probe = _drive(_engine(kind, ahead=False, max_slots=2), script)
+    others = set(probe[1].generated + probe[2].generated)
+    cut, eos = next((k, t) for k, t in enumerate(probe[0].generated)
+                    if k >= 2 and t not in others
+                    and t not in probe[0].generated[:k])
+    overshot, pages = {}, {}
+
+    def watch(eng):
+        a, b = (eng.request(rid) for rid in (0, 1))
+        if a.done and id(eng) not in overshot:
+            f = eng._flight
+            overshot[id(eng)] = (f is not None and bool(f.active[a.slot])
+                                 and f.reqs[a.slot] is a)
+        if not b.done:
+            pages[id(eng)] = list(eng.slot_pages[b.slot])
+
+    engines = [_engine(kind, ahead, max_slots=2, eos_token=eos)
+               for ahead in (True, False)]
+    got, want = (_drive(eng, script, watch) for eng in engines)
+    assert [overshot[id(eng)] for eng in engines] == [True, False]
+    _same(got, want)
+    assert got[0].generated == probe[0].generated[:cut + 1]
+    assert [r.generated for r in got[1:]] == [r.generated for r in probe[1:]]
+    # the other request's pages, and its row of state, hold the same
+    a, r = engines
+    axes = [s.shape.index(977) for s in a.serving.page_pools(a.c, 977, 16)]
+    for pa, pr, ax in zip(a._pools(), r._pools(), axes):
+        np.testing.assert_array_equal(
+            np.take(np.asarray(pa), pages[id(a)], axis=ax),
+            np.take(np.asarray(pr), pages[id(r)], axis=ax))
+    if kind == "hybrid":
+        assert got[1].slot == want[1].slot
+        for sa, sr in zip(a.state_rows([got[1].slot]),
+                          r.state_rows([want[1].slot])):
+            np.testing.assert_array_equal(sa, sr)
+    assert a.kv_stats()["pages_in_use"] == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_last_request_ends_on_eos_under_a_step_that_led_it(kind):
+    """Nothing is active any more and a step is still in the air, every row
+    of it one too many: has_work() holds until it is fetched."""
+    script = {0: [add(10, 30, 1)]}
+    gen = _drive(_engine(kind, ahead=False), script)[0].generated
+    cut, eos = next((k, t) for k, t in enumerate(gen)
+                    if k >= 2 and t not in gen[:k])
+    eng = _engine(kind, eos_token=eos)
+    req = eng.request(script[0][0](eng))
+    while not req.done:
+        eng.step()
+    assert eng._flight is not None and not eng.active.any()
+    assert eng.has_work()
+    assert eng.step() == {} and not eng.has_work()
+    assert req.generated == gen[:cut + 1]
+    assert eng.kv_stats()["decode_steps"] == cut + 1
+    assert eng.kv_stats()["pages_in_use"] == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_preemption_on_a_tiny_pool(kind):
+    """Three usable pages for two requests that need two each: the step
+    that finds the pool dry is fetched first, and the victim is requeued
+    with every token it drew."""
+    script = {0: [add(10, 20, 1), add(10, 20, 2)]}
+    engines = [_engine(kind, ahead, max_slots=2, num_pages=4)
+               for ahead in (True, False)]
+    got, want = (_drive(eng, script) for eng in engines)
+    for eng in engines:
+        assert eng.kv_stats()["preemptions"] >= 1
+    _same(got, want, exact=False)
+    assert [len(r.generated) for r in got] == [20, 20]
+
+
+def _guide(vocab):
+    """A token may not repeat the last one's residue mod 3: the mask for
+    token N + 1 follows from token N."""
+    tok = np.arange(vocab)
+    table = np.stack([np.where(tok % 3 == s, -1, tok % 3)
+                      for s in range(3)]).astype(np.int32)
+    return TokenGuide(table=table, pattern="residues")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_guided_request_runs_in_step(kind):
+    guide = _guide(MODELS[kind].vocab)
+    script = {0: [add(10, 8, 1, guide=guide), add(16, 20, 2)]}
+    seen = []
+
+    def watch(eng):
+        if any(r is not None and r.guide for r in eng.slot_req):
+            seen.append(eng.kv_stats()["decode_steps_ahead"])
+
+    engines = [_engine(kind, ahead) for ahead in (True, False)]
+    got, want = _drive(engines[0], script, watch), _drive(engines[1], script)
+    _same(got, want)
+    res = [t % 3 for t in got[0].generated]
+    assert res[0] != 0 and all(a != b for a, b in zip(res, res[1:]))
+    assert seen and set(seen) == {0}
+    # ... and the plain request goes ahead again once the guide has ended
+    assert engines[0].kv_stats()["decode_steps_ahead"] >= 10
+    assert engines[1].kv_stats()["decode_steps_ahead"] == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_cancel_with_a_step_in_flight(kind):
+    """The step in the air is fetched before the slot is taken: the
+    cancelled request keeps that token, the other one runs on."""
+    def cancel(eng):
+        assert eng._flight is not None and eng._flight.active[0]
+        eng.cancel(0)
+
+    script = {0: [add(10, 30, 1), add(20, 15, 2)], 5: [cancel]}
+    engines = [_engine(kind, ahead) for ahead in (True, False)]
+    got, want = (_drive(eng, script) for eng in engines)
+    _same(got, want)
+    assert len(got[0].generated) == 6 and len(got[1].generated) == 15
+    for eng in engines:
+        assert eng.kv_stats()["pages_in_use"] == 0
+
+
+def test_no_slot_is_given_out_under_a_step_in_flight():
+    """What keeps a cancel that arrives between step()'s look at the queue
+    and `_admit` from moving a slot whose step is still in the air."""
+    eng = _engine("per_head")
+    eng.add_request(_ids(10, 1), 8, 0.0)
+    eng.step()
+    queued = eng.add_request(_ids(12, 2), 4, 0.0)
+    assert eng._flight is not None and not eng.active.all()
+    assert eng._admit() == {} and [r.request_id for r in eng.queue] == [queued]
+    eng.step()                      # lands first, then admits
+    assert not eng.queue and eng.active.sum() == 2
+
+
+def test_a_first_token_that_ends_its_request_is_returned():
+    """ROADMAP D11 as it stands: the stream misses a first token only where
+    a decode step follows it in the admitting call."""
+    eng = _engine("per_head")
+    rid = eng.add_request(_ids(10, 1), 1, 0.0)
+    out = eng.step()
+    assert out == {rid: eng.finished[rid].generated[0]}
+    assert not eng.has_work() and eng.kv_stats()["decode_steps"] == 0
+
+
+def test_step_window_fetches_what_step_left_in_flight():
+    eng = _engine("per_head")
+    req = eng.request(eng.add_request(_ids(10, 1), 6, 0.0))
+    want = _drive(_engine("per_head", ahead=False), {0: [add(10, 6, 1)]})[0]
+    eng.step()
+    eng.step()
+    assert eng._flight is not None
+    while eng.has_work():
+        eng.step_window()
+    assert eng._flight is None and req.generated == want.generated
+
+
+# ------------------------------------------- the programs are the parent's
+
+# sha256 of the lowered text of each serving program, as the engine calls it
+# at _engine()'s sizes, taken on the commit before the decode loop ran ahead
+# (ea57df6): a change of schedule on the host moves none of them.
+PROGRAMS = {
+    "per_head": {
+        "decode_paged":
+            "1b3987354f695707f622b3b5b1d122f134f6cc5e94c56339502718f6cbf479d1",
+        "prefill_batch":
+            "7fa5792158f9e2cbedd1d91dcd51ffbd100fb5da31623fc8a8c611748f9a3d42",
+        "prefill_with_prefix_batch":
+            "c2f720bd8ba145b06a2610f9699a76c653222b51d8cda7b550f82a2e52ce2ca9",
+    },
+    "latent": {
+        "decode_paged":
+            "0e0725f3106899520d2755257e035251f0580776df1cd8c9175e47db02714c07",
+        "prefill_batch":
+            "b1c41f247149617e7e8ace033cdf8959953f951f51f7647c2b12cf7b626024eb",
+        "prefill_with_prefix_batch":
+            "55608f9284e73f4e9762991db555a5612d3a316a7100532f210adcc9dead919f",
+    },
+    "hybrid": {
+        "decode_paged":
+            "f778bd6228e5ce196e9bf2163ddc281a69b103bcbddf2b3272c2181e62133105",
+        "prefill_batch":
+            "55d33b24b642ab6f08c96bd8573ac35520d3debe00396bddb9d442801139e946",
+        "prefill_with_prefix_batch":
+            "4a3e060a6fda1c885f390ee70e47876fe0d4099fec9e89f42df16f25ed71afe2",
+    },
+}
+FINGERPRINTS = (
+    "d89dd74727145bda88afdc22fc6eab0855fe1bbce996cb6f538881373001c431")
+
+
+def _lowered_digests(kind) -> dict:
+    eng = _engine(kind)
+    c, B, page = eng.c, eng.e.max_slots, eng.e.page_size
+
+    def sds(*trees):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), trees)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    params, pools, rows = sds(eng.params, eng._pools(), eng.rows)
+    stats = () if eng._moe_acc is None else sds(eng._moe_acc)
+    n, S, Pp = 2, 32, 2
+    row_args = (*rows, i32(n), i32(n)) if rows else ()
+    prefix = (*pools, i32(n, Pp), i32(n))
+    # name -> (arguments, the first donated one, how many)
+    calls = {
+        "decode_paged": ((
+            params, *pools, *rows, i32(B), i32(B),
+            jax.ShapeDtypeStruct((B,), jnp.bool_), i32(B, 4), *stats),
+            1, len(pools) + len(rows)),
+        "prefill_batch": (
+            (params, i32(n, S), i32(n), *row_args, *stats), 3, len(rows)),
+        "prefill_with_prefix_batch": (
+            (params, i32(n, S), i32(n), *prefix, *row_args, *stats),
+            3 + len(prefix), len(rows)),
+    }
+    out = {}
+    for name, (args, first, donated) in calls.items():
+        text = jax.jit(
+            functools.partial(getattr(eng.serving, name), config=c),
+            donate_argnums=tuple(range(first, first + donated))).lower(
+            *args).as_text()
+        out[name] = hashlib.sha256(text.encode()).hexdigest()
+    return out
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_lowered_programs_are_the_parents(kind):
+    assert _lowered_digests(kind) == PROGRAMS[kind]
+
+
+def test_the_graph_fingerprints_are_the_parents():
+    with open(os.path.join(ROOT, "tools", "graphcheck",
+                           "fingerprints.json"), "rb") as f:
+        assert hashlib.sha256(f.read()).hexdigest() == FINGERPRINTS
