@@ -65,9 +65,13 @@ MODEL_ID = "qwen2-7b-smoke"
 # on the CPU, but the chip multiplies float32 in bf16 passes at JAX's
 # default precision: 1e-3 there (chip run, --tiny), hence 1e-2.
 LOGPROB_TOL = {"bfloat16": 0.1, "float32": 1e-2}
-# Same math on one device and on fsdp=4; only the reduction order (per-
-# shard partial sums, then a collective) and bf16 rounding points differ:
-# 3e-4 on losses of 5-12 over three steps (four-chip run).
+# Same math on one device and on fsdp=4; only the reduction order and bf16
+# rounding points differ: per-shard partial sums, then a collective, and
+# since PR 36 the loss head's log-sum-exp is summed per VOCABULARY slice
+# (a max and a sum of exponentials a chip, then two [tokens]-sized
+# reductions) where one device sums a whole row, and dx is reduce-
+# scattered from per-slice partials: at most 1.8e-4 on losses of 5-12
+# over three steps (four-chip run, PR 36; 3e-4 with the GSPMD head).
 FSDP_LOSS_TOL = {"bfloat16": 1e-2, "float32": 1e-2}
 
 

@@ -14,6 +14,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
@@ -323,6 +324,170 @@ def _xent(x, head, targets):
     return tgt - lse
 
 
+# Chunk the head only when the whole fp32 logits tensor would be large
+# enough to matter; below that the extra scan costs more than it saves.
+LOSS_CHUNK_MIN_BYTES = 1 << 30
+
+
+def _head_shard_axes(mesh, head_shape, batch: int):
+    """(gather axis, vocabulary axes, batch-only axes) when the chunked
+    head can run vocabulary-parallel on this mesh, else None.
+
+    Read off the declared table (parallel/sharding.py): the head is
+    [embed, vocab] -> P("fsdp", "tp"), the batch P(("dp", "fsdp")). The
+    axis that shards BOTH the head's model dimension and the batch is the
+    one GSPMD can only serve by moving the matrix; the tokens are gathered
+    over it instead. None (the GSPMD product) on one device, where that
+    axis has one chip, or where the sizes do not divide."""
+    from ray_tpu.parallel.sharding import ShardingRules, data_axes
+    if mesh is None or mesh.devices.size == 1:
+        return None
+    d_axis, v_axis = ShardingRules.default().spec(("embed", "vocab"))
+    batch_axes = data_axes(mesh)
+    if (not isinstance(d_axis, str) or d_axis not in batch_axes
+            or mesh.shape[d_axis] == 1):
+        return None
+    v_axes = tuple(a for a in (v_axis, d_axis)
+                   if a in mesh.axis_names and mesh.shape[a] > 1)
+
+    def size(axes):
+        return math.prod(mesh.shape[a] for a in axes)
+
+    d, v = head_shape
+    if d % mesh.shape[d_axis] or v % size(v_axes) or batch % size(batch_axes):
+        return None
+    return d_axis, v_axes, tuple(a for a in batch_axes
+                                 if a != d_axis and mesh.shape[a] > 1)
+
+
+def _xent_vocab_parallel(x, head, targets, mesh, axes, chunk: int):
+    """`_xent` over every sequence chunk, the head product vocabulary-
+    parallel: log p(target) [b, s] in fp32.
+
+    The declared head is sharded along the model dimension on the axis
+    that also shards the batch (`g`, fsdp), so `bsd,dv->bsv` wants every
+    token or the whole matrix on a chip, and GSPMD moves the matrix: once
+    a chunk, forward and backward, and its fp32 gradient back (13.9 GB
+    into each chip a step at Qwen2-7B's widths, PERF.md §6, PR 36). Here
+    the tokens move. One all-to-all over `g` turns the chip's [d/g, V]
+    slice into a [d, V/g] one (the only time head bytes travel, 3/4 of
+    the slice); per chunk the hidden states are gathered over `g`, each
+    chip computes fp32 logits for every token over ITS slice of the
+    vocabulary, and three [tokens]-sized reductions (max, sum of
+    exponentials, the target's logit) make the log-sum-exp exact. The
+    backward recomputes the chunk's logits (the rematerialisation the
+    GSPMD path has), forms the chip's slice of the softmax gradient, its
+    own COMPLETE [d, V/g] slice of the head's gradient (every token of
+    the group contracted locally, accumulated in fp32 over the chunks: no
+    reduction over `g` at all) and a partial dx that is reduce-scattered
+    back to the batch layout. The same sums as `_xent`, in another order.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel.sharding import data_axes, shard_map_compat
+    g, v_axes, dp_axes = axes
+    tp_axes = tuple(a for a in v_axes if a != g)    # vocabulary only
+    batch_axes = data_axes(mesh)
+    x_spec, t_spec = P(batch_axes, None, None), P(batch_axes, None)
+    head_spec = P(g, tp_axes or None)               # the declared layout
+    slice_spec = P(None, v_axes)
+
+    def chunks(a):
+        # [bl, s, ...] -> [nc, bl, chunk, ...]
+        bl, s = a.shape[:2]
+        a = a.reshape(bl, s // chunk, chunk, *a.shape[2:])
+        return jnp.moveaxis(a, 1, 0)
+
+    def gathered(a):
+        return jax.lax.all_gather(a, g, axis=0, tiled=True)
+
+    def local_logits(xa, ta, w):
+        """fp32 logits of the group's tokens over this chip's slice, the
+        exact row log-sum-exp, and where each target falls in the slice."""
+        logits = jnp.einsum("bsd,dv->bsv", xa, w,
+                            preferred_element_type=jnp.float32)
+        lo = 0
+        for a in v_axes:
+            lo = lo * mesh.shape[a] + jax.lax.axis_index(a)
+        col = ta - lo * w.shape[1]
+        m = jax.lax.pmax(jnp.max(logits, axis=-1), v_axes)
+        se = jax.lax.psum(
+            jnp.sum(jnp.exp(logits - m[..., None]), axis=-1), v_axes)
+        return logits, m + jnp.log(se), col
+
+    def mine(a):
+        bl = a.shape[0] // mesh.shape[g]
+        return jax.lax.dynamic_slice_in_dim(
+            a, jax.lax.axis_index(g) * bl, bl, axis=0)
+
+    def fwd_body(x, head, targets):
+        # [d/g, V/tp] -> [d, V/(tp*g)]: columns j*Vl.. of the chip's
+        # vocabulary range go to chip j of the group.
+        w = jax.lax.all_to_all(head, g, 1, 0, tiled=True)
+
+        def one(args):
+            xa, ta = gathered(args[0]), gathered(args[1])
+            logits, lse, col = local_logits(xa, ta, w)
+            hit = (col >= 0) & (col < w.shape[1])
+            tgt = jnp.take_along_axis(
+                logits, jnp.clip(col, 0, w.shape[1] - 1)[..., None],
+                axis=-1)[..., 0]
+            tgt = jax.lax.psum(jnp.where(hit, tgt, 0.0), v_axes)
+            return mine(tgt - lse)
+
+        ll = jax.lax.map(one, (chunks(x), chunks(targets)))
+        bl, s = targets.shape
+        return jnp.moveaxis(ll, 0, 1).reshape(bl, s), w
+
+    def bwd_body(x, w, targets, ct):
+        def one(dw, args):
+            xa, ta, ga = (gathered(a) for a in args)
+            logits, lse, col = local_logits(xa, ta, w)
+            onehot = col[..., None] == jnp.arange(w.shape[1])
+            dlogits = (onehot - jnp.exp(logits - lse[..., None])) \
+                * ga[..., None]
+            dw = dw + jnp.einsum("bsd,bsv->dv", xa, dlogits,
+                                 preferred_element_type=jnp.float32)
+            dxa = jnp.einsum("bsv,dv->bsd", dlogits, w,
+                             preferred_element_type=jnp.float32)
+            dx = jax.lax.psum_scatter(dxa, g, scatter_dimension=0,
+                                      tiled=True)
+            if tp_axes:
+                dx = jax.lax.psum(dx, tp_axes)
+            return dw, dx.astype(x.dtype)
+
+        dw, dx = jax.lax.scan(
+            one, jnp.zeros(w.shape, jnp.float32),
+            (chunks(x), chunks(targets), chunks(ct)))
+        if dp_axes:         # the head is replicated over them
+            dw = jax.lax.psum(dw, dp_axes)
+        bl, s, d = x.shape
+        dx = jnp.moveaxis(dx, 0, 1).reshape(bl, s, d)
+        # back to the declared layout, [d/g, V/tp]
+        return dx, jax.lax.all_to_all(dw.astype(w.dtype), g, 0, 1,
+                                      tiled=True)
+
+    @jax.custom_vjp
+    def xent(x, head, targets):
+        return fwd(x, head, targets)[0]
+
+    def fwd(x, head, targets):
+        ll, w = shard_map_compat(
+            fwd_body, mesh, (x_spec, head_spec, t_spec),
+            (t_spec, slice_spec))(x, head, targets)
+        return ll, (x, w, targets)
+
+    def bwd(res, ct):
+        x, w, targets = res
+        dx, dhead = shard_map_compat(
+            bwd_body, mesh, (x_spec, slice_spec, t_spec, t_spec),
+            (x_spec, head_spec))(x, w, targets, ct)
+        return dx, dhead, None
+
+    xent.defvjp(fwd, bwd)
+    return xent(x, head, targets)
+
+
 def loss_fn(params, batch, config: ModelConfig, mesh=None,
             loss_chunk: int = 512):
     """Next-token cross entropy; batch = {"tokens": [b, s+1]} or
@@ -331,6 +496,19 @@ def loss_fn(params, batch, config: ModelConfig, mesh=None,
     The [b, s, vocab] fp32 logits tensor dominates training HBM at scale, so
     the head+softmax runs in rematerialized sequence chunks: peak logits
     memory is b*loss_chunk*vocab and the backward recomputes each chunk.
+
+    Where the head's layout is decided: the DECLARED layout is
+    parallel/sharding.py's table (`lm_head` [embed, vocab] -> P("fsdp",
+    "tp"), a tied table its transpose), and the optimizer state, the
+    checkpoints and the serving engine all read that one table. Under an
+    fsdp axis of more than one chip the chunked head does not leave the
+    product to GSPMD, which can only serve a batch and a model dimension
+    sharded over the same axis by gathering the whole matrix in every
+    chunk: `_xent_vocab_parallel` turns the chip's slice into a
+    vocabulary slice once a step and moves the tokens instead. `mesh=None`,
+    one device, an fsdp axis of one, sizes that do not divide (then the
+    head FALLS BACK to the GSPMD product; nothing is padded) and a logits
+    tensor under LOSS_CHUNK_MIN_BYTES keep the plain `_xent` program.
     """
     if "tokens" in batch:
         inputs = batch["tokens"][:, :-1]
@@ -340,17 +518,20 @@ def loss_fn(params, batch, config: ModelConfig, mesh=None,
     x = hidden_states(params, inputs, config, mesh)
     head = (params["embed"].T if config.tie_embeddings else params["lm_head"])
     b, s, d = x.shape
-    # Chunk only when the full fp32 logits tensor would be large enough to
-    # matter (>1 GiB); below that the extra scan costs more than it saves.
     if (s % loss_chunk == 0 and s > loss_chunk
-            and 4 * b * s * config.vocab > (1 << 30)):
-        nc = s // loss_chunk
-        xc = x.reshape(b, nc, loss_chunk, d).transpose(1, 0, 2, 3)
-        tc = targets.reshape(b, nc, loss_chunk).transpose(1, 0, 2)
-        ll = jax.lax.map(
-            jax.checkpoint(lambda args: _xent(args[0], head, args[1])),
-            (xc, tc))                                # [nc, b, loss_chunk]
-        ll = ll.transpose(1, 0, 2).reshape(b, s)
+            and 4 * b * s * config.vocab > LOSS_CHUNK_MIN_BYTES):
+        axes = _head_shard_axes(mesh, head.shape, b)
+        if axes is not None:
+            ll = _xent_vocab_parallel(x, head, targets, mesh, axes,
+                                      loss_chunk)
+        else:
+            nc = s // loss_chunk
+            xc = x.reshape(b, nc, loss_chunk, d).transpose(1, 0, 2, 3)
+            tc = targets.reshape(b, nc, loss_chunk).transpose(1, 0, 2)
+            ll = jax.lax.map(
+                jax.checkpoint(lambda args: _xent(args[0], head, args[1])),
+                (xc, tc))                                # [nc, b, loss_chunk]
+            ll = ll.transpose(1, 0, 2).reshape(b, s)
     else:
         ll = _xent(x, head, targets)
     mask = batch.get("mask")

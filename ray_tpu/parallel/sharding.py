@@ -5,6 +5,22 @@ The scaling-book recipe: annotate arrays with *logical* axis names
 to mesh axes with a rules table, and let GSPMD insert collectives. FSDP is
 just "embed→fsdp on params + gather before use"; TP is "mlp/heads→tp";
 sequence parallelism is "seq→sp".
+
+One product is NOT left to GSPMD: the loss head. The table below is where
+its layout is DECLARED (`lm_head` [embed, vocab] → P("fsdp", "tp"), a tied
+table its transpose), and the optimizer state, the checkpoints, graphcheck
+and the serving engine all read that one declaration. But the batch is
+sharded over fsdp too, so `bsd,dv->bsv` wants every token or the whole
+matrix on a chip, and "gather before use" moves the matrix — the largest
+in the model, once a rematerialised loss chunk, and its fp32 gradient
+back. `models/transformer.loss_fn` reads `ShardingRules.default()` and
+`data_axes(mesh)` and, where one axis shards both, turns the chip's
+[d/fsdp, V] slice into a [d, V/fsdp] one by one all-to-all a step and
+moves the TOKENS between chips instead (`_xent_vocab_parallel`). The
+declared layout stays as it is so that nothing else follows: a layout
+declared by vocabulary would save that all-to-all (0.2 GB out of a chip
+at Qwen2-7B's widths) and would refuse every vocabulary that fsdp × tp
+does not divide, at `device_put`, where the table knows no shapes.
 """
 
 from __future__ import annotations

@@ -164,3 +164,38 @@ def __graphcheck__(gc):
     # the mesh must carry the axis name even when it is not being tested.
     gc.register("train.step", build,
                 meshes=({"dp": 2, "fsdp": 2, "tp": 1},))
+
+    def build_lm(mesh):
+        # The decoder's own loss at Qwen2's ratios cut small, but with a
+        # logits tensor over models/transformer.LOSS_CHUNK_MIN_BYTES, so
+        # the chunked head is in the graph. Its collective counts are the
+        # fingerprint of the vocabulary-parallel head: tokens gathered and
+        # dx reduce-scattered per chunk, the head's slice exchanged by one
+        # all-to-all each way, and no gather or reduction of the
+        # [d, vocab] matrix in any loop body
+        # (tests/test_train_loss_sharding.py reads the shapes).
+        from ray_tpu.models import (ModelConfig, init_params, loss_fn,
+                                    param_logical_axes)
+        cfg = ModelConfig(vocab=65536, d_model=128, n_layers=2, n_heads=4,
+                          n_kv_heads=2, d_ff=256, dtype="bfloat16",
+                          remat=True, tie_embeddings=False,
+                          attn_impl="reference")
+        param_axes = param_logical_axes(cfg)
+        init_fn, step_fn, compile_for, _ = make_train_step(
+            lambda p, b: loss_fn(p, b, cfg, mesh), optax.adamw(1e-4), mesh,
+            param_axes)
+        params = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0)))
+        state = jax.eval_shape(init_fn, params)
+        batch = {"tokens": jax.ShapeDtypeStruct((4, 2049), jnp.int32)}
+        specs = declared_param_specs(param_axes)
+        return gc.GraphSpec(
+            name="train.lm_step", fn=step_fn, args=(state, batch),
+            jit_fn=compile_for(state, batch), donate_argnums=(0,),
+            declared_in_specs=(("'lm_head'", specs["lm_head"]),
+                               ("'embed'", specs["embed"])),
+            expect_sharded=("lm_head", "embed", "wq", "wd"),
+            min_donate_bytes=1 << 16, arg_names=("state", "batch"))
+
+    gc.register("train.lm_step", build_lm,
+                meshes=({"dp": 1, "fsdp": 4, "tp": 1},))

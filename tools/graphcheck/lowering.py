@@ -121,7 +121,11 @@ def lower_graph(spec: GraphSpec) -> LoweredGraph:
                 compiled = lowered.compile()
                 hlo = compiled.as_text()
                 try:
-                    input_shardings = list(compiled.input_shardings[0])
+                    # one sharding a flattened leaf, in flat_in's order
+                    # (an argument that is a pytree, as a TrainState,
+                    # comes back as a pytree of shardings)
+                    input_shardings = jax.tree.leaves(
+                        compiled.input_shardings[0])
                 except Exception:  # noqa: BLE001 — backend-optional surface
                     input_shardings = None
             except Exception as e:  # noqa: BLE001 — surfaced as a finding
