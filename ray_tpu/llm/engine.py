@@ -23,6 +23,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
+import time
 import weakref
 from functools import partial
 
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu import diagnostics
 from ray_tpu.models import ModelConfig, init_params, model_module
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.ops.layers import apply_rope, last_rows, rmsnorm, rope
@@ -61,11 +63,17 @@ SNAPSHOT_ROWS = 16
 ADMIT_BUCKETS = 4
 
 
-def _shared_jit(key: tuple, factory):
+def _shared_jit(key: tuple, program, **jit_kwargs):
+    """The process's one `jax.jit` of `program()` under `key`. The callable
+    takes the key's first element as its name, so a trace's module line
+    and a compile's log say `jit_decode_paged`, where a partial or a
+    lambda would leave `jit__unknown` or `jit__lambda`."""
     with _JIT_CACHE_LOCK:
         fn = _JIT_CACHE.get(key)
         if fn is None:
-            fn = _JIT_CACHE[key] = factory()
+            f = program()
+            f.__name__ = key[0]
+            fn = _JIT_CACHE[key] = jax.jit(f, **jit_kwargs)
         return fn
 
 
@@ -133,6 +141,12 @@ class Request:
     # The slot it decodes in, or decoded in last (its row of the row
     # pools: InferenceEngine.state_rows).
     slot: int | None = None
+    # Stamped always (time.perf_counter_ns; 0 = not yet): when add_request
+    # took it, and the start of the admission that gave it a slot (the
+    # first, if it was preempted). The `ray_tpu.request` span's start and
+    # its `queue_ms` come from them.
+    t_arrive_ns: int = dataclasses.field(default_factory=time.perf_counter_ns)
+    t_slot_ns: int = 0
 
     def __post_init__(self):
         self.n_prompt = len(self.prompt)
@@ -690,12 +704,11 @@ def _samplers():
     """Two compiled samplers: the plain one (no sorts) serves the default
     top_k=0/top_p=1 case on the hot decode loop; the truncating one
     compiles the top-k/top-p masking only when some request asks for it."""
-    return (_shared_jit(("sample",), lambda: jax.jit(sample)),
+    return (_shared_jit(("sample",), lambda: sample),
             _shared_jit(
                 ("sample_trunc",),
-                lambda: jax.jit(
-                    lambda lg, t, k, p, tk, m=None: sample(
-                        lg, t, k, top_p=p, top_k=tk, mask=m))))
+                lambda: lambda lg, t, k, p, tk, m=None: sample(
+                    lg, t, k, top_p=p, top_k=tk, mask=m)))
 
 
 def _resolve_params(model_config: ModelConfig, params, mesh, rules,
@@ -915,9 +928,8 @@ class InferenceEngine:
         # the full KV through a fresh HBM allocation (~GBs/step).
         insert = self.serving.insert_batch
         self._insert_batch = _shared_jit(
-            (insert.__name__,),
-            lambda: jax.jit(insert, donate_argnums=tuple(
-                range(len(self._pools())))))
+            (insert.__name__,), lambda: insert,
+            donate_argnums=tuple(range(len(self._pools()))))
         self._prefill_batches: dict[tuple, object] = {}
         if mesh is not None and "tp" in mesh.axis_names:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1039,8 +1051,7 @@ class InferenceEngine:
         kept: collections.deque[Request] = collections.deque()
         for req in self.queue:
             if req.request_id in rids:
-                req.done = True
-                self.finished[req.request_id] = req
+                self._finish(req)
             else:
                 kept.append(req)
         self.queue = kept
@@ -1048,8 +1059,7 @@ class InferenceEngine:
             req = self.slot_req[i]
             if req is None or req.request_id not in rids:
                 continue
-            req.done = True
-            self.finished[req.request_id] = req
+            self._finish(req)
             self.active[i] = False
             self.slot_req[i] = None
             self._release_slot(i)
@@ -1277,6 +1287,14 @@ class InferenceEngine:
 
     def _admit(self) -> dict[int, int]:
         self._apply_cancels()
+        if not self.queue:    # a decode turn's call: nothing to do, no span
+            return {}
+        with diagnostics.span("ray_tpu.engine.admit") as sp:
+            return self._admit_queued(sp)
+
+    def _admit_queued(self, sp) -> dict[int, int]:
+        """`_admit` with a request in the queue, under its span `sp`."""
+        t_ns = time.perf_counter_ns()
         admitted: dict[int, int] = {}
         pending: list[tuple] = []  # (slot, req, last-logits row) to sample
         e = self.e
@@ -1290,97 +1308,98 @@ class InferenceEngine:
         # allocate pages. No device work yet, so a whole admission burst
         # can share one batched prefill dispatch below (one dispatch and
         # one host sync instead of one per prompt).
-        planned: list[dict] = []
-        rows = 0    # prompt-bucket rows planned so far, against the budget
-        while free and self.queue:
-            req = self.queue.popleft()
-            slot = free[0]
-            n = len(req.prompt)
-            if req.kv_handoff is not None:
-                # Disaggregated handoff: splice the prefill worker's KV
-                # into the prefix cache NOW (pump thread — page
-                # bookkeeping is single-threaded here), so _find_prefix
-                # below hits it and only the tail re-prefills.
-                ks_h, vs_h = req.kv_handoff
-                req.kv_handoff = None
-                self.import_kv(req.prompt, ks_h, vs_h)
-            pre_pages = self._find_prefix(req.prompt)
-            hit = len(pre_pages)
-            suffix = req.prompt[hit * page:]
-            ns = len(suffix)
-            chunk = self._chunk_size()
-            is_partial = bool(chunk) and ns > max(
-                b for b in self.e.prompt_buckets if b <= self.e.max_len)
-            if is_partial:
-                # Chunked prefill: admit only the next page-aligned chunk;
-                # phase 3 registers its pages and requeues the request, so
-                # the next step continues from the longer prefix. Decode
-                # windows for already-running slots interleave in between.
-                suffix = suffix[:chunk]
-                ns = chunk
-                n = hit * page + chunk
-            bucket = self._bucket(ns)
-            if planned and rows + bucket > self._admit_rows:
-                # The step's row budget is spent: the rest of the queue
-                # waits for the next step (decode runs in between).
-                self.queue.appendleft(req)
-                break
-            rows += bucket
-            # Pin the matched prefix pages FIRST: they may sit ref-0 in
-            # the eviction LRU, and the suffix allocation below must not
-            # be able to evict and reuse them.
-            for pid in pre_pages:
-                self._incref_page(pid)
-            # Recurrent state: resume from the snapshot at the prefix's
-            # end (pinned like its pages), end in the slot's own row, or,
-            # for a partial chunk, in a snapshot row keyed as the page
-            # that ends there.
-            src_row = dst_row = None
-            if self.rows:
-                if hit:
-                    src_row = self.snap_of_hash[
-                        self._prefix_hash(req.prompt[:hit * page])]
-                    self._pin_snap(src_row)
-                dst_row = self._alloc_snap() if is_partial else slot
-            no_row = bool(self.rows) and dst_row is None
-            # Pages covering [hit*page, n): allocated up front; growth
-            # pages come later, one decode page at a time.
-            need = -(-n // page) - hit
-            new_pages = []
-            while len(new_pages) < need and not no_row:
-                pid = self._alloc_page()
-                if pid is None:
+        with diagnostics.span("ray_tpu.engine.admit.plan"):
+            planned: list[dict] = []
+            rows = 0    # prompt-bucket rows planned so far, against the budget
+            while free and self.queue:
+                req = self.queue.popleft()
+                slot = free[0]
+                n = len(req.prompt)
+                if req.kv_handoff is not None:
+                    # Disaggregated handoff: splice the prefill worker's KV
+                    # into the prefix cache NOW (pump thread — page
+                    # bookkeeping is single-threaded here), so _find_prefix
+                    # below hits it and only the tail re-prefills.
+                    ks_h, vs_h = req.kv_handoff
+                    req.kv_handoff = None
+                    self.import_kv(req.prompt, ks_h, vs_h)
+                pre_pages = self._find_prefix(req.prompt)
+                hit = len(pre_pages)
+                suffix = req.prompt[hit * page:]
+                ns = len(suffix)
+                chunk = self._chunk_size()
+                is_partial = bool(chunk) and ns > max(
+                    b for b in self.e.prompt_buckets if b <= self.e.max_len)
+                if is_partial:
+                    # Chunked prefill: admit only the next page-aligned chunk;
+                    # phase 3 registers its pages and requeues the request, so
+                    # the next step continues from the longer prefix. Decode
+                    # windows for already-running slots interleave in between.
+                    suffix = suffix[:chunk]
+                    ns = chunk
+                    n = hit * page + chunk
+                bucket = self._bucket(ns)
+                if planned and rows + bucket > self._admit_rows:
+                    # The step's row budget is spent: the rest of the queue
+                    # waits for the next step (decode runs in between).
+                    self.queue.appendleft(req)
                     break
-                new_pages.append(pid)
-            if len(new_pages) < need:
-                # Pool exhausted (pages, or every snapshot row pinned):
-                # put everything back and stop admitting.
-                self.free_pages.extend(new_pages)
+                rows += bucket
+                # Pin the matched prefix pages FIRST: they may sit ref-0 in
+                # the eviction LRU, and the suffix allocation below must not
+                # be able to evict and reuse them.
                 for pid in pre_pages:
-                    self._decref_page(pid)
-                for row in (src_row, dst_row if is_partial else None):
-                    if row is not None:
-                        self._unpin_snap(row)
-                self.queue.appendleft(req)
-                break
-            for pid in new_pages:
-                self.page_refs[pid] = 1
-            if hit:
-                self.prefix_hits += 1
-                self.snapshot_hits += src_row is not None
-            if is_partial:
-                # A partial chunk never occupies the slot — and must not
-                # reuse its id either: a later full admission in this same
-                # burst takes free[0], and a shared id would collide in
-                # logits_of below.
-                slot = None
-            else:
-                free.pop(0)
-            planned.append(dict(slot=slot, req=req, n=n, ns=ns,
-                                bucket=bucket, hit=hit, partial=is_partial,
-                                suffix=suffix, pre_pages=pre_pages,
-                                new_pages=new_pages, src_row=src_row,
-                                dst_row=dst_row))
+                    self._incref_page(pid)
+                # Recurrent state: resume from the snapshot at the prefix's
+                # end (pinned like its pages), end in the slot's own row, or,
+                # for a partial chunk, in a snapshot row keyed as the page
+                # that ends there.
+                src_row = dst_row = None
+                if self.rows:
+                    if hit:
+                        src_row = self.snap_of_hash[
+                            self._prefix_hash(req.prompt[:hit * page])]
+                        self._pin_snap(src_row)
+                    dst_row = self._alloc_snap() if is_partial else slot
+                no_row = bool(self.rows) and dst_row is None
+                # Pages covering [hit*page, n): allocated up front; growth
+                # pages come later, one decode page at a time.
+                need = -(-n // page) - hit
+                new_pages = []
+                while len(new_pages) < need and not no_row:
+                    pid = self._alloc_page()
+                    if pid is None:
+                        break
+                    new_pages.append(pid)
+                if len(new_pages) < need:
+                    # Pool exhausted (pages, or every snapshot row pinned):
+                    # put everything back and stop admitting.
+                    self.free_pages.extend(new_pages)
+                    for pid in pre_pages:
+                        self._decref_page(pid)
+                    for row in (src_row, dst_row if is_partial else None):
+                        if row is not None:
+                            self._unpin_snap(row)
+                    self.queue.appendleft(req)
+                    break
+                for pid in new_pages:
+                    self.page_refs[pid] = 1
+                if hit:
+                    self.prefix_hits += 1
+                    self.snapshot_hits += src_row is not None
+                if is_partial:
+                    # A partial chunk never occupies the slot — and must not
+                    # reuse its id either: a later full admission in this same
+                    # burst takes free[0], and a shared id would collide in
+                    # logits_of below.
+                    slot = None
+                else:
+                    free.pop(0)
+                planned.append(dict(slot=slot, req=req, n=n, ns=ns,
+                                    bucket=bucket, hit=hit, partial=is_partial,
+                                    suffix=suffix, pre_pages=pre_pages,
+                                    new_pages=new_pages, src_row=src_row,
+                                    dst_row=dst_row))
 
         # Phase 2 — device work, grouped: prefix-hit prompts batch by
         # (suffix bucket, prefix-page bucket), the rest by suffix bucket —
@@ -1418,61 +1437,65 @@ class InferenceEngine:
                     dsts[j] = p["dst_row"]
                     if p["hit"]:
                         srcs[j] = p["src_row"]
-            self._prefill_group(group, logits_of, toks, lens, tabs, pres,
-                                plens, srcs, dsts)
+            with diagnostics.span("ray_tpu.engine.admit.prefill"):
+                self._prefill_group(group, logits_of, toks, lens, tabs,
+                                    pres, plens, srcs, dsts)
 
         # Phase 3 — host-side registration.
-        for p in planned:
-            slot, req = p["slot"], p["req"]
-            n, hit, new_pages = p["n"], p["hit"], p["new_pages"]
-            # Register the full suffix pages for future prefix hits.
-            if e.prefix_cache:
-                for i in range(hit, n // page):
-                    pid = new_pages[i - hit]
-                    h = self._prefix_hash(req.prompt[:(i + 1) * page])
-                    if h not in self.page_hash:
-                        self.page_hash[h] = pid
-                        self.hash_of_page[pid] = h
-            if p["src_row"] is not None:
-                self._unpin_snap(p["src_row"])
-            if p["partial"] and self.rows:
-                # the state at this chunk's end, under its last page's key
-                h = self._prefix_hash(req.prompt[:n])
-                if h in self.snap_of_hash:     # an older copy of the same
-                    self._drop_snap(self.snap_of_hash[h])
-                self.snap_of_hash[h] = p["dst_row"]
-                self.hash_of_snap[p["dst_row"]] = h
-                self._unpin_snap(p["dst_row"])
-            if p["partial"]:
-                # Chunk prefilled and registered; hand the pages to the
-                # prefix cache (ref 0 -> protected in the LRU until the
-                # continuation re-pins them) and put the request back at
-                # the head of the queue for its next chunk.
-                for pid in p["pre_pages"] + new_pages:
-                    self._decref_page(pid)
-                self.queue.appendleft(req)
-                continue
-            self.slot_pages[slot] = p["pre_pages"] + new_pages
-            self.slot_req[slot] = req
-            req.slot = slot
-            self.lengths[slot] = n
-            self.active[slot] = True
-            self.hist[slot, :n] = req.prompt
-            if req.resume_token is not None:
-                first = req.resume_token  # already in req.generated
-                req.resume_token = None
-                self.last_tokens[slot] = first
-                self.hist[slot, n] = first
-                self._maybe_finish(slot, first)
-            else:
-                # Defer the first-token sampling: one batched readback for
-                # the whole admission burst instead of a fence per prompt.
-                pending.append((slot, req, logits_of[slot]))
-            self._dev_dirty = True  # slot state changed by this admission
+        with diagnostics.span("ray_tpu.engine.admit.register"):
+            for p in planned:
+                slot, req = p["slot"], p["req"]
+                n, hit, new_pages = p["n"], p["hit"], p["new_pages"]
+                # Register the full suffix pages for future prefix hits.
+                if e.prefix_cache:
+                    for i in range(hit, n // page):
+                        pid = new_pages[i - hit]
+                        h = self._prefix_hash(req.prompt[:(i + 1) * page])
+                        if h not in self.page_hash:
+                            self.page_hash[h] = pid
+                            self.hash_of_page[pid] = h
+                if p["src_row"] is not None:
+                    self._unpin_snap(p["src_row"])
+                if p["partial"] and self.rows:
+                    # the state at this chunk's end, under its last page's key
+                    h = self._prefix_hash(req.prompt[:n])
+                    if h in self.snap_of_hash:     # an older copy of the same
+                        self._drop_snap(self.snap_of_hash[h])
+                    self.snap_of_hash[h] = p["dst_row"]
+                    self.hash_of_snap[p["dst_row"]] = h
+                    self._unpin_snap(p["dst_row"])
+                if p["partial"]:
+                    # Chunk prefilled and registered; hand the pages to the
+                    # prefix cache (ref 0 -> protected in the LRU until the
+                    # continuation re-pins them) and put the request back at
+                    # the head of the queue for its next chunk.
+                    for pid in p["pre_pages"] + new_pages:
+                        self._decref_page(pid)
+                    self.queue.appendleft(req)
+                    continue
+                self.slot_pages[slot] = p["pre_pages"] + new_pages
+                self.slot_req[slot] = req
+                req.slot = slot
+                req.t_slot_ns = req.t_slot_ns or t_ns
+                self.lengths[slot] = n
+                self.active[slot] = True
+                self.hist[slot, :n] = req.prompt
+                if req.resume_token is not None:
+                    first = req.resume_token  # already in req.generated
+                    req.resume_token = None
+                    self.last_tokens[slot] = first
+                    self.hist[slot, n] = first
+                    self._maybe_finish(slot, first)
+                else:
+                    # Defer the first-token sampling: one batched readback for
+                    # the whole admission burst instead of a fence per prompt.
+                    pending.append((slot, req, logits_of[slot]))
+                self._dev_dirty = True  # slot state changed by this admission
         if pending:  # one fence for the burst
-            toks, logps = self._sample_rows(
-                jnp.stack([row for _s, _r, row in pending]),
-                [r for _s, r, _l in pending])
+            with diagnostics.span("ray_tpu.engine.admit.sample"):
+                toks, logps = self._sample_rows(
+                    jnp.stack([row for _s, _r, row in pending]),
+                    [r for _s, r, _l in pending])
             for j, (slot, req, _l) in enumerate(pending):
                 first = int(toks[j])
                 if req.logprobs:
@@ -1483,6 +1506,9 @@ class InferenceEngine:
                 self.hist[slot, self.lengths[slot]] = first
                 self._advance_guide(req, first)
                 self._maybe_finish(slot, first)
+        if sp.on:
+            sp.set(rows=sum(p["bucket"] for p in planned),
+                   fenced=int(bool(pending)))
         return admitted
 
     def _prefill_group(self, group: list, logits_of: dict, toks, lens, tabs,
@@ -1505,9 +1531,8 @@ class InferenceEngine:
             program = getattr(self.serving, name)
             donate = tuple(range(n_before, n_before + len(self.rows)))
             fn = cache[key] = _shared_jit(
-                (name, self.c),
-                lambda: jax.jit(partial(program, config=self.c),
-                                donate_argnums=donate))
+                (name, self.c), lambda: partial(program, config=self.c),
+                donate_argnums=donate)
         toks, lens, tabs = (jnp.asarray(a) for a in (toks, lens, tabs))
         args = (self.params, toks, lens)
         if hit:
@@ -1591,14 +1616,25 @@ class InferenceEngine:
                                    if pairs else 0.0),
         }
 
+    def _finish(self, req: Request):
+        """`req` is over: handed to `finished`, and (while spans are
+        recorded) its life written as one `ray_tpu.request` span, from its
+        arrival to here."""
+        req.done = True
+        self.finished[req.request_id] = req
+        if diagnostics.recording():
+            diagnostics.record(
+                "ray_tpu.request", req.t_arrive_ns, time.perf_counter_ns(),
+                queue_ms=((req.t_slot_ns - req.t_arrive_ns) / 1e6
+                          if req.t_slot_ns else None))
+
     def _maybe_finish(self, slot: int, token: int):
         req = self.slot_req[slot]
         total = self.lengths[slot] + 1  # +1: the just-sampled token
         if (token == self.e.eos_token
                 or len(req.generated) >= req.max_new_tokens
                 or total >= self.e.max_len):
-            req.done = True
-            self.finished[req.request_id] = req
+            self._finish(req)
             self.active[slot] = False
             self.slot_req[slot] = None
             self._release_slot(slot)
@@ -1665,36 +1701,38 @@ class InferenceEngine:
         f, self._flight = self._flight, None
         if f is None:
             return {}
-        tokens = np.asarray(f.tokens)
-        logps = None if f.logps is None else np.asarray(f.logps)
-        emitted: dict[int, int] = {}
-        for i in np.flatnonzero(f.active):
-            req = f.reqs[i]
-            if self.slot_req[i] is not req:
-                # The slot ended on eos_token (or was cancelled) at the
-                # step before, which this one led: its row ran once too
-                # often, and the token is no part of the request. What the
-                # row wrote, a K/V column in a page that was the slot's
-                # own (never a shared prompt page: those lie below the
-                # first generated position) and the slot's own row of
-                # state, nobody else sees: pages and rows are handed out
-                # on the host only after this fetch's step was dispatched,
-                # so every program that writes them for their next owner
-                # runs after it on the device, and a reader is masked to
-                # the positions its owner wrote.
-                continue
-            tok = int(tokens[i])
-            if req.logprobs:
-                req.token_logprobs.append(float(logps[i]))
-            req.generated.append(tok)
-            emitted[req.request_id] = tok
-            self.lengths[i] += 1
-            self.last_tokens[i] = tok
-            if self.lengths[i] < self.e.max_len:
-                self.hist[i, self.lengths[i]] = tok
-            self._advance_guide(req, tok)
-            self._maybe_finish(i, tok)
-        self._dev_dirty = True  # single-step path mutates host-side state
+        with diagnostics.span("ray_tpu.engine.land"):
+            with diagnostics.span("ray_tpu.engine.land.fence"):
+                tokens = np.asarray(f.tokens)
+                logps = None if f.logps is None else np.asarray(f.logps)
+            emitted: dict[int, int] = {}
+            for i in np.flatnonzero(f.active):
+                req = f.reqs[i]
+                if self.slot_req[i] is not req:
+                    # The slot ended on eos_token (or was cancelled) at the
+                    # step before, which this one led: its row ran once too
+                    # often, and the token is no part of the request. What the
+                    # row wrote, a K/V column in a page that was the slot's
+                    # own (never a shared prompt page: those lie below the
+                    # first generated position) and the slot's own row of
+                    # state, nobody else sees: pages and rows are handed out
+                    # on the host only after this fetch's step was dispatched,
+                    # so every program that writes them for their next owner
+                    # runs after it on the device, and a reader is masked to
+                    # the positions its owner wrote.
+                    continue
+                tok = int(tokens[i])
+                if req.logprobs:
+                    req.token_logprobs.append(float(logps[i]))
+                req.generated.append(tok)
+                emitted[req.request_id] = tok
+                self.lengths[i] += 1
+                self.last_tokens[i] = tok
+                if self.lengths[i] < self.e.max_len:
+                    self.hist[i, self.lengths[i]] = tok
+                self._advance_guide(req, tok)
+                self._maybe_finish(i, tok)
+            self._dev_dirty = True  # single-step path mutates host-side state
         return emitted
 
     def _grow_pages(self, horizon: int = 1) -> bool:
@@ -1719,9 +1757,7 @@ class InferenceEngine:
                         # Nothing preemptable: finish this request early
                         # rather than deadlock the pump (pool too small
                         # for even one sequence — a config error).
-                        req = self.slot_req[i]
-                        req.done = True
-                        self.finished[req.request_id] = req
+                        self._finish(self.slot_req[i])
                         self.active[i] = False
                         self.slot_req[i] = None
                         self._release_slot(i)
@@ -1777,38 +1813,40 @@ class InferenceEngine:
             tokens = prev.tokens
             self.decode_steps_ahead += 1
         self.decode_steps += 1
-        tables = self._build_tables(active)
-        p_bucket = tables.shape[1]
-        pools = self._pools()
-        n_donated = len(pools) + len(self.rows)
-        fn = self._decode_paged.get(p_bucket)
-        if fn is None:
-            fn = _shared_jit(
-                ("decode_paged", self.c),
-                lambda: jax.jit(
-                    partial(self.serving.decode_paged, config=self.c),
-                    donate_argnums=tuple(range(1, 1 + n_donated))))
-            self._decode_paged[p_bucket] = fn
-        stats = () if self._moe_acc is None else (self._moe_acc,)
-        logits, *out = fn(
-            self.params, *pools, *self.rows, tokens, jnp.asarray(lengths),
-            jnp.asarray(active), jnp.asarray(tables), *stats)
-        if stats:
-            self._moe_acc = out.pop()
-        self._set_pools(out[:len(pools)])
-        self.rows = tuple(out[len(pools):])
-        reqs = [r if active[i] else None
-                for i, r in enumerate(self.slot_req)]
-        # what the host knows of this step's end without its tokens: a
-        # slot's count of tokens after it (one more is in flight when
-        # this step leads), and the length its sequence reaches
-        ends = np.array([
-            r is not None and (
-                len(r.generated) + (prev is not None) + 1 >= r.max_new_tokens
-                or lengths[i] + 2 >= e.max_len)
-            for i, r in enumerate(reqs)])
-        return _Flight(*self._sample_dispatch(logits, reqs), active, reqs,
-                       ends)
+        with diagnostics.span("ray_tpu.engine.decode", step=self.decode_steps,
+                              ahead=int(prev is not None)):
+            tables = self._build_tables(active)
+            p_bucket = tables.shape[1]
+            pools = self._pools()
+            n_donated = len(pools) + len(self.rows)
+            fn = self._decode_paged.get(p_bucket)
+            if fn is None:
+                fn = _shared_jit(
+                    ("decode_paged", self.c),
+                    lambda: partial(self.serving.decode_paged, config=self.c),
+                    donate_argnums=tuple(range(1, 1 + n_donated)))
+                self._decode_paged[p_bucket] = fn
+            stats = () if self._moe_acc is None else (self._moe_acc,)
+            logits, *out = fn(
+                self.params, *pools, *self.rows, tokens, jnp.asarray(lengths),
+                jnp.asarray(active), jnp.asarray(tables), *stats)
+            if stats:
+                self._moe_acc = out.pop()
+            self._set_pools(out[:len(pools)])
+            self.rows = tuple(out[len(pools):])
+            reqs = [r if active[i] else None
+                    for i, r in enumerate(self.slot_req)]
+            # what the host knows of this step's end without its tokens: a
+            # slot's count of tokens after it (one more is in flight when
+            # this step leads), and the length its sequence reaches
+            ends = np.array([
+                r is not None and (
+                    len(r.generated) + (prev is not None) + 1
+                    >= r.max_new_tokens
+                    or lengths[i] + 2 >= e.max_len)
+                for i, r in enumerate(reqs)])
+            return _Flight(*self._sample_dispatch(logits, reqs), active,
+                           reqs, ends)
 
     def _build_tables(self, active=None) -> np.ndarray:
         """Page tables [B, bucket] of the `active` slots (the host's view
@@ -1976,12 +2014,11 @@ class InferenceEngine:
             fn = _shared_jit(
                 ("decode_window", self.c, int(self.e.eos_token),
                  k_bucket, trunc, guided, want_logp),
-                lambda: jax.jit(
-                    partial(decode_window, config=self.c,
-                            eos_token=int(self.e.eos_token),
-                            n_steps=k_bucket, trunc=trunc, guided=guided,
-                            want_logp=want_logp),
-                    donate_argnums=(1, 2, 3, 4, 5, 12)))
+                lambda: partial(decode_window, config=self.c,
+                                eos_token=int(self.e.eos_token),
+                                n_steps=k_bucket, trunc=trunc,
+                                guided=guided, want_logp=want_logp),
+                donate_argnums=(1, 2, 3, 4, 5, 12))
             self._window_fns[key] = fn
         toks_d, lens_d, act_d = self._dev
         temps_d, tps_d, tks_d = self._dev_sampling
@@ -2086,10 +2123,10 @@ class InferenceEngine:
         if fn is None:
             fn = _shared_jit(
                 ("decode_window_spec", self.c, int(e.eos_token), iters, K),
-                lambda: jax.jit(partial(decode_window_spec, config=self.c,
-                                        eos_token=int(e.eos_token),
-                                        n_steps=iters, spec_k=K),
-                                donate_argnums=(1, 2, 3, 4, 5, 6, 9)))
+                lambda: partial(decode_window_spec, config=self.c,
+                                eos_token=int(e.eos_token),
+                                n_steps=iters, spec_k=K),
+                donate_argnums=(1, 2, 3, 4, 5, 6, 9))
             self._spec_window_fns[key] = fn
         self._sync_sampling()
         temps_d = self._dev_sampling[0]
@@ -2193,8 +2230,7 @@ class PrefillEngine:
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         self._prefill = _shared_jit(
-            ("prefill", self.c),
-            lambda: jax.jit(partial(prefill, config=self.c)))
+            ("prefill", self.c), lambda: partial(prefill, config=self.c))
         self._sample, self._sample_trunc = _samplers()
         self._key = jax.random.PRNGKey(seed + 1)
 

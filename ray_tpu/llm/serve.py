@@ -36,7 +36,7 @@ import threading
 import time
 import uuid
 
-from ray_tpu import serve
+from ray_tpu import diagnostics, serve
 from ray_tpu.core import chaos
 from ray_tpu.core.retry import Backoff
 from ray_tpu.core.status import (ActorDiedError, GetTimeoutError,
@@ -105,12 +105,22 @@ class _LLMServerImpl:
     # ---- engine pump ----
 
     def _loop(self):
+        idle = None   # the `ray_tpu.pump.idle` span of a quiet stretch
         while not self._stop:
             if not self.engine.has_work():
+                if idle is None:
+                    # ONE span from the turn the engine ran out of work to
+                    # the turn it has some again, not one a sleep
+                    idle = diagnostics.span("ray_tpu.pump.idle")
+                    idle.__enter__()
                 time.sleep(0.002)
                 continue
+            if idle is not None:
+                idle.__exit__(None, None, None)
+                idle = None
             try:
-                emitted = self.engine.step()
+                with diagnostics.span("ray_tpu.pump.step"):
+                    emitted = self.engine.step()
             except Exception:  # noqa: BLE001 — a dead pump hangs every
                 # pending AND future request on the replica; log and go on.
                 import traceback
@@ -118,7 +128,7 @@ class _LLMServerImpl:
                 time.sleep(0.1)
                 continue
             done = []
-            with self._lock:
+            with self._lock, diagnostics.span("ray_tpu.pump.fanout"):
                 # Per-token fanout to streaming subscribers.
                 for rid, tok in (emitted or {}).items():
                     sub = self._token_subs.get(rid)
@@ -139,6 +149,8 @@ class _LLMServerImpl:
                         self._discard.discard(rid)
             for loop, fut, req in done:
                 loop.call_soon_threadsafe(fut.set_result, req)
+        if idle is not None:
+            idle.__exit__(None, None, None)
 
     async def _submit(self, prompt_ids, max_new_tokens, temperature,
                       top_p=1.0, top_k=0, guide=None, logprobs=False):

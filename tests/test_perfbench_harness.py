@@ -1,6 +1,7 @@
 """Tier-1's hold on the code that decides a cell's `correct`: the
 benchmark's look-ups (perfbench/tests/test_lookups.py's cases, collected
-here because tier-1 collects `tests/` alone), the `deepseek_v2-serve-longdoc`
+here because tier-1 collects `tests/` alone; perfbench/tests/
+test_program_spans.py's beside them), the `deepseek_v2-serve-longdoc`
 and `nemotron3_nano_30b-serve-reasoning` cells end to end at their rehearsal
 sizes, their references handed a fault, and the latent kernel's and the
 state update kernel's cost functions against counts made by hand."""
@@ -14,6 +15,7 @@ import sys
 import pytest
 
 from perfbench.tests.test_lookups import *  # noqa: F401,F403 — its cases
+from perfbench.tests.test_program_spans import *  # noqa: F401,F403 — too
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "deepseek_v2-serve-longdoc"
@@ -216,3 +218,33 @@ def test_latent_attn_cost_by_hand():
     # 242 operations a byte at long lengths: the v5e's ridge (197e12 / 819e9)
     f, b = mod.cost_of_step([8192], model, 128)
     assert 225 < f / b < 245
+
+
+# ------------------------------------------- the program's spans, reduced
+
+
+def test_the_spans_tool_prints_the_programs_readings_beside_the_old_ones():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "tools",
+                                      "spans_run.py"),
+         "--workload", "qwen2_7b-serve-chat", "--seed", str(2**31 + 77),
+         "--seconds", "3", "--trace", "1", "--rehearsal"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] and out["failed"] == 0
+    assert out["compiles_in_window"] == 0 and out["metrics"] == {}
+    old = out["rehearsal_only_not_device_numbers"]
+    assert {"queue_wait_p50_ms", "batch_occupancy_pct", "decode_step_ms_p50",
+            "admit_gap_share_pct", "admit_step_ms_p50"} <= set(old)
+    prog = {k for k in out["extra"] if k.startswith("prog.")}
+    assert prog >= {"prog.step_host_ms_p50", "prog.admit_unfed_ms_p50",
+                    "prog.steps_ahead_pct", "prog.req_queue_ms_p50",
+                    "prog.prefill_fenced_ms_per_krow_p50",
+                    "prog.spans_dropped"}
+    assert out["extra"]["prog.spans_dropped"] == 0
+    # no device plane in a CPU's trace: like device_idle_pct.*, absent
+    assert "prog.idle_with_work_pct" not in prog
+    assert out["extra"]["prog.req_queue_ms_p50"] <= (
+        old["queue_wait_p50_ms"]["value"])
