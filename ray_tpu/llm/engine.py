@@ -33,6 +33,7 @@ import numpy as np
 
 from ray_tpu import diagnostics
 from ray_tpu.models import ModelConfig, init_params, model_module
+from ray_tpu.models.experts import N_STATS, expert_layer, stats_zero
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.ops.layers import apply_rope, last_rows, rmsnorm, rope
 
@@ -190,28 +191,59 @@ def _qkv(x, lp, c: ModelConfig, fence: bool = False):
 
 
 def _mlp_block(x, lp, c: ModelConfig):
+    """x [b, s, d] + the layer's feed-forward; a model's experts in the
+    GSPMD form, every expert over every row (transformer._moe: what a mesh
+    of several devices shards over "ep")."""
     from ray_tpu.models.transformer import _mlp, _moe
     normed = rmsnorm(x, lp["mlp_norm"], c.norm_eps)
     return x + (_moe(normed, lp, c) if c.moe_experts else _mlp(normed, lp))
+
+
+def _expert_block(x, lp, c: ModelConfig, routed):
+    """`_mlp_block` of a model with experts on ONE device -> (x, stats).
+    `routed` = (valid [b, s] bool, stats, the model's stacked layers, this
+    layer's index): each row of `valid` goes to the experts it chose and
+    padding to none (models/experts.py's layer, which counts what it routed
+    into `stats` and reads the expert weights out of the stack where they
+    lie). The caller names the form, "tiles": every expert over every
+    token where tokens are few (a decode step: transformer._moe's batched
+    products, the expert weights read once), the counted order and the
+    tiled grouped product where they are many (an admission)."""
+    valid, stats, layers, li = routed
+    b, s, d = x.shape
+    normed = rmsnorm(x, lp["mlp_norm"], c.norm_eps)
+    y, st = expert_layer(
+        normed.reshape(b * s, d),
+        {**lp, **{w: layers[w] for w in ("wg", "wu", "wd")}},
+        dataclasses.replace(c, moe_grouped="tiles"),
+        jnp.broadcast_to(valid, (b, s)).reshape(b * s), layer=li)
+    return x + y.reshape(b, s, d), stats + st
 
 
 def _embed(params, tokens):
     return jnp.take(params["embed"], tokens, axis=0)
 
 
-def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False):
-    """One layer of a per-head program, x [b, s, d] -> (x, kept). What a
-    program brings: `turn(t)` rotates q and k to its positions, and
+def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False,
+           routed=None):
+    """One layer of a per-head program, x [b, s, d] -> (x, kept, stats).
+    What a program brings: `turn(t)` rotates q and k to its positions, and
     `attend(q, k, v)` -> (attention output [b, s, h, hd] in any grouping
     of its axes, what the program keeps of this layer: its K and V, or
-    the pools it wrote them to). `fence` as in `_qkv`."""
+    the pools it wrote them to). `fence` as in `_qkv`. With `routed` (a
+    program that was handed the expert layers' counters: a model with
+    experts on one device, `_serving_of`) the feed-forward is
+    `_expert_block`'s; without, `_mlp_block`'s and `stats` is None."""
     b, s, _ = x.shape
     normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
     q, k, v = _qkv(normed, lp, c, fence)
     attn, kept = attend(turn(q), turn(k), v)
     attn = attn.reshape(b, s, c.n_heads * c.head_dim).astype(x.dtype)
     h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-    return _mlp_block(h, lp, c), kept
+    if routed is None:
+        return _mlp_block(h, lp, c), kept, None
+    x, stats = _expert_block(h, lp, c, routed)
+    return x, kept, stats
 
 
 def _prefill_attention(q, keys, values, prefix_len, pre_t: int,
@@ -246,13 +278,44 @@ def _head(x, params, c: ModelConfig, active=None, at=None):
                      logits, neg)
 
 
-def prefill_batch(params, tokens, lengths, config: ModelConfig):
+def _real_rows(s: int, lengths):
+    """[n, s] bool: the rows of a right-padded batch that hold a token."""
+    return jnp.arange(s)[None] < lengths[:, None]
+
+
+def _some(stats) -> tuple:
+    return () if stats is None else (stats,)
+
+
+def _layers(block, x, xs, layers, valid, stats):
+    """x through `block(x, xs[i], routed) -> (x, kept, stats)`, layer i
+    after layer i - 1, in one scan -> (x, kept [L, ...], stats). `routed`
+    is None without `stats`, else `_expert_block`'s, made here a layer."""
+    if stats is None:
+        return jax.lax.scan(lambda x, xi: block(x, xi, None)[:2], x, xs) + (
+            None,)
+
+    def one(x_stats, xi_li):
+        x, kept, stats = block(x_stats[0], xi_li[0],
+                               (valid, x_stats[1], layers, xi_li[1]))
+        return (x, stats), kept
+
+    n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    (x, stats), kept = jax.lax.scan(one, (x, stats),
+                                    (xs, jnp.arange(n_layers)))
+    return x, kept, stats
+
+
+def prefill_batch(params, tokens, lengths, stats=None, *,
+                  config: ModelConfig):
     """tokens [n, S] (right-padded), lengths [n] -> (logits [n, vocab]
     fp32 at each request's last token, the one row that is sampled from,
-    k,v caches [L, n, S, hkv, hd]). Causal; padding contributes garbage
-    KV beyond each true length, which insert never reads (length mask).
-    Batched so an admission burst pays ONE dispatch, not one per prompt
-    (the vLLM-style batched prefill role)."""
+    k,v caches [L, n, S, hkv, hd], [stats]). Causal; padding contributes
+    garbage KV beyond each true length, which insert never reads (length
+    mask), and is sent to no expert. Batched so an admission burst pays
+    ONE dispatch, not one per prompt (the vLLM-style batched prefill
+    role). `stats` (`_Serving`: the expert layers' counters, `_mlp_block`)
+    comes back counted up."""
     c = config
     x = _embed(params, tokens)
     n, s = tokens.shape
@@ -268,21 +331,23 @@ def prefill_batch(params, tokens, lengths, config: ModelConfig):
                                   v.transpose(0, 2, 1, 3), no_prefix, 0,
                                   c), (k, v)
 
-    x, (ks, vs) = jax.lax.scan(
-        lambda x, lp: _block(x, lp, c, turn, attend), x, params["layers"])
-    return _head(last_rows(x, lengths), params, c), ks, vs
+    x, (ks, vs), stats = _layers(
+        lambda x, lp, routed: _block(x, lp, c, turn, attend, routed=routed),
+        x, params["layers"], params["layers"],
+        None if stats is None else _real_rows(s, lengths), stats)
+    return (_head(last_rows(x, lengths), params, c), ks, vs) + _some(stats)
 
 
 def prefill(params, tokens, lengths, config: ModelConfig):
     """tokens [1, S], lengths [1] -> (logits [vocab] at the last token,
     k/v [L, S, hkv, hd]); the single-prompt view of prefill_batch
     (PrefillEngine's program)."""
-    last, ks, vs = prefill_batch(params, tokens, lengths, config)
+    last, ks, vs = prefill_batch(params, tokens, lengths, config=config)
     return last[0], ks[:, 0], vs[:, 0]
 
 
 def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
-                              prefix_pages, prefix_len,
+                              prefix_pages, prefix_len, stats=None, *,
                               config: ModelConfig):
     """Prefill only the SUFFIX of prompts whose prefix pages are already
     cached (prefix caching), a whole burst per dispatch. tokens [n, S] =
@@ -291,7 +356,7 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
     Cached K is stored post-RoPE at absolute positions, so it is reused
     as-is; suffix positions offset by prefix_len. Returns (logits
     [n, vocab] f32 at each suffix's last token, suffix k/v caches
-    [L, n, S, hkv, hd])."""
+    [L, n, S, hkv, hd], [stats]); `stats` as in prefill_batch."""
     c = config
     x = _embed(params, tokens)
     n, s = tokens.shape
@@ -311,13 +376,15 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
         return _prefill_attention(q, behind(pk, k), behind(pv, v),
                                   prefix_len, pre_t, c), (k, v)
 
-    def layer(x, scan_in):
+    def layer(x, scan_in, routed):
         lp, pk, pv = scan_in
         return _block(x, lp, c, partial(apply_rope, sin=sin, cos=cos),
-                      partial(attend, pk, pv))
+                      partial(attend, pk, pv), routed=routed)
 
-    x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], pool_k, pool_v))
-    return _head(last_rows(x, lengths), params, c), ks, vs
+    x, (ks, vs), stats = _layers(
+        layer, x, (params["layers"], pool_k, pool_v), params["layers"],
+        None if stats is None else _real_rows(s, lengths), stats)
+    return (_head(last_rows(x, lengths), params, c), ks, vs) + _some(stats)
 
 
 def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
@@ -346,12 +413,14 @@ def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
 
 
 def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
-                 page_tables, config: ModelConfig):
+                 page_tables, stats=None, *, config: ModelConfig):
     """One token for every slot against the paged pool. page_tables
     [B, P] page ids in position order (0 = unused -> scratch page, whose
     garbage the position mask hides). The new token's KV is written at
     (write_page, lengths % page); compute scales with the bucketed P,
     not the model's max context. Pool layout [L, hkv, N, hd, page].
+    -> (logits, pool_k, pool_v, [stats]): `stats` as in prefill_batch, an
+    inactive slot's row the padding.
 
     TPU-shaped (the three costs that matter on this hardware):
     - the layer loop is UNROLLED python, not lax.scan with the pools as
@@ -420,14 +489,16 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
 
     for li in range(c.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        x, (pool_k, pool_v) = _block(
+        x, (pool_k, pool_v), stats = _block(
             x, lp, c, partial(apply_rope, sin=sin, cos=cos),
-            partial(attend, li, pool_k, pool_v), fence=True)
-    return _head(x, params, c, active, at=0), pool_k, pool_v
+            partial(attend, li, pool_k, pool_v), fence=True,
+            routed=None if stats is None else (
+                active[:, None], stats, params["layers"], li))
+    return (_head(x, params, c, active, at=0), pool_k, pool_v) + _some(stats)
 
 
 def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
-                 page_tables, config: ModelConfig):
+                 page_tables, stats=None, *, config: ModelConfig):
     """Speculative-verify forward: S tokens per slot (the pending token +
     S-1 drafts) at consecutive positions lengths..lengths+S-1, in ONE
     model pass. Writes all S tokens' KV (rejected positions hold garbage
@@ -435,7 +506,8 @@ def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
     logits [B, S, vocab] — logits[:, j] predicts the token AFTER input j.
     Same unrolled-layer/donated-pool structure as decode_paged; attention
     runs the multi-query Pallas kernel (one pass over the slot's pages for
-    all S queries)."""
+    all S queries). `stats` as in decode_paged (every position of an
+    active slot is routed, a rejected draft's too)."""
     from ray_tpu.ops.paged_attention import paged_verify_insert_attention
     c = config
     x = _embed(params, tokens)                             # [B, S, d]
@@ -454,10 +526,12 @@ def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
 
     for li in range(c.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        x, (pool_k, pool_v) = _block(
+        x, (pool_k, pool_v), stats = _block(
             x, lp, c, partial(apply_rope, sin=sin, cos=cos),
-            partial(attend, li, pool_k, pool_v), fence=True)
-    return _head(x, params, c, active), pool_k, pool_v
+            partial(attend, li, pool_k, pool_v), fence=True,
+            routed=None if stats is None else (
+                active[:, None], stats, params["layers"], li))
+    return (_head(x, params, c, active), pool_k, pool_v) + _some(stats)
 
 
 def ngram_draft(hist, lengths, last_tokens, k: int):
@@ -528,7 +602,7 @@ def spec_accept_sample(logits, tin, temps, key):
 
 
 def decode_window_spec(params, pool_k, pool_v, tokens, lengths, active,
-                       hist, page_tables, temps, key,
+                       hist, page_tables, temps, key, stats=None, *,
                        config: ModelConfig, eos_token: int, n_steps: int,
                        spec_k: int):
     """Speculative decode window: each of `n_steps` scan iterations
@@ -539,7 +613,8 @@ def decode_window_spec(params, pool_k, pool_v, tokens, lengths, active,
     sampled rows use delta-proposal rejection sampling, so every emitted
     token is an exact draw from the temperature-scaled target
     distribution (Leviathan et al. 2023). Returns out blocks
-    [n_steps, B, spec_k+1] (-1 = nothing emitted at that position).
+    [n_steps, B, spec_k+1] (-1 = nothing emitted at that position);
+    `stats` as in verify_paged, handed on from pass to pass.
 
     Parity: vLLM ngram speculative decoding
     (`python/ray/llm/_internal/serve/deployments/llm/vllm/` inherits it);
@@ -551,11 +626,13 @@ def decode_window_spec(params, pool_k, pool_v, tokens, lengths, active,
     jj = jnp.arange(K + 1)[None]                           # [1, K+1]
 
     def one(carry, _):
-        pk, pv, toks, lens, act, hst, key = carry
+        pk, pv, toks, lens, act, hst, key, stats = carry
         drafts = ngram_draft(hst, lens, toks, K)           # [B, K]
         tin = jnp.concatenate([toks[:, None], drafts], axis=1)
-        logits, pk, pv = verify_paged(params, pk, pv, tin, lens, act,
-                                      page_tables, config)
+        logits, pk, pv, *stats = verify_paged(
+            params, pk, pv, tin, lens, act, page_tables, stats,
+            config=config)
+        stats = stats[0] if stats else None
         key, kacc = jax.random.split(key)
         acc, bonus, g = spec_accept_sample(logits, tin, temps, kacc)
         drafts_p = jnp.concatenate(
@@ -589,18 +666,20 @@ def decode_window_spec(params, pool_k, pool_v, tokens, lengths, active,
         toks = jnp.where(act, bonus, toks)
         lens = jnp.where(act, lens + acc + 1, lens)
         act = act & ~stop
-        return (pk, pv, toks, lens, act, hst, key), e
+        return (pk, pv, toks, lens, act, hst, key, stats), e
 
-    carry = (pool_k, pool_v, tokens, lengths, active, hist, key)
-    (pool_k, pool_v, tokens, lengths, active, hist, key), out_seq = (
+    carry = (pool_k, pool_v, tokens, lengths, active, hist, key, stats)
+    (pool_k, pool_v, tokens, lengths, active, hist, key, stats), out_seq = (
         jax.lax.scan(one, carry, None, length=n_steps))
-    return pool_k, pool_v, tokens, lengths, active, hist, key, out_seq
+    return (pool_k, pool_v, tokens, lengths, active, hist, key,
+            out_seq) + _some(stats)
 
 
 def decode_window(params, pool_k, pool_v, tokens, lengths, active,
                   page_tables, temps, top_ps, top_ks, gtables, gstates,
-                  key, config: ModelConfig, eos_token: int, n_steps: int,
-                  trunc: bool, guided: bool, want_logp: bool = False):
+                  key, stats=None, *, config: ModelConfig, eos_token: int,
+                  n_steps: int, trunc: bool, guided: bool,
+                  want_logp: bool = False):
     """`n_steps` decode+sample steps in ONE compiled program (lax.scan),
     sampled tokens staying device-resident between steps. The host fences
     once per window instead of once per token (fewer host syncs: the
@@ -620,14 +699,17 @@ def decode_window(params, pool_k, pool_v, tokens, lengths, active,
     (tokens [n_steps, B], logps [n_steps, B]).
 
     Within a window page tables are frozen, so the caller bounds n_steps
-    by every active slot's remaining page room.
+    by every active slot's remaining page room. `stats` as in
+    decode_paged, handed on from step to step.
     """
     B = tokens.shape[0]
 
     def one(carry, _):
-        pk, pv, toks, lens, act, gst, key = carry
-        logits, pk, pv = decode_paged(params, pk, pv, toks, lens, act,
-                                      page_tables, config)
+        pk, pv, toks, lens, act, gst, key, stats = carry
+        logits, pk, pv, *stats = decode_paged(
+            params, pk, pv, toks, lens, act, page_tables, stats,
+            config=config)
+        stats = stats[0] if stats else None
         key, sub = jax.random.split(key)
         mask = None
         if guided:
@@ -652,12 +734,13 @@ def decode_window(params, pool_k, pool_v, tokens, lengths, active,
                             jnp.maximum(row[jnp.arange(B), nxt], 0), gst)
         if eos_token >= 0:
             act = act & (nxt != eos_token)
-        return (pk, pv, nxt, lens, act, gst, key), outs
+        return (pk, pv, nxt, lens, act, gst, key, stats), outs
 
-    carry = (pool_k, pool_v, tokens, lengths, active, gstates, key)
-    (pool_k, pool_v, tokens, lengths, active, gstates, key), out_seq = (
+    carry = (pool_k, pool_v, tokens, lengths, active, gstates, key, stats)
+    (pool_k, pool_v, tokens, lengths, active, gstates, key, stats), out_seq = (
         jax.lax.scan(one, carry, None, length=n_steps))
-    return pool_k, pool_v, tokens, lengths, active, key, out_seq
+    return (pool_k, pool_v, tokens, lengths, active, key,
+            out_seq) + _some(stats)
 
 
 def sample(logits, temperature, key, top_p=None, top_k=None, mask=None):
@@ -776,9 +859,13 @@ def _per_head_pools(c: ModelConfig, num_pages: int, page: int) -> tuple:
     return (jax.ShapeDtypeStruct(shape, c.jdtype),) * 2
 
 
-def _serving_of(c: ModelConfig) -> _Serving:
+def _serving_of(c: ModelConfig, mesh=None) -> _Serving:
     if c.kv_cache == "per_head":
-        return _Serving(_per_head_pools, None, None, prefill_batch,
+        # experts on ONE device go through the expert layer, which counts;
+        # under a mesh they stay in the GSPMD form (`_mlp_block`)
+        counted = c.moe_experts and (mesh is None or mesh.devices.size == 1)
+        return _Serving(_per_head_pools, None,
+                        stats_zero if counted else None, prefill_batch,
                         prefill_with_prefix_batch, insert_pages_batch,
                         decode_paged)
     m = model_module(c)
@@ -835,7 +922,7 @@ class InferenceEngine:
         # (ModelConfig.kv_cache) brings its own four programs in its
         # module, over pools of its own shapes (_Serving); page accounting,
         # prefix hashing, chunked prefill and preemption below are shared.
-        self.serving = _serving_of(model_config)
+        self.serving = _serving_of(model_config, mesh)
         self._own = model_config.kv_cache != "per_head"
         if self._own:
             if self.e.speculation is not None:
@@ -1539,11 +1626,7 @@ class InferenceEngine:
             args += (*pools, jnp.asarray(pres), jnp.asarray(plens))
         if self.rows:
             args += (*self.rows, jnp.asarray(srcs), jnp.asarray(dsts))
-        if self._moe_acc is not None:
-            args += (self._moe_acc,)
-        last, *out = fn(*args)
-        if self._moe_acc is not None:
-            self._moe_acc = out.pop()
+        last, *out = self._counted(fn(*args, *self._stats()))
         if self.rows:
             out, self.rows = out[:-len(self.rows)], tuple(
                 out[-len(self.rows):])
@@ -1596,14 +1679,25 @@ class InferenceEngine:
         number of pages), if the prefix cache keeps it."""
         return self.snap_of_hash.get(self._prefix_hash(prefix))
 
+    def _stats(self) -> tuple:
+        """The last argument of a program that counts (`_Serving`)."""
+        return () if self._moe_acc is None else (self._moe_acc,)
+
+    def _counted(self, out: tuple) -> tuple:
+        """A program's results without the counters `_stats` handed it,
+        which it gives back last and counted up."""
+        if self._moe_acc is None:
+            return out
+        *out, self._moe_acc = out
+        return tuple(out)
+
     def moe_stats(self) -> dict:
-        """What the expert layers of a model that holds a share of its
-        experts (models/experts.py) routed since the engine began: counted
-        on the device inside the programs and fetched only here (never a
-        host fence in step())."""
+        """What the expert layers (models/experts.py: of a model that
+        holds a share of its experts, or of a per-head model on one device)
+        routed since the engine began: counted on the device inside the
+        programs and fetched only here (never a host fence in step())."""
         if self._moe_acc is None:
             return {}
-        N_STATS = model_module(self.c).N_STATS
         fresh, self._moe_acc = self._moe_acc, jnp.zeros_like(self._moe_acc)
         self._moe_total += np.asarray(fresh)
         tokens, pairs, none_held, calls = map(int, self._moe_total[:N_STATS])
@@ -1826,12 +1920,9 @@ class InferenceEngine:
                     lambda: partial(self.serving.decode_paged, config=self.c),
                     donate_argnums=tuple(range(1, 1 + n_donated)))
                 self._decode_paged[p_bucket] = fn
-            stats = () if self._moe_acc is None else (self._moe_acc,)
-            logits, *out = fn(
+            logits, *out = self._counted(fn(
                 self.params, *pools, *self.rows, tokens, jnp.asarray(lengths),
-                jnp.asarray(active), jnp.asarray(tables), *stats)
-            if stats:
-                self._moe_acc = out.pop()
+                jnp.asarray(active), jnp.asarray(tables), *self._stats()))
             self._set_pools(out[:len(pools)])
             self.rows = tuple(out[len(pools):])
             reqs = [r if active[i] else None
@@ -2023,10 +2114,10 @@ class InferenceEngine:
         toks_d, lens_d, act_d = self._dev
         temps_d, tps_d, tks_d = self._dev_sampling
         (self.cache_k, self.cache_v, toks_d, lens_d, act_d,
-         self._dev_key, out_seq) = fn(
+         self._dev_key, out_seq) = self._counted(fn(
             self.params, self.cache_k, self.cache_v, toks_d, lens_d,
             act_d, jnp.asarray(tables), temps_d, tps_d, tks_d,
-            gtables_d, gstates_d, self._dev_key)
+            gtables_d, gstates_d, self._dev_key, *self._stats()))
         self._dev = (toks_d, lens_d, act_d)
         if want_logp:
             out = np.asarray(out_seq[0])  # ONE fence per window
@@ -2132,10 +2223,10 @@ class InferenceEngine:
         temps_d = self._dev_sampling[0]
         toks_d, lens_d, act_d = self._dev
         (self.cache_k, self.cache_v, toks_d, lens_d, act_d,
-         self._dev_hist, self._dev_key, out_seq) = fn(
+         self._dev_hist, self._dev_key, out_seq) = self._counted(fn(
             self.params, self.cache_k, self.cache_v, toks_d, lens_d,
             act_d, self._dev_hist, jnp.asarray(tables), temps_d,
-            self._dev_key)
+            self._dev_key, *self._stats()))
         self._dev = (toks_d, lens_d, act_d)
         out = np.asarray(out_seq)  # [iters, B, K+1]; ONE fence
         w_draft = w_acc = 0

@@ -1,5 +1,11 @@
-"""The expert layer of a chip that HOLDS a share of the routed experts,
-for the models that have one (models/deepseek_v2.py, models/nemotron_h.py).
+"""The expert layer of ONE chip: the routed experts it holds, all of them
+or a share. Who calls it: models/deepseek_v2.py and models/nemotron_h.py
+from their own serving programs, and llm/engine.py's per-head programs
+(`_mlp_block`: Mixtral's kind, every expert held, softmax scores, top-k
+renormalised: `route()`'s defaults) where they run on one device. Under a
+mesh, and in training, a per-head model's experts are
+models/transformer._moe's (every expert over every token, the expert axis
+sharded over "ep").
 
 The layer is told which experts it holds: `moe_experts` of the
 `moe_router_experts` the router scores, group `moe_held_group`. It routes
@@ -32,8 +38,10 @@ What a configuration chooses (`ModelConfig`):
                     deepseek_v2's, as its cell has always run it) or
                     "tiles" (counted order, plain products over tiles, a
                     dense batched product where tokens are few: the one
-                    form nemotron_h's programs run with on the chip; the
-                    comment above `_TILE_ROWS` has the runs).
+                    form nemotron_h's programs run with on the chip, and
+                    the one llm/engine.py's per-head programs name where
+                    they call the layer; the comment above `_TILE_ROWS`
+                    has the runs).
 """
 
 from __future__ import annotations
@@ -126,15 +134,22 @@ def route(x, lp, c: ModelConfig):
     return w, idx
 
 
-def _grouped_mlp(rows, lp, sizes, c: ModelConfig):
+def _held(lp, name: str, layer):
+    """The held experts' `name` weights [E, ...]: `lp[name]`, or layer
+    `layer` of it where `lp[name]` is a stack of layers [L, E, ...]."""
+    return lp[name] if layer is None else lp[name][layer]
+
+
+def _grouped_mlp(rows, lp, sizes, c: ModelConfig, layer):
     if c.moe_grouped == "tiles":
-        return _grouped_mlp_tiles(rows, lp, sizes, c)
+        return _grouped_mlp_tiles(rows, lp, sizes, c, layer)
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
     if c.mlp_act == "relu2":
-        u = jax.nn.relu(dot(rows, lp["wu"]))
-        return dot(u * u, lp["wd"])
-    act = jax.nn.silu(dot(rows, lp["wg"])) * dot(rows, lp["wu"])
-    return dot(act, lp["wd"])
+        u = jax.nn.relu(dot(rows, _held(lp, "wu", layer)))
+        return dot(u * u, _held(lp, "wd", layer))
+    act = (jax.nn.silu(dot(rows, _held(lp, "wg", layer)))
+           * dot(rows, _held(lp, "wu", layer)))
+    return dot(act, _held(lp, "wd", layer))
 
 
 # `moe_grouped == "tiles"` (nemotron3_nano_30b's): no sort and no
@@ -160,17 +175,48 @@ def _grouped_mlp(rows, lp, sizes, c: ModelConfig):
 #   order (at most blocks + experts - 1 of them), each one product of the
 #   block with that expert's weights, kept where the row is the expert's;
 # - few tokens (decode): no dispatch at all, `held_dense`.
-_TILE_ROWS = 256      # rows a tile at 2048 rows a pass or more, else
+# Which of the two, and the rows a tile, follow from the rows and the
+# experts, not from the widths: a tile's product reads its expert's weights
+# whole, 2 * rows * d * f operations over 2 * d * f bytes, so under ~240
+# rows (the v5e's operations a byte) the read is what the tile costs
+# whatever d and f are, and past it the rows are: a tile of `_TILE_ROWS` is
+# where the two meet. The walk makes at most rows / tile + experts - 1
+# tiles, so full tiles pay once a pass holds half a tile an expert
+# (`_full_tiles`). Below that most tiles straddle experts, where a small
+# tile wastes fewer masked rows than it re-reads weights; and below that,
+# too, every expert over every token costs the walk's reads (every expert
+# is hit) with no order, gather or loop round them. The constants were set
+# on nemotron3_nano_30b's 16 experts of [2688, 1856], 6 a token (dense at
+# 64 x 16 and up to 256 x 16, full tiles from 2048 rows a pass: its
+# programs get what they got); for Mixtral's 8 of [4096, 14336], 2 a
+# token, the rule gives the dense product up to 256 tokens and full tiles
+# from 512, and one layer alone on the chip agrees (PR 40, ms, every row
+# real; transformer._moe / dense / walk at 64 / 256 / 512 rows a tile /
+# ragged_dot): 256 tokens 5.06 / 5.39 / 8.31 / 5.90 / - / 10.05, 512
+# tokens 9.04 / 9.36 / 12.27 / 7.05 / - / 11.23, 2048 tokens 33.06 / - /
+# - / 13.78 / 16.37 / 19.23 (PERF.md section 5 has the table).
+_TILE_ROWS = 256      # rows a tile where `_full_tiles`, else
 _SMALL_TILE_ROWS = 64
 _DENSE_ROWS = 4096    # tokens x held experts up to which every held expert
-#                       runs over every token (decode: 64 x 16)
+#                       may run over every token (decode: 64 x 16)
 
 
-def _one_expert(x, lp, e, c: ModelConfig):
+def _full_tiles(rows: int, E: int) -> bool:
+    """Whether a pass of `rows` sorted rows over E held experts holds half
+    a `_TILE_ROWS` tile an expert or more."""
+    return 2 * rows >= E * _TILE_ROWS
+
+
+def _one_expert(x, lp, e, c: ModelConfig, layer):
     """Expert e's feed-forward of x [rows, d], its weights read where they
-    lie (a dynamic slice that XLA fuses into the product)."""
+    lie (a dynamic slice that XLA fuses into the product): ONE slice of
+    what `lp` holds, a stack of layers too (`expert_layer`)."""
     def w(name):
-        return jax.lax.dynamic_index_in_dim(lp[name], e, 0, keepdims=False)
+        if layer is None:
+            return jax.lax.dynamic_index_in_dim(lp[name], e, 0,
+                                                keepdims=False)
+        return jax.lax.dynamic_slice(
+            lp[name], (layer, e, 0, 0), (1, 1) + lp[name].shape[2:])[0, 0]
 
     if c.mlp_act == "relu2":
         u = jax.nn.relu(jnp.dot(x, w("wu")))
@@ -179,11 +225,12 @@ def _one_expert(x, lp, e, c: ModelConfig):
                    w("wd"))
 
 
-def _grouped_mlp_tiles(rows, lp, sizes, c: ModelConfig):
+def _grouped_mlp_tiles(rows, lp, sizes, c: ModelConfig, layer):
     """rows [R, d] sorted by expert, sizes [E] rows an expert (their sum at
     most R) -> [R, d]; rows past the sum are left zero."""
     R, d = rows.shape
-    tm = _TILE_ROWS if R >= 2048 else min(_SMALL_TILE_ROWS, R)
+    tm = (_TILE_ROWS if _full_tiles(R, sizes.shape[0])
+          else min(_SMALL_TILE_ROWS, R))
     pad = -R % tm
     if pad:
         rows = jnp.pad(rows, [(0, pad), (0, 0)])
@@ -199,7 +246,7 @@ def _grouped_mlp_tiles(rows, lp, sizes, c: ModelConfig):
         e = jnp.minimum(jnp.sum(t >= tile_ends), E - 1)
         at = (first[e] + t - (tile_ends[e] - tiles[e])) * tm
         y = _one_expert(jax.lax.dynamic_slice(rows, (at, 0), (tm, d)), lp, e,
-                        c)
+                        c, layer)
         r = at + jnp.arange(tm)
         mine = (r >= starts[e]) & (r < ends[e])
         old = jax.lax.dynamic_slice(out, (at, 0), (tm, d))
@@ -212,19 +259,20 @@ def _grouped_mlp_tiles(rows, lp, sizes, c: ModelConfig):
     return out[:R]
 
 
-def held_dense(x, lp, c: ModelConfig, w, local, held):
+def held_dense(x, lp, c: ModelConfig, w, local, held, layer=None):
     """x [T, d], few tokens: every held expert over every token, weighted
     by the router's weight where the token chose it (w, local, held
     [T, k]) -> [T, d] float32. One batched product over the experts, the
     stacked weights read once where they lie (transformer._moe's form):
     all a decode step costs; no sort, no gather."""
     if c.mlp_act == "relu2":
-        u = jax.nn.relu(jnp.einsum("td,edf->etf", x, lp["wu"]))
+        u = jax.nn.relu(jnp.einsum("td,edf->etf", x, _held(lp, "wu", layer)))
         act = u * u
     else:
-        act = (jax.nn.silu(jnp.einsum("td,edf->etf", x, lp["wg"]))
-               * jnp.einsum("td,edf->etf", x, lp["wu"]))
-    y = jnp.einsum("etf,efd->etd", act, lp["wd"])
+        act = (jax.nn.silu(jnp.einsum("td,edf->etf", x,
+                                      _held(lp, "wg", layer)))
+               * jnp.einsum("td,edf->etf", x, _held(lp, "wu", layer)))
+    y = jnp.einsum("etf,efd->etd", act, _held(lp, "wd", layer))
     gate = jnp.sum(
         jnp.where(held[..., None] & (local[..., None]
                                      == jnp.arange(c.moe_experts)),
@@ -290,16 +338,27 @@ def _order_by_count(held, local, E: int):
     return token_of, where_sorted, counts, ends
 
 
-def expert_layer(x, lp, c: ModelConfig, valid):
+def expert_layer(x, lp, c: ModelConfig, valid, layer=None):
     """x [T, d] (normed), valid [T] bool (padding routes nowhere) ->
-    (held experts' part + shared experts [T, d], stats [N_STATS + E])."""
+    (held experts' part + shared experts [T, d], stats [N_STATS + E]).
+
+    `layer`: the experts' `wg`, `wu`, `wd` in `lp` are stacks over a
+    model's layers [L, E, ...] and this (an int, or traced) is the layer
+    to read. A model that keeps its layers stacked (models/transformer.py)
+    hands the stack down: a layer's slice taken outside is an operand of
+    the loop over tiles, which XLA makes a COPY of the layer's experts
+    (5.3 GiB of temporaries at Mixtral's two layers, described-chip
+    compile, PR 40); one slice [layer, expert] inside the loop reads the
+    weights where they lie."""
     T, d = x.shape
     E, k = c.moe_experts, c.moe_top_k
     with jax.named_scope("expert_layer"):
         w, idx = route(x, lp, c)
         local = idx - c.moe_held_group * E
         held = (local >= 0) & (local < E) & valid[:, None]      # [T, k]
-        dense = c.moe_grouped == "tiles" and T * E <= _DENSE_ROWS
+        rows_a_pass = min(T * k, max(T, _MIN_PASS_ROWS))
+        dense = (c.moe_grouped == "tiles" and T * E <= _DENSE_ROWS
+                 and not _full_tiles(rows_a_pass, E))
         if dense:
             counts = jnp.sum(
                 held[..., None] & (local[..., None] == jnp.arange(E)),
@@ -313,14 +372,13 @@ def expert_layer(x, lp, c: ModelConfig, valid):
             token_of, where_sorted, counts, ends = _order_by_sort(
                 held, local, E)
         n_held = ends[-1]
-        rows_a_pass = min(T * k, max(T, _MIN_PASS_ROWS))
 
         def one_pass(start, y):
             rows = jnp.take(x, token_of(start + jnp.arange(rows_a_pass)),
                             axis=0)
             sizes = (jnp.clip(ends, start, start + rows_a_pass)
                      - jnp.clip(ends - counts, start, start + rows_a_pass))
-            out = _grouped_mlp(rows, lp, sizes, c)
+            out = _grouped_mlp(rows, lp, sizes, c, layer)
             rel = where_sorted - start
             here = held & (rel >= 0) & (rel < rows_a_pass)
             for j in range(k):      # a row gather a choice; no scatter
@@ -332,7 +390,7 @@ def expert_layer(x, lp, c: ModelConfig, valid):
 
         y = jnp.zeros((T, d), jnp.float32)
         if dense:
-            y = held_dense(x, lp, c, w, local, held)
+            y = held_dense(x, lp, c, w, local, held, layer)
         elif rows_a_pass == T * k:
             y = one_pass(0, y)
         else:

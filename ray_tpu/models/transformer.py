@@ -235,7 +235,12 @@ def _attention(x, lp, c: ModelConfig, sin, cos, mesh):
 
 def _moe(x, lp, c: ModelConfig):
     """Top-k MoE in GSPMD dense form: every expert computes, the router's
-    top-k weights zero the rest; the "expert" einsum axis shards over "ep"."""
+    top-k weights zero the rest; the "expert" einsum axis shards over "ep".
+    Who calls it: the trainer and `forward` (`hidden_states`), and the
+    serving engine's per-head programs under a mesh of several devices
+    (llm/engine._mlp_block). On one device those programs send a token to
+    the experts it chose instead (llm/engine._expert_block ->
+    models/experts.expert_layer: the same router, the same weights)."""
     probs = jax.nn.softmax(
         jnp.einsum("bsd,dx->bsx", x, lp["router"],
                    preferred_element_type=jnp.float32), axis=-1)

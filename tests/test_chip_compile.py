@@ -371,6 +371,40 @@ def test_jamba_decode_leaves_its_pools_where_they_lie(topo, monkeypatch):
     assert moved.findall(text) == []
 
 
+def test_mixtral_prefill_reads_its_experts_where_they_lie(topo, monkeypatch):
+    """The per-head `prefill_batch` over ONE 1024-row bucket at Mixtral-
+    8x7B's published widths (2 of its layers, the chat cell's), the expert
+    layer in the block (`stats` handed in): it compiles, and its
+    temporaries stay under the three [1, 1024, 8, 14336] arrays the form
+    that computes every expert for every row carried. With a layer's
+    slice of the stacked expert weights taken outside the loop over tiles
+    the same compile held 5.3 GiB of temporaries: a copy of both layers'
+    experts."""
+    from ray_tpu.llm.engine import prefill_batch
+    from ray_tpu.models import configs, experts, init_params
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    c = configs.mixtral_8x7b(n_layers=2, remat=False)
+    assert (c.d_model, c.d_ff, c.moe_experts, c.moe_top_k) == (
+        4096, 14336, 8, 2)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0))))
+    compiled = jax.jit(partial(prefill_batch, config=c)).lower(
+        params, sds((1, 1024)), sds((1,)),
+        sds((experts.N_STATS + c.moe_experts,))).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text   # the flash kernel
+    assert "f32[1,1024,8,14336]" not in text
+    assert "bf16[1,1024,8,14336]" not in text
+    every_expert = 3 * 1024 * 8 * 14336 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < every_expert / 4
+
+
 @pytest.mark.parametrize("program", ["prefill_batch",
                                      "prefill_with_prefix_batch"])
 def test_prefill_programs_hold_no_scores_and_no_prompt_logits(
