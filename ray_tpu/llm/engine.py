@@ -221,10 +221,12 @@ def _expert_block(x, lp, c: ModelConfig, routed):
     layer's index): each row of `valid` goes to the experts it chose and
     padding to none (models/experts.py's layer, which counts what it routed
     into `stats` and reads the expert weights out of the stack where they
-    lie). The caller names the form, "tiles": every expert over every
-    token where tokens are few (a decode step: transformer._moe's batched
-    products, the expert weights read once), the counted order and the
-    tiled grouped product where they are many (an admission)."""
+    lie). The caller names the form, "tiles": where tokens are few (a
+    decode step) the experts they chose, walked one by one while fewer
+    than all are hit, else every expert over every token
+    (transformer._moe's batched products, the expert weights read once);
+    the counted order and the tiled grouped product where they are many
+    (an admission)."""
     valid, stats, layers, li = routed
     b, s, d = x.shape
     normed = rmsnorm(x, lp["mlp_norm"], c.norm_eps)
@@ -1915,12 +1917,18 @@ class InferenceEngine:
             return {}
         fresh, self._moe_acc = self._moe_acc, jnp.zeros_like(self._moe_acc)
         self._moe_total += np.asarray(fresh)
-        tokens, pairs, none_held, calls = map(int, self._moe_total[:N_STATS])
+        tokens, pairs, none_held, calls, few, n_read = map(
+            int, self._moe_total[:N_STATS])
         load = self._moe_total[N_STATS:]
         return {
             "expert_layer_calls": calls, "routed_tokens": tokens,
             "held_pairs": pairs, "tokens_without_held_expert": none_held,
             "held_expert_load": load.tolist(),
+            # of the few-token calls (a decode step's): the experts whose
+            # weights they read, over all they hold
+            "few_token_calls": few,
+            "experts_read_share": (n_read / (few * len(load))
+                                   if few else 0.0),
             "load_max_over_mean": (float(load.max() / load.mean())
                                    if pairs else 0.0),
         }

@@ -36,12 +36,15 @@ What a configuration chooses (`ModelConfig`):
   moe_grouped       how the order and the grouped product are computed:
                     "ragged_dot" (a stable sort, XLA's ragged-dot kernel:
                     deepseek_v2's, as its cell has always run it) or
-                    "tiles" (counted order, plain products over tiles, a
-                    dense batched product where tokens are few: the one
-                    form nemotron_h's programs run with on the chip, and
-                    the one llm/engine.py's per-head programs name where
-                    they call the layer; the comment above `_TILE_ROWS`
-                    has the runs).
+                    "tiles" (counted order, plain products over tiles;
+                    where tokens are few, no order at all: the experts
+                    this call's tokens chose, walked a turn each where an
+                    expert is large against a turn's overhead, Mixtral's
+                    352 MB, else every held expert in one batched product:
+                    the one form nemotron_h's programs run with on the
+                    chip, and the one llm/engine.py's per-head programs
+                    name where they call the layer; the comments above
+                    `_TILE_ROWS` and `_TURN_BYTES` have the runs).
 """
 
 from __future__ import annotations
@@ -61,8 +64,10 @@ from ray_tpu.ops.layers import swiglu
 # passes of this many rows (one pass unless routing is badly skewed).
 _MIN_PASS_ROWS = 4096
 
-N_STATS = 4   # routed tokens, pairs on held experts, tokens with no held
-#               expert, expert-layer calls; then one load count an expert
+N_STATS = 6   # routed tokens, pairs on held experts, tokens with no held
+#               expert, expert-layer calls, those of them in the few-token
+#               form, experts whose weights these read; then one load
+#               count an expert
 
 
 def stats_zero(c: ModelConfig):
@@ -176,7 +181,10 @@ def _grouped_mlp(rows, lp, sizes, c: ModelConfig, layer):
 #   of a few experts, and the (block, expert) pairs that exist are walked in
 #   order (at most blocks + experts - 1 of them), each one product of the
 #   block with that expert's weights, kept where the row is the expert's;
-# - few tokens (decode): no dispatch at all, `held_dense`.
+# - few tokens (decode): no dispatch at all, `held_few`: every held expert
+#   over every token in one batched product (`held_dense`), or, where
+#   `_TURN_BYTES` below lets a shape have it, the experts that were HIT
+#   alone, a turn each (`held_walk`).
 # Which of the two, and the rows a tile, follow from the rows and the
 # experts, not from the widths: a tile's product reads its expert's weights
 # whole, 2 * rows * d * f operations over 2 * d * f bytes, so under ~240
@@ -201,6 +209,52 @@ _TILE_ROWS = 256      # rows a tile where `_full_tiles`, else
 _SMALL_TILE_ROWS = 64
 _DENSE_ROWS = 4096    # tokens x held experts up to which every held expert
 #                       may run over every token (decode: 64 x 16)
+
+
+
+# The few-token form reads what its tokens chose. A decode step of 6
+# active rows of 16 hits 6.6 of Mixtral's 8 experts and one row hits 2, yet
+# the batched product streams all 8 (2.8 GB a layer: 7.5 of the chat cell's
+# 8.4 ms step). `held_walk` takes the hit experts a turn each; a turn is
+# three cold-started products and a trip of XLA's loop, so it costs its
+# expert's read plus an overhead that does not shrink with the expert. One
+# layer alone on the chip (PR 43; ms; walk at 1 / half / all experts hit
+# against the batched product; PERF.md section 5 has every hit count):
+#   Mixtral   8 x [4096, 14336] swiglu, 16 rows: 0.517 / 1.942 / 3.842, 3.797
+#   Laguna   32 x [3072, 1024]  swiglu, 24 rows: 0.068 / 0.602 / 1.174, 0.867
+#   nemotron 16 x [2688, 1856]  relu2,  64 rows: 0.093 / 0.332 / 0.599, 0.459
+# A turn costs 475 us at Mixtral's 352 MB an expert, which IS an eighth of
+# the batched product (overhead under 1 us, lost in the read), 35.7 us at
+# Laguna's 18.9 MB against 27.1 (8.6 us more: 6.0 MB at the batched
+# product's own 697 GB/s) and 33.8 us at nemotron's 20 MB against 28.7 (5.1
+# us: 3.5 MB); a walk also pays 30 to 60 us once. `_TURN_BYTES` is the
+# largest of the three with a third of room. The call's own hit count
+# decides on the device (`lax.cond`): walk where n_hit turns read less than
+# the batched product's E experts, so every expert hit is the batched
+# product exactly as before (3.804 ms through the branch, 3.797 without).
+# The branch is not free where experts are small: the batched product ran
+# 0.03 ms slower inside it at Laguna's shape (3 %) and 0.06 to 0.08 ms at
+# nemotron's (13 to 17 %; the same products, started cold behind the
+# predicate), and nemotron's 64 rows hit 15.3 of its 16 experts on nearly
+# every step. So a shape gets the branch and the walk only where the walk
+# pays even with E - 1 experts hit, i.e. a turn's overhead is under 1 /
+# (E - 1) of its read: Mixtral (0.024 against 0.143) does; Laguna (0.44
+# against 0.032) and nemotron (0.42 against 0.067) keep the batched
+# product alone and their programs' text. What that leaves on the table at
+# Laguna (20 of 32 hit: 0.742 against 0.872 ms) wants ONE kernel whose
+# grid runs over the hit list, not this loop (PERF.md section 7). Rows: a
+# turn multiplies ALL T rows, free only while T is far under the ridge; and
+# at 256 rows the compiler copied both layers' `wg` and `wu` into another
+# layout for the branch's batched product (1855 MiB of temporaries,
+# described-chip compile, PR 43), at 64 and 16 it does not: the walk stops
+# at `_SMALL_TILE_ROWS`.
+_TURN_BYTES = 8 << 20
+
+
+def _walk_pays(n_hit, E: int, expert_bytes: int):
+    """Whether `n_hit` turns of the few-token walk (an int, or traced: the
+    call's own count) cost less than ONE batched product over all E."""
+    return n_hit * (1.0 + _TURN_BYTES / expert_bytes) < E
 
 
 def _full_tiles(rows: int, E: int) -> bool:
@@ -261,12 +315,27 @@ def _grouped_mlp_tiles(rows, lp, sizes, c: ModelConfig, layer):
     return out[:R]
 
 
+def _expert_bytes(lp, c: ModelConfig) -> int:
+    """The bytes of ONE expert's weights as `lp` holds them."""
+    names = ("wu", "wd") if c.mlp_act == "relu2" else ("wg", "wu", "wd")
+    return sum(lp[n].shape[-2] * lp[n].shape[-1] * lp[n].dtype.itemsize
+               for n in names)
+
+
+def _gate(w, local, held, E: int):
+    """[T, E] float32: the router's weight where the token chose the held
+    expert (w, local, held [T, k]), else 0."""
+    return jnp.sum(
+        jnp.where(held[..., None] & (local[..., None] == jnp.arange(E)),
+                  w[..., None], 0.0), axis=1)
+
+
 def held_dense(x, lp, c: ModelConfig, w, local, held, layer=None):
     """x [T, d], few tokens: every held expert over every token, weighted
     by the router's weight where the token chose it (w, local, held
     [T, k]) -> [T, d] float32. One batched product over the experts, the
     stacked weights read once where they lie (transformer._moe's form):
-    all a decode step costs; no sort, no gather."""
+    no sort, no gather."""
     if c.mlp_act == "relu2":
         u = jax.nn.relu(jnp.einsum("td,edf->etf", x, _held(lp, "wu", layer)))
         act = u * u
@@ -275,11 +344,55 @@ def held_dense(x, lp, c: ModelConfig, w, local, held, layer=None):
                                       _held(lp, "wg", layer)))
                * jnp.einsum("td,edf->etf", x, _held(lp, "wu", layer)))
     y = jnp.einsum("etf,efd->etd", act, _held(lp, "wd", layer))
-    gate = jnp.sum(
-        jnp.where(held[..., None] & (local[..., None]
-                                     == jnp.arange(c.moe_experts)),
-                  w[..., None], 0.0), axis=1)                     # [T, E]
-    return jnp.einsum("etd,te->td", y.astype(jnp.float32), gate)
+    return jnp.einsum("etd,te->td", y.astype(jnp.float32),
+                      _gate(w, local, held, c.moe_experts))
+
+
+def held_walk(x, lp, c: ModelConfig, w, local, held, hit_ends, layer=None):
+    """The same sum over the experts that were HIT alone, in the order of
+    their ids (`hit_ends` [E]: hit experts up to and with each one): a
+    turn an expert, its weights sliced where they lie (`_one_expert`) and
+    its product of ALL T rows added under its column of the gate. An
+    expert nobody chose adds an exact zero to `held_dense`'s sum and is
+    not read here. Rows are few, the read is the cost: no sort, no
+    gather."""
+    gate = _gate(w, local, held, c.moe_experts)
+
+    def turn(i_y):
+        i, y = i_y
+        e = jnp.sum(i >= hit_ends)
+        g = jax.lax.dynamic_slice_in_dim(gate, e, 1, axis=1)       # [T, 1]
+        return i + 1, y + g * _one_expert(x, lp, e, c, layer).astype(
+            jnp.float32)
+
+    return jax.lax.while_loop(
+        lambda i_y: i_y[0] < hit_ends[-1], turn,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))[1]
+
+
+def held_few(x, lp, c: ModelConfig, w, local, held, counts, layer=None):
+    """x [T, d], few tokens (w, local, held [T, k]; counts [E] pairs an
+    expert received) -> (the held experts' part [T, d] float32, experts
+    whose weights were read). ONE sum, over the experts this call's tokens
+    chose: by `held_walk` where reading the hit experts a turn each costs
+    less than reading all E in one batched product (`_walk_pays`, decided
+    on the device from this call's own hit count), else by `held_dense`,
+    which is the walk's every-expert-hit case without its turns. A shape
+    whose walk would not pay with E - 1 experts hit has no branch in its
+    program at all (the comment above `_TURN_BYTES`)."""
+    E = c.moe_experts
+    nbytes = _expert_bytes(lp, c)
+    if x.shape[0] > _SMALL_TILE_ROWS or not _walk_pays(E - 1, E, nbytes):
+        return held_dense(x, lp, c, w, local, held, layer), jnp.int32(E)
+    hit_ends = jnp.cumsum(counts > 0, dtype=jnp.int32)
+    walks = _walk_pays(hit_ends[-1], E, nbytes)
+    # the branches close over `lp` WHOLE and slice inside: a layer's slice
+    # handed in as an operand would be a copy of the layer's experts
+    y = jax.lax.cond(
+        walks,
+        lambda: held_walk(x, lp, c, w, local, held, hit_ends, layer),
+        lambda: held_dense(x, lp, c, w, local, held, layer))
+    return y, jnp.where(walks, hit_ends[-1], E)
 
 
 def shared_expert(x, lp, c: ModelConfig):
@@ -391,8 +504,9 @@ def expert_layer(x, lp, c: ModelConfig, valid, layer=None):
             return y
 
         y = jnp.zeros((T, d), jnp.float32)
+        n_read = jnp.int32(0)
         if dense:
-            y = held_dense(x, lp, c, w, local, held, layer)
+            y, n_read = held_few(x, lp, c, w, local, held, counts, layer)
         elif rows_a_pass == T * k:
             y = one_pass(0, y)
         else:
@@ -406,5 +520,5 @@ def expert_layer(x, lp, c: ModelConfig, valid, layer=None):
         stats = jnp.concatenate([jnp.stack([
             jnp.sum(valid, dtype=jnp.int32), n_held,
             jnp.sum(valid & ~held.any(1), dtype=jnp.int32),
-            jnp.int32(1)]), counts])
+            jnp.int32(1), jnp.int32(dense), n_read]), counts])
     return y, stats

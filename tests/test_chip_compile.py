@@ -441,6 +441,41 @@ def test_mixtral_prefill_reads_its_experts_where_they_lie(topo, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < every_expert / 4
 
 
+def test_mixtral_decode_walks_its_experts_where_they_lie(topo, monkeypatch):
+    """The per-head `decode_paged` at the chat cell's geometry (16 slots,
+    2 layers of Mixtral-8x7B's published widths): each expert layer has
+    its branch (the hit experts walked, or all 8 in one batched product)
+    and the walk's loop, and neither is handed a copy of the experts: the
+    branches close over the stack of layers and slice inside. One matrix
+    of one layer copied is 896 MiB of temporaries; a batched product over
+    256 rows in such a branch had both layers' `wg` and `wu` copied for
+    another layout (1855 MiB), which is why the walk stops at a small
+    tile of rows."""
+    from ray_tpu.llm.engine import decode_paged
+    from ray_tpu.models import configs, experts, init_params
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    c = configs.mixtral_8x7b(n_layers=2, remat=False)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0))))
+    pool = sds((2, c.n_kv_heads, 257, c.head_dim, PAGE), jnp.bfloat16)
+    compiled = jax.jit(partial(decode_paged, config=c),
+                       donate_argnums=(1, 2)).lower(
+        params, pool, pool, sds((16,)), sds((16,)), sds((16,), jnp.bool_),
+        sds((16, P_SEQ)), sds((experts.N_STATS + c.moe_experts,))).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 2
+    assert len(re.findall(r" while\(", text)) == 2
+    assert re.findall(r"bf16\[(?:\d,)*8,(?:4096,14336|14336,4096)\]\S* copy\(",
+                      text) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 @pytest.mark.parametrize("program", ["prefill_batch",
                                      "prefill_with_prefix_batch"])
 def test_prefill_programs_hold_no_scores_and_no_prompt_logits(

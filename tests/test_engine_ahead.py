@@ -301,7 +301,12 @@ def test_step_window_fetches_what_step_left_in_flight():
 
 # sha256 of the lowered text of each serving program, as the engine calls it
 # at _engine()'s sizes, taken on the commit before the decode loop ran ahead
-# (ea57df6): a change of schedule on the host moves none of them.
+# (ea57df6): a change of schedule on the host moves none of them. The two
+# kinds with experts were taken again at PR 43, whose counters grew two
+# slots (`experts.N_STATS` 4 -> 6: the stats argument and result, their
+# sum, and two constants more in each expert layer's concatenate: 14 lines
+# of `deepseek_v2`'s programs, 34 of the hybrid's); every other line is
+# the parent's, the values' numbering apart.
 PROGRAMS = {
     "per_head": {
         "decode_paged":
@@ -313,19 +318,19 @@ PROGRAMS = {
     },
     "latent": {
         "decode_paged":
-            "0e0725f3106899520d2755257e035251f0580776df1cd8c9175e47db02714c07",
+            "979f1dae2be5a2245df52197728d3a37a961658f4f1a2892746b7885cd4bf4a4",
         "prefill_batch":
-            "b1c41f247149617e7e8ace033cdf8959953f951f51f7647c2b12cf7b626024eb",
+            "e3a3298cb174d097bf93da4de2632023ed9497544b436f686f87565de692b430",
         "prefill_with_prefix_batch":
-            "55608f9284e73f4e9762991db555a5612d3a316a7100532f210adcc9dead919f",
+            "1b8927245278c049f665d7f51944ad00c33722acf5650cec15fb8eb8602e3950",
     },
     "hybrid": {
         "decode_paged":
-            "f778bd6228e5ce196e9bf2163ddc281a69b103bcbddf2b3272c2181e62133105",
+            "54eb863618eac5b3d2e03811ee494061e7b2743d5f54004c13466aefaf02d72b",
         "prefill_batch":
-            "55d33b24b642ab6f08c96bd8573ac35520d3debe00396bddb9d442801139e946",
+            "b7a9b155bff79759c0263a7097fcc6270b9f73287177e60f59bf0678cff00264",
         "prefill_with_prefix_batch":
-            "4a3e060a6fda1c885f390ee70e47876fe0d4099fec9e89f42df16f25ed71afe2",
+            "d16ee88eadd7266f743b74e883f950d3831334829f378177d971348823b1f0d2",
     },
 }
 # sha256 of the engine's entries (`llm.*`) of tools/graphcheck/

@@ -4,6 +4,7 @@ told which rows are real, and must equal the same programs with
 transformer._moe in the block (the form a mesh keeps), whichever branch
 of the layer a shape takes."""
 
+import dataclasses
 import re
 from functools import partial
 
@@ -117,12 +118,18 @@ def test_prefix_prefill_equals_the_gspmd_form(params, monkeypatch, branch):
     assert st[0] == MOE.n_layers * int(lengths.sum()) and st[1] == K * st[0]
 
 
-@pytest.mark.parametrize("dense_rows", [4096, 0],
-                         ids=["dense", "dispatched"])
-def test_decode_paged_equals_the_gspmd_form(params, monkeypatch, dense_rows):
+@pytest.mark.parametrize("dense_rows, turn_bytes",
+                         [(4096, None), (4096, 0), (0, None)],
+                         ids=["dense", "walked", "dispatched"])
+def test_decode_paged_equals_the_gspmd_form(params, monkeypatch, dense_rows,
+                                            turn_bytes):
     """Three slots, one inactive: its row is the padding, and what it
-    writes goes to the scratch page (page 0), garbage by contract."""
+    writes goes to the scratch page (page 0), garbage by contract.
+    "walked": a turn costs nothing, so experts this small get the branch
+    too and the two active rows' 2 to 4 hit experts are walked."""
     monkeypatch.setattr(experts, "_DENSE_ROWS", dense_rows)
+    if turn_bytes is not None:
+        monkeypatch.setattr(experts, "_TURN_BYTES", turn_bytes)
     pool = jax.random.normal(
         jax.random.PRNGKey(1),
         (MOE.n_layers, MOE.n_kv_heads, 7, MOE.head_dim, PAGE), jnp.float32)
@@ -138,6 +145,80 @@ def test_decode_paged_equals_the_gspmd_form(params, monkeypatch, dense_rows):
                                    rtol=0)
     st = np.asarray(got[3])
     assert st[0] == MOE.n_layers * 2 and st[1] == K * st[0]
+    few = MOE.n_layers if dense_rows else 0
+    assert st[4] == few and K * few <= st[5] <= E * few
+    if turn_bytes is None:
+        assert st[5] == E * few           # experts this small: all read
+
+
+# hit pattern -> (top-k, rows that are valid of 16, router column lifted,
+# the fewest and the most experts that may be hit)
+_HITS = {
+    "no_row_valid": (2, 0, None, 0, 0),
+    "one_expert_hit": (1, 16, 2, 1, 1),
+    "some_hit": (2, 1, None, 1, E - 1),
+    "every_expert_hit": (2, 16, None, E, E),
+    "stacked_layer": (2, 3, None, 1, E - 1),
+}
+
+
+@pytest.mark.parametrize("pattern", sorted(_HITS))
+def test_the_few_token_walk_equals_the_batched_product(monkeypatch, pattern):
+    """One layer, 16 rows: where a turn costs nothing the layer walks the
+    experts that were hit (all hit: the batched product, by the same
+    rule) and must equal the batched product alone; the two counters say
+    which experts' weights were read."""
+    k, n_valid, lifted, fewest, most = _HITS[pattern]
+    c = dataclasses.replace(MOE, moe_top_k=k, moe_grouped="tiles",
+                            moe_d_ff=F)
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 8))
+    lp = experts.init_expert_weights(
+        lambda shape, fan_in: jax.random.normal(next(keys), shape)
+        * fan_in ** -0.5, c, None)
+    if lifted is not None:      # every row's first choice
+        lp["router"] = lp["router"].at[:, lifted].set(0.0) + 9.0 * (
+            jnp.arange(E) == lifted)
+    x = jnp.abs(jax.random.normal(next(keys), (16, c.d_model)))
+    valid = jnp.arange(16) < n_valid
+    layer = None
+    if pattern == "stacked_layer":
+        other = jax.tree_util.tree_map(lambda a: a[::-1] * 0.5, lp)
+        lp = {**lp, **{n: jnp.stack([other[n], lp[n]])
+                       for n in ("wg", "wu", "wd")}}
+        layer = jnp.int32(1)
+    monkeypatch.setattr(experts, "_TURN_BYTES", float("inf"))
+    want, st_want = experts.expert_layer(x, lp, c, valid, layer)
+    monkeypatch.setattr(experts, "_TURN_BYTES", 0)
+    got, st = jax.jit(partial(experts.expert_layer, c=c))(
+        x, lp, valid=valid, layer=layer)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+    n_hit = int((np.asarray(st[experts.N_STATS:]) > 0).sum())
+    assert fewest <= n_hit <= most
+    assert st[4] == 1 and st[5] == n_hit      # all E where all were hit
+    assert st_want[4] == 1 and st_want[5] == E
+    np.testing.assert_array_equal(np.delete(st, 5), np.delete(st_want, 5))
+    if not n_valid:
+        np.testing.assert_array_equal(got, 0.0)
+
+
+# held experts, an expert's weights in bf16: the decode shapes of the three
+# cells whose programs reach the few-token form (PERF.md section 5)
+_CELLS = {
+    "mixtral_8x7b": (8, 3 * 4096 * 14336 * 2, True),
+    "laguna_s_2_1": (32, 3 * 3072 * 1024 * 2, False),
+    "nemotron3_nano_30b": (16, 2 * 2688 * 1856 * 2, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_which_shapes_get_the_walk(cell):
+    """The rule reads an expert's bytes, not a model: a program gets the
+    branch and the walk only where they pay with all but one expert hit.
+    Mixtral's 352 MB experts do; at 19 and 20 MB a turn's overhead is a
+    third of its read and the batched product stays alone."""
+    held, nbytes, walks = _CELLS[cell]
+    assert bool(experts._walk_pays(held - 1, held, nbytes)) is walks
+    assert not experts._walk_pays(held, held, nbytes)    # all hit: batched
 
 
 def _lowered(program, stats, *args):
@@ -161,22 +242,32 @@ def _program_args(params, name, n, S):
     return (params, _i32(n, S), _i32(n), *prefix)
 
 
-# program, requests, rows a request, whether the expert layer may loop
+# program, requests, rows a request, whether the expert layer walks tiles,
+# what a turn of the few-token walk costs (None: as shipped)
 _SHAPES = {
-    "prefill_many_rows": ("prefill_batch", 2, 128, True),
-    "prefix_prefill_many_rows": ("prefill_with_prefix_batch", 2, 128, True),
-    "prefill_few_rows": ("prefill_batch", 1, 64, False),
-    "decode": ("decode_paged", 4, 1, False),
+    "prefill_many_rows": ("prefill_batch", 2, 128, True, None),
+    "prefix_prefill_many_rows": ("prefill_with_prefix_batch", 2, 128, True,
+                                 None),
+    "prefill_few_rows": ("prefill_batch", 1, 64, False, None),
+    "prefill_over_a_small_tile": ("prefill_batch", 1, 128, False, 0),
+    "decode": ("decode_paged", 4, 1, False, 0),
+    "decode_small_experts": ("decode_paged", 4, 1, False, None),
 }
 
 
 @pytest.mark.parametrize("shape", sorted(_SHAPES))
-def test_the_lowered_programs_route(params, shape):
+def test_the_lowered_programs_route(params, monkeypatch, shape):
     """A many-row prefill carries no [n, s, E, d_ff] intermediate (nor the
-    few-token form's [E, n * s, d_ff]) and walks its tiles in loops; a
-    few-row prefill and `decode_paged` gain no `while` over the GSPMD
-    form's text (the kernel's own, off the chip, are in both)."""
-    name, n, S, loops = _SHAPES[shape]
+    few-token form's [E, n * s, d_ff]) and walks its tiles in loops. A
+    few-row prefill and `decode_paged` keep the [E, n * s, d_ff] batched
+    product in their text (every expert hit) and, where the walk can pay
+    ("decode": a turn that costs nothing), gain exactly one branch and
+    one loop an expert layer over the GSPMD form's text; experts as small
+    as these under the turn's real cost, and more rows than a small tile,
+    gain neither (the kernel's own `while`, off the chip, is in both)."""
+    name, n, S, loops, turn_bytes = _SHAPES[shape]
+    if turn_bytes is not None:
+        monkeypatch.setattr(experts, "_TURN_BYTES", turn_bytes)
     program = {"prefill_batch": prefill_batch, "decode_paged": decode_paged,
                "prefill_with_prefix_batch": prefill_with_prefix_batch}[name]
     args = _program_args(params, name, n, S)
@@ -187,10 +278,15 @@ def test_the_lowered_programs_route(params, shape):
     few = "tensor<%dx%dx%dxf32>" % (E, n * S, F)
     assert every in old and every not in new
     whiles = [len(re.findall(r"stablehlo\.while", t)) for t in (old, new)]
+    branches = [len(re.findall(r"stablehlo\.(?:case|if)", t))
+                for t in (old, new)]
     if loops:
         assert few not in new and whiles[1] > whiles[0]
-    else:
-        assert few in new and whiles[1] == whiles[0]
+        return
+    walked = shape == "decode"
+    assert few in new
+    assert whiles[1] - whiles[0] == branches[1] - branches[0]
+    assert whiles[1] - whiles[0] in ((1, MOE.n_layers) if walked else (0,))
 
 
 def test_moe_stats_count_the_real_tokens(params):
@@ -214,6 +310,9 @@ def test_moe_stats_count_the_real_tokens(params):
     assert st["held_pairs"] == sum(st["held_expert_load"])
     assert st["tokens_without_held_expert"] == 0
     assert st["expert_layer_calls"] % MOE.n_layers == 0
+    # every call here is of few rows, and experts this small are all read
+    assert st["few_token_calls"] == st["expert_layer_calls"]
+    assert st["experts_read_share"] == 1.0
     assert eng.moe_stats() == st
     # the windows count too (generate() decodes in windows)
     eng.generate([[3, 4, 5, 6, 7]], max_new_tokens=new)
@@ -221,6 +320,32 @@ def test_moe_stats_count_the_real_tokens(params):
     assert st2["routed_tokens"] >= st["routed_tokens"] + MOE.n_layers * (
         5 + new - 1)
     assert st2["held_pairs"] == K * st2["routed_tokens"]
+
+
+def test_experts_read_share_by_hand(monkeypatch):
+    """Where a turn costs nothing the walk runs: ONE request of a
+    one-token prompt feeds every expert layer call one real row or none,
+    a real row hits its K distinct experts, so the calls read K experts a
+    routed token of the E they hold."""
+    monkeypatch.setattr(experts, "_TURN_BYTES", 0)
+    # a configuration of its own: the engine's programs are shared by
+    # configuration and shape, traced once a process
+    own = dataclasses.replace(MOE, vocab=MOE.vocab - 1)
+    eng = InferenceEngine(
+        own, EngineConfig(max_slots=2, max_len=64, page_size=PAGE,
+                          prompt_buckets=(16,), eos_token=-1),
+        params=init_params(own, jax.random.PRNGKey(7)))
+    new = 5
+    eng.add_request([9], max_new_tokens=new)
+    while eng.has_work():
+        eng.step()
+    st = eng.moe_stats()
+    assert st["routed_tokens"] == MOE.n_layers * new
+    assert st["few_token_calls"] == st["expert_layer_calls"]
+    assert st["few_token_calls"] >= st["routed_tokens"]
+    assert st["experts_read_share"] == pytest.approx(
+        K * st["routed_tokens"] / (st["few_token_calls"] * E), abs=1e-12)
+    assert 0 < st["experts_read_share"] <= K / E
 
 
 def test_under_a_mesh_the_experts_keep_the_gspmd_form(params):

@@ -371,36 +371,48 @@ def test_the_eight_shares_and_the_shared_expert_once_make_the_layer(
     np.testing.assert_allclose(parts + shared, want, atol=TOL)
 
 
-@pytest.mark.parametrize("dense_rows,pass_rows,tile_rows", [
-    (4096, 4096, 64),   # few tokens: every held expert over every token
-    (0, 4096, 64),      # one pass, one block of rows: a tile an expert
-    (0, 4096, 8),       # blocks that hold rows of several experts
-    (0, 48, 8),         # the pairs in three passes of 48 rows
+@pytest.mark.parametrize("dense_rows,pass_rows,tile_rows,turn_bytes", [
+    (4096, 4096, 64, None),  # few tokens: every held expert, every token
+    (4096, 4096, 64, 0),     # few tokens, a turn costs nothing: the walk
+    (0, 4096, 64, None),     # one pass, one block of rows: a tile an expert
+    (0, 4096, 8, None),      # blocks that hold rows of several experts
+    (0, 48, 8, None),        # the pairs in three passes of 48 rows
 ])
 def test_the_forms_of_the_held_experts_product_agree(
-        reference, monkeypatch, dense_rows, pass_rows, tile_rows):
-    """`moe_grouped="tiles"`: the dense form (decode), the tile walk, and
-    the tile walk in passes all equal the reference's loop over experts;
-    padding routes nowhere; the stats are the same counts."""
+        reference, monkeypatch, dense_rows, pass_rows, tile_rows,
+        turn_bytes):
+    """`moe_grouped="tiles"`: the dense form (decode), the walk over the
+    hit experts (which experts as small as the cell's never get: a relu2
+    model's big ones would), the tile walk, and the tile walk in passes
+    all equal the reference's loop over experts; padding routes nowhere;
+    the stats are the same counts."""
     monkeypatch.setattr(experts, "_DENSE_ROWS", dense_rows)
+    if turn_bytes is not None:
+        monkeypatch.setattr(experts, "_TURN_BYTES", turn_bytes)
     monkeypatch.setattr(experts, "_MIN_PASS_ROWS", pass_rows)
     monkeypatch.setattr(experts, "_SMALL_TILE_ROWS", tile_rows)
     c = configs.tiny_hybrid()
     lp = init_params(c, jax.random.PRNGKey(0))["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(2), (48, c.d_model))
-    valid = jnp.arange(48) < 40
+    real = 40 if turn_bytes is None else 2    # 2 rows hit 6 of 8 at most
+    valid = jnp.arange(48) < real
     got, st = experts.expert_layer(x, lp, c, valid)
     want, _ = reference._experts(x, lp, c)
     shared = experts.shared_expert(x, lp, c)
-    np.testing.assert_allclose(got[:40], want[:40], atol=TOL)
-    np.testing.assert_allclose(got[40:], shared[40:], atol=TOL)
-    assert int(st[0]) == 40 and int(st[1]) == 40 * c.moe_top_k
+    np.testing.assert_allclose(got[:real], want[:real], atol=TOL)
+    np.testing.assert_allclose(got[real:], shared[real:], atol=TOL)
+    assert int(st[0]) == real and int(st[1]) == real * c.moe_top_k
     assert int(st[1]) == int(st[experts.N_STATS:].sum())
     # and XLA's ragged-dot over the sorted pairs (deepseek_v2's way)
     old, st_old = experts.expert_layer(
         x, lp, dataclasses.replace(c, moe_grouped="ragged_dot"), valid)
     np.testing.assert_allclose(got, old, atol=TOL)
-    np.testing.assert_array_equal(st, st_old)
+    few = slice(4, 6)       # the few-token form's two counters
+    np.testing.assert_array_equal(np.delete(st, few), np.delete(st_old, few))
+    assert list(st_old[few]) == [0, 0]
+    hit = int((np.asarray(st[experts.N_STATS:]) > 0).sum())
+    assert list(st[few]) == ([1, c.moe_experts if turn_bytes is None
+                              else hit] if dense_rows else [0, 0])
 
 
 def test_sigmoid_router_by_hand():
