@@ -36,6 +36,7 @@ import numpy as np
 from ray_tpu import diagnostics
 from ray_tpu.models import ModelConfig, init_params, model_module
 from ray_tpu.models.experts import N_STATS, expert_layer, stats_zero
+from ray_tpu.models.transformer import exit_step, exit_zero
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.ops.layers import apply_rope, last_rows, rmsnorm, rope
 
@@ -97,7 +98,9 @@ class EngineConfig:
     kv_layout: str = "paged"
     page_size: int = 128           # tokens per KV page (TPU lane-friendly)
     num_pages: int | None = None   # pool size; None = slots*ceil(max_len/
-    #                                page)+1 (every slot can reach max_len)
+    #                                page)+1 (every slot can reach max_len).
+    #                                A page holds `page_size` tokens of every
+    #                                cache layer (kv_stats()["page_bytes"])
     prefix_cache: bool = True      # reuse full prompt pages across requests
     # Rows one step may admit (the prompt buckets of the requests it plans,
     # added up); None = ADMIT_BUCKETS times the largest bucket. A model
@@ -212,7 +215,10 @@ def _mlp_block(x, lp, c: ModelConfig):
     of several devices shards over "ep")."""
     from ray_tpu.models.transformer import _mlp, _moe
     normed = rmsnorm(x, lp["mlp_norm"], c.norm_eps)
-    return x + (_moe(normed, lp, c) if c.moe_experts else _mlp(normed, lp))
+    y = _moe(normed, lp, c) if c.moe_experts else _mlp(normed, lp)
+    if c.post_norms:
+        y = rmsnorm(y, lp["mlp_post_norm"], c.norm_eps)
+    return x + y
 
 
 def _expert_block(x, lp, c: ModelConfig, routed):
@@ -235,7 +241,10 @@ def _expert_block(x, lp, c: ModelConfig, routed):
         {**lp, **{w: layers[w] for w in ("wg", "wu", "wd")}},
         dataclasses.replace(c, moe_grouped="tiles"),
         jnp.broadcast_to(valid, (b, s)).reshape(b * s), layer=li)
-    return x + y.reshape(b, s, d), stats + st
+    y = y.reshape(b, s, d)
+    if c.post_norms:
+        y = rmsnorm(y, lp["mlp_post_norm"], c.norm_eps)
+    return x + y, stats + st
 
 
 def _embed(params, tokens):
@@ -251,13 +260,18 @@ def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False,
     the pools it wrote them to). `fence` as in `_qkv`. With `routed` (a
     program that was handed the expert layers' counters: a model with
     experts on one device, `_serving_of`) the feed-forward is
-    `_expert_block`'s; without, `_mlp_block`'s and `stats` is None."""
+    `_expert_block`'s; without, `_mlp_block`'s and `stats` is None. With
+    `ModelConfig.post_norms` both sublayers' outputs are normed before the
+    residual add."""
     b, s, _ = x.shape
     normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
     q, k, v = _qkv(normed, lp, c, fence)
     attn, kept = attend(turn(q), turn(k), v)
     attn = attn.reshape(b, s, c.n_heads * c.head_dim).astype(x.dtype)
-    h = x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
+    out = jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
+    if c.post_norms:
+        out = rmsnorm(out, lp["attn_post_norm"], c.norm_eps)
+    h = x + out
     if routed is None:
         return _mlp_block(h, lp, c), kept, None
     x, stats = _expert_block(h, lp, c, routed)
@@ -277,13 +291,16 @@ def _prefill_attention(q, keys, values, prefix_len, pre_t: int,
     return out.transpose(0, 2, 1, 3)
 
 
-def _head(x, params, c: ModelConfig, active=None, at=None):
+def _head(x, params, c: ModelConfig, active=None, at=None,
+          closed: bool = False):
     """Final norm and the fp32 head: x [b, s, d] -> logits [b, s, vocab],
     or [b, vocab] at the one position `at` of every sequence (or from
     x [b, d], the rows a prefill program picked itself). An inactive
     slot (`active` [b]) reads -1e30 except token 0: it must not corrupt
-    metrics downstream, and argmax / categorical stay defined."""
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
+    metrics downstream, and argmax / categorical stay defined. `closed`:
+    x is a closed pass's state (`_passes`), normed already."""
+    if not closed:
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
     head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
     if at is not None:
         x = x[:, at]
@@ -324,16 +341,44 @@ def _layers(block, x, xs, layers, valid, stats):
     return x, kept, stats
 
 
+def _passes(c: ModelConfig, params, x, one_pass, rows, stats):
+    """x through the layer stack, `c.loops` times over the ONE set of layer
+    weights -> (the rows the head reads [n, d], what every cache layer
+    keeps [loops * L, ...], stats). `one_pass(x, t, stats) -> (x, kept
+    [L, ...], stats)` runs pass t's layers, which read and keep cache
+    layers t * L ..; `rows(x)` picks the head's rows. One pass: that is all
+    (the head norms its rows). More: a scan over the passes, `final_norm`
+    closing each and feeding the next, the exit rule (transformer.
+    exit_step) choosing a row's pass; the rows come back closed."""
+    if c.loops == 1:
+        x, kept, stats = one_pass(x, 0, stats)
+        return rows(x), kept, stats
+
+    def turn(carry, t):
+        x, state, *stats = carry
+        with jax.named_scope("pass"):
+            x, kept, stats = one_pass(x, t, stats[0] if stats else None)
+            x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        return (x, exit_step(params, c, t, rows(x), state),
+                *_some(stats)), kept
+
+    (_, state, *stats), kept = jax.lax.scan(
+        turn, (x, exit_zero(rows(x)), *_some(stats)), jnp.arange(c.loops))
+    return (state[0], jax.tree_util.tree_map(
+        lambda a: a.reshape(-1, *a.shape[2:]), kept),
+        stats[0] if stats else None)
+
+
 def prefill_batch(params, tokens, lengths, stats=None, *,
                   config: ModelConfig):
     """tokens [n, S] (right-padded), lengths [n] -> (logits [n, vocab]
     fp32 at each request's last token, the one row that is sampled from,
-    k,v caches [L, n, S, hkv, hd], [stats]). Causal; padding contributes
-    garbage KV beyond each true length, which insert never reads (length
-    mask), and is sent to no expert. Batched so an admission burst pays
-    ONE dispatch, not one per prompt (the vLLM-style batched prefill
-    role). `stats` (`_Serving`: the expert layers' counters, `_mlp_block`)
-    comes back counted up."""
+    k,v caches [cache layers, n, S, hkv, hd], [stats]). Causal; padding
+    contributes garbage KV beyond each true length, which insert never
+    reads (length mask), and is sent to no expert. Batched so an admission
+    burst pays ONE dispatch, not one per prompt (the vLLM-style batched
+    prefill role). `stats` (`_Serving`: the expert layers' counters,
+    `_mlp_block`) comes back counted up."""
     c = config
     x = _embed(params, tokens)
     n, s = tokens.shape
@@ -349,11 +394,14 @@ def prefill_batch(params, tokens, lengths, stats=None, *,
                                   v.transpose(0, 2, 1, 3), no_prefix, 0,
                                   c), (k, v)
 
-    x, (ks, vs), stats = _layers(
-        lambda x, lp, routed: _block(x, lp, c, turn, attend, routed=routed),
-        x, params["layers"], params["layers"],
-        None if stats is None else _real_rows(s, lengths), stats)
-    return (_head(last_rows(x, lengths), params, c), ks, vs) + _some(stats)
+    valid = None if stats is None else _real_rows(s, lengths)
+    x, (ks, vs), stats = _passes(
+        c, params, x, lambda x, _t, stats: _layers(
+            lambda x, lp, routed: _block(x, lp, c, turn, attend,
+                                         routed=routed),
+            x, params["layers"], params["layers"], valid, stats),
+        lambda x: last_rows(x, lengths), stats)
+    return (_head(x, params, c, closed=c.loops > 1), ks, vs) + _some(stats)
 
 
 def prefill(params, tokens, lengths, config: ModelConfig):
@@ -374,8 +422,17 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
     Cached K is stored post-RoPE at absolute positions, so it is reused
     as-is; suffix positions offset by prefix_len. Returns (logits
     [n, vocab] f32 at each suffix's last token, suffix k/v caches
-    [L, n, S, hkv, hd], [stats]); `stats` as in prefill_batch."""
+    [cache layers, n, S, hkv, hd], [stats]); `stats` as in prefill_batch.
+    A looped stack's pass t reads cache layers t * L .. of the pools (its
+    OWN keys of the prefix), one layer's slice at a time."""
     c = config
+    if c.loops > 1 and prefix_pages.shape[1] == 1:
+        # A table of one page turns the page gather into a slice, and XLA
+        # then re-lays-out BOTH WHOLE pools for it (2 x 4 GiB of
+        # temporaries at ouro_2_6b's 43 pages: the described-chip compile
+        # refuses it, and so did the chip). Two pages, the second scratch
+        # and masked by prefix_len, stay a gather.
+        prefix_pages = jnp.pad(prefix_pages, ((0, 0), (0, 1)))
     x = _embed(params, tokens)
     n, s = tokens.shape
     pre_t = prefix_pages.shape[1] * pool_k.shape[4]
@@ -396,20 +453,31 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
 
     def layer(x, scan_in, routed):
         lp, pk, pv = scan_in
+        if c.loops > 1:     # pk: the cache layer's index in both pools
+            pk, pv = (jax.lax.dynamic_index_in_dim(pool, pk, keepdims=False)
+                      for pool in (pool_k, pool_v))
         return _block(x, lp, c, partial(apply_rope, sin=sin, cos=cos),
                       partial(attend, pk, pv), routed=routed)
 
-    x, (ks, vs), stats = _layers(
-        layer, x, (params["layers"], pool_k, pool_v), params["layers"],
-        None if stats is None else _real_rows(s, lengths), stats)
-    return (_head(last_rows(x, lengths), params, c), ks, vs) + _some(stats)
+    def one_pass(x, t, stats):
+        if c.loops == 1:
+            xs = (params["layers"], pool_k, pool_v)
+        else:
+            at = t * c.n_layers + jnp.arange(c.n_layers)
+            xs = (params["layers"], at, at)
+        return _layers(layer, x, xs, params["layers"], valid, stats)
+
+    valid = None if stats is None else _real_rows(s, lengths)
+    x, (ks, vs), stats = _passes(c, params, x, one_pass,
+                                 lambda x: last_rows(x, lengths), stats)
+    return (_head(x, params, c, closed=c.loops > 1), ks, vs) + _some(stats)
 
 
 def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
     """insert_pages for a whole admission burst in one dispatch.
-    ks/vs [L, n, S, hkv, hd]; page_ids [n, n_tab] (0 = scratch, where
-    duplicate writes may race — scratch holds garbage by contract);
-    lengths [n]."""
+    ks/vs [L, n, S, hkv, hd], L the pools' cache layers; page_ids
+    [n, n_tab] (0 = scratch, where duplicate writes may race — scratch
+    holds garbage by contract); lengths [n]."""
     L, n, S, hkv, hd = ks.shape
     page = pool_k.shape[4]
     n_tab = page_ids.shape[1]
@@ -436,7 +504,10 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     [B, P] page ids in position order (0 = unused -> scratch page, whose
     garbage the position mask hides). The new token's KV is written at
     (write_page, lengths % page); compute scales with the bucketed P,
-    not the model's max context. Pool layout [L, hkv, N, hd, page].
+    not the model's max context. Pool layout [cache layers, hkv, N, hd,
+    page]: a layer of K and V a layer of the model, and of a looped stack
+    (`ModelConfig.loops` > 1) one a pass and layer, pass t's layer l
+    reading and writing cache layer t * n_layers + l.
     -> (logits, pool_k, pool_v, [stats]): `stats` as in prefill_batch, an
     inactive slot's row the padding.
 
@@ -444,7 +515,11 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     - the layer loop is UNROLLED python, not lax.scan with the pools as
       scan xs/ys — scan materializes a fresh stacked pool output every
       step (a full-pool HBM copy per token: measured ~30ms/step for a
-      0.6GB pool);
+      0.6GB pool). A looped stack's PASSES are a loop whose carry is the
+      pools (a carry stays where it lies), the layers unrolled inside it;
+      the cache layer then reaches the kernel as a traced scalar (it
+      prefetches its layer anyway), and the token's K and V are written
+      BY the kernel (`paged_decode_insert_attention`);
     - the pools are touched only where they lie: one
       `dynamic_update_slice` column a slot for the write, and the kernel
       reads the stacked pool at the layer it is handed. A scatter
@@ -459,7 +534,8 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
       owns — XLA lowers the gather-then-attend formulation at ~10% of
       HBM bandwidth and it dominated the whole step (measured 40+ ms vs
       ~1.5ms/step for the same KV working set through the kernel)."""
-    from ray_tpu.ops.paged_attention import paged_decode_attention
+    from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                             paged_decode_insert_attention)
     c = config
     B, P = page_tables.shape
     page = pool_k.shape[4]
@@ -477,6 +553,10 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     # and V column of a slot lands at the same place
     w_at = [(w_page[b], w_off[b]) for b in range(B)]
     zero = jnp.zeros((), jnp.int32)
+    # a looped stack writes through its tables: an inactive slot's row is
+    # all scratch
+    write_tables = (jnp.where(active[:, None], page_tables, 0)
+                    if c.loops > 1 else None)
 
     def write(pool, new, li):
         # token KV [B,1,hkv,hd] -> per slot a column [1,hkv,1,hd,1] at
@@ -498,6 +578,17 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
         return pool
 
     def attend(li, pool_k, pool_v, q, k, v):
+        if c.loops > 1:
+            # the write rides the kernel's own page DMA (its docstring has
+            # what 2,688 column updates a step cost ouro_2_6b). What makes
+            # it pay is updates a step x tiles a column, not the passes:
+            # `loops` stands in for that only so that no other model's
+            # lowered text moves in the PR that brought it. ROADMAP S15
+            # (2026-10-01) hands every model this call and deletes write().
+            attn, pool_k, pool_v = paged_decode_insert_attention(
+                q[:, 0], pool_k, pool_v, k[:, 0], v[:, 0], lengths + 1,
+                write_tables, layer=li, name="looped_paged_decode")
+            return attn, (pool_k, pool_v)
         pool_k, pool_v = write(pool_k, k, li), write(pool_v, v, li)
         # attend INCLUSIVE of the just-written token: positions
         # < lengths+1 == positions <= lengths
@@ -505,14 +596,37 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
             q[:, 0], pool_k, pool_v, lengths + 1, page_tables, layer=li)
         return attn, (pool_k, pool_v)
 
-    for li in range(c.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        x, (pool_k, pool_v), stats = _block(
-            x, lp, c, partial(apply_rope, sin=sin, cos=cos),
-            partial(attend, li, pool_k, pool_v), fence=True,
-            routed=None if stats is None else (
-                active[:, None], stats, params["layers"], li))
-    return (_head(x, params, c, active, at=0), pool_k, pool_v) + _some(stats)
+    def layers(x, pool_k, pool_v, stats, first):
+        # one pass: cache layers first .. first + n_layers
+        for li in range(c.n_layers):
+            lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+            x, (pool_k, pool_v), stats = _block(
+                x, lp, c, partial(apply_rope, sin=sin, cos=cos),
+                partial(attend, first + li, pool_k, pool_v), fence=True,
+                routed=None if stats is None else (
+                    active[:, None], stats, params["layers"], li))
+        return x, pool_k, pool_v, stats
+
+    if c.loops == 1:
+        x, pool_k, pool_v, stats = layers(x, pool_k, pool_v, stats, 0)
+        return (_head(x, params, c, active, at=0), pool_k,
+                pool_v) + _some(stats)
+
+    def turn(t, carry):
+        x, state, pool_k, pool_v, *stats = carry
+        with jax.named_scope("pass"):
+            x, pool_k, pool_v, stats = layers(
+                x, pool_k, pool_v, stats[0] if stats else None,
+                t * c.n_layers)
+            x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        return (x, exit_step(params, c, t, x[:, 0], state), pool_k, pool_v,
+                *_some(stats))
+
+    _, state, pool_k, pool_v, *stats = jax.lax.fori_loop(
+        0, c.loops, turn,
+        (x, exit_zero(x[:, 0]), pool_k, pool_v, *_some(stats)))
+    return (_head(state[0], params, c, active, closed=True), pool_k,
+            pool_v) + tuple(stats)
 
 
 def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
@@ -828,9 +942,16 @@ def _resolve_params(model_config: ModelConfig, params, mesh, rules,
 
 
 def _refuse(c: ModelConfig, what: str):
-    """What a model that brings its own serving programs does not run
-    with: each is a program this file spells for per-head K and V alone
-    (ROADMAP D1)."""
+    """What a model that brings its own serving programs, or a looped
+    stack, does not run with: each is a program this file spells for
+    per-head K and V of layers that run once (ROADMAP D1)."""
+    if c.kv_cache == "per_head":
+        raise ValueError(
+            f"ModelConfig.loops={c.loops} runs its {c.n_layers} layers "
+            f"{c.loops} times, a pass with K and V of its own "
+            f"({c.cache_layers} cache layers), which does not run with "
+            f"{what}: only prefill_batch, prefill_with_prefix_batch, "
+            f"insert and decode_paged loop over the passes")
     keeps = {
         "latent": f"ModelConfig.attention={c.attention!r} keeps a latent KV "
                   f"cache",
@@ -884,11 +1005,12 @@ class _Serving:
 
 
 def _per_head_pools(c: ModelConfig, num_pages: int, page: int) -> tuple:
-    # [L, hkv, N, hd, page] — kv-heads outermost after layers and
+    # [cache layers, hkv, N, hd, page] — a cache layer a layer (of a looped
+    # stack: a pass and layer), kv-heads outermost after them and
     # head_dim BEFORE page so the Pallas decode kernel can DMA
     # per-page blocks [hkv, hd, page] whose trailing dims
     # (hd, 128) satisfy Mosaic's (8, 128) tiling.
-    shape = (c.n_layers, c.n_kv_heads, num_pages, c.head_dim, page)
+    shape = (c.cache_layers, c.n_kv_heads, num_pages, c.head_dim, page)
     return (jax.ShapeDtypeStruct(shape, c.jdtype),) * 2
 
 
@@ -957,7 +1079,10 @@ class InferenceEngine:
         # module, over pools of its own shapes (_Serving); page accounting,
         # prefix hashing, chunked prefill and preemption below are shared.
         self.serving = _serving_of(model_config, mesh)
-        self._own = model_config.kv_cache != "per_head"
+        # (a looped stack keeps per-head K and V and this file's four
+        # programs, and is refused what the others are)
+        self._own = (model_config.kv_cache != "per_head"
+                     or model_config.loops > 1)
         if self._own:
             if self.e.speculation is not None:
                 _refuse(model_config, "EngineConfig.speculation="
@@ -1843,8 +1968,14 @@ class InferenceEngine:
 
     def kv_stats(self) -> dict:
         """Pool/HBM accounting for tests, the dashboard, and the bench."""
+        pools = [p for p in (self.cache_k, self.cache_v) if p is not None]
         return {
             "layout": "paged", "num_pages": self.num_pages,
+            # layers of K and V (or of latents) a token keeps in the page
+            # pools, and the bytes one page takes of them: pages x
+            # page_bytes = bytes, for any model
+            "cache_layers": pools[0].shape[0],
+            "page_bytes": sum(p.nbytes // self.num_pages for p in pools),
             "free_pages": len(self.free_pages),
             "cached_pages": len(self.cached_lru),
             "pages_in_use": self.num_pages - 1 - len(self.free_pages)
@@ -2569,9 +2700,9 @@ class PrefillEngine:
         self.c = model_config
         self.e = engine_config or EngineConfig()
         self.mesh = mesh
-        if model_config.kv_cache != "per_head":
+        if model_config.kv_cache != "per_head" or model_config.loops > 1:
             _refuse(model_config, "the prefill pool, which exports "
-                                  "per-head K and V")
+                                  "per-head K and V of layers that run once")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         self._prefill = _shared_jit(

@@ -18,20 +18,26 @@ def model_module(config: ModelConfig):
     """The module that implements `config`: nemotron_h where it has a
     `layer_pattern`, windowed where it has an `attn_pattern`, else by its
     `attention` field this package's transformer ("gqa") or deepseek_v2
-    ("mla")."""
+    ("mla"). A looped stack (`loops` > 1) and a layer's two further norms
+    (`post_norms`) are the transformer's alone: the others refuse them."""
     if config.layer_pattern:
-        from ray_tpu.models import nemotron_h
-        return nemotron_h
-    if config.attn_pattern:
-        from ray_tpu.models import windowed
-        return windowed
-    if config.attention == "mla":
-        from ray_tpu.models import deepseek_v2
-        return deepseek_v2
-    if config.attention != "gqa":
+        from ray_tpu.models import nemotron_h as module
+    elif config.attn_pattern:
+        from ray_tpu.models import windowed as module
+    elif config.attention == "mla":
+        from ray_tpu.models import deepseek_v2 as module
+    elif config.attention != "gqa":
         raise ValueError(f"ModelConfig.attention={config.attention!r}: "
                          f"\"gqa\" or \"mla\"")
-    return transformer
+    else:
+        return transformer
+    if config.loops > 1 or config.post_norms:
+        raise ValueError(
+            f"ModelConfig.loops={config.loops}, post_norms="
+            f"{config.post_norms}: {module.__name__} runs its layers once, "
+            f"each with two norms; only models/transformer.py (attention="
+            f"\"gqa\", no layer_pattern, no attn_pattern) loops")
+    return module
 
 
 def init_params(config: ModelConfig, key) -> dict:

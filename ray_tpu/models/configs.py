@@ -220,3 +220,25 @@ def tiny_laguna(**kw) -> ModelConfig:
         moe_shared_experts=1, moe_shared_d_ff=32, moe_score="sigmoid",
         moe_routed_scale=2.5, moe_norm_topk=True, moe_scale_normed=True,
         moe_grouped="tiles")
+
+
+def ouro_2_6b(**kw) -> ModelConfig:
+    """ByteDance Ouro-2.6B at its published sizes: 48 layers run 4 times
+    over one set of weights (192 cache layers), 16 heads of 128 on 16 K/V
+    heads (a group of one), four norms a layer, the final norm closing
+    every pass, an exit gate a pass (threshold 1: the last pass), an untied
+    head."""
+    return _preset(
+        kw, vocab=49152, d_model=2048, n_layers=48, n_heads=16,
+        n_kv_heads=16, head_size=128, d_ff=5632, rope_theta=1e6,
+        norm_eps=1e-6, dtype="bfloat16", tie_embeddings=False, loops=4,
+        exit_threshold=1.0, post_norms=True)
+
+
+def tiny_ouro(**kw) -> ModelConfig:
+    """CPU-test scale of ouro_2_6b's structure: 3 layers run 4 times, 4
+    heads of 16 on 4 K/V heads, four norms a layer, an untied head."""
+    return _preset(
+        kw, vocab=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        head_size=16, d_ff=96, rope_theta=1e6, norm_eps=1e-6,
+        tie_embeddings=False, loops=4, exit_threshold=1.0, post_norms=True)

@@ -122,10 +122,28 @@ class ModelConfig:
     # "per_head": o_h <- sigmoid(u W_gate)_h * o_h, one scalar a head and
     # token from the layer's normed input, before W_o. "" = no gate.
     attn_gate: str = ""
+    # --- a looped stack (loops > 1): this file and llm/engine.py ---
+    # The `n_layers` layers run `loops` times over ONE set of weights, pass
+    # t's layer l with K and V of its own (cache layer t * n_layers + l);
+    # `final_norm` closes every pass and its output is the next pass's
+    # input; an exit gate (one linear unit on the closed pass's state)
+    # chooses, a position at a time, the pass whose state the head reads:
+    # the first whose exit probabilities add up to `exit_threshold` (the
+    # last at 1.0). Every pass is computed whatever the gate says.
+    loops: int = 1
+    exit_threshold: float = 1.0
+    # Two further norms a layer, on the attention's and the feed-forward's
+    # OUTPUT before the residual add (`attn_post_norm`, `mlp_post_norm`).
+    post_norms: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K and V a token keeps: one a pass and layer."""
+        return self.loops * self.n_layers
 
     @property
     def kv_cache(self) -> str:
@@ -176,6 +194,17 @@ def init_params(config: ModelConfig, key) -> dict:
         "wo": dense_init(ks[3], (L, c.n_heads * hd, d), c.n_heads * hd),
         "mlp_norm": norm_init((L, d)),
     }
+    if c.post_norms:
+        # Seeded gains of (2 L)^-1/2, the depth-scaled residual init at
+        # the normed outputs: at gains of 1 every sublayer adds a unit-RMS
+        # vector whatever its input, a looped stack's closing norm makes
+        # that the whole stream's size at each pass's start, and the
+        # seeded function then amplifies rounding ten times and more
+        # (Ouro-2.6B's widths on the chip: bfloat16 forward against the
+        # float32 reference, median |d log p| 0.20-0.31 at gains of 1,
+        # 0.019 at 0.1; PERF.md section 2, PR 45)
+        post = jnp.full((L, d), (2 * L) ** -0.5, dt)
+        layer.update({"attn_post_norm": post, "mlp_post_norm": post})
     if c.moe_experts:
         X = c.moe_experts
         layer.update({
@@ -198,6 +227,10 @@ def init_params(config: ModelConfig, key) -> dict:
     }
     if not c.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (d, c.vocab), d)
+    if c.loops > 1:
+        params["exit_gate"] = {
+            "w": dense_init(jax.random.fold_in(k_head, 1), (d,), d),
+            "b": jnp.zeros((), dt)}
     return params
 
 
@@ -212,6 +245,9 @@ def param_logical_axes(config: ModelConfig) -> dict:
         "wo": ("layer", "heads", "embed"),
         "mlp_norm": ("layer", None),
     }
+    if c.post_norms:
+        layer.update({"attn_post_norm": ("layer", None),
+                      "mlp_post_norm": ("layer", None)})
     if c.moe_experts:
         layer.update({
             "router": ("layer", "embed", None),
@@ -232,6 +268,8 @@ def param_logical_axes(config: ModelConfig) -> dict:
     }
     if not c.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if c.loops > 1:
+        axes["exit_gate"] = {"w": (None,), "b": ()}
     return axes
 
 
@@ -289,8 +327,41 @@ def _mlp(x, lp):
     return swiglu(x, lp["wg"], lp["wu"], lp["wd"])
 
 
+def exit_zero(rows) -> tuple:
+    """The exit rule's state before the first pass, for rows shaped as
+    `rows` [..., d]: (the state the head reads, the probability that no
+    pass so far was the exit, the exit probabilities added up, whether the
+    exit pass is behind)."""
+    lead = rows.shape[:-1]
+    return (jnp.zeros_like(rows), jnp.ones(lead, jnp.float32),
+            jnp.zeros(lead, jnp.float32), jnp.zeros(lead, bool))
+
+
+def exit_step(params, c: ModelConfig, t, rows, state) -> tuple:
+    """The exit rule past pass `t` (traced or not), whose closed state is
+    `rows` [..., d]: lambda_t = sigmoid(rows . w + b) in float32, p_t =
+    lambda_t * prod_{s<t}(1 - lambda_s) (the last pass takes what is
+    left), and the exit pass is the first whose p add up to
+    `exit_threshold`, the last if none does. -> `exit_zero`'s tuple, moved
+    on. It chooses what the head reads; it skips nothing."""
+    h_exit, still, cdf, chosen = state
+    gate = params["exit_gate"]
+    with jax.named_scope("exit_gate"):
+        lam = jax.nn.sigmoid(
+            jnp.einsum("...d,d->...", rows.astype(jnp.float32),
+                       gate["w"].astype(jnp.float32))
+            + gate["b"].astype(jnp.float32))
+        last = t == c.loops - 1
+        cdf = cdf + jnp.where(last, still, lam * still)
+        now = ~chosen & ((cdf >= c.exit_threshold) | last)
+        return (jnp.where(now[..., None], rows, h_exit),
+                still * (1.0 - lam), cdf, chosen | now)
+
+
 def hidden_states(params, tokens, config: ModelConfig, mesh=None):
-    """tokens [batch, seq] -> final-norm hidden states [batch, seq, d]."""
+    """tokens [batch, seq] -> final-norm hidden states [batch, seq, d]: of
+    a looped stack (`loops` > 1) the closed state of each position's exit
+    pass."""
     c = config
     if mesh is not None and mesh.devices.size > 1:
         # One-hot matmul lookup instead of gather (the iota-embed trick):
@@ -314,11 +385,16 @@ def hidden_states(params, tokens, config: ModelConfig, mesh=None):
     sin, cos = rope(positions, c.head_dim, c.rope_theta)
 
     def layer_body(x, lp):
-        h = x + _attention(rmsnorm(x, lp["attn_norm"], c.norm_eps),
-                           lp, c, sin, cos, mesh)
+        a = _attention(rmsnorm(x, lp["attn_norm"], c.norm_eps),
+                       lp, c, sin, cos, mesh)
+        if c.post_norms:
+            a = rmsnorm(a, lp["attn_post_norm"], c.norm_eps)
+        h = x + a
         normed = rmsnorm(h, lp["mlp_norm"], c.norm_eps)
-        out = h + (_moe(normed, lp, c) if c.moe_experts else _mlp(normed, lp))
-        return out, None
+        m = _moe(normed, lp, c) if c.moe_experts else _mlp(normed, lp)
+        if c.post_norms:
+            m = rmsnorm(m, lp["mlp_post_norm"], c.norm_eps)
+        return h + m, None
 
     body = layer_body
     if c.remat:
@@ -326,9 +402,23 @@ def hidden_states(params, tokens, config: ModelConfig, mesh=None):
     unroll = c.unroll_layers
     if unroll is None:
         unroll = (not c.remat and c.n_layers <= 12 and c.d_model <= 1024)
-    x, _ = jax.lax.scan(body, x, params["layers"],
-                        unroll=c.n_layers if unroll else 1)
-    return rmsnorm(x, params["final_norm"], c.norm_eps)
+
+    def one_pass(x):
+        x, _ = jax.lax.scan(body, x, params["layers"],
+                            unroll=c.n_layers if unroll else 1)
+        return rmsnorm(x, params["final_norm"], c.norm_eps)
+
+    if c.loops == 1:
+        return one_pass(x)
+
+    def turn(carry, t):
+        with jax.named_scope("pass"):
+            x = one_pass(carry[0])
+        return (x, exit_step(params, c, t, x, carry[1])), None
+
+    (_, state), _ = jax.lax.scan(turn, (x, exit_zero(x)),
+                                 jnp.arange(c.loops))
+    return state[0]
 
 
 def forward(params, tokens, config: ModelConfig, mesh=None):
@@ -543,6 +633,12 @@ def loss_fn(params, batch, config: ModelConfig, mesh=None,
     head FALLS BACK to the GSPMD product; nothing is padded) and a logits
     tensor under LOSS_CHUNK_MIN_BYTES keep the plain `_xent` program.
     """
+    if config.loops > 1:
+        raise ValueError(
+            f"ModelConfig.loops={config.loops}: loss_fn does not train a "
+            f"looped stack (its loss over the passes' exits and "
+            f"rematerialisation over passes are not written); forward() "
+            f"and the serving engine run it")
     if "tokens" in batch:
         inputs = batch["tokens"][:, :-1]
         targets = batch["tokens"][:, 1:]

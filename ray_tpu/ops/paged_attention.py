@@ -85,14 +85,14 @@ def paged_decode_attention(q, pool_k, pool_v, lengths, page_tables, *,
     mode is also ~100x slower than XLA on CPU)."""
     import os
     if os.environ.get("RAY_TPU_PAGED_ATTN_IMPL") == "xla":
-        return _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables,
-                                 lows, layer=layer)
+        return _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables,
+                                    lows, layer)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     page, hd = pool_k.shape[4], pool_k.shape[3]
     if not interpret and not _mosaic_tiles(page, hd):
-        return _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables,
-                                 lows, layer=layer)
+        return _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables,
+                                    lows, layer)
     return _paged_decode_dma(q, pool_k, pool_v, lengths, page_tables, layer,
                              lows, interpret=interpret, name=name)
 
@@ -102,6 +102,18 @@ def _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables, lows=None, *,
                       layer: int):
     return paged_decode_attention_reference(
         q, pool_k[layer], pool_v[layer], lengths, page_tables, lows)
+
+
+def _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables, lows,
+                         layer):
+    """The XLA gather formulation at `layer`, an int or a traced scalar (a
+    looped stack's cache layer, inside its loop over passes)."""
+    if isinstance(layer, int):
+        return _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables,
+                                 lows, layer=layer)
+    return paged_decode_attention_reference(
+        q, *(jax.lax.dynamic_index_in_dim(p, layer, keepdims=False)
+             for p in (pool_k, pool_v)), lengths, page_tables, lows)
 
 
 def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
@@ -397,6 +409,55 @@ def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     o_ref[0] = (acc_ref[...] / l.reshape(hkv, gq, 1)).astype(o_ref.dtype)
 
 
+def _fused_layer_kernel(layer_ref, lengths_ref, tables_ref, *refs,
+                        **statics):
+    """`_fused_kernel` at layer `layer_ref[0]`, one more prefetched scalar
+    (a looped stack's cache layer, traced inside its loop over passes)."""
+    _fused_kernel(lengths_ref, tables_ref, *refs, layer=layer_ref[0],
+                  **statics)
+
+
+def paged_decode_insert_attention(q, pool_k, pool_v, knew, vnew, lengths,
+                                  page_tables, *, layer, name: str,
+                                  interpret: bool | None = None):
+    """One decode token a slot with its K/V insert FUSED into the
+    attention kernel (`_fused_kernel` at one query a slot): q [B, h, hd],
+    knew / vnew [B, hkv, hd] the token's own K and V, written at position
+    lengths - 1 of cache layer `layer` (an int or a traced scalar) as the
+    page that holds it streams through VMEM; the slot attends positions <
+    lengths. -> (attn [B, h, hd], pool_k, pool_v), the pools aliased.
+
+    Why a looped stack's decode takes it: a column written by
+    `dynamic_update_slice` is read-modified-written a tile at a time, 128
+    tiles at 16 K/V heads of 128 (7.1 us an update on the v5e, 2,688
+    updates a step on ouro_2_6b: 19.7 of its 58 ms step, PERF.md section
+    5, PR 45); here the write is one more page DMA of a page the kernel
+    holds already. Off the chip (interpret mode does not carry the
+    kernel's write-back through the aliasing, see
+    paged_verify_insert_attention) and for pages Mosaic cannot tile: the
+    XLA column insert, then `paged_decode_attention`.
+
+    So tier-1 EXECUTES only the fallback, and test_chip_compile only
+    compiles the fused kernel at a traced layer and a group of one: its
+    numbers are checked on the chip alone, by the cell's `correct` and by
+    `perfbench/tools/checkdist_ouro.py` (the verify skill has the command):
+    run that after any edit to `_fused_kernel` or `_verify_insert_call`."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    page, hd = pool_k.shape[4], pool_k.shape[3]
+    if interpret or not _mosaic_tiles(page, hd):
+        pool_k, pool_v = _insert_tokens_xla(
+            pool_k, pool_v, knew[:, None], vnew[:, None], lengths,
+            page_tables, layer)
+        return paged_decode_attention(
+            q, pool_k, pool_v, lengths, page_tables, layer=layer,
+            interpret=interpret, name=name), pool_k, pool_v
+    out, pool_k, pool_v = _verify_insert_call(
+        q[:, None], pool_k, pool_v, knew[:, None], vnew[:, None], lengths,
+        page_tables, layer, name=name)
+    return out[:, 0], pool_k, pool_v
+
+
 def paged_verify_insert_attention(q, pool_k, pool_v, knew, vnew,
                                   lengths, page_tables, layer: int, *,
                                   interpret: bool | None = None):
@@ -459,6 +520,16 @@ def _insert_tokens_xla(pool_k, pool_v, knew, vnew, lengths,
 def _verify_insert_dma(q, k_pages, v_pages, knew, vnew, lengths,
                        page_tables, *, layer: int = 0,
                        interpret: bool = False):
+    return _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
+                               page_tables, layer, interpret=interpret)
+
+
+def _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
+                        page_tables, layer, *, interpret: bool = False,
+                        name: str | None = None):
+    """The fused kernel's call. `layer` an int: a static of the kernel
+    (the speculative verify programs', unrolled over layers); a traced
+    scalar: prefetched before the two the kernel takes anyway."""
     B, S, h, hd = q.shape
     L, hkv, N, _, page = k_pages.shape
     assert h % hkv == 0, (h, hkv)
@@ -470,26 +541,33 @@ def _verify_insert_dma(q, k_pages, v_pages, knew, vnew, lengths,
     kn = knew.transpose(0, 2, 3, 1).reshape(B, hkv * hd, S)
     vn = vnew.transpose(0, 2, 3, 1).reshape(B, hkv * hd, S)
     scale = 1.0 / float(np.sqrt(hd))
-    kernel = functools.partial(_fused_kernel, page=page, scale=scale,
-                               pages_per_seq=P, n_q=S, layer=layer)
+    statics = dict(page=page, scale=scale, pages_per_seq=P, n_q=S)
+    if isinstance(layer, int):
+        kernel = functools.partial(_fused_kernel, layer=layer, **statics)
+        scalars = (lengths, page_tables)
+    else:
+        kernel = functools.partial(_fused_layer_kernel, **statics)
+        scalars = (jnp.asarray(layer, jnp.int32).reshape(1), lengths,
+                   page_tables)
+    n_sc = len(scalars)
     out, k_pages, v_pages = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=n_sc,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, hkv, g * S, hd),
-                             lambda b, lens, tbl: (b, 0, 0, 0)),
+                             lambda b, *_scalars: (b, 0, 0, 0)),
                 pl.BlockSpec((1, hkv * hd, S),
-                             lambda b, lens, tbl: (b, 0, 0)),
+                             lambda b, *_scalars: (b, 0, 0)),
                 pl.BlockSpec((1, hkv * hd, S),
-                             lambda b, lens, tbl: (b, 0, 0)),
+                             lambda b, *_scalars: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),      # k_pages in HBM
                 pl.BlockSpec(memory_space=pl.ANY),      # v_pages in HBM
             ],
             out_specs=[
                 pl.BlockSpec((1, hkv, g * S, hd),
-                             lambda b, lens, tbl: (b, 0, 0, 0)),
+                             lambda b, *_scalars: (b, 0, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),      # aliased k_pages
                 pl.BlockSpec(memory_space=pl.ANY),      # aliased v_pages
             ],
@@ -509,12 +587,13 @@ def _verify_insert_dma(q, k_pages, v_pages, knew, vnew, lengths,
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
         # operand indices count the scalar-prefetch args first:
-        # 0=lengths 1=tables 2=q 3=knew 4=vnew 5=k_pages 6=v_pages
-        input_output_aliases={5: 1, 6: 2},
+        # [layer] lengths tables | q knew vnew k_pages v_pages
+        input_output_aliases={n_sc + 3: 1, n_sc + 4: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths, page_tables, q4, kn, vn, k_pages, v_pages)
+        name=name,
+    )(*scalars, q4, kn, vn, k_pages, v_pages)
     out = out.reshape(B, hkv, g, S, hd).transpose(0, 3, 1, 2, 4).reshape(
         B, S, h, hd)
     return out, k_pages, v_pages

@@ -232,6 +232,27 @@ def _window(kind, h):
     return build
 
 
+def _looped_decode(topo):
+    """ouro_2_6b's paged decode call: 16 query heads on 16 K/V heads (a
+    GROUP OF ONE: a q block of [16, 1, 128], one row a head in both
+    products, a page DMA of [16, 128, 128]), 7 slots over the cell's 43
+    pages of 192 cache layers, the token's K and V written by the kernel,
+    the cache layer a traced scalar as inside the loop over passes."""
+    from ray_tpu.ops import paged_attention as pa
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    pool, new = sds((192, 16, 43, HD, PAGE)), sds((7, 16, HD))
+    return (lambda q, pk, pv, kn, vn, ln, tb, layer:
+            pa.paged_decode_insert_attention(
+                q, pk, pv, kn, vn, ln, tb, layer=layer,
+                name="looped_paged_decode", interpret=False)), (
+        new, pool, pool, new, new, sds((7,), jnp.int32),
+        sds((7, 6), jnp.int32), sds((), jnp.int32))
+
+
 CASES = {
     "flash_fwd_2x2048": _flash((2, 2048), grad=False),
     "flash_bwd_2x2048": _flash((2, 2048), grad=True),
@@ -260,6 +281,10 @@ CASES = {
     "swa_paged_decode_6_to_1": _window("decode", 48),
     "gqa_prefill_laguna_full_1x8192_over_prefix": _gqa_prefill(48, 8, 1, 8192,
                                                                8192),
+    "looped_paged_decode_ouro_group_of_one": _looped_decode,
+    "gqa_prefill_ouro_2_6b_1x512": _gqa_prefill(16, 16, 1, 512, 0),
+    "gqa_prefill_ouro_2_6b_1x256_over_prefix": _gqa_prefill(16, 16, 1, 256,
+                                                            512),
 }
 
 
@@ -285,7 +310,20 @@ def _qwen2_7b(layers, one):
     return c, params
 
 
-def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
+def _ouro_2_6b(layers, one):
+    """(config, parameter shapes on device `one`) of `layers` layers at
+    ouro_2_6b's published widths, run its four times."""
+    from ray_tpu.models import configs, init_params
+    c = configs.ouro_2_6b(n_layers=layers)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0))))
+    return c, params
+
+
+@pytest.mark.parametrize("stack", ["once", "looped"])
+def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch,
+                                                      stack):
     """The whole `decode_paged` program at the qwen2_7b chat cell's
     geometry (2 of its layers, 16 slots, 257 pages), pools donated: the
     optimized HLO holds no `copy`, `scatter` or `slice` whose result is a
@@ -293,32 +331,70 @@ def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch):
     pool. With a scatter over the page axis and the kernel called on
     `pool[li]` this compile held 2 + 2 whole-pool copies, 4 scatters and
     4 per-layer slices, and 98.5 MiB of temporaries against a 64.25 MiB
-    pool."""
+    pool. "looped": the same at ouro_2_6b's geometry (2 of its layers run
+    four times: 8 cache layers of 16 K/V heads, 7 slots, the cell's 43
+    pages), where the passes are ONE loop that carries the pools and the
+    cache layer reaches the updates and the kernel as a traced scalar: a
+    dynamic slice of a cache layer would be a copy too."""
     from ray_tpu.llm.engine import decode_paged
     # the dispatcher asks the backend whether to interpret the kernel;
     # the process is on the CPU, the compile is for the chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layers, slots, n_pages = 2, 16, 257
     one = SingleDeviceSharding(topo.devices[0])
-    c, params = _qwen2_7b(layers, one)
+    layers = 2
+    if stack == "once":
+        slots, n_pages, hkv, p_seq = 16, 257, HKV, P_SEQ
+        c, params = _qwen2_7b(layers, one)
+    else:
+        slots, n_pages, hkv, p_seq = 7, 43, 16, 6
+        c, params = _ouro_2_6b(layers, one)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-    pool = sds((layers, HKV, n_pages, HD, PAGE), jnp.bfloat16)
+    pool = sds((c.cache_layers, hkv, n_pages, HD, PAGE), jnp.bfloat16)
     compiled = jax.jit(partial(decode_paged, config=c),
                        donate_argnums=(1, 2)).lower(
         params, pool, pool, sds((slots,), jnp.int32),
         sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
-        sds((slots, P_SEQ), jnp.int32)).compile()
+        sds((slots, p_seq), jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= layers
+    assert (" while(" in text) == (stack == "looped")
     pool_sized = re.compile(
-        r"= bf16\[(?:%d|1),%d,%d,%d,%d\]\S* (copy|scatter|slice)[-(]"
-        % (layers, HKV, n_pages, HD, PAGE))
+        r"= bf16\[(?:%d|1),%d,%d,%d,%d\]\S* "
+        r"(copy|scatter|slice|dynamic-slice)[-(]"
+        % (c.cache_layers, hkv, n_pages, HD, PAGE))
     assert pool_sized.findall(text) == []
-    pool_bytes = layers * HKV * n_pages * HD * PAGE * 2
+    pool_bytes = c.cache_layers * hkv * n_pages * HD * PAGE * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+@pytest.mark.parametrize("prefix_pages", [1, 4])
+def test_looped_prefix_prefill_copies_no_pool(topo, monkeypatch,
+                                              prefix_pages):
+    """`prefill_with_prefix_batch` of a looped stack (ouro_2_6b's widths, 2
+    layers run four times, the cell's 43 pages) reads a pass's cache layers
+    out of the whole pools by a traced index: no `copy` of a whole pool in
+    its HLO. Over a table of ONE page XLA turned the page gather into a
+    slice and re-laid-out both whole pools for it (2 x 4 GiB at 48 layers:
+    `Used 21.84G of 15.75G hbm`, on the chip); the program pads such a
+    table to two."""
+    from ray_tpu.llm.engine import prefill_with_prefix_batch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    c, params = _ouro_2_6b(2, one)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    pool = sds((c.cache_layers, 16, 43, HD, PAGE), jnp.bfloat16)
+    text = jax.jit(partial(prefill_with_prefix_batch, config=c)).lower(
+        params, sds((1, 256)), sds((1,)), pool, pool,
+        sds((1, prefix_pages)), sds((1,))).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.findall(r"= bf16\[%d,16,43,%d,%d\]\S* copy\("
+                      % (c.cache_layers, HD, PAGE), text) == []
 
 
 def test_hybrid_decode_leaves_its_pools_where_they_lie(topo, monkeypatch):
