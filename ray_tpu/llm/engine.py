@@ -503,11 +503,12 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
     """One token for every slot against the paged pool. page_tables
     [B, P] page ids in position order (0 = unused -> scratch page, whose
     garbage the position mask hides). The new token's KV is written at
-    (write_page, lengths % page); compute scales with the bucketed P,
-    not the model's max context. Pool layout [cache layers, hkv, N, hd,
-    page]: a layer of K and V a layer of the model, and of a looped stack
-    (`ModelConfig.loops` > 1) one a pass and layer, pass t's layer l
-    reading and writing cache layer t * n_layers + l.
+    position `lengths` of its slot, by the attention kernel; compute
+    scales with the bucketed P, not the model's max context. Pool layout
+    [cache layers, hkv, N, hd, page]: a layer of K and V a layer of the
+    model, and of a looped stack (`ModelConfig.loops` > 1) one a pass and
+    layer, pass t's layer l reading and writing cache layer
+    t * n_layers + l.
     -> (logits, pool_k, pool_v, [stats]): `stats` as in prefill_batch, an
     inactive slot's row the padding.
 
@@ -517,83 +518,47 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
       step (a full-pool HBM copy per token: measured ~30ms/step for a
       0.6GB pool). A looped stack's PASSES are a loop whose carry is the
       pools (a carry stays where it lies), the layers unrolled inside it;
-      the cache layer then reaches the kernel as a traced scalar (it
-      prefetches its layer anyway), and the token's K and V are written
-      BY the kernel (`paged_decode_insert_attention`);
-    - the pools are touched only where they lie: one
-      `dynamic_update_slice` column a slot for the write, and the kernel
-      reads the stacked pool at the layer it is handed. A scatter
+      the cache layer reaches the kernel as a scalar it prefetches,
+      traced inside that loop;
+    - the pools are touched only where they lie, and only by the kernel:
+      it reads the stacked pool at the layer it is handed and writes the
+      token's K and V into the page that holds them as that page streams
+      through VMEM (`paged_decode_insert_attention`: ONE write path for
+      every per-head model; an inactive slot moves nothing). A scatter
       `pool.at[li, heads, w_page, :, w_off].set(...)` indexes the pool's
       minor (page) axis, so XLA moved the whole donated pool into an
       hd-minor layout, scattered there, sliced each `pool[li]` out and
       re-tiled it for the kernel, and moved the pool back: 6.8 ms of
-      qwen2_7b's 18.9 ms step on the v5e, 0.9 ms as it is now (PERF.md
-      section 5, PR 29);
+      qwen2_7b's 18.9 ms step on the v5e (PR 29); a `dynamic_update_slice`
+      column a slot, pool and layer, which followed, was 384 updates and
+      0.68 ms of op time plus 0.21 ms of gaps between them in its 10.45
+      ms step, 21.8 of ouro_2_6b's 58.9 (PERF.md section 5, PR 45, PR 46);
     - attention runs the Pallas paged-decode kernel
       (ops/paged_attention.py), which DMAs exactly the pages each slot
       owns — XLA lowers the gather-then-attend formulation at ~10% of
       HBM bandwidth and it dominated the whole step (measured 40+ ms vs
       ~1.5ms/step for the same KV working set through the kernel)."""
-    from ray_tpu.ops.paged_attention import (paged_decode_attention,
-                                             paged_decode_insert_attention)
+    from ray_tpu.ops.paged_attention import paged_decode_insert_attention
     c = config
-    B, P = page_tables.shape
-    page = pool_k.shape[4]
     x = _embed(params, tokens)[:, None, :]  # [B,1,d]
     sin, cos = rope(lengths[:, None], c.head_dim, c.rope_theta)
-    w_idx = jnp.clip(lengths // page, 0, P - 1)
-    w_page = jnp.take_along_axis(page_tables, w_idx[:, None], 1)[:, 0]
-    # overshooting slots past the table bucket write scratch, not their
-    # last real page (same guard as verify_paged)
-    w_page = jnp.where(lengths // page >= P, 0, w_page)
-    w_page = jnp.where(active, w_page, 0)  # inactive -> scratch page
-    w_off = lengths % page
-
-    # per slot the (page, offset) scalars, taken once: every layer's K
-    # and V column of a slot lands at the same place
-    w_at = [(w_page[b], w_off[b]) for b in range(B)]
-    zero = jnp.zeros((), jnp.int32)
-    # a looped stack writes through its tables: an inactive slot's row is
-    # all scratch
-    write_tables = (jnp.where(active[:, None], page_tables, 0)
-                    if c.loops > 1 else None)
-
-    def write(pool, new, li):
-        # token KV [B,1,hkv,hd] -> per slot a column [1,hkv,1,hd,1] at
-        # (li, 0, w_page, 0, w_off). The columns are laid out once as the
-        # pool has them (hd down the sublanes, one slot a lane), so an
-        # update is a lane slice and not a re-laid-out copy of its own;
-        # every index is an int32 scalar in range by construction, so no
-        # wrap-around arithmetic is staged. Both keep the program small:
-        # qwen2_7b makes 384 updates a step, and what each drags along
-        # is paid at every trace, compile and cache look-up of warm-up.
-        cols = new.astype(pool.dtype).reshape(
-            B, c.n_kv_heads, c.head_dim).transpose(1, 2, 0).reshape(
-            1, c.n_kv_heads, 1, c.head_dim, B)
-        layer = jnp.full((), li, jnp.int32)
-        for b, (pg, off) in enumerate(w_at):
-            pool = jax.lax.dynamic_update_slice(
-                pool, jax.lax.slice_in_dim(cols, b, b + 1, axis=4),
-                (layer, zero, pg, zero, off), allow_negative_indices=False)
-        return pool
+    # attend INCLUSIVE of the token being written: positions < lengths + 1.
+    # An inactive slot's 0 moves nothing (no page read, merged or written
+    # back; its output row is 0 and _head ignores it), and its table is
+    # all scratch for the fallback off the chip, whose insert has no such
+    # length. A slot past its table bucket overlaps no page of it: the
+    # kernel writes nothing.
+    limits = jnp.where(active, lengths + 1, 0)
+    write_tables = jnp.where(active[:, None], page_tables, 0)
+    # the call's name in a device trace, which the benchmark's readers
+    # match: looped_attn_* a looped stack's, paged_attn_* (`^_paged_decode`)
+    # every other per-head model's
+    name = "looped_paged_decode" if c.loops > 1 else "_paged_decode_insert"
 
     def attend(li, pool_k, pool_v, q, k, v):
-        if c.loops > 1:
-            # the write rides the kernel's own page DMA (its docstring has
-            # what 2,688 column updates a step cost ouro_2_6b). What makes
-            # it pay is updates a step x tiles a column, not the passes:
-            # `loops` stands in for that only so that no other model's
-            # lowered text moves in the PR that brought it. ROADMAP S15
-            # (2026-10-01) hands every model this call and deletes write().
-            attn, pool_k, pool_v = paged_decode_insert_attention(
-                q[:, 0], pool_k, pool_v, k[:, 0], v[:, 0], lengths + 1,
-                write_tables, layer=li, name="looped_paged_decode")
-            return attn, (pool_k, pool_v)
-        pool_k, pool_v = write(pool_k, k, li), write(pool_v, v, li)
-        # attend INCLUSIVE of the just-written token: positions
-        # < lengths+1 == positions <= lengths
-        attn = paged_decode_attention(
-            q[:, 0], pool_k, pool_v, lengths + 1, page_tables, layer=li)
+        attn, pool_k, pool_v = paged_decode_insert_attention(
+            q[:, 0], pool_k, pool_v, k[:, 0], v[:, 0], limits, write_tables,
+            layer=li, name=name)
         return attn, (pool_k, pool_v)
 
     def layers(x, pool_k, pool_v, stats, first):

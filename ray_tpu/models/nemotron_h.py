@@ -383,7 +383,7 @@ def _attention_decode(u, lp, c: ModelConfig, layer: int, pool_k, pool_v,
                       lengths, page_tables, w_at):
     """u [B, d], one token a slot: its K and V columns written where the
     pools lie (one dynamic_update_slice a slot, as llm/engine.decode_paged
-    and for its reasons), then the paged kernel over the slot's pages."""
+    did until PR 46; ROADMAP S15), then the paged kernel over its pages."""
     B = u.shape[0]
     hold = jax.lax.optimization_barrier
     zero = jnp.zeros((), jnp.int32)

@@ -215,7 +215,7 @@ def _attention_prefill(u, lp, c: ModelConfig, kind: str, turn, prefix,
 def _write_columns(pool, new, layer: int, w_at, c: ModelConfig):
     """One token's K or V a slot, new [B, hkv, hd], into layer `layer` of
     `pool` at w_at[b] = (page, offset): one dynamic_update_slice a slot,
-    as llm/engine.decode_paged and for its reasons."""
+    as llm/engine.decode_paged wrote until PR 46 (ROADMAP S15: next)."""
     B = new.shape[0]
     zero = jnp.zeros((), jnp.int32)
     at_layer = jnp.full((), layer, jnp.int32)

@@ -284,14 +284,22 @@ def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
                   kbuf, vbuf, m_ref, l_ref, acc_ref, sem, wsem, *,
                   page: int, scale: float, pages_per_seq: int, n_q: int,
                   layer: int):
-    """Verify attention with the KV INSERT fused in (JetStream-style):
-    the kernel already streams every page of the slot; when the page
-    holding the n_q new tokens passes through VMEM, their K/V columns are
-    merged in (one [hkv*hd, n_q] x [n_q, page] one-hot matmul) and the
-    merged page is DMAd back to the pool, which is input/output-aliased.
-    Token-granular XLA scatters serialized at ~2us/row and cost more than
-    the whole forward; here the write rides the DMA pipeline the attend
-    already pays for."""
+    """Attention with the KV INSERT fused in (JetStream-style): the kernel
+    already streams every page of the slot; when the page holding the n_q
+    new tokens passes through VMEM, their K/V columns are merged in and
+    the merged page is DMAd back to the pool, which is input/output-
+    aliased. Token-granular XLA scatters serialized at ~2us/row and cost
+    more than the whole forward; here the write rides the DMA pipeline the
+    attend already pays for. The written values are BITWISE the new K and
+    V (a select, no product through the MXU: speculation's greedy
+    exactness rests on it).
+
+    The new tokens' blocks differ with the static n_q, a shape: n_q > 1
+    (speculative verify) [hkv*hd, n_q], rolled along the page's lanes to
+    where the tokens land; n_q == 1 (every decode step) the token's
+    [hkv, hd] as the projections leave them, copied into every column, so
+    that no re-lay-out runs before the call (it was 14 us a call on
+    ouro_2_6b, PERF.md section 6, PR 46)."""
     b = pl.program_id(0)
     length = lengths_ref[b]          # = base + 1 (limit of query 0)
     base = length - 1                # position of the first new token
@@ -321,12 +329,21 @@ def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
 
     q = q_ref[0].astype(jnp.float32)               # [hkv, g*n_q, hd]
     hkv, gq, hd = q.shape
-    # page-padded new-token blocks in NATIVE dtype (bitwise-exact writes)
-    knew = knew_ref[0]                             # [hkv*hd, n_q]
-    vnew = vnew_ref[0]
-    zpad = jnp.zeros((knew.shape[0], page - n_q), knew.dtype)
-    knew_pad = jnp.concatenate([knew, zpad], axis=1)
-    vnew_pad = jnp.concatenate([vnew, zpad.astype(vnew.dtype)], axis=1)
+
+    def landed(new_ref, at):
+        """The new tokens' block as a page [hkv, hd, page] whose column
+        `at` + j holds token j, `at` in (-n_q, page) (float32: roll and
+        the transpose only lower for 32-bit lanes, and bf16 -> f32 -> bf16
+        is exact, so the write stays bitwise)."""
+        new = new_ref[0].astype(jnp.float32)
+        if n_q == 1:  # [hkv, hd] down the sublanes, then hd under the page:
+            # the token in every column
+            return jnp.swapaxes(jnp.broadcast_to(
+                new[:, None, :], (hkv, page, hd)), 1, 2)
+        pad = jnp.zeros((new.shape[0], page - n_q), new.dtype)
+        return pltpu.roll(jnp.concatenate([new, pad], axis=1),  # [hkv*hd, n_q]
+                          jax.lax.rem(at + page, page), 1).reshape(
+                              hkv, hd, page)
 
     def body(i, _):
         slot = jax.lax.rem(i, 2)
@@ -344,36 +361,20 @@ def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
         @pl.when(overlaps)
         def _merge():
             pid = tables_ref[b, i]
-            # Token j lands at column base+j-lo. Shift the (page-padded)
-            # new-token block so column p holds token p-(base-lo), then
-            # select the covered columns. Roll+select keeps the written
-            # values BITWISE exact — a one-hot matmul merge would round
-            # through the MXU's bf16 multiply and break the speculative
-            # greedy-exactness contract.
-            cols = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-            idx = cols - (base - lo)
-            sel = (idx >= 0) & (idx < n_q)             # [1, page]
-            shift = jax.lax.rem(base - lo + page, page)
-            # roll only lowers for 32-bit lanes; bf16 -> f32 -> bf16 is
-            # exact (f32 is a superset), so the write stays bitwise
-            newk = pltpu.roll(knew_pad.astype(jnp.float32), shift,
-                              1).reshape(hkv, hd, page)
-            newv = pltpu.roll(vnew_pad.astype(jnp.float32), shift,
-                              1).reshape(hkv, hd, page)
-            sel = sel.reshape(1, 1, page)
-            kbuf[slot] = jnp.where(sel, newk.astype(kbuf.dtype),
-                                   kbuf[slot])
-            vbuf[slot] = jnp.where(sel, newv.astype(vbuf.dtype),
-                                   vbuf[slot])
+            # Token j lands at column base+j-lo: select the covered
+            # columns of the block shifted there.
+            at = base - lo
+            idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2) - at
+            sel = (idx >= 0) & (idx < n_q)
+            kbuf[slot] = jnp.where(
+                sel, landed(knew_ref, at).astype(kbuf.dtype), kbuf[slot])
+            vbuf[slot] = jnp.where(
+                sel, landed(vnew_ref, at).astype(vbuf.dtype), vbuf[slot])
             # write the merged page back to the (aliased) pool
             pltpu.make_async_copy(
                 kbuf.at[slot], k_hbm.at[layer, :, pid], wsem.at[0]).start()
             pltpu.make_async_copy(
                 vbuf.at[slot], v_hbm.at[layer, :, pid], wsem.at[1]).start()
-            pltpu.make_async_copy(
-                kbuf.at[slot], k_hbm.at[layer, :, pid], wsem.at[0]).wait()
-            pltpu.make_async_copy(
-                vbuf.at[slot], v_hbm.at[layer, :, pid], wsem.at[1]).wait()
 
         k = kbuf[slot].astype(jnp.float32)             # [hkv, hd, page]
         v = vbuf[slot].astype(jnp.float32)
@@ -401,6 +402,18 @@ def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
         acc_ref[...] = acc_ref[...] * alpha[:, None].reshape(
             hkv, gq, 1) + pv
         m_ref[...] = m_new
+
+        @pl.when(overlaps)
+        def _written():
+            # the write-back flew under the page's flash update (5 % of a
+            # call at 16 K/V heads, PERF.md section 6, PR 46); nothing
+            # lands in kbuf[slot] before the next turn's prefetch
+            pid = tables_ref[b, i]
+            pltpu.make_async_copy(
+                kbuf.at[slot], k_hbm.at[layer, :, pid], wsem.at[0]).wait()
+            pltpu.make_async_copy(
+                vbuf.at[slot], v_hbm.at[layer, :, pid], wsem.at[1]).wait()
+
         return 0
 
     jax.lax.fori_loop(0, npg, body, 0)
@@ -425,23 +438,28 @@ def paged_decode_insert_attention(q, pool_k, pool_v, knew, vnew, lengths,
     knew / vnew [B, hkv, hd] the token's own K and V, written at position
     lengths - 1 of cache layer `layer` (an int or a traced scalar) as the
     page that holds it streams through VMEM; the slot attends positions <
-    lengths. -> (attn [B, h, hd], pool_k, pool_v), the pools aliased.
+    lengths. A slot of length 0 moves nothing (no page read, none written;
+    its row of the output is 0), and one whose position lies past its
+    table writes nothing. -> (attn [B, h, hd], pool_k, pool_v), the pools
+    aliased.
 
-    Why a looped stack's decode takes it: a column written by
-    `dynamic_update_slice` is read-modified-written a tile at a time, 128
-    tiles at 16 K/V heads of 128 (7.1 us an update on the v5e, 2,688
-    updates a step on ouro_2_6b: 19.7 of its 58 ms step, PERF.md section
-    5, PR 45); here the write is one more page DMA of a page the kernel
-    holds already. Off the chip (interpret mode does not carry the
+    Why every per-head decode step takes it: a column written by
+    `dynamic_update_slice` is read-modified-written a tile at a time (0.57
+    us + 0.051 us a tile on the v5e: 2.2 us at qwen2_7b's 4 K/V heads, 384
+    updates and 0.86 ms of its 10.5 ms step; 7.1 us at ouro_2_6b's 16,
+    2,688 updates and 19.7 ms of its 58 ms step), whatever the slot holds;
+    here the write is one more page DMA of a page the kernel holds
+    already, and an idle slot costs nothing (PERF.md sections 5 and 6, PR
+    45 and PR 46). Off the chip (interpret mode does not carry the
     kernel's write-back through the aliasing, see
     paged_verify_insert_attention) and for pages Mosaic cannot tile: the
     XLA column insert, then `paged_decode_attention`.
 
     So tier-1 EXECUTES only the fallback, and test_chip_compile only
-    compiles the fused kernel at a traced layer and a group of one: its
-    numbers are checked on the chip alone, by the cell's `correct` and by
-    `perfbench/tools/checkdist_ouro.py` (the verify skill has the command):
-    run that after any edit to `_fused_kernel` or `_verify_insert_call`."""
+    compiles the fused kernel: its numbers are checked on the chip alone,
+    by the cells' `correct` and by `perfbench/tools/checkdist.py` and
+    `checkdist_ouro.py` (the verify skill has the commands): run those
+    after any edit to `_fused_kernel` or `_verify_insert_call`."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     page, hd = pool_k.shape[4], pool_k.shape[3]
@@ -452,10 +470,22 @@ def paged_decode_insert_attention(q, pool_k, pool_v, knew, vnew, lengths,
         return paged_decode_attention(
             q, pool_k, pool_v, lengths, page_tables, layer=layer,
             interpret=interpret, name=name), pool_k, pool_v
-    out, pool_k, pool_v = _verify_insert_call(
-        q[:, None], pool_k, pool_v, knew[:, None], vnew[:, None], lengths,
+    return _decode_insert_dma(q, pool_k, pool_v, knew, vnew, lengths,
+                              page_tables, jnp.asarray(layer, jnp.int32),
+                              name=name)
+
+
+@functools.partial(jax.jit, static_argnames=("name",))
+def _decode_insert_dma(q, k_pages, v_pages, knew, vnew, lengths, page_tables,
+                       layer, *, name: str):
+    """The layer a prefetched scalar whether the program unrolls its
+    layers or loops over passes, and the call a jit of its own: the L
+    calls of a decode program share ONE traced, lowered and compiled
+    kernel, as `_dma_kernel`'s do."""
+    out, k_pages, v_pages = _verify_insert_call(
+        q[:, None], k_pages, v_pages, knew[:, None], vnew[:, None], lengths,
         page_tables, layer, name=name)
-    return out[:, 0], pool_k, pool_v
+    return out[:, 0], k_pages, v_pages
 
 
 def paged_verify_insert_attention(q, pool_k, pool_v, knew, vnew,
@@ -537,9 +567,13 @@ def _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
     P = page_tables.shape[1]
     q4 = q.reshape(B, S, hkv, g, hd).transpose(0, 2, 3, 1, 4).reshape(
         B, hkv, g * S, hd)
-    # [B, S, hkv, hd] -> [B, hkv*hd, S] for the in-kernel one-hot matmul
-    kn = knew.transpose(0, 2, 3, 1).reshape(B, hkv * hd, S)
-    vn = vnew.transpose(0, 2, 3, 1).reshape(B, hkv * hd, S)
+    if S == 1:   # one token a slot: its K and V as they come, [B, hkv, hd]
+        kn, vn = knew[:, 0], vnew[:, 0]
+    else:        # [B, S, hkv, hd] -> [B, hkv*hd, S]: tokens along the lanes
+        kn = knew.transpose(0, 2, 3, 1).reshape(B, hkv * hd, S)
+        vn = vnew.transpose(0, 2, 3, 1).reshape(B, hkv * hd, S)
+    new_spec = pl.BlockSpec((1,) + kn.shape[1:],
+                            lambda b, *_scalars: (b,) + (0,) * (kn.ndim - 1))
     scale = 1.0 / float(np.sqrt(hd))
     statics = dict(page=page, scale=scale, pages_per_seq=P, n_q=S)
     if isinstance(layer, int):
@@ -558,10 +592,8 @@ def _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
             in_specs=[
                 pl.BlockSpec((1, hkv, g * S, hd),
                              lambda b, *_scalars: (b, 0, 0, 0)),
-                pl.BlockSpec((1, hkv * hd, S),
-                             lambda b, *_scalars: (b, 0, 0)),
-                pl.BlockSpec((1, hkv * hd, S),
-                             lambda b, *_scalars: (b, 0, 0)),
+                new_spec,
+                new_spec,
                 pl.BlockSpec(memory_space=pl.ANY),      # k_pages in HBM
                 pl.BlockSpec(memory_space=pl.ANY),      # v_pages in HBM
             ],
@@ -720,6 +752,7 @@ def paged_decode_attention_reference(q, k_pages, v_pages, lengths,
     if lows is not None:
         mask &= jnp.arange(T)[None, None, None] >= lows[:, None, None, None]
     s = jnp.where(mask, s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
+    # a slot of length 0 attends nothing: a row of 0, as the kernels give
+    pr = jnp.where(mask, jax.nn.softmax(s, axis=-1), 0.0)
     out = jnp.einsum("bkgt,bktd->bkgd", pr, cv.astype(jnp.float32))
     return out.reshape(B, h, hd).astype(q.dtype)

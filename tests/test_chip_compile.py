@@ -101,6 +101,14 @@ def _paged(kind):
             return (lambda *a: pa.paged_decode_attention(
                 *a, layer=1, interpret=False)), (
                 q, pool, pool, lengths, tables)
+        if kind == "decode_insert":   # decode_paged's call of every layer
+            q, new = sds((SLOTS, H, HD), jnp.bfloat16), sds(
+                (SLOTS, HKV, HD), jnp.bfloat16)
+            return (lambda q, pk, pv, kn, vn, ln, tb:
+                    pa.paged_decode_insert_attention(
+                        q, pk, pv, kn, vn, ln, tb, layer=1,
+                        name="_paged_decode_insert", interpret=False)), (
+                q, pool, pool, new, new, lengths, tables)
         S = 5  # spec_k=4 drafts + the token they follow
         q = sds((SLOTS, S, H, HD), jnp.bfloat16)
         if kind == "verify":
@@ -261,6 +269,7 @@ CASES = {
     "paged_decode": _paged("decode"),
     "paged_verify": _paged("verify"),
     "paged_verify_insert": _paged("verify_insert"),
+    "paged_decode_insert": _paged("decode_insert"),
     "latent_decode": _latent("decode"),
     "mla_prefill_over_prefix": _latent("prefill"),
     "gqa_prefill_qwen2_7b_1x1024": _gqa_prefill(28, 4, 1, 1024, 0),
@@ -326,16 +335,20 @@ def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch,
                                                       stack):
     """The whole `decode_paged` program at the qwen2_7b chat cell's
     geometry (2 of its layers, 16 slots, 257 pages), pools donated: the
-    optimized HLO holds no `copy`, `scatter` or `slice` whose result is a
-    pool or one layer of it, and the program's temporaries do not hold a
-    pool. With a scatter over the page axis and the kernel called on
-    `pool[li]` this compile held 2 + 2 whole-pool copies, 4 scatters and
-    4 per-layer slices, and 98.5 MiB of temporaries against a 64.25 MiB
-    pool. "looped": the same at ouro_2_6b's geometry (2 of its layers run
-    four times: 8 cache layers of 16 K/V heads, 7 slots, the cell's 43
-    pages), where the passes are ONE loop that carries the pools and the
-    cache layer reaches the updates and the kernel as a traced scalar: a
-    dynamic slice of a cache layer would be a copy too."""
+    optimized HLO holds no `copy`, `scatter`, `slice` or
+    `dynamic-update-slice` whose result is a pool or one layer of it, a
+    layer's kernel call writes the token's K and V itself (both pools
+    among its results, aliased to its operands), and the program's
+    temporaries do not hold a pool. With a scatter over the page axis and
+    the kernel called on `pool[li]` this compile held 2 + 2 whole-pool
+    copies, 4 scatters and 4 per-layer slices, and 98.5 MiB of temporaries
+    against a 64.25 MiB pool; with a column a slot written by
+    `dynamic_update_slice` (PR 29 to PR 45) 64 updates of a pool.
+    "looped": the same at ouro_2_6b's geometry (2 of its layers run four
+    times: 8 cache layers of 16 K/V heads, 7 slots, the cell's 43 pages),
+    where the passes are ONE loop that carries the pools and the cache
+    layer reaches the kernel as a traced scalar: a dynamic slice of a
+    cache layer would be a copy too."""
     from ray_tpu.llm.engine import decode_paged
     # the dispatcher asks the backend whether to interpret the kernel;
     # the process is on the CPU, the compile is for the chip
@@ -359,11 +372,18 @@ def test_decode_paged_leaves_its_pools_where_they_lie(topo, monkeypatch,
         sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
         sds((slots, p_seq), jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= layers
     assert (" while(" in text) == (stack == "looped")
+    shape = r"bf16\[%d,%d,%d,%d,%d\]\S*" % (c.cache_layers, hkv, n_pages, HD,
+                                           PAGE)
+    writing = re.findall(
+        r"= \(\S+ %s, %s\) custom-call\(.*"
+        r'custom_call_target="tpu_custom_call".*'
+        r"output_to_operand_aliasing=\{\{1\}: \(\d+, \{\}\), "
+        r"\{2\}: \(\d+, \{\}\)\}" % (shape, shape), text)
+    assert len(writing) == text.count("tpu_custom_call") == layers
     pool_sized = re.compile(
         r"= bf16\[(?:%d|1),%d,%d,%d,%d\]\S* "
-        r"(copy|scatter|slice|dynamic-slice)[-(]"
+        r"(copy|scatter|slice|dynamic-slice|dynamic-update-slice)[-(]"
         % (c.cache_layers, hkv, n_pages, HD, PAGE))
     assert pool_sized.findall(text) == []
     pool_bytes = c.cache_layers * hkv * n_pages * HD * PAGE * 2

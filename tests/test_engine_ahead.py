@@ -306,11 +306,15 @@ def test_step_window_fetches_what_step_left_in_flight():
 # slots (`experts.N_STATS` 4 -> 6: the stats argument and result, their
 # sum, and two constants more in each expert layer's concatenate: 14 lines
 # of `deepseek_v2`'s programs, 34 of the hybrid's); every other line is
-# the parent's, the values' numbering apart.
+# the parent's, the values' numbering apart. The per-head `decode_paged`
+# was taken again at PR 46: the token's K and V go to the attention call
+# (off the chip its XLA insert) where 2 * slots * layers column updates
+# stood; its two prefill programs and the six programs of the other kinds
+# passed as they were.
 PROGRAMS = {
     "per_head": {
         "decode_paged":
-            "1b3987354f695707f622b3b5b1d122f134f6cc5e94c56339502718f6cbf479d1",
+            "ddfb2b7a0ca6ac68da44e5d92d38adb2588ff9dcd29ef545126b03b25cb9d7a0",
         "prefill_batch":
             "7fa5792158f9e2cbedd1d91dcd51ffbd100fb5da31623fc8a8c611748f9a3d42",
         "prefill_with_prefix_batch":
@@ -335,9 +339,12 @@ PROGRAMS = {
 }
 # sha256 of the engine's entries (`llm.*`) of tools/graphcheck/
 # fingerprints.json, as sorted JSON: the parent's file gives the same. (It
-# was the whole file's hash until PR 36 added a train graph's entry.)
+# was the whole file's hash until PR 36 added a train graph's entry.) Taken
+# again at PR 46 for `llm.decode_paged@1dev` and `llm.decode_pool_window@1dev`
+# (`decode_window` steps `decode_paged`): flops and bytes of the CPU's
+# lowering moved, donation and aliasing did not; the five others stand.
 FINGERPRINTS = (
-    "8940eb752d69dfe233c3780f2906ca2c96a5c1641fba0c55c2269696825f6c51")
+    "5432a3ba08b29d84f1611825e5023359e4dc74b3be2c4b752a496d18cfdbbe6d")
 
 
 def _lowered_digests(kind) -> dict:

@@ -724,12 +724,12 @@ _PAGED_CONFIGS = {
 _PAGE, _SLOTS, _TABLE = 16, 2, 2
 
 
-def _paged_args(c):
+def _paged_args(c, page=_PAGE):
     """(params, pool_k, pool_v, page_tables) for `_SLOTS` slots of
     `_TABLE` pages each; page 0 is the engine's scratch page."""
     params = init_params(c, jax.random.PRNGKey(7))
     pool = jnp.zeros((c.n_layers, c.n_kv_heads, 1 + _SLOTS * _TABLE,
-                      c.head_dim, _PAGE), jnp.float32)
+                      c.head_dim, page), jnp.float32)
     tables = 1 + jnp.arange(_SLOTS * _TABLE, dtype=jnp.int32).reshape(
         _SLOTS, _TABLE)
     return params, pool, pool, tables
@@ -815,7 +815,9 @@ def _offences(jaxpr, tainted, is_view, offends):
     `offends(primitive, output shapes)` names: [(primitive, shapes)].
     `tainted` is a set of jaxpr vars; the outputs of an equation that
     `is_view(primitive, positions of its tainted inputs)` accepts join
-    it, and a call's inner jaxpr is walked with the taint carried in."""
+    it, as do the outputs a kernel aliases to its tainted inputs (the
+    pools a fused insert writes where they lie), and a call's inner jaxpr
+    is walked with the taint carried in and out."""
     found = []
     for eqn in jaxpr.eqns:
         hit = [i for i, v in enumerate(eqn.invars)
@@ -828,11 +830,16 @@ def _offences(jaxpr, tainted, is_view, offends):
                  if isinstance(p, (jex_core.Jaxpr, jex_core.ClosedJaxpr))]
         if is_view(name, hit):
             tainted.update(eqn.outvars)
+        elif name == "pallas_call":
+            tainted.update(eqn.outvars[o] for i, o in
+                           eqn.params["input_output_aliases"] if i in hit)
         elif len(inner) == 1 and name in ("pjit", "jit", "closed_call",
                                           "custom_jvp_call"):
             sub = getattr(inner[0], "jaxpr", inner[0])
-            found += _offences(sub, tainted | {sub.invars[i] for i in hit},
-                               is_view, offends)
+            inside = tainted | {sub.invars[i] for i in hit}
+            found += _offences(sub, inside, is_view, offends)
+            tainted.update(out for out, var in zip(eqn.outvars, sub.outvars)
+                           if var in inside)
         elif offends(name, shapes):
             found.append((name, shapes))
     return found
@@ -880,30 +887,38 @@ def test_paged_programs_read_weights_in_place(family, program):
 
 
 def _pool_touches(jaxpr, pools, per_layer):
-    """Equations that scatter into a KV pool or build a `per_layer`-shaped
-    array out of one (`pool[li]`, with or without its unit axis). `pools`:
-    the jaxpr vars that are a pool; a `dynamic_update_slice` INTO a pool
-    is a pool."""
+    """Equations that scatter into a KV pool, update a slice of one or
+    build a `per_layer`-shaped array out of one (`pool[li]`, with or
+    without its unit axis). `pools`: the jaxpr vars that are a pool; what a
+    kernel aliases to a pool is a pool (`_offences`)."""
     size = int(np.prod(per_layer))
     return _offences(
-        jaxpr, pools,
-        lambda name, hit: name == "dynamic_update_slice" and hit == [0],
-        lambda name, shapes: name.startswith("scatter") or any(
+        jaxpr, pools, lambda name, hit: False,
+        lambda name, shapes: name.startswith("scatter") or (
+            name == "dynamic_update_slice") or any(
             s[-len(per_layer):] == per_layer and int(np.prod(s)) == size
             for s in shapes))
 
 
 @pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
-def test_decode_paged_touches_its_pools_in_place(family):
-    """`decode_paged` writes the new token's K and V columns into the
-    stacked pools where they lie and hands the kernel the stacked pools:
-    no `scatter` over a pool and no per-layer view `pool[li]`. The
-    scatter indexed the pool's minor (page) axis, so on the chip XLA
-    re-laid the whole pool out and back and re-tiled a `pool[li]` slice
-    for every layer's kernel call, on every token (ledger PR 28:
-    `copy.*` of `bf16[12,4,257,128,128]`, 23 % of qwen2_7b's busy time)."""
+def test_decode_paged_touches_its_pools_in_place(family, monkeypatch):
+    """`decode_paged` hands the kernel the stacked pools and takes them
+    back from it, the new token's K and V written by the kernel where the
+    pools lie (a call a layer, both pools aliased): no `scatter` over a
+    pool, no `dynamic_update_slice` into one and no per-layer view
+    `pool[li]`. The scatter indexed the pool's minor (page) axis, so on
+    the chip XLA re-laid the whole pool out and back and re-tiled a
+    `pool[li]` slice for every layer's kernel call, on every token (ledger
+    PR 28: `copy.*` of `bf16[12,4,257,128,128]`, 23 % of qwen2_7b's busy
+    time); the 384 column updates a step that followed were 0.86 ms of its
+    10.5 (PERF.md section 5, PR 46). The program traced is the CHIP's
+    (`make_jaxpr` only traces): off it the call falls back to a scatter,
+    which no cell runs."""
+    # the dispatcher asks the backend whether to interpret the kernel, and
+    # Mosaic tiles pages of 128 tokens alone
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     c = _PAGED_CONFIGS[family]
-    params, pool_k, pool_v, tables = _paged_args(c)
+    params, pool_k, pool_v, tables = _paged_args(c, page=128)
     args = (params, pool_k, pool_v, jnp.ones((_SLOTS,), jnp.int32),
             jnp.zeros((_SLOTS,), jnp.int32), jnp.ones((_SLOTS,), jnp.bool_),
             tables)
@@ -913,21 +928,39 @@ def test_decode_paged_touches_its_pools_in_place(family):
              if jax.tree_util.keystr(path) in ("[1]", "[2]")}
     assert len(pools) == 2
     assert _pool_touches(closed.jaxpr, pools, pool_k.shape[1:]) == []
-    # the guard sees what it guards against: the parent's two expressions
+    calls = [e for e in closed.jaxpr.eqns
+             if e.params.get("name") == "_decode_insert_dma"]
+    assert len(calls) == c.n_layers
+    for call in calls:
+        (kernel,) = [e for e in call.params["jaxpr"].jaxpr.eqns
+                     if e.primitive.name == "pallas_call"]
+        assert len(kernel.params["input_output_aliases"]) == 2
+    assert closed.jaxpr.outvars[1] in pools and (
+        closed.jaxpr.outvars[2] in pools)     # the taint reached the end
+    # the guard sees what it guards against: the three expressions the
+    # program had before (PR 29's scatter and view, PR 46's column update)
     old = jax.make_jaxpr(lambda p: (
         p.at[0, jnp.arange(2)[:, None], jnp.array([[1, 2]]), :,
-             jnp.array([[0, 3]])].set(1.0), p[1]))(pool_k)
+             jnp.array([[0, 3]])].set(1.0), p[1],
+        jax.lax.dynamic_update_slice(
+            p, jnp.ones((1, p.shape[1], 1, p.shape[3], 1), p.dtype),
+            (0, 0, 1, 0, 3))))(pool_k)
     assert sorted(n for n, _ in _pool_touches(
         old.jaxpr, set(old.jaxpr.invars), pool_k.shape[1:])) == [
-            "scatter", "slice"]
+            "dynamic_update_slice", "scatter", "slice"]
 
 
 def _decode_paged_scatter(params, pool_k, pool_v, tokens, lengths, active,
                           page_tables, c):
     """`decode_paged` as it stood before PR 29, kept as the reference of
     the write path: one scatter over (head, page, offset) per pool and
-    layer, and the kernel over the per-layer view `pool[li]`."""
+    layer, and the kernel over the per-layer view `pool[li]`; an inactive
+    slot's column goes to the scratch page. A looped stack's passes are
+    spelled out (pass t's layer l in cache layer t * n_layers + l, each
+    pass closed by the final norm, the exit rule choosing what the head
+    reads)."""
     from ray_tpu.llm.engine import _mlp_block, _qkv
+    from ray_tpu.models.transformer import exit_step, exit_zero
     from ray_tpu.ops.layers import apply_rope, rmsnorm, rope
     from ray_tpu.ops.paged_attention import paged_decode_attention
     B, P = page_tables.shape
@@ -940,23 +973,34 @@ def _decode_paged_scatter(params, pool_k, pool_v, tokens, lengths, active,
     w_page = jnp.where(active, w_page, 0)
     w_off = lengths % page
     hkv_idx = jnp.arange(c.n_kv_heads)[:, None]
-    for li in range(c.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        q, k, v = _qkv(rmsnorm(x, lp["attn_norm"], c.norm_eps), lp, c,
-                       fence=True)
-        q, k = apply_rope(q, sin, cos), apply_rope(k, sin, cos)
-        pool_k = pool_k.at[li, hkv_idx, w_page[None], :, w_off[None]].set(
-            k[:, 0].transpose(1, 0, 2).astype(pool_k.dtype))
-        pool_v = pool_v.at[li, hkv_idx, w_page[None], :, w_off[None]].set(
-            v[:, 0].transpose(1, 0, 2).astype(pool_v.dtype))
-        attn = paged_decode_attention(
-            q[:, 0], pool_k[li][None], pool_v[li][None], lengths + 1,
-            page_tables, layer=0)
-        attn = attn.reshape(B, 1, c.n_heads * c.head_dim).astype(x.dtype)
-        x = _mlp_block(x + jnp.einsum("bsq,qd->bsd", attn, lp["wo"]), lp, c)
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
+    state = exit_zero(x[:, 0])
+    for t in range(c.loops):
+        for li in range(c.n_layers):
+            at = t * c.n_layers + li
+            lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
+            q, k, v = _qkv(rmsnorm(x, lp["attn_norm"], c.norm_eps), lp, c,
+                           fence=True)
+            q, k = apply_rope(q, sin, cos), apply_rope(k, sin, cos)
+            pool_k = pool_k.at[at, hkv_idx, w_page[None], :,
+                               w_off[None]].set(
+                k[:, 0].transpose(1, 0, 2).astype(pool_k.dtype))
+            pool_v = pool_v.at[at, hkv_idx, w_page[None], :,
+                               w_off[None]].set(
+                v[:, 0].transpose(1, 0, 2).astype(pool_v.dtype))
+            attn = paged_decode_attention(
+                q[:, 0], pool_k[at][None], pool_v[at][None], lengths + 1,
+                page_tables, layer=0)
+            attn = attn.reshape(B, 1, c.n_heads * c.head_dim).astype(x.dtype)
+            out = jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
+            if c.post_norms:
+                out = rmsnorm(out, lp["attn_post_norm"], c.norm_eps)
+            x = _mlp_block(x + out, lp, c)
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        if c.loops > 1:
+            state = exit_step(params, c, t, x[:, 0], state)
+    x = state[0] if c.loops > 1 else x[:, 0]
     head = params["embed"].T if c.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x[:, 0].astype(jnp.float32),
+    logits = jnp.einsum("bd,dv->bv", x.astype(jnp.float32),
                         head.astype(jnp.float32))
     neg = jnp.full_like(logits, -1e30).at[:, 0].set(0.0)
     return jnp.where(active[:, None], logits, neg), pool_k, pool_v
@@ -1008,3 +1052,50 @@ def test_decode_paged_writes_what_the_scatter_wrote(case):
         own = written[:, :, int(to_scratch):]
         np.testing.assert_array_equal(new[~own], before[~own])
         assert (new[own] != before[own]).mean() > 0.9
+
+
+@pytest.mark.parametrize("loops", [1, 4])
+def test_decode_paged_moves_nothing_for_an_inactive_slot(loops):
+    """Five slots, three of them inactive (one that never held a request:
+    length 0, a table of zeros; two whose requests ended, tables and
+    lengths still as they were, one of them at a page's last column):
+    every real page of both pools keeps its bytes for them, in every cache
+    layer of every pass, and the two active slots' columns and logits are
+    what the scatter's reference writes and reads, bitwise (a looped
+    stack's passes a loop here, spelled out there). The kernel is handed a length of 0 for an
+    inactive slot, so on the chip it neither reads, merges nor writes back
+    a page for it; off the chip the fallback's insert sends its column to
+    the scratch page."""
+    from ray_tpu.models import configs
+    c = (configs.tiny_ouro(dtype="bfloat16") if loops > 1 else
+         ModelConfig(vocab=300, d_model=64, n_layers=2, n_heads=4,
+                     n_kv_heads=2, d_ff=128, dtype="bfloat16"))
+    assert c.loops == loops
+    lengths = [20, 0, 7, 31, 16]
+    active = [1, 0, 0, 0, 1]
+    tables = [[1, 2], [0, 0], [3, 4], [5, 6], [7, 8]]
+    params = init_params(c, jax.random.PRNGKey(7))
+    shape = (c.cache_layers, c.n_kv_heads, 9, c.head_dim, _PAGE)
+    pool_k = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.bfloat16)
+    pool_v = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.bfloat16)
+    args = (params, pool_k, pool_v,
+            jnp.asarray([11, 12, 13, 14, 15], jnp.int32),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(active, jnp.bool_),
+            jnp.asarray(tables, jnp.int32))
+    got = jax.jit(partial(decode_paged, config=c))(*args)
+    want = jax.jit(partial(_decode_paged_scatter, c=c))(*args)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert (np.asarray(got[0])[1:4, 1:] == -1e30).all()
+    written = np.zeros(shape, bool)
+    for n, a, tab in zip(lengths, active, tables):
+        if a:
+            written[:, :, tab[n // _PAGE], :, n % _PAGE] = True
+    assert written.sum() == 2 * shape[0] * shape[1] * shape[3]
+    for new, ref, before in zip(got[1:], want[1:], (pool_k, pool_v)):
+        new, ref, before = (np.asarray(a.astype(jnp.float32))[:, :, 1:]
+                            for a in (new, ref, before))
+        own = written[:, :, 1:]
+        np.testing.assert_array_equal(new[~own], before[~own])
+        np.testing.assert_array_equal(new[own], ref[own])
+        assert (new[own] != before[own]).mean() > 0.9
+
