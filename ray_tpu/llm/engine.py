@@ -251,8 +251,105 @@ def _embed(params, tokens):
     return jnp.take(params["embed"], tokens, axis=0)
 
 
+# Rows a tile of the prefill programs' walk. A prompt is padded to its
+# bucket, and every matrix product of a layer but attention's is a row's
+# own: where a bucket is several tiles, the two prefill programs run those
+# products over the tiles that hold a token, a loop whose trip count the
+# device takes from `lengths` (models/experts._grouped_mlp_tiles' loop, for
+# rows that were never sorted). A bucket of one tile has nothing to skip
+# but whole padded requests, and its programs keep the straight pass and
+# their text; so does every program that is handed no lengths (decode,
+# verify, the windows). 256: a tile's products re-read the layer's weights,
+# at qwen2_7b's widths 466 MB (0.57 ms) for 0.60 ms of products, and run at
+# 0.86 of the straight pass's rate; 512-row tiles run at 0.96 of it and
+# cannot tell a 600-token prompt from a 1024-token one (PERF.md section 5).
+_TILE_ROWS = 256
+
+
+def _walked(s: int) -> bool:
+    """Whether a prefill program of [n, s] rows walks its tiles."""
+    return s > _TILE_ROWS and s % _TILE_ROWS == 0
+
+
+def _tiles_of(lengths):
+    """lengths [n] -> the tiles of each request's rows that hold a token,
+    tile j where j * _TILE_ROWS < its length (a numpy array on the host,
+    for `prefill_rows_run`, as on the device)."""
+    return -(-lengths // _TILE_ROWS)
+
+
+def _rows_run(lengths: np.ndarray, s: int) -> int:
+    """The rows a per-head prefill program of [len(lengths), s] runs its
+    row-wise products over, on the host: its real tiles', or all of them
+    where its bucket is one tile."""
+    if not _walked(s):
+        return len(lengths) * s
+    return int(_tiles_of(lengths).sum()) * _TILE_ROWS
+
+
+def _tile_order(lengths, s: int):
+    """What `_walk` takes of a right-padded [n, s] batch of `lengths`: (the
+    ids of the tiles of its flattened rows, those that hold a token first;
+    how many do), or None where the bucket is one tile."""
+    if not _walked(s):
+        return None
+    real = _tiles_of(lengths)
+    skipped = jnp.arange(s // _TILE_ROWS)[None] >= real[:, None]
+    return (jnp.argsort(skipped.reshape(-1), stable=True).astype(jnp.int32),
+            jnp.sum(real, dtype=jnp.int32))
+
+
+def _straight_or_walked(tiles, run):
+    """`run(tiles)`, a whole prefill program. A bucket of one tile:
+    `run(None)`, the straight pass. More: a branch on the device, the
+    straight pass where every tile holds a token (a prompt that fills its
+    bucket, a long prompt's first chunk: a tile's products fall short of
+    the whole batch's, `_TILE_ROWS`), else the walk; whole programs, so
+    that the straight one is compiled as it was alone."""
+    if tiles is None:
+        return run(None)
+    return jax.lax.cond(tiles[1] == tiles[0].shape[0],
+                        lambda: run(None), lambda: run(tiles))
+
+
+def _walk(tiles, ins, widths, dtype, body):
+    """`body(this_turn, *tile of every ins [R, ...]) -> a tile of every
+    output`, over the tiles that hold a token alone -> the outputs [R, w]
+    for w in `widths`, zero where a tile was skipped. `tiles` = (ids
+    [R / _TILE_ROWS], the real tiles' first; how many are real).
+    `this_turn(a)` is `a`, bound to the turn: a weight sliced out of a
+    stack at an index that does not change in the loop is moved out of it
+    by the compiler, a copy of the layer (428 MiB of temporaries at
+    qwen2_7b's widths, described-chip compile, PR 47), where a slice in the
+    loop is read by its product where it lies."""
+    order, count = tiles
+    rows = ins[0].shape[0]
+
+    def turn(i_outs):
+        i, outs = i_outs
+        at = order[i] * _TILE_ROWS
+        got = body(lambda a: jax.lax.optimization_barrier((a, i))[0],
+                   *(jax.lax.dynamic_slice(
+                       a, (at, 0), (_TILE_ROWS, a.shape[1])) for a in ins))
+        return i + 1, tuple(
+            jax.lax.dynamic_update_slice(o, g.astype(dtype), (at, 0))
+            for o, g in zip(outs, got))
+
+    return jax.lax.while_loop(
+        lambda i_outs: i_outs[0] < count, turn,
+        (jnp.int32(0), tuple(jnp.zeros((rows, w), dtype) for w in widths)))[1]
+
+
+def _attn_out(x, attn, lp, c: ModelConfig):
+    """x [b, s, d] + `wo` of the heads' outputs attn [b, s, h * hd]."""
+    out = jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
+    if c.post_norms:
+        out = rmsnorm(out, lp["attn_post_norm"], c.norm_eps)
+    return x + out
+
+
 def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False,
-           routed=None):
+           routed=None, walk=None):
     """One layer of a per-head program, x [b, s, d] -> (x, kept, stats).
     What a program brings: `turn(t)` rotates q and k to its positions, and
     `attend(q, k, v)` -> (attention output [b, s, h, hd] in any grouping
@@ -262,16 +359,59 @@ def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False,
     experts on one device, `_serving_of`) the feed-forward is
     `_expert_block`'s; without, `_mlp_block`'s and `stats` is None. With
     `ModelConfig.post_norms` both sublayers' outputs are normed before the
-    residual add."""
-    b, s, _ = x.shape
-    normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
-    q, k, v = _qkv(normed, lp, c, fence)
+    residual add.
+
+    With `walk` = (`_tile_order`'s two, the model's stacked layers, this
+    layer's index: a prefill program whose bucket is several tiles) the
+    layer's row-wise stretches, the norm and projections before attention
+    and `wo` to the feed-forward's residual after it, run over the tiles
+    that hold a token (`_walk`): a skipped tile's x, K and V are zero,
+    which no real row attends to, `insert_pages_batch` masks and
+    `last_rows` never reads. Attention between them sees every row, as it
+    did. Experts stay outside the walk: `expert_layer` runs its real pairs
+    already."""
+    b, s, d = x.shape
+    if walk is None:
+        normed = rmsnorm(x, lp["attn_norm"], c.norm_eps)
+        q, k, v = _qkv(normed, lp, c, fence)
+    else:
+        *tiles, layers, li = walk
+
+        def here(this_turn, *names):
+            # `lp` with these weights sliced out of the stack INSIDE the
+            # loop; `lp`'s own, an operand of the loop, would be a copy of
+            # the layer (experts.expert_layer's `layer`)
+            at = this_turn(li)
+            return {**lp, **{w: jax.lax.dynamic_index_in_dim(
+                layers[w], at, keepdims=False) for w in names}}
+
+        def qkv(this_turn, xt):
+            normed = rmsnorm(xt[None], lp["attn_norm"], c.norm_eps)
+            return [t.reshape(_TILE_ROWS, -1) for t in _qkv(
+                normed, here(this_turn, "wq", "wk", "wv"), c)]
+
+        hd = c.head_dim
+        q, k, v = (t.reshape(b, s, -1, hd) for t in _walk(
+            tiles, (x.reshape(b * s, d),),
+            (c.n_heads * hd, c.n_kv_heads * hd, c.n_kv_heads * hd),
+            x.dtype, qkv))
     attn, kept = attend(turn(q), turn(k), v)
     attn = attn.reshape(b, s, c.n_heads * c.head_dim).astype(x.dtype)
-    out = jnp.einsum("bsq,qd->bsd", attn, lp["wo"])
-    if c.post_norms:
-        out = rmsnorm(out, lp["attn_post_norm"], c.norm_eps)
-    h = x + out
+    if walk is None:
+        h = _attn_out(x, attn, lp, c)
+    else:
+        def out(this_turn, xt, at):
+            w = here(this_turn, "wo", *(
+                () if c.moe_experts else ("wg", "wu", "wd")))
+            ht = _attn_out(xt[None], at[None], w, c)
+            if not c.moe_experts:
+                ht = _mlp_block(ht, w, c)
+            return (ht[0],)
+
+        h = _walk(tiles, (x.reshape(b * s, d), attn.reshape(b * s, -1)),
+                  (d,), x.dtype, out)[0].reshape(b, s, d)
+        if not c.moe_experts:
+            return h, kept, None
     if routed is None:
         return _mlp_block(h, lp, c), kept, None
     x, stats = _expert_block(h, lp, c, routed)
@@ -322,23 +462,26 @@ def _some(stats) -> tuple:
     return () if stats is None else (stats,)
 
 
-def _layers(block, x, xs, layers, valid, stats):
-    """x through `block(x, xs[i], routed) -> (x, kept, stats)`, layer i
-    after layer i - 1, in one scan -> (x, kept [L, ...], stats). `routed`
-    is None without `stats`, else `_expert_block`'s, made here a layer."""
-    if stats is None:
-        return jax.lax.scan(lambda x, xi: block(x, xi, None)[:2], x, xs) + (
-            None,)
+def _layers(block, x, xs, layers, valid, stats, tiles=None):
+    """x through `block(x, xs[i], routed, walk) -> (x, kept, stats)`, layer
+    i after layer i - 1, in one scan -> (x, kept [L, ...], stats). `routed`
+    is None without `stats`, else `_expert_block`'s, and `walk` None
+    without `tiles` (`_tile_order`), else `_block`'s: made here a layer."""
+    if stats is None and tiles is None:
+        return jax.lax.scan(
+            lambda x, xi: block(x, xi, None, None)[:2], x, xs) + (None,)
 
     def one(x_stats, xi_li):
-        x, kept, stats = block(x_stats[0], xi_li[0],
-                               (valid, x_stats[1], layers, xi_li[1]))
-        return (x, stats), kept
+        (x, *stats), (xi, li) = x_stats, xi_li
+        x, kept, stats = block(
+            x, xi, (valid, stats[0], layers, li) if stats else None,
+            None if tiles is None else (*tiles, layers, li))
+        return (x, *_some(stats)), kept
 
     n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
-    (x, stats), kept = jax.lax.scan(one, (x, stats),
-                                    (xs, jnp.arange(n_layers)))
-    return x, kept, stats
+    (x, *stats), kept = jax.lax.scan(one, (x, *_some(stats)),
+                                     (xs, jnp.arange(n_layers)))
+    return x, kept, stats[0] if stats else None
 
 
 def _passes(c: ModelConfig, params, x, one_pass, rows, stats):
@@ -380,28 +523,33 @@ def prefill_batch(params, tokens, lengths, stats=None, *,
     prefill role). `stats` (`_Serving`: the expert layers' counters,
     `_mlp_block`) comes back counted up."""
     c = config
-    x = _embed(params, tokens)
     n, s = tokens.shape
-    sin, cos = rope(jnp.arange(s), c.head_dim, c.rope_theta)
-    no_prefix = jnp.zeros((n,), jnp.int32)
 
-    def turn(t):  # [None] stays in here, staged once a use inside the
-        # scan: the lowered text keys this program's compile-cache entry
-        return apply_rope(t, sin[None], cos[None])
+    def run(tiles):
+        x = _embed(params, tokens)
+        sin, cos = rope(jnp.arange(s), c.head_dim, c.rope_theta)
+        no_prefix = jnp.zeros((n,), jnp.int32)
 
-    def attend(q, k, v):
-        return _prefill_attention(q, k.transpose(0, 2, 1, 3),
-                                  v.transpose(0, 2, 1, 3), no_prefix, 0,
-                                  c), (k, v)
+        def turn(t):  # [None] stays in here, staged once a use inside the
+            # scan: the lowered text keys this program's compile-cache entry
+            return apply_rope(t, sin[None], cos[None])
 
-    valid = None if stats is None else _real_rows(s, lengths)
-    x, (ks, vs), stats = _passes(
-        c, params, x, lambda x, _t, stats: _layers(
-            lambda x, lp, routed: _block(x, lp, c, turn, attend,
-                                         routed=routed),
-            x, params["layers"], params["layers"], valid, stats),
-        lambda x: last_rows(x, lengths), stats)
-    return (_head(x, params, c, closed=c.loops > 1), ks, vs) + _some(stats)
+        def attend(q, k, v):
+            return _prefill_attention(q, k.transpose(0, 2, 1, 3),
+                                      v.transpose(0, 2, 1, 3), no_prefix, 0,
+                                      c), (k, v)
+
+        valid = None if stats is None else _real_rows(s, lengths)
+        x, (ks, vs), counted = _passes(
+            c, params, x, lambda x, _t, stats: _layers(
+                lambda x, lp, routed, walk: _block(
+                    x, lp, c, turn, attend, routed=routed, walk=walk),
+                x, params["layers"], params["layers"], valid, stats, tiles),
+            lambda x: last_rows(x, lengths), stats)
+        return (_head(x, params, c, closed=c.loops > 1), ks, vs) + _some(
+            counted)
+
+    return _straight_or_walked(_tile_order(lengths, s), run)
 
 
 def prefill(params, tokens, lengths, config: ModelConfig):
@@ -433,11 +581,8 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
         # refuses it, and so did the chip). Two pages, the second scratch
         # and masked by prefix_len, stay a gather.
         prefix_pages = jnp.pad(prefix_pages, ((0, 0), (0, 1)))
-    x = _embed(params, tokens)
     n, s = tokens.shape
     pre_t = prefix_pages.shape[1] * pool_k.shape[4]
-    positions = prefix_len[:, None] + jnp.arange(s)[None]      # [n, S]
-    sin, cos = rope(positions, c.head_dim, c.rope_theta)
 
     def behind(pages, new):  # pages [hkv, N, hd, page], new [n, S, hkv, hd]
         # [hkv, n, Pp, hd, page] -> [n, hkv, Pp, page, hd]
@@ -451,26 +596,36 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
         return _prefill_attention(q, behind(pk, k), behind(pv, v),
                                   prefix_len, pre_t, c), (k, v)
 
-    def layer(x, scan_in, routed):
-        lp, pk, pv = scan_in
-        if c.loops > 1:     # pk: the cache layer's index in both pools
-            pk, pv = (jax.lax.dynamic_index_in_dim(pool, pk, keepdims=False)
-                      for pool in (pool_k, pool_v))
-        return _block(x, lp, c, partial(apply_rope, sin=sin, cos=cos),
-                      partial(attend, pk, pv), routed=routed)
+    def run(tiles):
+        x = _embed(params, tokens)
+        positions = prefix_len[:, None] + jnp.arange(s)[None]      # [n, S]
+        sin, cos = rope(positions, c.head_dim, c.rope_theta)
 
-    def one_pass(x, t, stats):
-        if c.loops == 1:
-            xs = (params["layers"], pool_k, pool_v)
-        else:
-            at = t * c.n_layers + jnp.arange(c.n_layers)
-            xs = (params["layers"], at, at)
-        return _layers(layer, x, xs, params["layers"], valid, stats)
+        def layer(x, scan_in, routed, walk):
+            lp, pk, pv = scan_in
+            if c.loops > 1:     # pk: the cache layer's index in both pools
+                pk, pv = (
+                    jax.lax.dynamic_index_in_dim(pool, pk, keepdims=False)
+                    for pool in (pool_k, pool_v))
+            return _block(x, lp, c, partial(apply_rope, sin=sin, cos=cos),
+                          partial(attend, pk, pv), routed=routed, walk=walk)
 
-    valid = None if stats is None else _real_rows(s, lengths)
-    x, (ks, vs), stats = _passes(c, params, x, one_pass,
-                                 lambda x: last_rows(x, lengths), stats)
-    return (_head(x, params, c, closed=c.loops > 1), ks, vs) + _some(stats)
+        def one_pass(x, t, stats):
+            if c.loops == 1:
+                xs = (params["layers"], pool_k, pool_v)
+            else:
+                at = t * c.n_layers + jnp.arange(c.n_layers)
+                xs = (params["layers"], at, at)
+            return _layers(layer, x, xs, params["layers"], valid, stats,
+                           tiles)
+
+        valid = None if stats is None else _real_rows(s, lengths)
+        x, (ks, vs), counted = _passes(
+            c, params, x, one_pass, lambda x: last_rows(x, lengths), stats)
+        return (_head(x, params, c, closed=c.loops > 1), ks, vs) + _some(
+            counted)
+
+    return _straight_or_walked(_tile_order(lengths, s), run)
 
 
 def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
@@ -1141,6 +1296,10 @@ class InferenceEngine:
                                             range(e.max_slots)]
         self.prefix_hits = 0
         self.preemptions = 0
+        # rows of every prefill dispatch: its [n, S] batch's, and those of
+        # them its row-wise products ran over (`_rows_run`)
+        self.prefill_rows_bucketed = 0
+        self.prefill_rows_run = 0
         # decode compile buckets over pages-in-use: powers of two up
         # to the per-slot page bound, then the bound. A power of two that
         # the bound is within a quarter of is left out: the two would be
@@ -1639,6 +1798,7 @@ class InferenceEngine:
         t_ns = time.perf_counter_ns()
         admitted: dict[int, int] = {}
         pending: list[tuple] = []  # (slot, req, last-logits row) to sample
+        rows_run_before = self.prefill_rows_run
         e = self.e
         page = e.page_size
         # No slot is given out under a decode step in flight (step() lands
@@ -1885,6 +2045,7 @@ class InferenceEngine:
                 self._maybe_finish(slot, first)
         if sp.on:
             sp.set(rows=sum(p["bucket"] for p in planned),
+                   rows_run=self.prefill_rows_run - rows_run_before,
                    fenced=int(bool(pending)))
         return admitted
 
@@ -1914,6 +2075,12 @@ class InferenceEngine:
                 (name, self.c, *statics.values()),
                 lambda: partial(program, config=self.c, **statics),
                 donate_argnums=donate)
+        # only the per-head programs walk their tiles; every other kind's
+        # run their bucket (ROADMAP S13)
+        self.prefill_rows_bucketed += toks.size
+        self.prefill_rows_run += (
+            _rows_run(lens, toks.shape[1]) if self.c.kv_cache == "per_head"
+            else toks.size)
         to_device = partial(jax.tree_util.tree_map, jnp.asarray)
         toks, lens, tabs = to_device((toks, lens, tabs))
         args = (self.params, toks, lens)
@@ -1959,6 +2126,11 @@ class InferenceEngine:
             "window_pages_released": self.window_pages_released,
             "prefix_hits": self.prefix_hits,
             "preemptions": self.preemptions,
+            # rows the prefill dispatches were padded to (n x bucket), and
+            # rows their row-wise products ran over (the tiles that hold a
+            # token, where a program walks them)
+            "prefill_rows_bucketed": self.prefill_rows_bucketed,
+            "prefill_rows_run": self.prefill_rows_run,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
             # step()'s decode steps, and those of them dispatched before

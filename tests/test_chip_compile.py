@@ -607,3 +607,37 @@ def test_prefill_programs_hold_no_scores_and_no_prompt_logits(
     arrays = set(re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
     assert sorted(a for a in arrays
                   if offends([int(d) for d in a.split(",")])) == []
+
+
+@pytest.mark.parametrize("program", ["prefill_batch",
+                                     "prefill_with_prefix_batch"])
+def test_walked_prefill_reads_its_weights_where_they_lie(topo, monkeypatch,
+                                                         program):
+    """The same admission, whose 1024 rows are four tiles: the program
+    holds its layers twice, the straight pass and the walk over the tiles
+    that hold a token (a scan each, and in the walk's two loops a layer),
+    and neither is handed a copy of a layer's weights. With the weights
+    sliced out of the stack at the layer's index, which does not change
+    inside a walk's loop, the compiler moved the slices out of the loop:
+    428 MiB of temporaries, a layer's feed-forward copied (one of its three
+    matrices is 129.5 MiB)."""
+    from ray_tpu.llm import engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, s, pre_pages, n_pages = 2, 1024, 8, 257
+    assert engine._walked(s)
+    one = SingleDeviceSharding(topo.devices[0])
+    c, params = _qwen2_7b(layers, one)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    args = (params, sds((1, s)), sds((1,)))
+    if program == "prefill_with_prefix_batch":
+        pool = sds((layers, HKV, n_pages, HD, PAGE), jnp.bfloat16)
+        args += (pool, pool, sds((1, pre_pages)), sds((1,)))
+    compiled = jax.jit(partial(getattr(engine, program), config=c)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    assert len(re.findall(r" while\(", text)) == 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20

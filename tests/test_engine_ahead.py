@@ -29,6 +29,9 @@ MODELS = {
     "hybrid": configs.tiny_hybrid(moe_experts=2, moe_held_group=1),
 }
 KINDS = sorted(MODELS)
+# the per-head kind with experts on one device: its prefill programs alone,
+# at a shape they walk (`WALKED` below)
+EXPERTS = {"experts": configs.tiny_moe()}
 # float32 on both sides; a request that was preempted at another token
 # re-prefills another length, and sums in another order
 TOL = 2e-5
@@ -36,7 +39,7 @@ TOL = 2e-5
 
 @functools.lru_cache(maxsize=None)
 def _params(kind):
-    return InferenceEngine(MODELS[kind], EngineConfig(
+    return InferenceEngine({**MODELS, **EXPERTS}[kind], EngineConfig(
         max_slots=1, max_len=32, page_size=16, prompt_buckets=(16,)),
         seed=3).params
 
@@ -44,8 +47,8 @@ def _params(kind):
 def _engine(kind, ahead=True, **kw):
     e = dict(max_slots=3, max_len=160, page_size=16, prompt_buckets=(16, 32),
              eos_token=-1)
-    eng = InferenceEngine(MODELS[kind], EngineConfig(**{**e, **kw}),
-                          params=_params(kind))
+    eng = InferenceEngine({**MODELS, **EXPERTS}[kind],
+                          EngineConfig(**{**e, **kw}), params=_params(kind))
     if not ahead:
         # the reference loop: every step is fetched before the next is
         # dispatched, from the tokens the host holds
@@ -347,7 +350,28 @@ FINGERPRINTS = (
     "5432a3ba08b29d84f1611825e5023359e4dc74b3be2c4b752a496d18cfdbbe6d")
 
 
-def _lowered_digests(kind) -> dict:
+# The two per-head prefill programs at a shape of more than one tile, [2,
+# 1024] rows, where their row-wise products walk the tiles that hold a token
+# (llm/engine.py `_walk`, PR 47): the dense kind and the one with experts.
+# PROGRAMS' shapes are one tile, which the walk must not touch; these say
+# when the walk's own text moves.
+WALKED = {
+    "per_head": {
+        "prefill_batch":
+            "cb854b0811e71c27c328160fdc43f0f830085c47aae83583e1724846a35146e7",
+        "prefill_with_prefix_batch":
+            "22df2ad916fe37bb097687f74aee1cf854a4ca5c5bc66c561c08af0d1d8f102a",
+    },
+    "experts": {
+        "prefill_batch":
+            "f43416130f1aa691a0af84eb52e9e04c6f20a2f3604fb1571b771ec37008e5e6",
+        "prefill_with_prefix_batch":
+            "7bd977cee3b766081b60d002bac57228c25a83b2d3cfa32c73bc7403bb1dc04a",
+    },
+}
+
+
+def _lowered_digests(kind, n=2, S=32, names=None) -> dict:
     eng = _engine(kind)
     c, B, page = eng.c, eng.e.max_slots, eng.e.page_size
 
@@ -360,7 +384,7 @@ def _lowered_digests(kind) -> dict:
 
     params, pools, rows = sds(eng.params, eng._pools(), eng.rows)
     stats = () if eng._moe_acc is None else sds(eng._moe_acc)
-    n, S, Pp = 2, 32, 2
+    Pp = 2
     row_args = (*rows, i32(n), i32(n)) if rows else ()
     prefix = (*pools, i32(n, Pp), i32(n))
     # name -> (arguments, the first donated one, how many)
@@ -377,6 +401,8 @@ def _lowered_digests(kind) -> dict:
     }
     out = {}
     for name, (args, first, donated) in calls.items():
+        if names is not None and name not in names:
+            continue
         text = jax.jit(
             functools.partial(getattr(eng.serving, name), config=c),
             donate_argnums=tuple(range(first, first + donated))).lower(
@@ -388,6 +414,11 @@ def _lowered_digests(kind) -> dict:
 @pytest.mark.parametrize("kind", KINDS)
 def test_the_lowered_programs_are_the_parents(kind):
     assert _lowered_digests(kind) == PROGRAMS[kind]
+
+
+@pytest.mark.parametrize("kind", sorted(WALKED))
+def test_the_walked_prefill_programs_are_as_recorded(kind):
+    assert _lowered_digests(kind, 2, 1024, WALKED[kind]) == WALKED[kind]
 
 
 def test_the_graph_fingerprints_are_the_parents():

@@ -236,7 +236,8 @@ def test_an_admission_says_what_it_admitted():
     _run(eng, [(10, 4), (12, 4), (30, 4)])
     records, _ = diagnostics.spans()
     admits = _named(records, "engine.admit")
-    assert admits[0].attrs == {"rows": 16 + 16 + 32, "fenced": 1}
+    assert admits[0].attrs == {"rows": 16 + 16 + 32,
+                               "rows_run": 16 + 16 + 32, "fenced": 1}
     # ONE prefill span a group: the two prompts of bucket 16, the one of 32
     pre = [r for r in _named(records, "engine.admit.prefill")
            if r.parent == admits[0].id]
