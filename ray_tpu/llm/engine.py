@@ -112,14 +112,11 @@ class EngineConfig:
     # span: every slot its window and a page to spare, and room for one
     # chunk's held tail beside its continuation's own pages)
     num_window_pages: int | None = None
-    # --- speculative decoding (parity: vLLM ngram speculation under the
-    # reference's llm stack; greedy windows only — sampled slots fall back
-    # to the plain window) ---
-    speculation: str | None = None  # None | "ngram"
-    spec_k: int = 4                 # drafts verified per model pass;
-    #                                 keep <= 4 — the folded verify
-    #                                 kernel's Mosaic lowering falls off
-    #                                 a cliff at S=8 (measured ~20x)
+    # The engine speculates nothing: InferenceEngine refuses any
+    # `speculation` but None, and reads `spec_k` nowhere. The two fields
+    # are here only because perfbench/tests/test_lookups.py passes them.
+    speculation: str | None = None
+    spec_k: int = 4
 
 
 @dataclasses.dataclass
@@ -142,8 +139,7 @@ class Request:
     guide: object | None = None
     guide_state: int = 0
     # OpenAI logprobs: when True, token_logprobs collects log p(token)
-    # for each generated token (computed in-scan; spec windows fall back
-    # to the plain path for these requests).
+    # for each generated token.
     logprobs: bool = False
     token_logprobs: list = dataclasses.field(default_factory=list)
     # Disaggregated serving: a (ks, vs) prompt-KV handoff exported by a
@@ -258,8 +254,8 @@ def _embed(params, tokens):
 # device takes from `lengths` (models/experts._grouped_mlp_tiles' loop, for
 # rows that were never sorted). A bucket of one tile has nothing to skip
 # but whole padded requests, and its programs keep the straight pass and
-# their text; so does every program that is handed no lengths (decode,
-# verify, the windows). 256: a tile's products re-read the layer's weights,
+# their text; so does the program that is handed no lengths to walk
+# (decode). 256: a tile's products re-read the layer's weights,
 # at qwen2_7b's widths 466 MB (0.57 ms) for 0.60 ms of products, and run at
 # 0.86 of the straight pass's rate; 512-row tiles run at 0.96 of it and
 # cannot tell a 600-token prompt from a 1024-token one (PERF.md section 5).
@@ -749,252 +745,6 @@ def decode_paged(params, pool_k, pool_v, tokens, lengths, active,
             pool_v) + tuple(stats)
 
 
-def verify_paged(params, pool_k, pool_v, tokens, lengths, active,
-                 page_tables, stats=None, *, config: ModelConfig):
-    """Speculative-verify forward: S tokens per slot (the pending token +
-    S-1 drafts) at consecutive positions lengths..lengths+S-1, in ONE
-    model pass. Writes all S tokens' KV (rejected positions hold garbage
-    the position masks hide until real tokens overwrite them) and returns
-    logits [B, S, vocab] — logits[:, j] predicts the token AFTER input j.
-    Same unrolled-layer/donated-pool structure as decode_paged; attention
-    runs the multi-query Pallas kernel (one pass over the slot's pages for
-    all S queries). `stats` as in decode_paged (every position of an
-    active slot is routed, a rejected draft's too)."""
-    from ray_tpu.ops.paged_attention import paged_verify_insert_attention
-    c = config
-    x = _embed(params, tokens)                             # [B, S, d]
-    positions = lengths[:, None] + jnp.arange(tokens.shape[1])[None]
-    sin, cos = rope(positions, c.head_dim, c.rope_theta)
-
-    def attend(li, pool_k, pool_v, q, k, v):
-        # Insert is FUSED into the attention kernel: the new tokens'
-        # K/V merge into the page already streaming through VMEM and the
-        # merged page DMAs back to the aliased pool — token-granular XLA
-        # scatters serialized at ~2us/row and cost more than the whole
-        # forward (measured; see ops/paged_attention.py).
-        attn, pool_k, pool_v = paged_verify_insert_attention(
-            q, pool_k, pool_v, k, v, lengths + 1, page_tables, li)
-        return attn, (pool_k, pool_v)
-
-    for li in range(c.n_layers):
-        lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-        x, (pool_k, pool_v), stats = _block(
-            x, lp, c, partial(apply_rope, sin=sin, cos=cos),
-            partial(attend, li, pool_k, pool_v), fence=True,
-            routed=None if stats is None else (
-                active[:, None], stats, params["layers"], li))
-    return (_head(x, params, c, active), pool_k, pool_v) + _some(stats)
-
-
-def ngram_draft(hist, lengths, last_tokens, k: int):
-    """Propose k draft tokens per slot by matching the trailing 2-gram
-    (hist[len-1], pending) against earlier history and copying what
-    followed the MOST RECENT match (the vLLM ngram-speculator policy;
-    device-side so drafting never fences the host). hist [B, H] holds all
-    known tokens: positions < len are fed, hist[len] is the pending
-    token. No match -> repeat the pending token (cheap, usually
-    rejected)."""
-    B, H = hist.shape
-    c0 = jnp.take_along_axis(
-        hist, jnp.clip(lengths - 1, 0)[:, None], 1)[:, 0]
-    c1 = last_tokens
-    idx = jnp.arange(H - 1)
-    m = ((hist[:, :-1] == c0[:, None]) & (hist[:, 1:] == c1[:, None])
-         & (idx[None] < (lengths - 1)[:, None]))
-    p = jnp.max(jnp.where(m, idx[None], -1), axis=1)       # [B]
-    found = p >= 0
-    start = jnp.where(found, p + 2, 0)
-    gat = jnp.clip(start[:, None] + jnp.arange(k)[None], 0, H - 1)
-    drafts = jnp.take_along_axis(hist, gat, 1)
-    return jnp.where(found[:, None], drafts, c1[:, None])
-
-
-def spec_accept_sample(logits, tin, temps, key):
-    """Accept/resample step of delta-proposal speculative SAMPLING
-    (Leviathan et al.: with a deterministic draft d, accept w.p.
-    p(d); on reject, sample the residual — p with d's mass removed,
-    renormalized — which makes every emitted token an EXACT sample from
-    the target distribution). temps==0 rows reduce to the greedy
-    accept-iff-argmax rule with argmax picks, so one path serves mixed
-    batches bit-exactly for the greedy rows.
-
-    logits [B, K+1, V] (position j predicts the token AFTER input j),
-    tin [B, K+1] (pending token + K drafts), temps [B].
-    Returns (acc [B] accepted-draft count, final [B] the
-    resampled/bonus token at position acc, g_argmax [B, K+1])."""
-    B, K1, V = logits.shape
-    K = K1 - 1
-    greedy = (temps <= 0.0)[:, None]                       # [B, 1]
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None, None]
-    probs = jax.nn.softmax(scaled, axis=-1)                # [B, K+1, V]
-    g = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, K+1]
-    drafts = tin[:, 1:]                                    # [B, K]
-    p_d = jnp.take_along_axis(
-        probs[:, :K], drafts[..., None], -1)[..., 0]       # [B, K]
-    key, ku = jax.random.split(key)
-    u = jax.random.uniform(ku, (B, K))
-    ok = jnp.where(greedy, g[:, :K] == drafts, u < p_d)
-    acc = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
-    # Token at position acc: greedy -> argmax; sampled -> residual
-    # (reject, acc < K) or the plain target (bonus, acc == K).
-    probs_r = jnp.take_along_axis(
-        probs, acc[:, None, None], 1)[:, 0]                # [B, V]
-    d_r = jnp.take_along_axis(
-        tin, jnp.minimum(acc + 1, K)[:, None], 1)[:, 0]    # draft at acc
-    excl = jax.nn.one_hot(d_r, V, dtype=probs_r.dtype)
-    resid = jnp.where((acc < K)[:, None], probs_r * (1.0 - excl),
-                      probs_r)
-    resid = resid / jnp.maximum(resid.sum(-1, keepdims=True), 1e-30)
-    key, ks = jax.random.split(key)
-    sampled = jax.random.categorical(ks, jnp.log(resid + 1e-30), axis=-1)
-    bonus_g = jnp.take_along_axis(g, acc[:, None], 1)[:, 0]
-    final = jnp.where(greedy[:, 0], bonus_g,
-                      sampled.astype(jnp.int32))
-    return acc, final, g
-
-
-def decode_window_spec(params, pool_k, pool_v, tokens, lengths, active,
-                       hist, page_tables, temps, key, stats=None, *,
-                       config: ModelConfig, eos_token: int, n_steps: int,
-                       spec_k: int):
-    """Speculative decode window: each of `n_steps` scan iterations
-    drafts spec_k tokens by device-side n-gram lookup, verifies them in
-    ONE multi-token forward (verify_paged), and emits accepted-prefix +
-    1 final token — between 1 and spec_k+1 tokens per model pass.
-    Greedy (temp 0) rows are bitwise-identical to plain greedy decoding;
-    sampled rows use delta-proposal rejection sampling, so every emitted
-    token is an exact draw from the temperature-scaled target
-    distribution (Leviathan et al. 2023). Returns out blocks
-    [n_steps, B, spec_k+1] (-1 = nothing emitted at that position);
-    `stats` as in verify_paged, handed on from pass to pass.
-
-    Parity: vLLM ngram speculative decoding
-    (`python/ray/llm/_internal/serve/deployments/llm/vllm/` inherits it);
-    redesigned for TPU — static [B, K+1] verify shapes, drafting and
-    acceptance fully on-device inside the window scan."""
-    K = spec_k
-    B = tokens.shape[0]
-    H = hist.shape[1]
-    jj = jnp.arange(K + 1)[None]                           # [1, K+1]
-
-    def one(carry, _):
-        pk, pv, toks, lens, act, hst, key, stats = carry
-        drafts = ngram_draft(hst, lens, toks, K)           # [B, K]
-        tin = jnp.concatenate([toks[:, None], drafts], axis=1)
-        logits, pk, pv, *stats = verify_paged(
-            params, pk, pv, tin, lens, act, page_tables, stats,
-            config=config)
-        stats = stats[0] if stats else None
-        key, kacc = jax.random.split(key)
-        acc, bonus, g = spec_accept_sample(logits, tin, temps, kacc)
-        drafts_p = jnp.concatenate(
-            [drafts, jnp.zeros((B, 1), jnp.int32)], axis=1)
-        e = jnp.where(jj == acc[:, None], bonus[:, None],
-                      jnp.where(jj < acc[:, None], drafts_p, -1))
-        if eos_token >= 0:
-            is_eos = e == eos_token
-            # drop everything after the first emitted EOS
-            after = (jnp.cumsum(is_eos.astype(jnp.int32), axis=1)
-                     - is_eos.astype(jnp.int32)) > 0
-            e = jnp.where(after, -1, e)
-            stop = (e == eos_token).any(axis=1)
-        else:
-            stop = jnp.zeros((B,), bool)
-        e = jnp.where(act[:, None], e, -1)
-        stop = stop & act
-        # history update: emitted tokens live at positions lens+1+j
-        s0 = jnp.minimum(lens + 1, H - (K + 1))
-        offset = lens + 1 - s0                             # >= 0
-        src_j = jnp.clip(jj - offset[:, None], 0, K)
-        val = jnp.take_along_axis(e, src_j, 1)
-        gathered = jax.vmap(
-            lambda h, s: jax.lax.dynamic_slice(h, (s,), (K + 1,))
-        )(hst, s0)
-        write = (jj >= offset[:, None]) & (val >= 0) & act[:, None]
-        upd = jnp.where(write, val, gathered)
-        hst = jax.vmap(
-            lambda h, u, s: jax.lax.dynamic_update_slice(h, u, (s,))
-        )(hst, upd, s0)
-        toks = jnp.where(act, bonus, toks)
-        lens = jnp.where(act, lens + acc + 1, lens)
-        act = act & ~stop
-        return (pk, pv, toks, lens, act, hst, key, stats), e
-
-    carry = (pool_k, pool_v, tokens, lengths, active, hist, key, stats)
-    (pool_k, pool_v, tokens, lengths, active, hist, key, stats), out_seq = (
-        jax.lax.scan(one, carry, None, length=n_steps))
-    return (pool_k, pool_v, tokens, lengths, active, hist, key,
-            out_seq) + _some(stats)
-
-
-def decode_window(params, pool_k, pool_v, tokens, lengths, active,
-                  page_tables, temps, top_ps, top_ks, gtables, gstates,
-                  key, stats=None, *, config: ModelConfig, eos_token: int,
-                  n_steps: int, trunc: bool, guided: bool,
-                  want_logp: bool = False):
-    """`n_steps` decode+sample steps in ONE compiled program (lax.scan),
-    sampled tokens staying device-resident between steps. The host fences
-    once per window instead of once per token (fewer host syncs: the
-    device is not left idle while the host reads one token back and
-    dispatches the next step). EOS flips `active` on-device; the host
-    discards any overshoot when it reads the [n_steps, B] token block
-    back.
-
-    `guided` (static): constrained decoding. gtables [B, S, V] stacked
-    per-slot token-transition tables (unguided slots: an all-zeros row —
-    every token allowed), gstates [B] the per-slot DFA state, which rides
-    the scan carry so constraint enforcement never fences the host
-    (guided.py; the role of vLLM's outlines logits processors).
-
-    `want_logp` (static): also emit log p(sampled token) per step
-    (log-softmax gather; OpenAI logprobs). The block becomes
-    (tokens [n_steps, B], logps [n_steps, B]).
-
-    Within a window page tables are frozen, so the caller bounds n_steps
-    by every active slot's remaining page room. `stats` as in
-    decode_paged, handed on from step to step.
-    """
-    B = tokens.shape[0]
-
-    def one(carry, _):
-        pk, pv, toks, lens, act, gst, key, stats = carry
-        logits, pk, pv, *stats = decode_paged(
-            params, pk, pv, toks, lens, act, page_tables, stats,
-            config=config)
-        stats = stats[0] if stats else None
-        key, sub = jax.random.split(key)
-        mask = None
-        if guided:
-            row = gtables[jnp.arange(B), gst]          # [B, V]
-            mask = row >= 0
-        if trunc:
-            nxt = sample(logits, temps, sub, top_p=top_ps, top_k=top_ks,
-                         mask=mask)
-        else:
-            nxt = sample(logits, temps, sub, mask=mask)
-        nxt = jnp.where(act, nxt.astype(jnp.int32), 0)
-        out = jnp.where(act, nxt, -1)  # -1 = slot emitted nothing
-        if want_logp:
-            logp_all = jax.nn.log_softmax(logits, axis=-1)
-            logp = jnp.take_along_axis(logp_all, nxt[:, None], 1)[:, 0]
-            outs = (out, jnp.where(act, logp, 0.0))
-        else:
-            outs = out
-        lens = jnp.where(act, lens + 1, lens)
-        if guided:
-            gst = jnp.where(act,
-                            jnp.maximum(row[jnp.arange(B), nxt], 0), gst)
-        if eos_token >= 0:
-            act = act & (nxt != eos_token)
-        return (pk, pv, nxt, lens, act, gst, key, stats), outs
-
-    carry = (pool_k, pool_v, tokens, lengths, active, gstates, key, stats)
-    (pool_k, pool_v, tokens, lengths, active, gstates, key, stats), out_seq = (
-        jax.lax.scan(one, carry, None, length=n_steps))
-    return (pool_k, pool_v, tokens, lengths, active, key,
-            out_seq) + _some(stats)
-
-
 def sample(logits, temperature, key, top_p=None, top_k=None, mask=None):
     """Per-row temperature (0 = greedy) with optional nucleus (top_p) and
     top_k truncation — all branch-free under jit.
@@ -1194,6 +944,12 @@ class InferenceEngine:
                 f"keeps one KV layout, \"paged\" (a pool of pages; what a "
                 f"page holds follows ModelConfig.attention="
                 f"{model_config.attention!r})")
+        if self.e.speculation is not None:
+            raise ValueError(
+                f"EngineConfig.speculation={self.e.speculation!r}: the "
+                f"engine keeps one decode loop, step(), which drafts "
+                f"nothing (ROADMAP D17: no served or measured path ever "
+                f"reached the n-gram window loop)")
         # A model with another kind of cache than per-head K and V
         # (ModelConfig.kv_cache) brings its own four programs in its
         # module, over pools of its own shapes (_Serving); page accounting,
@@ -1203,13 +959,9 @@ class InferenceEngine:
         # programs, and is refused what the others are)
         self._own = (model_config.kv_cache != "per_head"
                      or model_config.loops > 1)
-        if self._own:
-            if self.e.speculation is not None:
-                _refuse(model_config, "EngineConfig.speculation="
-                                      f"{self.e.speculation!r}")
-            if mesh is not None and mesh.devices.size > 1:
-                _refuse(model_config, f"a mesh of {dict(mesh.shape)} "
-                                      f"(tensor parallelism)")
+        if self._own and mesh is not None and mesh.devices.size > 1:
+            _refuse(model_config, f"a mesh of {dict(mesh.shape)} "
+                                  f"(tensor parallelism)")
         self.params = _resolve_params(model_config, params, mesh, rules,
                                       seed)
         c, e = self.c, self.e
@@ -1317,21 +1069,9 @@ class InferenceEngine:
         self._page_buckets = pb
         self._decode_paged: dict[int, object] = {}
         self._prefill_pre: dict[tuple, object] = {}
-        self._window_fns: dict[tuple, object] = {}
-        self._win_buckets = (1, 2, 4, 8, 16, 32, 64)
         self._flight: _Flight | None = None  # step()'s step in the air
         self.decode_steps = 0        # dispatched by step()
         self.decode_steps_ahead = 0  # ... before the step before was fetched
-        # Device-resident decode state of the windows (uploaded only when
-        # the host view changed): a per-step upload is a host sync like a
-        # download.
-        self._dev = None           # (tokens, lengths, active) on device
-        self._dev_dirty = True
-        self._dev_key = jax.random.PRNGKey(seed + 2)
-        self._dev_sampling = None  # (temps, top_ps, top_ks) device
-        self._dev_sampling_fp = None
-        self._dev_gtables = None   # stacked guide tables [B, S, V]
-        self._guide_fp = None
         # Donate the pool/cache: without donation every step round-trips
         # the full KV through a fresh HBM allocation (~GBs/step).
         insert = self.serving.insert_batch
@@ -1345,21 +1085,6 @@ class InferenceEngine:
             kv_sharding = NamedSharding(mesh, P(None, "tp"))
             self.cache_k = jax.device_put(self.cache_k, kv_sharding)
             self.cache_v = jax.device_put(self.cache_v, kv_sharding)
-
-        # Speculative decoding state (step()/_admit write the host history
-        # mirror unconditionally; the device twin is the spec window's).
-        self._spec = e.speculation == "ngram"
-        if self._spec and e.spec_k + 1 > e.page_size:
-            # verify writes span at most 2 pages per slot
-            raise ValueError(
-                f"spec_k+1 ({e.spec_k + 1}) must not exceed "
-                f"page_size ({e.page_size})")
-        self.hist = np.zeros((e.max_slots, e.max_len), np.int32)
-        self._dev_hist = None
-        self._spec_window_fns: dict[tuple, object] = {}
-        self.spec_drafted = 0
-        self.spec_accepted = 0
-        self._spec_alpha = 0.0  # acceptance-rate EMA (window sizing)
 
         self._sample, self._sample_trunc = _samplers()
         self._key = jax.random.PRNGKey(seed + 1)
@@ -1475,7 +1200,6 @@ class InferenceEngine:
             self.active[i] = False
             self.slot_req[i] = None
             self._release_slot(i)
-            self._dev_dirty = True
 
     def has_work(self) -> bool:
         """Whether step() has anything left to do or to hand over. True
@@ -1836,7 +1560,7 @@ class InferenceEngine:
                     # Chunked prefill: admit only the next page-aligned chunk;
                     # phase 3 registers its pages and requeues the request, so
                     # the next step continues from the longer prefix. Decode
-                    # windows for already-running slots interleave in between.
+                    # steps of the slots already running pass in between.
                     suffix = suffix[:chunk]
                     ns = chunk
                     n = hit * page + chunk
@@ -2016,18 +1740,15 @@ class InferenceEngine:
                 req.t_slot_ns = req.t_slot_ns or t_ns
                 self.lengths[slot] = n
                 self.active[slot] = True
-                self.hist[slot, :n] = req.prompt
                 if req.resume_token is not None:
                     first = req.resume_token  # already in req.generated
                     req.resume_token = None
                     self.last_tokens[slot] = first
-                    self.hist[slot, n] = first
                     self._maybe_finish(slot, first)
                 else:
                     # Defer the first-token sampling: one batched readback for
                     # the whole admission burst instead of a fence per prompt.
                     pending.append((slot, req, logits_of[slot]))
-                self._dev_dirty = True  # slot state changed by this admission
         if pending:  # one fence for the burst
             with diagnostics.span("ray_tpu.engine.admit.sample"):
                 toks, logps = self._sample_rows(
@@ -2040,7 +1761,6 @@ class InferenceEngine:
                 req.generated.append(first)
                 admitted[req.request_id] = first
                 self.last_tokens[slot] = first
-                self.hist[slot, self.lengths[slot]] = first
                 self._advance_guide(req, first)
                 self._maybe_finish(slot, first)
         if sp.on:
@@ -2131,8 +1851,6 @@ class InferenceEngine:
             # token, where a program walks them)
             "prefill_rows_bucketed": self.prefill_rows_bucketed,
             "prefill_rows_run": self.prefill_rows_run,
-            "spec_drafted": self.spec_drafted,
-            "spec_accepted": self.spec_accepted,
             # step()'s decode steps, and those of them dispatched before
             # the step before was fetched (the rest waited for the host)
             "decode_steps": self.decode_steps,
@@ -2266,13 +1984,13 @@ class InferenceEngine:
         takes a slot; a request is queued and a slot is free or about to
         be (an admission moves slots and draws the new slot's first token
         on the host); a guide's mask for the next token follows from this
-        one; speculation keeps the token history whole on the host; or
-        the pool cannot grow every slot its next page, and a victim of
-        preemption is requeued with all its tokens."""
+        one; or a pool cannot grow every slot its next page (the page
+        pool, then the window pool), and a victim of preemption is
+        requeued with all its tokens."""
         f = self._flight
         if f is None:
             return True
-        if self._cancel_rids or self._spec or (
+        if self._cancel_rids or (
                 self.queue and (f.ends.any() or not self.active.all())):
             return False
         if any(r is not None and r.guide is not None for r in self.slot_req):
@@ -2317,29 +2035,20 @@ class InferenceEngine:
                 emitted[req.request_id] = tok
                 self.lengths[i] += 1
                 self.last_tokens[i] = tok
-                if self.lengths[i] < self.e.max_len:
-                    self.hist[i, self.lengths[i]] = tok
                 self._advance_guide(req, tok)
                 self._maybe_finish(i, tok)
-            self._dev_dirty = True  # single-step path mutates host-side state
         return emitted
 
-    def _grow_pages(self, horizon: int = 1) -> bool:
-        """Ensure every active slot has pages for its next `horizon`
-        tokens, preempting when the pool is dry. Returns False if nothing
-        is left active."""
+    def _grow_pages(self) -> bool:
+        """Ensure every active slot has the page of its next token,
+        preempting when the pool is dry. Returns False if nothing is left
+        active."""
         e = self.e
-        page = e.page_size
-        changed = False
         for i in range(e.max_slots):
             if not self.active[i]:
                 continue
-            req = self.slot_req[i]
-            rem = min(horizon, req.max_new_tokens - len(req.generated) + 1)
-            last_pos = int(self.lengths[i]) + max(rem, 1) - 1
-            pi = min(last_pos, e.max_len - 1) // page
+            pi = min(int(self.lengths[i]), e.max_len - 1) // e.page_size
             while pi >= len(self.slot_pages[i]):
-                changed = True
                 pid = self._alloc_page()
                 if pid is None:
                     if not self._make_room(i):
@@ -2347,16 +2056,10 @@ class InferenceEngine:
                     continue
                 self.page_refs[pid] = 1
                 self.slot_pages[i].append(pid)
-            # window pools slide a step at a time (step_window is refused)
             while (self.win_span and self.active[i]
                    and not self._slide_window(i, int(self.lengths[i]))):
-                changed = True
                 if not self._make_room(i):
                     break
-        if changed:
-            # Page growth changes only the tables, but a preemption inside
-            # the growth loop also changed slot state — resync both.
-            self._dev_dirty = True
         return bool(self.active.any())
 
     def _make_room(self, i: int) -> bool:
@@ -2397,7 +2100,7 @@ class InferenceEngine:
         the CPU backend jnp.asarray can alias the host's buffer."""
         e, prev = self.e, self._flight
         if prev is None:
-            if not self._grow_pages(1):
+            if not self._grow_pages():
                 return None
             lengths, active = self.lengths.copy(), self.active.copy()
             tokens = jnp.asarray(self.last_tokens.copy())
@@ -2451,11 +2154,9 @@ class InferenceEngine:
             return _Flight(*self._sample_dispatch(logits, reqs), active,
                            reqs, ends)
 
-    def _build_tables(self, active=None) -> np.ndarray:
-        """Page tables [B, bucket] of the `active` slots (the host's view
-        of them unless given)."""
+    def _build_tables(self, active) -> np.ndarray:
+        """Page tables [B, bucket] of the `active` slots."""
         e = self.e
-        active = self.active if active is None else active
         p_need = max(
             (len(self.slot_pages[i]) for i in range(e.max_slots)
              if active[i]), default=1)
@@ -2476,52 +2177,6 @@ class InferenceEngine:
             tables[i, :len(self.slot_win[i])] = self.slot_win[i]
             starts[i] = self.slot_win_first[i] * self.e.page_size
         return tables, starts
-
-    def _sync_device_state(self):
-        if self._dev_dirty or self._dev is None:
-            # .copy() before upload: on the CPU backend jnp.asarray can
-            # alias the host buffer zero-copy, and the window jits DONATE
-            # these args — XLA would reuse the memory and scribble over
-            # self.last_tokens/lengths/active behind the host's back
-            # (active slots silently flipping off, requests stranded).
-            self._dev = (jnp.asarray(self.last_tokens.copy()),
-                         jnp.asarray(self.lengths.copy()),
-                         jnp.asarray(self.active.copy()))
-            if self._spec:
-                self._dev_hist = jnp.asarray(self.hist.copy())
-            self._dev_dirty = False
-
-    def _sync_guides(self):
-        """(guided?, stacked tables [B, S, V], states [B]) for the window
-        jit. The stacked table re-uploads only when the slot->guide map
-        changes; the [B] state vector is tiny and re-uploads per window.
-        Unguided slots get an all-zeros table row: every token allowed,
-        state pinned to 0."""
-        e = self.e
-        reqs = [self.slot_req[i] for i in range(e.max_slots)]
-        # Keyed on the guide's monotonic serial, NOT id(): after the serve
-        # layer's LRU evicts a TokenGuide, a newly compiled guide can reuse
-        # the same id() on the same slot and the stale device table would
-        # silently keep enforcing the old constraint.
-        fp = tuple((i, r.guide.serial) for i, r in enumerate(reqs)
-                   if r is not None and r.guide is not None)
-        if not fp:
-            return False, jnp.zeros((1, 1, 1), jnp.int32), \
-                jnp.zeros((e.max_slots,), jnp.int32)
-        if fp != self._guide_fp or self._dev_gtables is None:
-            S = max(r.guide.n_states for r in reqs
-                    if r is not None and r.guide is not None)
-            tab = np.zeros((e.max_slots, S, self.c.vocab), np.int32)
-            for i, r in enumerate(reqs):
-                if r is not None and r.guide is not None:
-                    g = r.guide.table
-                    tab[i, :g.shape[0]] = g
-            self._dev_gtables = jnp.asarray(tab)
-            self._guide_fp = fp
-        states = jnp.asarray(
-            [r.guide_state if (r is not None and r.guide is not None)
-             else 0 for r in reqs], jnp.int32)
-        return True, self._dev_gtables, states
 
     def _sample_dispatch(self, logits, reqs) -> tuple:
         """One token a row of `logits` [len(reqs), vocab] by that row's
@@ -2561,246 +2216,6 @@ class InferenceEngine:
             req.guide_state = max(int(req.guide.table[req.guide_state,
                                                       tok]), 0)
 
-    def _sync_sampling(self):
-        temps, top_ps, top_ks = _sampling_of(self.slot_req)
-        fp = (temps.tobytes(), top_ps.tobytes(), top_ks.tobytes())
-        if fp != self._dev_sampling_fp:
-            self._dev_sampling = (jnp.asarray(temps), jnp.asarray(top_ps),
-                                  jnp.asarray(top_ks))
-            self._dev_sampling_fp = fp
-        trunc = bool((top_ks != 0).any() or (top_ps < 1.0).any())
-        return trunc
-
-    def _run_window(self) -> dict[int, int]:
-        """Decode up to a bucketed number of tokens per slot in one
-        compiled dispatch + one host readback (see decode_window)."""
-        e = self.e
-        page = e.page_size
-        # Window size: the MAX remaining work across slots — slots that
-        # finish earlier keep "decoding" into scratch and the host
-        # discards their overshoot, which is far cheaper than paying the
-        # fence again. Only a pool-starved slot (growth failed) binds the
-        # window down to its real page room.
-        rems = [self.slot_req[i].max_new_tokens
-                - len(self.slot_req[i].generated)
-                for i in range(e.max_slots)
-                if self.active[i] and self.slot_req[i] is not None]
-        horizon = max(1, min(self._win_buckets[-1], max(rems, default=1)))
-        if self.queue:
-            # Requests are waiting to admit (free slot next pass, or a
-            # chunked prefill resuming one chunk per pass): keep windows
-            # short so admission interleaves with decode instead of
-            # stalling behind a 64-token window.
-            horizon = min(horizon, 8)
-        if not self._grow_pages(horizon):
-            return {}
-        limit = horizon
-        for i in range(e.max_slots):
-            if not self.active[i]:
-                continue
-            room = len(self.slot_pages[i]) * page - int(self.lengths[i])
-            rem = (self.slot_req[i].max_new_tokens
-                   - len(self.slot_req[i].generated))
-            if room < min(horizon, rem):
-                limit = min(limit, max(room, 1))
-        if limit == horizon:
-            # Round UP to one window: slots that finish early overshoot
-            # into discarded tokens, which is cheaper than another fence.
-            k_bucket = min(b for b in self._win_buckets if b >= limit)
-        else:
-            # Pool-starved slot: its room is a hard bound (tokens past it
-            # are garbage it still needs) — round DOWN.
-            k_bucket = max(b for b in self._win_buckets if b <= limit)
-        trunc = self._sync_sampling()
-        guided, gtables_d, gstates_d = self._sync_guides()
-        want_logp = any(
-            self.slot_req[i] is not None and self.slot_req[i].logprobs
-            for i in range(e.max_slots) if self.active[i])
-        self._sync_device_state()
-        tables = self._build_tables()
-        key = (tables.shape[1], k_bucket, trunc, guided,
-               gtables_d.shape if guided else None, want_logp)
-        fn = self._window_fns.get(key)
-        if fn is None:
-            # Static lowering args in the shared key; shapes stay out
-            # (the wrapper's aval cache covers them).
-            fn = _shared_jit(
-                ("decode_window", self.c, int(self.e.eos_token),
-                 k_bucket, trunc, guided, want_logp),
-                lambda: partial(decode_window, config=self.c,
-                                eos_token=int(self.e.eos_token),
-                                n_steps=k_bucket, trunc=trunc,
-                                guided=guided, want_logp=want_logp),
-                donate_argnums=(1, 2, 3, 4, 5, 12))
-            self._window_fns[key] = fn
-        toks_d, lens_d, act_d = self._dev
-        temps_d, tps_d, tks_d = self._dev_sampling
-        (self.cache_k, self.cache_v, toks_d, lens_d, act_d,
-         self._dev_key, out_seq) = self._counted(fn(
-            self.params, self.cache_k, self.cache_v, toks_d, lens_d,
-            act_d, jnp.asarray(tables), temps_d, tps_d, tks_d,
-            gtables_d, gstates_d, self._dev_key, *self._stats()))
-        self._dev = (toks_d, lens_d, act_d)
-        if want_logp:
-            out = np.asarray(out_seq[0])  # ONE fence per window
-            logps = np.asarray(out_seq[1])
-        else:
-            out = np.asarray(out_seq)
-            logps = None
-        emitted: dict[int, int] = {}
-        for k in range(out.shape[0]):
-            for i in range(e.max_slots):
-                tok = int(out[k, i])
-                if tok < 0 or not self.active[i]:
-                    continue
-                req = self.slot_req[i]
-                if req.logprobs and logps is not None:
-                    req.token_logprobs.append(float(logps[k, i]))
-                req.generated.append(tok)
-                emitted[req.request_id] = tok
-                self.lengths[i] += 1
-                self.last_tokens[i] = tok
-                if self.lengths[i] < e.max_len:
-                    self.hist[i, self.lengths[i]] = tok
-                self._advance_guide(req, tok)
-                self._maybe_finish(i, tok)
-                if not self.active[i] and tok != e.eos_token:
-                    # Finished by max_new/max_len: the device still thinks
-                    # this slot is live — resync before the next window.
-                    self._dev_dirty = True
-        if self._spec:
-            # device hist was not advanced by the plain window; force a
-            # re-upload before the next speculative window
-            self._dev_hist = None
-        return emitted
-
-    def _spec_applicable(self) -> bool:
-        """Speculation serves greedy AND plain-temperature slots (delta-
-        proposal rejection sampling keeps sampled outputs exact); top-k /
-        top-p truncation, guided decoding, and logprobs route the window
-        to the plain path."""
-        if not self._spec:
-            return False
-        for i in range(self.e.max_slots):
-            r = self.slot_req[i]
-            if not self.active[i] or r is None:
-                continue
-            if (r.top_k != 0 or r.top_p < 1.0
-                    or r.guide is not None or r.logprobs):
-                return False
-        return True
-
-    def _run_window_spec(self) -> dict[int, int] | None:
-        """Speculative window: `iters` draft+verify scan steps, each
-        emitting 1..spec_k+1 tokens per slot. Returns None to fall back
-        to the plain window (pool-starved slot needs its token-granular
-        room binding)."""
-        e = self.e
-        page = e.page_size
-        K = e.spec_k
-        rems = [self.slot_req[i].max_new_tokens
-                - len(self.slot_req[i].generated)
-                for i in range(e.max_slots)
-                if self.active[i] and self.slot_req[i] is not None]
-        # Size the window by EXPECTED tokens per iteration (acceptance
-        # EMA), not the optimistic K+1: at low acceptance an
-        # optimistically-short window would finish only a third of the
-        # work and pay the host fence three times. Overshoot iterations
-        # are discarded compute; the trade against another fence is
-        # ROADMAP S5's to re-measure on the chip.
-        expected = 1.0 + self._spec_alpha * K
-        iters = max(1, -(-int(max(rems, default=1)) // max(int(expected),
-                                                           1)))
-        if self.queue:
-            iters = min(iters, 2)  # keep admission interleaving
-        iters = min(next((b for b in self._win_buckets if b >= iters),
-                         self._win_buckets[-1]), self._win_buckets[-1])
-        if not self._grow_pages(iters * (K + 1)):
-            return {}
-        for i in range(e.max_slots):
-            if not self.active[i]:
-                continue
-            room = len(self.slot_pages[i]) * page - int(self.lengths[i])
-            rem = (self.slot_req[i].max_new_tokens
-                   - len(self.slot_req[i].generated))
-            if room < min(K + 1, rem):
-                return None  # pool-starved: plain window binds per-token
-        self._sync_device_state()
-        if self._dev_hist is None:
-            # .copy(): the spec window donates hist; a zero-copy upload
-            # would hand self.hist's buffer to XLA (see _sync_device_state)
-            self._dev_hist = jnp.asarray(self.hist.copy())
-        tables = self._build_tables()
-        key = (tables.shape[1], iters)
-        fn = self._spec_window_fns.get(key)
-        if fn is None:
-            fn = _shared_jit(
-                ("decode_window_spec", self.c, int(e.eos_token), iters, K),
-                lambda: partial(decode_window_spec, config=self.c,
-                                eos_token=int(e.eos_token),
-                                n_steps=iters, spec_k=K),
-                donate_argnums=(1, 2, 3, 4, 5, 6, 9))
-            self._spec_window_fns[key] = fn
-        self._sync_sampling()
-        temps_d = self._dev_sampling[0]
-        toks_d, lens_d, act_d = self._dev
-        (self.cache_k, self.cache_v, toks_d, lens_d, act_d,
-         self._dev_hist, self._dev_key, out_seq) = self._counted(fn(
-            self.params, self.cache_k, self.cache_v, toks_d, lens_d,
-            act_d, self._dev_hist, jnp.asarray(tables), temps_d,
-            self._dev_key, *self._stats()))
-        self._dev = (toks_d, lens_d, act_d)
-        out = np.asarray(out_seq)  # [iters, B, K+1]; ONE fence
-        w_draft = w_acc = 0
-        emitted: dict[int, int] = {}
-        for it in range(out.shape[0]):
-            for i in range(e.max_slots):
-                if not self.active[i]:
-                    continue
-                row = out[it, i]
-                n_emit = int((row >= 0).sum())
-                if n_emit == 0:
-                    continue
-                self.spec_drafted += K
-                self.spec_accepted += n_emit - 1
-                w_draft += K
-                w_acc += n_emit - 1
-                for j in range(K + 1):
-                    tok = int(row[j])
-                    if tok < 0:
-                        continue
-                    if not self.active[i]:
-                        self._dev_dirty = True  # overshoot past host finish
-                        break
-                    req = self.slot_req[i]
-                    req.generated.append(tok)
-                    emitted[req.request_id] = tok
-                    self.lengths[i] += 1
-                    self.last_tokens[i] = tok
-                    if self.lengths[i] < e.max_len:
-                        self.hist[i, self.lengths[i]] = tok
-                    self._maybe_finish(i, tok)
-                    if not self.active[i] and tok != e.eos_token:
-                        self._dev_dirty = True
-        if w_draft:
-            self._spec_alpha = (0.5 * self._spec_alpha
-                                + 0.5 * (w_acc / w_draft))
-        return emitted
-
-    def step_window(self) -> dict[int, int]:
-        """Admit queued prompts, then decode a whole window."""
-        if self._own:
-            _refuse(self.c, "step_window() (decode_window); call step()")
-        emitted = self._land()   # a caller may mix it with step()
-        emitted.update(self._admit())
-        if self.active.any():
-            upd = (self._run_window_spec() if self._spec_applicable()
-                   else None)
-            if upd is None:
-                upd = self._run_window()
-            emitted.update(upd)
-        return emitted
-
     # ---- conveniences ----
 
     def generate(self, prompts: list, max_new_tokens=None,
@@ -2810,9 +2225,8 @@ class InferenceEngine:
         stream through)."""
         ids = [self.add_request(p, max_new_tokens, temperature)
                for p in prompts]
-        step = self.step if self._own else self.step_window
         while self.has_work():
-            step()
+            self.step()
         out = []
         for rid in ids:
             req = self.finished.pop(rid)
@@ -2892,7 +2306,7 @@ class PrefillEngine:
 
 
 def __graphcheck__(gc):
-    """graphcheck hook (tools/graphcheck): the four steady-state serving
+    """graphcheck hook (tools/graphcheck): the steady-state serving
     graphs, lowered at a tiny config. Pins per graph: the KV pool/cache
     donation pattern (dropping one silently doubles the pool's HBM), zero
     host callbacks on the decode hot loop, and the collective/flops
@@ -2943,23 +2357,12 @@ def __graphcheck__(gc):
             arg_names=("pool_k", "pool_v", "ks", "vs", "page_ids",
                        "lengths"))
 
-    def build_spec_verify(mesh):
-        return gc.GraphSpec(
-            name="llm.spec_verify", fn=partial(verify_paged, config=c),
-            args=(_params(), _pool(), _pool(),
-                  _sds((slots, 3), jnp.int32), _sds((slots,), jnp.int32),
-                  _sds((slots,), jnp.bool_), _sds((slots, ptab),
-                                                  jnp.int32)),
-            donate_argnums=(1, 2), min_donate_bytes=16384,
-            arg_names=("params", "pool_k", "pool_v", "tokens", "lengths",
-                       "active", "page_tables"))
-
     # ---- disaggregated serving plane (llm/serve.py) ----
-    # The prefill-pool export graph, the decode-pool steady-state window,
-    # and the decode-side KV-handoff import (the splice fed by the host
-    # device_put of the sealed arena object). Pinning these keeps router
-    # churn from silently swapping decode graphs or dropping the pool
-    # donations (a dropped donation doubles every decode replica's HBM).
+    # The prefill-pool export graph and the decode-side KV-handoff import
+    # (the splice fed by the host device_put of the sealed arena object;
+    # the decode pool's step is llm.decode_paged above). Pinning these
+    # keeps router churn from silently dropping the pool donations (a
+    # dropped donation doubles every decode replica's HBM).
 
     def build_prefill_pool(mesh):
         return gc.GraphSpec(
@@ -2967,22 +2370,6 @@ def __graphcheck__(gc):
             args=(_params(), _sds((1, 32), jnp.int32),
                   _sds((1,), jnp.int32)),
             arg_names=("params", "tokens", "lengths"))
-
-    def build_decode_window(mesh):
-        return gc.GraphSpec(
-            name="llm.decode_pool_window",
-            fn=partial(decode_window, config=c, eos_token=2, n_steps=2,
-                       trunc=False, guided=False, want_logp=False),
-            args=(_params(), _pool(), _pool(), _sds((slots,), jnp.int32),
-                  _sds((slots,), jnp.int32), _sds((slots,), jnp.bool_),
-                  _sds((slots, ptab), jnp.int32),
-                  _sds((slots,), jnp.float32), _sds((slots,), jnp.float32),
-                  _sds((slots,), jnp.int32), _sds((1, 1, 1), jnp.int32),
-                  _sds((slots,), jnp.int32), _sds((2,), jnp.uint32)),
-            donate_argnums=(1, 2), min_donate_bytes=16384,
-            arg_names=("params", "pool_k", "pool_v", "tokens", "lengths",
-                       "active", "page_tables", "temps", "top_ps",
-                       "top_ks", "gtables", "gstates", "key"))
 
     def build_kv_handoff(mesh):
         # import_kv's splice: ONE request, a multi-page contiguous handoff
@@ -3000,7 +2387,5 @@ def __graphcheck__(gc):
     gc.register("llm.prefill", build_prefill)
     gc.register("llm.decode_paged", build_decode)
     gc.register("llm.insert_kv", build_insert)
-    gc.register("llm.spec_verify", build_spec_verify)
     gc.register("llm.prefill_pool", build_prefill_pool)
-    gc.register("llm.decode_pool_window", build_decode_window)
     gc.register("llm.kv_handoff", build_kv_handoff)

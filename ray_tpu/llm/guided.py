@@ -4,11 +4,13 @@ Parity: the guided-decoding capability the reference inherits from vLLM
 (`python/ray/llm/_internal/serve/deployments/llm/vllm/` — outlines-style
 `guided_json` / `guided_regex` request fields). TPU-native redesign: the
 constraint compiles AHEAD of decoding into a dense token-transition table
-`[n_states, vocab]` (next-state, -1 = token disallowed). The table is
-device-resident and the per-slot DFA state rides the decode window's scan
-carry, so constraint enforcement adds one gather + one where per step and
-never fences the host — the outlines/vLLM pattern of a host-side logits
-processor would serialize the whole decode loop through Python here.
+`[n_states, vocab]` (next-state, -1 = token disallowed). The engine's
+sampler takes the current state's row as a [slots, vocab] mask (one
+`where` a step), and the slot's state moves on the host as each token is
+fetched: a table lookup, where the outlines/vLLM pattern of a host-side
+logits processor runs Python over the vocabulary every token. A slot with
+a guide keeps `step()` in step with the host (`InferenceEngine._may_lead`):
+its next mask follows from this token.
 
 Pipeline: regex (or JSON schema -> regex) -> Thompson NFA -> subset DFA
 over BYTES -> prune states that cannot reach an accepting state (a model
@@ -19,7 +21,6 @@ token-level table by running each tokenizer piece through the byte DFA.
 from __future__ import annotations
 
 import dataclasses
-import itertools
 
 import numpy as np
 
@@ -362,9 +363,6 @@ def compile_byte_dfa(pattern: str) -> ByteDFA:
 # ---------------- token-level table ----------------
 
 
-_guide_serial = itertools.count(1)
-
-
 @dataclasses.dataclass
 class TokenGuide:
     """table[s, tok] = next DFA state, or -1 when `tok` is disallowed in
@@ -373,16 +371,6 @@ class TokenGuide:
 
     table: np.ndarray          # [n_states, vocab] int32
     pattern: str
-    # Process-wide monotonic identity: device-table upload fingerprints
-    # key on this instead of id() — after an LRU eviction a newly compiled
-    # guide can land on a reused id() and silently keep enforcing the old
-    # constraint (engine._sync_guides).
-    serial: int = dataclasses.field(
-        default_factory=lambda: next(_guide_serial))
-
-    @property
-    def n_states(self) -> int:
-        return self.table.shape[0]
 
 
 def _token_bytes(tokenizer, vocab: int) -> list[bytes | None]:

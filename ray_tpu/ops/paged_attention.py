@@ -30,8 +30,8 @@ Layouts:
   lengths      [B]  number of valid tokens (attend positions < lengths)
   page_tables  [B, P]  page ids in position order (entry 0 = scratch page)
 
-The `*_reference` functions and `paged_verify_attention` take ONE
-layer's pages [n_kv_heads, num_pages, head_dim, page_size].
+`paged_decode_attention_reference` takes ONE layer's pages
+[n_kv_heads, num_pages, head_dim, page_size].
 
 Returns [B, n_heads, head_dim].
 """
@@ -135,11 +135,12 @@ def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     grid than computing (measured ~0.8ms per layer call vs ~0.2ms for
     this shape).
 
-    n_q > 1 (speculative verify): the q block carries n_q query tokens per
-    slot folded into the head-group axis with the query index MINOR
-    ([hkv, g*n_q, hd], layout [g, n_q]); query j sits at absolute position
-    lengths-1+j, so its causal limit is lengths+j. The flash accumulators
-    simply widen by n_q rows."""
+    n_q > 1 (no caller passes it: the decode programs ask one query a
+    slot): the q block carries n_q query tokens per slot folded into the
+    head-group axis with the query index MINOR ([hkv, g*n_q, hd], layout
+    [g, n_q]); query j sits at absolute position lengths-1+j, so its
+    causal limit is lengths+j. The flash accumulators simply widen by n_q
+    rows."""
     b = pl.program_id(0)
     layer = layer_ref[0]
     length = lengths_ref[b]
@@ -291,15 +292,16 @@ def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     aliased. Token-granular XLA scatters serialized at ~2us/row and cost
     more than the whole forward; here the write rides the DMA pipeline the
     attend already pays for. The written values are BITWISE the new K and
-    V (a select, no product through the MXU: speculation's greedy
-    exactness rests on it).
+    V (a select, no product through the MXU).
 
     The new tokens' blocks differ with the static n_q, a shape: n_q > 1
-    (speculative verify) [hkv*hd, n_q], rolled along the page's lanes to
-    where the tokens land; n_q == 1 (every decode step) the token's
-    [hkv, hd] as the projections leave them, copied into every column, so
-    that no re-lay-out runs before the call (it was 14 us a call on
-    ouro_2_6b, PERF.md section 6, PR 46)."""
+    (several tokens a slot at consecutive positions, query j attending
+    positions < lengths + j: what a draft source inside step() would
+    call; nothing does today) [hkv*hd, n_q], rolled along the page's
+    lanes to where the tokens land; n_q == 1 (every decode step) the
+    token's [hkv, hd] as the projections leave them, copied into every
+    column, so that no re-lay-out runs before the call (it was 14 us a
+    call on ouro_2_6b, PERF.md section 6, PR 46)."""
     b = pl.program_id(0)
     length = lengths_ref[b]          # = base + 1 (limit of query 0)
     base = length - 1                # position of the first new token
@@ -451,15 +453,15 @@ def paged_decode_insert_attention(q, pool_k, pool_v, knew, vnew, lengths,
     here the write is one more page DMA of a page the kernel holds
     already, and an idle slot costs nothing (PERF.md sections 5 and 6, PR
     45 and PR 46). Off the chip (interpret mode does not carry the
-    kernel's write-back through the aliasing, see
-    paged_verify_insert_attention) and for pages Mosaic cannot tile: the
-    XLA column insert, then `paged_decode_attention`.
+    kernel's in-place HBM write-back through the input/output aliasing:
+    the aliased outputs come back unmodified) and for pages Mosaic cannot
+    tile: the XLA column insert, then `paged_decode_attention`.
 
     So tier-1 EXECUTES only the fallback, and test_chip_compile only
     compiles the fused kernel: its numbers are checked on the chip alone,
     by the cells' `correct` and by `perfbench/tools/checkdist.py` and
     `checkdist_ouro.py` (the verify skill has the commands): run those
-    after any edit to `_fused_kernel` or `_verify_insert_call`."""
+    after any edit to `_fused_kernel` or `_fused_insert_call`."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     page, hd = pool_k.shape[4], pool_k.shape[3]
@@ -482,47 +484,10 @@ def _decode_insert_dma(q, k_pages, v_pages, knew, vnew, lengths, page_tables,
     layers or loops over passes, and the call a jit of its own: the L
     calls of a decode program share ONE traced, lowered and compiled
     kernel, as `_dma_kernel`'s do."""
-    out, k_pages, v_pages = _verify_insert_call(
+    out, k_pages, v_pages = _fused_insert_call(
         q[:, None], k_pages, v_pages, knew[:, None], vnew[:, None], lengths,
         page_tables, layer, name=name)
     return out[:, 0], k_pages, v_pages
-
-
-def paged_verify_insert_attention(q, pool_k, pool_v, knew, vnew,
-                                  lengths, page_tables, layer: int, *,
-                                  interpret: bool | None = None):
-    """Fused insert+attend for the speculative verify step, against ONE
-    layer of the stacked pools.
-
-    q [B, S, h, hd]; knew/vnew [B, S, hkv, hd] are the S new tokens'
-    K/V, written into pool[layer] at positions lengths-1..lengths-1+S-1
-    as a side effect (the pools are input/output-aliased, so the caller
-    gets the same buffers back — no copies); query j attends
-    pos < lengths + j. Returns (attn [B, S, h, hd], pool_k, pool_v)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    page, hd = pool_k.shape[4], pool_k.shape[3]
-    # Interpret mode does not propagate the kernel's in-place HBM
-    # writebacks through the input/output aliasing (verified empirically:
-    # the aliased outputs come back unmodified), so CPU paths — tests and
-    # the multichip dryrun — take the XLA insert+attend fallback.
-    if interpret or not _mosaic_tiles(page, hd):
-        return _verify_insert_xla(q, pool_k, pool_v, knew, vnew,
-                                  lengths, page_tables, layer)
-    return _verify_insert_dma(q, pool_k, pool_v, knew, vnew, lengths,
-                              page_tables, layer=layer,
-                              interpret=False)
-
-
-@functools.partial(jax.jit, static_argnames=("layer",))
-def _verify_insert_xla(q, pool_k, pool_v, knew, vnew, lengths,
-                       page_tables, layer):
-    pool_k, pool_v = _insert_tokens_xla(pool_k, pool_v, knew, vnew,
-                                        lengths, page_tables, layer)
-    out = paged_verify_attention_reference(q, pool_k[layer],
-                                           pool_v[layer], lengths,
-                                           page_tables)
-    return out, pool_k, pool_v
 
 
 def _insert_tokens_xla(pool_k, pool_v, knew, vnew, lengths,
@@ -545,21 +510,13 @@ def _insert_tokens_xla(pool_k, pool_v, knew, vnew, lengths,
     return pool_k, pool_v
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "layer"),
-                   donate_argnums=(1, 2))
-def _verify_insert_dma(q, k_pages, v_pages, knew, vnew, lengths,
-                       page_tables, *, layer: int = 0,
-                       interpret: bool = False):
-    return _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
-                               page_tables, layer, interpret=interpret)
-
-
-def _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
+def _fused_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
                         page_tables, layer, *, interpret: bool = False,
                         name: str | None = None):
-    """The fused kernel's call. `layer` an int: a static of the kernel
-    (the speculative verify programs', unrolled over layers); a traced
-    scalar: prefetched before the two the kernel takes anyway."""
+    """The fused kernel's call: q [B, S, h, hd], knew / vnew
+    [B, S, hkv, hd], S = 1 from `_decode_insert_dma`. `layer` an int: a
+    static of the kernel; a traced scalar (the decode programs'):
+    prefetched before the two the kernel takes anyway."""
     B, S, h, hd = q.shape
     L, hkv, N, _, page = k_pages.shape
     assert h % hkv == 0, (h, hkv)
@@ -629,105 +586,6 @@ def _verify_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
     out = out.reshape(B, hkv, g, S, hd).transpose(0, 3, 1, 2, 4).reshape(
         B, S, h, hd)
     return out, k_pages, v_pages
-
-
-def paged_verify_attention(q, k_pages, v_pages, lengths, page_tables, *,
-                           interpret: bool | None = None):
-    """Multi-query paged attention for speculative verify: q [B, S, h, hd]
-    holds S query tokens per slot at consecutive positions, whose KV is
-    already written to the pool; query j attends pos < lengths + j
-    (`lengths` = the causal limit of query 0, i.e. its position + 1).
-    Returns [B, S, h, hd]. Same DMA pipeline as decode — the S queries
-    fold into the head-group axis, so verifying K drafts costs ONE pass
-    over the slot's pages instead of K+1."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    page, hd = k_pages.shape[3], k_pages.shape[2]
-    if not interpret and not _mosaic_tiles(page, hd):
-        return _paged_verify_xla(q, k_pages, v_pages, lengths, page_tables)
-    return _paged_verify_dma(q, k_pages, v_pages, lengths, page_tables,
-                             interpret=interpret)
-
-
-@jax.jit
-def _paged_verify_xla(q, k_pages, v_pages, lengths, page_tables):
-    return paged_verify_attention_reference(q, k_pages, v_pages, lengths,
-                                            page_tables)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_verify_dma(q, k_pages, v_pages, lengths, page_tables, *,
-                      interpret: bool = False):
-    B, S, h, hd = q.shape
-    hkv, N, _, page = k_pages.shape
-    assert h % hkv == 0, (h, hkv)
-    g = h // hkv
-    P = page_tables.shape[1]
-    # fold queries into the group axis, query index MINOR: [g, S]
-    q4 = q.reshape(B, S, hkv, g, hd).transpose(0, 2, 3, 1, 4).reshape(
-        B, hkv, g * S, hd)
-    scale = 1.0 / float(np.sqrt(hd))
-    kernel = functools.partial(_dma_kernel, page=page, scale=scale,
-                               pages_per_seq=P, n_q=S)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, hkv, g * S, hd),
-                             lambda b, lyr, lens, tbl: (b, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),      # k_pages in HBM
-                pl.BlockSpec(memory_space=pl.ANY),      # v_pages in HBM
-            ],
-            out_specs=pl.BlockSpec((1, hkv, g * S, hd),
-                                   lambda b, lyr, lens, tbl: (b, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, hkv, hd, page), k_pages.dtype),  # kbuf
-                pltpu.VMEM((2, hkv, hd, page), v_pages.dtype),  # vbuf
-                pltpu.VMEM((hkv * g * S, 128), jnp.float32),    # m
-                pltpu.VMEM((hkv * g * S, 128), jnp.float32),    # l
-                pltpu.VMEM((hkv, g * S, hd), jnp.float32),      # acc
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, hkv, g * S, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.zeros((1,), jnp.int32), lengths, page_tables, q4, k_pages[None],
-      v_pages[None])   # one layer's pages as a stack of one
-    return out.reshape(B, hkv, g, S, hd).transpose(0, 3, 1, 2, 4).reshape(
-        B, S, h, hd)
-
-
-def paged_verify_attention_reference(q, k_pages, v_pages, lengths,
-                                     page_tables):
-    """Dense reference for the verify path: gather pages, per-query causal
-    mask (query j: pos < lengths + j), softmax."""
-    B, S, h, hd = q.shape
-    hkv, N, _, page = k_pages.shape
-    g = h // hkv
-    P = page_tables.shape[1]
-    T = P * page
-    ck = k_pages[:, page_tables]          # [hkv, B, P, hd, page]
-    cv = v_pages[:, page_tables]
-    ck = jnp.moveaxis(ck, 0, 1).transpose(0, 1, 2, 4, 3).reshape(
-        B, hkv, T, hd)
-    cv = jnp.moveaxis(cv, 0, 1).transpose(0, 1, 2, 4, 3).reshape(
-        B, hkv, T, hd)
-    q5 = q.reshape(B, S, hkv, g, hd).transpose(0, 2, 3, 1, 4).astype(
-        jnp.float32)                      # [B, hkv, g, S, hd]
-    s = jnp.einsum("bkgsd,bktd->bkgst", q5, ck.astype(jnp.float32))
-    s = s / np.sqrt(hd)
-    limit = lengths[:, None] + jnp.arange(S)[None]          # [B, S]
-    mask = (jnp.arange(T)[None, None, None, None]
-            < limit[:, None, None, :, None])
-    s = jnp.where(mask, s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgst,bktd->bkgsd", pr, cv.astype(jnp.float32))
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, h, hd).astype(
-        q.dtype)
 
 
 def paged_decode_attention_reference(q, k_pages, v_pages, lengths,

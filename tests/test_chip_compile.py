@@ -94,31 +94,20 @@ def _paged(kind):
 
         lengths = sds((SLOTS,), jnp.int32)
         tables = sds((SLOTS, P_SEQ), jnp.int32)
-        pages = sds((HKV, N_PAGES, HD, PAGE), jnp.bfloat16)
         pool = sds((2, HKV, N_PAGES, HD, PAGE), jnp.bfloat16)
         if kind == "decode":
             q = sds((SLOTS, H, HD), jnp.bfloat16)
             return (lambda *a: pa.paged_decode_attention(
                 *a, layer=1, interpret=False)), (
                 q, pool, pool, lengths, tables)
-        if kind == "decode_insert":   # decode_paged's call of every layer
-            q, new = sds((SLOTS, H, HD), jnp.bfloat16), sds(
-                (SLOTS, HKV, HD), jnp.bfloat16)
-            return (lambda q, pk, pv, kn, vn, ln, tb:
-                    pa.paged_decode_insert_attention(
-                        q, pk, pv, kn, vn, ln, tb, layer=1,
-                        name="_paged_decode_insert", interpret=False)), (
-                q, pool, pool, new, new, lengths, tables)
-        S = 5  # spec_k=4 drafts + the token they follow
-        q = sds((SLOTS, S, H, HD), jnp.bfloat16)
-        if kind == "verify":
-            return (lambda *a: pa.paged_verify_attention(
-                *a, interpret=False)), (q, pages, pages, lengths, tables)
-        new = sds((SLOTS, S, HKV, HD), jnp.bfloat16)
+        # decode_insert: decode_paged's call of every layer
+        q, new = sds((SLOTS, H, HD), jnp.bfloat16), sds(
+            (SLOTS, HKV, HD), jnp.bfloat16)
         return (lambda q, pk, pv, kn, vn, ln, tb:
-                pa.paged_verify_insert_attention(
-                    q, pk, pv, kn, vn, ln, tb, layer=1, interpret=False)
-                ), (q, pool, pool, new, new, lengths, tables)
+                pa.paged_decode_insert_attention(
+                    q, pk, pv, kn, vn, ln, tb, layer=1,
+                    name="_paged_decode_insert", interpret=False)), (
+            q, pool, pool, new, new, lengths, tables)
     return build
 
 
@@ -267,8 +256,6 @@ CASES = {
     "flash_bwd_1x8192": _flash((1, 8192), grad=True),
     "flash_bwd_fsdp2_tp2": _flash_sharded,
     "paged_decode": _paged("decode"),
-    "paged_verify": _paged("verify"),
-    "paged_verify_insert": _paged("verify_insert"),
     "paged_decode_insert": _paged("decode_insert"),
     "latent_decode": _latent("decode"),
     "mla_prefill_over_prefix": _latent("prefill"),

@@ -288,18 +288,6 @@ def test_a_first_token_that_ends_its_request_is_returned():
     assert not eng.has_work() and eng.kv_stats()["decode_steps"] == 0
 
 
-def test_step_window_fetches_what_step_left_in_flight():
-    eng = _engine("per_head")
-    req = eng.request(eng.add_request(_ids(10, 1), 6, 0.0))
-    want = _drive(_engine("per_head", ahead=False), {0: [add(10, 6, 1)]})[0]
-    eng.step()
-    eng.step()
-    assert eng._flight is not None
-    while eng.has_work():
-        eng.step_window()
-    assert eng._flight is None and req.generated == want.generated
-
-
 # ------------------------------------------- the programs are the parent's
 
 # sha256 of the lowered text of each serving program, as the engine calls it
@@ -341,13 +329,11 @@ PROGRAMS = {
     },
 }
 # sha256 of the engine's entries (`llm.*`) of tools/graphcheck/
-# fingerprints.json, as sorted JSON: the parent's file gives the same. (It
-# was the whole file's hash until PR 36 added a train graph's entry.) Taken
-# again at PR 46 for `llm.decode_paged@1dev` and `llm.decode_pool_window@1dev`
-# (`decode_window` steps `decode_paged`): flops and bytes of the CPU's
-# lowering moved, donation and aliasing did not; the five others stand.
+# fingerprints.json, as sorted JSON. Taken again at PR 48 for the two
+# entries that left with their programs (the window loop's and the
+# speculative verify's); the five that stay are the parent's to the byte.
 FINGERPRINTS = (
-    "5432a3ba08b29d84f1611825e5023359e4dc74b3be2c4b752a496d18cfdbbe6d")
+    "925a231d70e327a227844935f6c2c937af5fd64582399000559363fd80d6e824")
 
 
 # The two per-head prefill programs at a shape of more than one tile, [2,
@@ -425,6 +411,6 @@ def test_the_graph_fingerprints_are_the_parents():
     with open(os.path.join(ROOT, "tools", "graphcheck",
                            "fingerprints.json")) as f:
         llm = {k: v for k, v in json.load(f).items() if k.startswith("llm.")}
-    assert len(llm) == 7
+    assert len(llm) == 5
     assert hashlib.sha256(json.dumps(llm, sort_keys=True).encode()
                           ).hexdigest() == FINGERPRINTS

@@ -119,7 +119,7 @@ def test_engine_guided_regex():
     rid = eng.add_request([5, 6, 7], max_new_tokens=16, temperature=0.0,
                           guide=g)
     while eng.has_work():
-        eng.step_window()
+        eng.step()
     out = eng.finished.pop(rid).generated
     if out and out[-1] == tok.eos_id:
         out = out[:-1]
@@ -146,7 +146,7 @@ def test_engine_guided_json_schema():
     rid_g = eng.add_request([10, 11, 12], max_new_tokens=64,
                             temperature=0.8, guide=g)
     while eng.has_work():
-        eng.step_window()
+        eng.step()
     out = eng.finished.pop(rid_g).generated
     if out and out[-1] == tok.eos_id:
         out = out[:-1]
@@ -165,21 +165,23 @@ def test_engine_guided_survives_preemption():
     tok = ByteTokenizer()
     g = compile_token_guide("[ab]{20}c", tok, vocab=300,
                             eos_id=tok.eos_id)
+    # (a victim is re-prefilled in one piece: its 3 + 22 tokens fit the
+    # largest bucket)
     eng = InferenceEngine(
-        cfg, EngineConfig(max_slots=4, max_len=64, prompt_buckets=(16,),
+        cfg, EngineConfig(max_slots=4, max_len=64, prompt_buckets=(16, 32),
                           eos_token=tok.eos_id, page_size=8,
                           num_pages=10), params=params)
     rids = [eng.add_request([3 + i, 4, 5], max_new_tokens=40,
                             temperature=0.0, guide=g) for i in range(4)]
     while eng.has_work():
-        eng.step_window()
+        eng.step()
     import re
     for rid in rids:
         out = eng.finished.pop(rid).generated
         if out and out[-1] == tok.eos_id:
             out = out[:-1]
         assert re.fullmatch("[ab]{20}c", tok.decode(out))
-    assert eng.preemptions > 0 or True  # preemption is load-dependent
+    assert eng.preemptions > 0
 
 
 def test_openai_guided_json_http(ray_start_regular):

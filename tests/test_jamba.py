@@ -421,10 +421,11 @@ def _tp_mesh():
 
 @pytest.mark.parametrize("what,build", [
     ("speculation", lambda: _engine(speculation="ngram")),
-    ("step_window", lambda: _engine().step_window()),
     ("prefill pool", lambda: PrefillEngine(TINY, EngineConfig(
         max_slots=1, max_len=64, page_size=16, prompt_buckets=(16,)))),
 ])
 def test_what_recurrent_state_does_not_run_with_names_the_field(what, build):
-    with pytest.raises(ValueError, match="layer_pattern"):
+    # speculation meets the engine's one check, whatever the model
+    with pytest.raises(ValueError, match="one decode loop" if what
+                       == "speculation" else "layer_pattern"):
         build()

@@ -16,7 +16,7 @@ import pytest
 from ray_tpu.llm import EngineConfig, InferenceEngine, LLMConfig
 from ray_tpu.llm.engine import (decode_paged, insert_pages_batch,
                                 prefill_batch, prefill_with_prefix_batch,
-                                sample, verify_paged)
+                                sample)
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models import ModelConfig, forward, init_params
 
@@ -444,7 +444,7 @@ def test_chunked_prefill_interleaves_with_decode(tiny_params):
     progressed_during_admission = False
     prev = 0
     while eng.has_work():
-        eng.step_window()
+        eng.step()
         cur = short_progress()
         if eng.queue and cur > prev:
             # the long prompt is still chunk-admitting, yet the short
@@ -495,7 +495,7 @@ def test_engine_logprobs_match_forward(tiny_params):
     rid = eng.add_request(prompt, max_new_tokens=5, temperature=0.0,
                           logprobs=True)
     while eng.has_work():
-        eng.step_window()
+        eng.step()
     req = eng.finished.pop(rid)
     assert len(req.token_logprobs) == len(req.generated) == 5
     # naive reference (jitted fixed-length forward — see _naive_greedy)
@@ -571,11 +571,11 @@ def test_engine_cancel_frees_slot_and_finishes(tiny_params):
                                temperature=0.0)
     r_queued = eng.add_request([8, 9], max_new_tokens=50, temperature=0.0)
     for _ in range(3):
-        eng.step_window()
+        eng.step()
     assert eng.active.any()
     eng.cancel(r_active)
     eng.cancel(r_queued)
-    eng.step_window()
+    eng.step()
     assert r_active in eng.finished and r_queued in eng.finished
     assert len(eng.finished[r_active].generated) >= 1
     assert eng.finished[r_queued].generated == []
@@ -713,7 +713,7 @@ def test_decode_steady_state_no_recompiles(tiny_params):
         "steady-state decode recompiled"
 
 
-# ---- the paged decode / verify programs, as pure functions ----
+# ---- the paged decode program, as a pure function ----
 
 _PAGED_CONFIGS = {
     "dense": TINY,
@@ -737,29 +737,28 @@ def _paged_args(c, page=_PAGE):
 
 # The prefill programs run the head at each request's last token only, so
 # a row of "the logits prefill would give" is one call at that length:
-# ragged inside a batch, and over the cases every row the decode and
-# verify steps below produce (positions 0..7) for both slots.
+# ragged inside a batch, and over the cases every row the decode steps
+# below produce (positions 0..7) for both slots.
 _LAST = [(1, 8), (2, 7), (3, 6), (4, 5), (5, 4), (6, 3), (7, 2), (8, 1)]
 
 
 @pytest.mark.parametrize("lengths", _LAST, ids=lambda l: "len%d_%d" % l)
 @pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
-def test_paged_decode_and_verify_reproduce_prefill_logits(family, lengths):
-    """Token-by-token `decode_paged`, then one 3-token `verify_paged`,
-    against `prefill_batch` read at the same positions: the three
-    programs spell the layer separately and must stay one function of
-    the same `params["layers"]` leaves."""
+def test_paged_decode_reproduces_prefill_logits(family, lengths):
+    """Token-by-token `decode_paged` against `prefill_batch` read at the
+    same positions: the two programs call the one block with another
+    attention each and must stay one function of the same
+    `params["layers"]` leaves."""
     c = _PAGED_CONFIGS[family]
     params, pool_k, pool_v, tables = _paged_args(c)
-    n_decode, n_verify = 5, 3
+    n_decode = 8
     tokens = jax.random.randint(jax.random.PRNGKey(11),
-                                (_SLOTS, n_decode + n_verify), 1, c.vocab)
+                                (_SLOTS, n_decode), 1, c.vocab)
     lengths = jnp.asarray(lengths, jnp.int32)
     want, _, _ = jax.jit(partial(prefill_batch, config=c))(
         params, tokens, lengths)
     assert want.shape == (_SLOTS, c.vocab)
     decode = jax.jit(partial(decode_paged, config=c))
-    verify = jax.jit(partial(verify_paged, config=c))
     active = jnp.ones((_SLOTS,), jnp.bool_)
     rows = []                                       # [position][slot, vocab]
     for t in range(n_decode):
@@ -767,10 +766,6 @@ def test_paged_decode_and_verify_reproduce_prefill_logits(family, lengths):
             params, pool_k, pool_v, tokens[:, t],
             jnp.full((_SLOTS,), t, jnp.int32), active, tables)
         rows.append(got)
-    got, _, _ = verify(params, pool_k, pool_v, tokens[:, n_decode:],
-                       jnp.full((_SLOTS,), n_decode, jnp.int32), active,
-                       tables)
-    rows.extend(got[:, j] for j in range(n_verify))
     got = jnp.stack(rows, 1)                        # [slot, position, vocab]
     np.testing.assert_allclose(
         jnp.take_along_axis(got, (lengths - 1)[:, None, None], 1)[:, 0],
@@ -858,10 +853,10 @@ def _weight_copies(jaxpr, weights):
                      lambda name, _: name != "dot_general")
 
 
-@pytest.mark.parametrize("program", ["decode", "verify"])
+@pytest.mark.parametrize("program", ["decode"])   # the ids the records name
 @pytest.mark.parametrize("family", sorted(_PAGED_CONFIGS))
 def test_paged_programs_read_weights_in_place(family, program):
-    """Neither program builds a weight-sized array: every matrix of
+    """The program builds no weight-sized array: every matrix of
     `params["layers"]` goes from its per-layer view straight into a
     matmul. A `concatenate` of `wq|wk|wv` or `wg|wu` inside the jit is a
     read and a write of those weights on EVERY token — the replica's pump
@@ -871,12 +866,9 @@ def test_paged_programs_read_weights_in_place(family, program):
     params, pool_k, pool_v, tables = _paged_args(c)
     lengths = jnp.zeros((_SLOTS,), jnp.int32)
     active = jnp.ones((_SLOTS,), jnp.bool_)
-    if program == "decode":
-        fn, tokens = decode_paged, jnp.ones((_SLOTS,), jnp.int32)
-    else:
-        fn, tokens = verify_paged, jnp.ones((_SLOTS, 3), jnp.int32)
+    tokens = jnp.ones((_SLOTS,), jnp.int32)
     args = (params, pool_k, pool_v, tokens, lengths, active, tables)
-    closed = jax.make_jaxpr(partial(fn, config=c))(*args)
+    closed = jax.make_jaxpr(partial(decode_paged, config=c))(*args)
     leaves = jax.tree_util.tree_flatten_with_path(args)[0]
     weights = {
         var for (path, leaf), var in zip(leaves, closed.jaxpr.invars)

@@ -336,7 +336,6 @@ def _handoff():
 
 @pytest.mark.parametrize("what,make", [
     ("speculation", lambda: _engine(speculation="ngram")),
-    ("step_window", lambda: _engine().step_window()),
     ("PrefillEngine", lambda: PrefillEngine(
         TINY, EngineConfig(page_size=PAGE, prompt_buckets=(16,)))),
     ("kv_handoff", _handoff),
@@ -346,7 +345,9 @@ def _handoff():
         _params(), {"tokens": jnp.zeros((1, 9), jnp.int32)}, TINY)),
 ])
 def test_what_a_looped_stack_does_not_run_names_the_field(what, make):
-    with pytest.raises(ValueError, match=r"ModelConfig\.loops=4"):
+    # speculation meets the engine's one check, whatever the model
+    with pytest.raises(ValueError, match="one decode loop" if what
+                       == "speculation" else r"ModelConfig\.loops=4"):
         make()
 
 

@@ -83,7 +83,7 @@ def test_prefill_export_import_matches_engine(tiny_llm_params):
     rid = dec.add_request(prompt, 6, 0.0, resume_token=first,
                           kv_handoff=(ks, vs))
     while dec.has_work():
-        dec.step_window()
+        dec.step()
     assert dec.finished.pop(rid).generated == want
     assert dec.prefix_hits >= 1, "handoff pages must be prefix-hit"
 
@@ -94,7 +94,7 @@ def test_prefill_export_import_matches_engine(tiny_llm_params):
     rid2 = dec2.add_request(prompt + gen[:-1], 6 - len(gen) + 1, 0.0,
                             resume_token=gen[-1], kv_handoff=(ks, vs))
     while dec2.has_work():
-        dec2.step_window()
+        dec2.step()
     assert dec2.finished.pop(rid2).generated == gen[-1:] + want[3:]
 
 
