@@ -169,11 +169,24 @@ class Request:
 
 
 @dataclasses.dataclass
+class _FirstTokens:
+    """The first tokens of an admission burst, drawn and not fetched: they
+    stay on the device, the decode step after the admission reads them
+    there (`merge_tokens`), and `_land` fetches them with that step's."""
+    tokens: object      # [k] int32 on the device, a row a request
+    logps: object       # [k] log p(token) on the device; None if none asked
+    owners: list        # k (slot, request)
+    admitted: dict      # `_admit`'s result, whose values they will be
+
+
+@dataclasses.dataclass
 class _Flight:
     """A decode step that was dispatched and not fetched yet
     (InferenceEngine.step). The host's view (`lengths`, `active`,
     `last_tokens`, each request's `generated`) is the one BEFORE it until
-    `_land` fetches the tokens."""
+    `_land` fetches the tokens; of a slot that was admitted since (under
+    this step, into a slot it led once too often: `reqs[i]` is then no
+    longer the slot's request) the host's view is the new owner's."""
     tokens: object      # [B] int32 on the device: the token every slot drew
     logps: object       # [B] log p(token) on the device; None if none asked
     active: np.ndarray  # [B] bool: the slots that decoded in it
@@ -181,6 +194,18 @@ class _Flight:
     ends: np.ndarray    # [B] bool: its token is the slot's last, by
     #                     max_new_tokens or max_len (eos_token is known only
     #                     once the token is fetched)
+    firsts: _FirstTokens | None = None  # first tokens it read on the device
+    #                     and the host has not seen: `_land` applies them
+    #                     before the step's own
+
+
+def merge_tokens(base, host, keep, firsts, src):
+    """A decode step's `tokens` operand [B] where not every slot's token
+    lies in one place: `base` (the step in flight's, on the device) where
+    `keep`, else `host` (the host's upload), and row `src[i]` of `firsts`
+    (an admission's first tokens, on the device) where `src[i]` >= 0."""
+    out = jnp.where(keep, base, host)
+    return jnp.where(src >= 0, firsts[jnp.maximum(src, 0)], out)
 
 
 # ---------------- pure model steps ----------------
@@ -1070,8 +1095,15 @@ class InferenceEngine:
         self._decode_paged: dict[int, object] = {}
         self._prefill_pre: dict[tuple, object] = {}
         self._flight: _Flight | None = None  # step()'s step in the air
+        # an admission's first tokens, from `_admit` to the decode step
+        # that reads them on the device and carries them from there
+        self._firsts: _FirstTokens | None = None
+        self._merge = _shared_jit(("merge_tokens",), lambda: merge_tokens)
         self.decode_steps = 0        # dispatched by step()
         self.decode_steps_ahead = 0  # ... before the step before was fetched
+        self.admissions = 0          # `_admit` calls that dispatched a prefill
+        self.admissions_unfenced = 0      # ... and fetched no first token
+        self.admissions_under_flight = 0  # ... with a decode step in the air
         # Donate the pool/cache: without donation every step round-trips
         # the full KV through a fresh HBM allocation (~GBs/step).
         insert = self.serving.insert_batch
@@ -1519,17 +1551,20 @@ class InferenceEngine:
 
     def _admit_queued(self, sp) -> dict[int, int]:
         """`_admit` with a request in the queue, under its span `sp`."""
+        self._fetch_firsts()    # none, unless no decode step followed the
+        #                         last admission (it raised; a test's call)
         t_ns = time.perf_counter_ns()
         admitted: dict[int, int] = {}
         pending: list[tuple] = []  # (slot, req, last-logits row) to sample
         rows_run_before = self.prefill_rows_run
         e = self.e
         page = e.page_size
-        # No slot is given out under a decode step in flight (step() lands
-        # it first when a request could be admitted): the slot state below
-        # is that step's until its tokens are fetched.
-        free = [] if self._flight is not None else [
-            i for i in range(e.max_slots) if not self.active[i]]
+        # The slots free on the host's view, a decode step in flight or
+        # not: one that step merely `ends` is its own until it lands; one
+        # it led once too often (an end on eos_token) it only wrote, and
+        # every program dispatched from here on runs behind it.
+        under_flight = self._flight is not None
+        free = [i for i in range(e.max_slots) if not self.active[i]]
         # Phase 1 — host-side planning: pop requests, match prefixes,
         # allocate pages. No device work yet, so a whole admission burst
         # can share one batched prefill dispatch below (one dispatch and
@@ -1749,25 +1784,72 @@ class InferenceEngine:
                     # Defer the first-token sampling: one batched readback for
                     # the whole admission burst instead of a fence per prompt.
                     pending.append((slot, req, logits_of[slot]))
-        if pending:  # one fence for the burst
+        fenced = False
+        if pending:
+            # One sampler dispatch for the burst, and no fetch: the tokens
+            # stay on the device for the decode step that follows. One
+            # fence for the burst where the host must know a token now.
+            owners = [(slot, req) for slot, req, _l in pending]
+            fenced = any(self._first_token_now(slot, req)
+                         for slot, req in owners)
             with diagnostics.span("ray_tpu.engine.admit.sample"):
-                toks, logps = self._sample_rows(
+                self._firsts = _FirstTokens(*self._sample_dispatch(
                     jnp.stack([row for _s, _r, row in pending]),
-                    [r for _s, r, _l in pending])
-            for j, (slot, req, _l) in enumerate(pending):
-                first = int(toks[j])
-                if req.logprobs:
-                    req.token_logprobs.append(float(logps[j]))
-                req.generated.append(first)
-                admitted[req.request_id] = first
-                self.last_tokens[slot] = first
-                self._advance_guide(req, first)
-                self._maybe_finish(slot, first)
+                    [req for _s, req in owners]), owners, admitted)
+                admitted.update((req.request_id, None) for _s, req in owners)
+                if fenced:
+                    self._fetch_firsts()
+        if planned:
+            self.admissions += 1
+            self.admissions_unfenced += not fenced
+            self.admissions_under_flight += under_flight
         if sp.on:
             sp.set(rows=sum(p["bucket"] for p in planned),
                    rows_run=self.prefill_rows_run - rows_run_before,
-                   fenced=int(bool(pending)))
+                   fenced=int(fenced), under_flight=int(under_flight))
         return admitted
+
+    def _first_token_now(self, slot: int, req: Request) -> bool:
+        """Whether the host must see the first token of `req`, just
+        admitted to `slot`, before anything else is dispatched: no decode
+        step follows it (the request ends there by max_new_tokens or
+        max_len, and the call that admits it returns the token, ROADMAP
+        D11), or the mask of its next token follows from it (a guide)."""
+        return (req.guide is not None or req.max_new_tokens <= 1
+                or self.lengths[slot] + 1 >= self.e.max_len)
+
+    def _fetch_firsts(self):
+        """Fetch the first tokens `_admit` left on the device (a fence) and
+        move the host's view past them, as `_land` would with the decode
+        step that read them."""
+        ft, self._firsts = self._firsts, None
+        if ft is not None:
+            ft.admitted.update(self._apply_firsts(ft))
+
+    def _apply_firsts(self, ft: _FirstTokens) -> dict[int, int]:
+        """The host's view moves past the first tokens `ft`, fetched here:
+        {request_id: token}. A request that left its slot since (a cancel)
+        gets none."""
+        toks = np.asarray(ft.tokens)
+        logps = None if ft.logps is None else np.asarray(ft.logps)
+        firsts: dict[int, int] = {}
+        for j, (slot, req) in enumerate(ft.owners):
+            if self.slot_req[slot] is req:
+                firsts[req.request_id] = self._take_token(
+                    slot, req, toks[j], None if logps is None else logps[j])
+        return firsts
+
+    def _take_token(self, slot: int, req: Request, tok, logp) -> int:
+        """Token `tok`, fetched, becomes the newest of `req` in `slot`
+        (`lengths[slot]` counts what the cache holds before it)."""
+        tok = int(tok)
+        if req.logprobs:
+            req.token_logprobs.append(float(logp))
+        req.generated.append(tok)
+        self.last_tokens[slot] = tok
+        self._advance_guide(req, tok)
+        self._maybe_finish(slot, tok)
+        return tok
 
     def _prefill_group(self, group: list, logits_of: dict, toks, lens, tabs,
                        pres, plens, srcs, dsts):
@@ -1855,6 +1937,12 @@ class InferenceEngine:
             # the step before was fetched (the rest waited for the host)
             "decode_steps": self.decode_steps,
             "decode_steps_ahead": self.decode_steps_ahead,
+            # `_admit` calls that dispatched a prefill; those of them that
+            # waited for no first token (it stayed on the device), and
+            # those dispatched behind a decode step still in the air
+            "admissions": self.admissions,
+            "admissions_unfenced": self.admissions_unfenced,
+            "admissions_under_flight": self.admissions_under_flight,
             # recurrent state (zeros for a model that keeps none): a row a
             # running sequence; snapshots held, of them pinned by an
             # admission under way; prefix hits that resumed from one
@@ -1961,50 +2049,76 @@ class InferenceEngine:
         sits out. An end on eos_token shows only at the fetch: N + 1 then
         ran that row once too often, and `_land` throws its token away.
 
-        The step before is fetched FIRST, and the next one dispatched from
-        the host's view (in step, the device waiting as long), where
-        `_may_lead` says why. A prompt's first token is returned by the
-        call that admits it only if no decode step follows it there: the
-        stream has never carried it otherwise (ROADMAP D11)."""
+        An admission joins the device's stream the same way: with a slot
+        free on the host's view its prefill is planned and dispatched
+        BEHIND step N, still in the air; its first tokens stay on the
+        device, where step N + 1 reads them (`merge_tokens`), and come
+        back with N + 1's own, one fence for both (`_first_token_now` says
+        which requests keep a fence of their own). So a call waits for the
+        device once, admission or none.
+
+        The step before is fetched FIRST, and everything after it
+        dispatched from the host's view (in step, the device waiting as
+        long), where `_may_lead` says so before the admission; or between
+        the admission and the decode step, where it says so after (the
+        admission took pages, or brought a guide). A prompt's first token
+        is returned by the call that admits it only if no decode step
+        follows it there: the stream has never carried it otherwise
+        (ROADMAP D11); one that ends its request on eos_token comes back
+        with the step that read it."""
         emitted = {} if self._may_lead() else self._land()
+        queued = bool(self.queue)
         admitted = self._admit()
+        if queued and not self._may_lead():
+            emitted.update(self._land())
         flight = self._decode_paged_step()
         emitted.update(self._land())
         self._flight = flight
         led = set() if flight is None else {
             r.request_id for r in flight.reqs if r is not None}
         emitted.update({rid: tok for rid, tok in admitted.items()
-                        if rid not in led})
+                        if tok is not None and rid not in led})
         return emitted
 
     def _may_lead(self) -> bool:
-        """Whether the next decode step may be dispatched before the one
-        in flight is fetched (True too with nothing in flight). Not when
-        the host needs that step's tokens, or its slots, first: a cancel
-        takes a slot; a request is queued and a slot is free or about to
-        be (an admission moves slots and draws the new slot's first token
-        on the host); a guide's mask for the next token follows from this
-        one; or a pool cannot grow every slot its next page (the page
-        pool, then the window pool), and a victim of preemption is
-        requeued with all its tokens."""
+        """Whether what this call dispatches next (an admission, then the
+        decode step) may go out before the step in flight is fetched (True
+        too with nothing in flight). Not when the host needs that step's
+        tokens, or its slots, first: a cancel takes a slot; a request is
+        queued and the only slots it could take are those the step ends;
+        a guide's mask for the next token follows from this one; or a
+        pool cannot grow every slot its next page (the page pool, then the
+        window pool), and a victim of preemption is requeued with all its
+        tokens. step() asks twice, before `_admit` and after one that may
+        have taken pages."""
         f = self._flight
         if f is None:
             return True
         if self._cancel_rids or (
-                self.queue and (f.ends.any() or not self.active.all())):
+                self.queue and self.active.all() and f.ends.any()):
             return False
         if any(r is not None and r.guide is not None for r in self.slot_req):
             return False
-        lengths, active = self.lengths + f.active, self.active & ~f.ends
+        lengths, active, _own = self._past(f)
         short = self._pages_short(lengths, active)
         return (len(short) <= len(self.free_pages) + len(self.cached_lru)
                 and (not self.win_span or self._window_short(lengths, active)
                      <= len(self.free_win)))
 
+    def _past(self, f: _Flight) -> tuple:
+        """(lengths, active) as the host will have them once `f`, the step
+        in flight, has landed, as far as it knows without its tokens, and
+        the slots `f` moves: a slot it ran for a request that is gone (it
+        led an end on eos_token) may have a new owner by now, whom it
+        moves nothing."""
+        own = f.active & np.array([r is q for r, q in
+                                   zip(f.reqs, self.slot_req)])
+        return self.lengths + own, self.active & ~(f.ends & own), own
+
     def _land(self) -> dict[int, int]:
         """Fetch the tokens of the step in flight (the one host fence a
-        token) and move the host's view past it; {} with nothing in
-        flight."""
+        token), with the first tokens of the admission before it, and move
+        the host's view past both; {} with nothing in flight."""
         f, self._flight = self._flight, None
         if f is None:
             return {}
@@ -2012,7 +2126,9 @@ class InferenceEngine:
             with diagnostics.span("ray_tpu.engine.land.fence"):
                 tokens = np.asarray(f.tokens)
                 logps = None if f.logps is None else np.asarray(f.logps)
-            emitted: dict[int, int] = {}
+            # a first token that is eos_token ends its request here: the
+            # step ran that row once too often, as below
+            emitted = {} if f.firsts is None else self._apply_firsts(f.firsts)
             for i in np.flatnonzero(f.active):
                 req = f.reqs[i]
                 if self.slot_req[i] is not req:
@@ -2023,20 +2139,16 @@ class InferenceEngine:
                     # own (never a shared prompt page: those lie below the
                     # first generated position) and the slot's own row of
                     # state, nobody else sees: pages and rows are handed out
-                    # on the host only after this fetch's step was dispatched,
+                    # on the host only after this fetch's step was dispatched
+                    # (since PR 51 maybe before its fetch: the slot's request
+                    # is then its new owner, whom this step moves nothing),
                     # so every program that writes them for their next owner
                     # runs after it on the device, and a reader is masked to
                     # the positions its owner wrote.
                     continue
-                tok = int(tokens[i])
-                if req.logprobs:
-                    req.token_logprobs.append(float(logps[i]))
-                req.generated.append(tok)
-                emitted[req.request_id] = tok
                 self.lengths[i] += 1
-                self.last_tokens[i] = tok
-                self._advance_guide(req, tok)
-                self._maybe_finish(i, tok)
+                emitted[req.request_id] = self._take_token(
+                    i, req, tokens[i], None if logps is None else logps[i])
         return emitted
 
     def _grow_pages(self) -> bool:
@@ -2065,6 +2177,11 @@ class InferenceEngine:
     def _make_room(self, i: int) -> bool:
         """A pool is dry under slot i: preempt a victim, and say whether
         slot i is still there to try again."""
+        if self._firsts is not None:
+            # a victim is requeued with ALL its tokens, and a first token
+            # may end its request and free the page: fetch, then try again
+            self._fetch_firsts()
+            return bool(self.active[i])
         if not self._preempt_victim(i):
             # Nothing preemptable: finish this request early rather than
             # deadlock the pump (pool too small for even one sequence — a
@@ -2095,18 +2212,24 @@ class InferenceEngine:
         tokens are that step's, where the sampler left them on the device
         (the same shape and dtype as the host's upload: one program
         either way), its lengths that step's plus one, and the pool has
-        the pages (`_may_lead` counted them). Every upload is an array of
-        its own: the program may run after the host has moved on, and on
-        the CPU backend jnp.asarray can alias the host's buffer."""
+        the pages (`_may_lead` counted them). Either way a slot admitted
+        since the host's view last moved reads its token where the
+        admission left it: its first token on the device (`_firsts`), a
+        resumed one in the host's upload; `merge_tokens` puts the vector
+        together, and the step carries the first tokens to their fetch.
+        Every upload is an array of its own: the program may run after the
+        host has moved on, and on the CPU backend jnp.asarray can alias
+        the host's buffer."""
         e, prev = self.e, self._flight
         if prev is None:
             if not self._grow_pages():
                 return None
             lengths, active = self.lengths.copy(), self.active.copy()
-            tokens = jnp.asarray(self.last_tokens.copy())
+            keep = np.zeros(e.max_slots, bool)
         else:
-            lengths = self.lengths + prev.active
-            active = self.active & ~prev.ends
+            # `keep`: the slots whose token that step draws; any other's
+            # is the host's, or a first token's
+            lengths, active, keep = self._past(prev)
             if not active.any():
                 return None
             for i in self._pages_short(lengths, active):
@@ -2116,8 +2239,29 @@ class InferenceEngine:
             if self.win_span:
                 for i in np.flatnonzero(active):
                     self._slide_window(i, int(lengths[i]))
-            tokens = prev.tokens
             self.decode_steps_ahead += 1
+        firsts, self._firsts = self._firsts, None
+        if prev is not None and firsts is None and keep[active].all():
+            tokens = prev.tokens
+        elif prev is None and firsts is None:
+            tokens = jnp.asarray(self.last_tokens.copy())
+        else:
+            host = jnp.asarray(self.last_tokens.copy())
+            # row of `firsts` a slot (-1: none); without first tokens (a
+            # resumed request under a step in flight) the one-row program
+            src = np.full(e.max_slots, -1, np.int32)
+            if firsts is not None:
+                src[[slot for slot, _r in firsts.owners]] = np.arange(
+                    len(firsts.owners))
+            tokens = self._merge(
+                host if prev is None else prev.tokens, host,
+                jnp.asarray(keep), jnp.asarray(np.zeros(1, np.int32))
+                if firsts is None else firsts.tokens, jnp.asarray(src))
+        # requests with a first token the host has not seen: this call's,
+        # and those the step in flight carries
+        unseen = {id(r) for ft in (
+            firsts, None if prev is None else prev.firsts)
+            if ft is not None for _s, r in ft.owners}
         self.decode_steps += 1
         with diagnostics.span("ray_tpu.engine.decode", step=self.decode_steps,
                               ahead=int(prev is not None)):
@@ -2143,16 +2287,17 @@ class InferenceEngine:
             reqs = [r if active[i] else None
                     for i, r in enumerate(self.slot_req)]
             # what the host knows of this step's end without its tokens: a
-            # slot's count of tokens after it (one more is in flight when
-            # this step leads), and the length its sequence reaches
+            # slot's count of tokens after it (one more is in flight where
+            # this step leads the slot, and one where its first token is
+            # still on the device), and the length its sequence reaches
             ends = np.array([
                 r is not None and (
-                    len(r.generated) + (prev is not None) + 1
+                    len(r.generated) + (id(r) in unseen) + int(keep[i]) + 1
                     >= r.max_new_tokens
                     or lengths[i] + 2 >= e.max_len)
                 for i, r in enumerate(reqs)])
             return _Flight(*self._sample_dispatch(logits, reqs), active,
-                           reqs, ends)
+                           reqs, ends, firsts)
 
     def _build_tables(self, active) -> np.ndarray:
         """Page tables [B, bucket] of the `active` slots."""
@@ -2203,12 +2348,6 @@ class InferenceEngine:
             logps = jnp.take_along_axis(
                 jax.nn.log_softmax(logits, axis=-1), toks[:, None], 1)[:, 0]
         return toks, logps
-
-    def _sample_rows(self, logits, reqs) -> tuple:
-        """`_sample_dispatch`, fetched: on the host after ONE fence (a
-        second for the logprobs)."""
-        toks, logps = self._sample_dispatch(logits, reqs)
-        return np.asarray(toks), None if logps is None else np.asarray(logps)
 
     @staticmethod
     def _advance_guide(req: Request, tok: int):

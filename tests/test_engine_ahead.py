@@ -129,7 +129,9 @@ def test_has_work_while_a_token_is_in_flight(kind):
     eng = _engine(kind)
     req = eng.request(eng.add_request(_ids(10, 1), 3, 0.0))
     assert eng.step() == {}        # the first token is not streamed (D11)
-    assert eng._flight is not None and len(req.generated) == 1
+    # ... nor fetched: it rides the step in flight, which read it on the
+    # device
+    assert eng._flight.firsts is not None and req.generated == []
     calls = 1
     while eng.has_work():
         assert eng._flight is not None
@@ -265,17 +267,38 @@ def test_a_cancel_with_a_step_in_flight(kind):
         assert eng.kv_stats()["pages_in_use"] == 0
 
 
-def test_no_slot_is_given_out_under_a_step_in_flight():
-    """What keeps a cancel that arrives between step()'s look at the queue
-    and `_admit` from moving a slot whose step is still in the air."""
-    eng = _engine("per_head")
-    eng.add_request(_ids(10, 1), 8, 0.0)
-    eng.step()
-    queued = eng.add_request(_ids(12, 2), 4, 0.0)
-    assert eng._flight is not None and not eng.active.all()
-    assert eng._admit() == {} and [r.request_id for r in eng.queue] == [queued]
-    eng.step()                      # lands first, then admits
-    assert not eng.queue and eng.active.sum() == 2
+def test_a_cancel_between_the_look_at_the_queue_and_the_admission():
+    """A cancel that arrives after step() has looked (`_may_lead`) and
+    before `_admit` applies it: the slot goes to the queued request under
+    the step still in the air, which ran it for the cancelled one. That
+    step moves the new owner nothing, and the step after it reads the new
+    owner's first token."""
+    eng, ref = _engine("per_head", max_slots=1), _engine("per_head",
+                                                         ahead=False)
+    for e in (eng, ref):
+        e.add_request(_ids(10, 1), 8, 0.0)
+        e.step()
+        e.step()
+    gone = eng.request(0)
+    queued = [e.request(e.add_request(_ids(12, 2), 4, 0.0, logprobs=True))
+              for e in (eng, ref)]
+    flight = eng._flight
+    assert flight is not None and flight.reqs[0] is gone and eng._may_lead()
+    eng.cancel(0)
+    assert list(eng._admit()) == [queued[0].request_id]
+    assert gone.done and eng._flight is flight
+    assert eng.slot_req[0] is queued[0] and eng.lengths[0] == 12
+    st = eng.kv_stats()
+    assert (st["admissions"], st["admissions_under_flight"]) == (2, 1)
+    ref.cancel(0)
+    for e in (eng, ref):
+        while e.has_work():
+            e.step()
+    assert len(gone.generated) == 2     # the token of the step in the air
+    #                                     was dropped with the slot
+    _same(queued[:1], queued[1:])
+    assert len(queued[0].generated) == 4
+    assert eng.kv_stats()["pages_in_use"] == 0
 
 
 def test_a_first_token_that_ends_its_request_is_returned():

@@ -193,7 +193,9 @@ PARENT_KV_STATS = {
 def test_kv_stats_loses_the_two_speculation_counters_alone():
     st = _engine("tiny").kv_stats()
     assert PARENT_KV_STATS - set(st) == {"spec_drafted", "spec_accepted"}
-    assert set(st) <= PARENT_KV_STATS
+    # ... and since PR 51 counts its admissions three ways
+    assert set(st) - PARENT_KV_STATS == {
+        "admissions", "admissions_unfenced", "admissions_under_flight"}
     # what perfbench/harness/serve_cell._engine_counters reads
     assert st["preemptions"] == 0 and st["prefix_hits"] == 0
 

@@ -237,14 +237,22 @@ def test_an_admission_says_what_it_admitted():
     records, _ = diagnostics.spans()
     admits = _named(records, "engine.admit")
     assert admits[0].attrs == {"rows": 16 + 16 + 32,
-                               "rows_run": 16 + 16 + 32, "fenced": 1}
+                               "rows_run": 16 + 16 + 32, "fenced": 0,
+                               "under_flight": 0}
     # ONE prefill span a group: the two prompts of bucket 16, the one of 32
     pre = [r for r in _named(records, "engine.admit.prefill")
            if r.parent == admits[0].id]
     assert len(pre) == 2
+    # the burst's sampler is dispatched there and nothing is fetched: the
+    # three first tokens come back under the `land.fence` of the decode
+    # step that read them
     (sample,) = [r for r in _named(records, "engine.admit.sample")
                  if r.parent == admits[0].id]
     assert max(r.t1_ns for r in pre) <= sample.t0_ns
+    st = eng.kv_stats()
+    assert (st["admissions"], st["admissions_unfenced"]) == (2, 2)
+    fence = min(_named(records, "engine.land.fence"), key=lambda r: r.t0_ns)
+    assert admits[0].t1_ns <= fence.t0_ns
 
 
 def test_no_admit_span_round_the_empty_call_of_a_decode_turn():
@@ -259,8 +267,9 @@ def test_no_admit_span_round_the_empty_call_of_a_decode_turn():
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_chunked_prompts_request_span(kind):
-    """A prompt of two chunks: two admissions, the second fenced; the
-    request's span runs from its arrival, and `queue_ms` to its slot."""
+    """A prompt of two chunks: two admissions, neither fenced (the first
+    draws no token, the second's stays on the device); the request's span
+    runs from its arrival, and `queue_ms` to its slot."""
     eng = _engine(kind)
     _run(eng, [(50, 3)], seed=100)    # other tokens: no page to hit
     diagnostics.spans_on()
@@ -273,7 +282,8 @@ def test_a_chunked_prompts_request_span(kind):
     assert len(_named(records, "engine.decode")) == 5   # the first token
     #                                              is the admission's
     admits = _named(records, "engine.admit")
-    assert [r.attrs["fenced"] for r in admits] == [0, 1]
+    assert [r.attrs["fenced"] for r in admits] == [0, 0]
+    assert [r.attrs["under_flight"] for r in admits] == [0, 0]
     # the slot came with the SECOND admission: the wait spans the first
     assert admits[0].t1_ns <= admits[1].t0_ns <= req.t_slot_ns
     assert req.t_slot_ns <= admits[1].t1_ns
@@ -433,7 +443,8 @@ def test_the_harness_outside_timings_still_read_with_recording_on(replica):
     program_spans.collect(rep, rec, None)
     assert rec.values["prog.spans_dropped"] == 0
     assert len(rec.samples["prog.req_queue_ms"]) == 3
-    assert len(rec.samples["prog.prefill_fenced_ms_per_krow"]) >= 1
+    # no admission of this traffic waits for its first tokens any more
+    assert "prog.prefill_fenced_ms_per_krow" not in rec.samples
     assert len(rec.samples["prog.step_host_ms"]) >= 1
     assert len(rec.samples["prog.admit_unfed_ms"]) >= 1
     assert 0 <= rec.values["prog.steps_ahead_pct"] <= 100
