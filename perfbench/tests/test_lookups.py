@@ -8,6 +8,7 @@ modelcfg.py): what the code did for them by name it does by default."""
 
 import json
 import os
+import re
 
 import pytest
 
@@ -37,7 +38,8 @@ EXPECTED = [
     ("qwen2_7b-train-fsdp4", False, dict(QWEN, n_layers=20, remat=True),
      None),
     ("qwen2_7b-train-fsdp4", True, dict(QWEN, **TINY, remat=True), None),
-    ("mixtral_8x7b-serve-chat", False, MIXTRAL, ENGINE),
+    # the cell's own slots (cells/mixtral_8x7b-serve-chat.json, PR 53)
+    ("mixtral_8x7b-serve-chat", False, MIXTRAL, dict(ENGINE, max_slots=32)),
     ("mixtral_8x7b-serve-chat", True,
      {**MIXTRAL, **TINY, "n_kv_heads": 4, "moe_experts": 4}, TINY_ENGINE),
 ]
@@ -99,8 +101,8 @@ def test_lists_and_objects_arrive_hashable():
 
 
 @pytest.mark.parametrize("over,exc,names", [
-    ({"model_fields": {"kv_lora_rank": {"key": "kv_lora_rank"}},
-      "kv_lora_rank": 512}, ValueError, ("other.json", "kv_lora_rank")),
+    ({"model_fields": {"conv_kernel": {"key": "conv_kernel"}},
+      "conv_kernel": 4}, ValueError, ("other.json", "conv_kernel")),
     ({"model_fields": {"d_ff": {"key": "moe_intermediate_size"}}},
      KeyError, ("other.json", "d_ff", "moe_intermediate_size")),
     ({"model_fields": {"d_ff": {"key": "a", "value": 1}}},
@@ -191,6 +193,30 @@ def test_a_cost_function_is_found_under_the_cells_root_and_sees_the_file(
     got = readers.read_metric("latent_roofline_pct", rec,
                               os.path.join(root, "perfbench", "metrics"))
     assert got == pytest.approx(100.0 * (1e9 * 40 * 4 / 1e12) / 0.5)
+
+
+def _cells_of(*kinds):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        names = [w["name"] for w in json.load(f)["workloads"]]
+    return [n for n in names
+            if cells.load_cell(ROOT, n)["traffic"]["kind"] in kinds]
+
+
+@pytest.mark.parametrize("cell", _cells_of("open_loop"))
+def test_an_open_loop_cells_why_prints_the_rate_its_file_offers(cell):
+    """BENCHMARK.json's `why` is what a reader and the driver's reviewer
+    see; cells/<cell>.json's `rate_rps` is what runs. One number."""
+    found = cells.load_cell(ROOT, cell)
+    said = re.findall(r"(\d+(?:\.\d+)?) req/s", found["cell"]["why"])
+    assert said == [format(found["cellp"]["rate_rps"], "g")], found["cell"]
+
+
+@pytest.mark.parametrize("cell", _cells_of("open_loop", "closed_loop"))
+def test_a_why_that_names_its_slots_names_the_engines(cell):
+    found = cells.load_cell(ROOT, cell)
+    said = re.findall(r"(\d+) slots", found["cell"]["why"])
+    slots = modelcfg.engine_config(found["cfg"], found["cellp"]).max_slots
+    assert said in ([], [str(slots)]), found["cell"]
 
 
 def test_every_configuration_of_the_benchmark_passes_the_look_ups():
