@@ -37,7 +37,7 @@ from ray_tpu import diagnostics
 from ray_tpu.models import ModelConfig, init_params, model_module
 from ray_tpu.models.experts import N_STATS, expert_layer, stats_zero
 from ray_tpu.models.transformer import exit_step, exit_zero
-from ray_tpu.ops.attention import prefill_attention
+from ray_tpu.ops.attention import prefill_attention, prefill_blocks
 from ray_tpu.ops.layers import apply_rope, last_rows, rmsnorm, rope
 
 # ---- shared compiled-step cache -------------------------------------
@@ -308,6 +308,25 @@ def _rows_run(lengths: np.ndarray, s: int) -> int:
     return int(_tiles_of(lengths).sum()) * _TILE_ROWS
 
 
+def _attn_blocks(c: ModelConfig, lengths: np.ndarray, s: int):
+    """(The query blocks in the grids of one prefill dispatch's attention
+    kernels, those of them that hold a token), on the host: every attention
+    layer's call counted once, whatever its heads (a window layer's blocks
+    are `window_block` rows, a full one's `_PREFILL_BQ`)."""
+    if c.kv_cache == "windowed":
+        kinds = c.attn_pattern
+    else:
+        kinds = "F" * (c.layer_pattern.count("*") if c.layer_pattern
+                       else c.cache_layers)
+    blocks = run = 0
+    for kind in set(kinds):
+        grid, real = prefill_blocks(lengths, s,
+                                    c.window if kind == "W" else 0)
+        blocks += kinds.count(kind) * grid
+        run += kinds.count(kind) * real
+    return blocks, run
+
+
 def _tile_order(lengths, s: int):
     """What `_walk` takes of a right-padded [n, s] batch of `lengths`: (the
     ids of the tiles of its flattened rows, those that hold a token first;
@@ -439,16 +458,18 @@ def _block(x, lp, c: ModelConfig, turn, attend, fence: bool = False,
     return x, kept, stats
 
 
-def _prefill_attention(q, keys, values, prefix_len, pre_t: int,
+def _prefill_attention(q, keys, values, prefix_len, lengths, pre_t: int,
                        c: ModelConfig):
     """q [n, s, h, hd] over keys and values [n, hkv, pre_t + s, hd] = a
     cached prefix of which request i has prefix_len[i] positions | the
-    chunk itself, causal; -> [n, s, h, hd]. One flash kernel for both
-    prefill programs (the jnp reference where no chip is): no score array
-    in HBM, K and V read by head // (h // hkv), never repeated."""
+    chunk itself, of which lengths[i] rows are real, causal; -> [n, s, h,
+    hd]. One flash kernel for both prefill programs (the jnp reference
+    where no chip is): no score array in HBM, K and V read by head // (h //
+    hkv), never repeated, no query block run past a request's last row."""
     out = prefill_attention(
         q.transpose(0, 2, 1, 3), keys, values, prefix_len, pre_t=pre_t,
-        scale=c.head_dim ** -0.5, name="gqa_prefill_attention")
+        scale=c.head_dim ** -0.5, name="gqa_prefill_attention",
+        lengths=lengths)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -557,8 +578,8 @@ def prefill_batch(params, tokens, lengths, stats=None, *,
 
         def attend(q, k, v):
             return _prefill_attention(q, k.transpose(0, 2, 1, 3),
-                                      v.transpose(0, 2, 1, 3), no_prefix, 0,
-                                      c), (k, v)
+                                      v.transpose(0, 2, 1, 3), no_prefix,
+                                      lengths, 0, c), (k, v)
 
         valid = None if stats is None else _real_rows(s, lengths)
         x, (ks, vs), counted = _passes(
@@ -615,7 +636,7 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
 
     def attend(pk, pv, q, k, v):
         return _prefill_attention(q, behind(pk, k), behind(pv, v),
-                                  prefix_len, pre_t, c), (k, v)
+                                  prefix_len, lengths, pre_t, c), (k, v)
 
     def run(tiles):
         x = _embed(params, tokens)
@@ -1077,6 +1098,10 @@ class InferenceEngine:
         # them its row-wise products ran over (`_rows_run`)
         self.prefill_rows_bucketed = 0
         self.prefill_rows_run = 0
+        # query blocks in the grids of their attention kernels, and those
+        # of them that held a token (`_attn_blocks`): the rest were not run
+        self.prefill_attn_blocks = 0
+        self.prefill_attn_blocks_run = 0
         # decode compile buckets over pages-in-use: powers of two up
         # to the per-slot page bound, then the bound. A power of two that
         # the bound is within a quarter of is left out: the two would be
@@ -1883,6 +1908,9 @@ class InferenceEngine:
         self.prefill_rows_run += (
             _rows_run(lens, toks.shape[1]) if self.c.kv_cache == "per_head"
             else toks.size)
+        blocks, run = _attn_blocks(self.c, lens, toks.shape[1])
+        self.prefill_attn_blocks += blocks
+        self.prefill_attn_blocks_run += run
         to_device = partial(jax.tree_util.tree_map, jnp.asarray)
         toks, lens, tabs = to_device((toks, lens, tabs))
         args = (self.params, toks, lens)
@@ -1933,6 +1961,11 @@ class InferenceEngine:
             # token, where a program walks them)
             "prefill_rows_bucketed": self.prefill_rows_bucketed,
             "prefill_rows_run": self.prefill_rows_run,
+            # query blocks in the grids of the prefill dispatches' attention
+            # kernels (a layer's call each), and those that held a token:
+            # the kernel runs no block past a request's last row
+            "prefill_attn_blocks": self.prefill_attn_blocks,
+            "prefill_attn_blocks_run": self.prefill_attn_blocks_run,
             # step()'s decode steps, and those of them dispatched before
             # the step before was fetched (the rest waited for the host)
             "decode_steps": self.decode_steps,

@@ -161,16 +161,17 @@ def _mla_project(x, lp, c: ModelConfig, sin, cos, fence: bool = False):
             jnp.concatenate([c_kv, k_pe], axis=-1))
 
 
-def _attend_up_projected(q_nope, q_pe, keys, prefix_len, lp, c: ModelConfig,
-                         pre_t: int):
+def _attend_up_projected(q_nope, q_pe, keys, prefix_len, lengths, lp,
+                         c: ModelConfig, pre_t: int):
     """The up-projected form, one request at a time (the 192- and 128-wide
     per-head K and V of one request are 0.7 GB at 8192 keys; a burst's
-    would not fit beside the weights). q_* [n, S, h, .]; keys [n, pre_t + S,
-    latent] = cached prefix | this chunk; -> [n, S, h, v]."""
+    would not fit beside the weights). q_* [n, S, h, .], right-padded to
+    `lengths`; keys [n, pre_t + S, latent] = cached prefix | this chunk;
+    -> [n, S, h, v]."""
     rank = c.kv_lora_rank
 
     def one(args):
-        qn, qp, lat, plen = args
+        qn, qp, lat, plen, rows = args
         k = jnp.concatenate(
             [jnp.einsum("tr,hrn->htn", lat[:, :rank], lp["w_uk"]),
              jnp.broadcast_to(lat[None, :, rank:],
@@ -180,11 +181,11 @@ def _attend_up_projected(q_nope, q_pe, keys, prefix_len, lp, c: ModelConfig,
         q = jnp.concatenate([qn, qp], axis=-1).transpose(1, 0, 2)
         o = mla_prefill_attention(
             q[None], k[None], v[None], plen[None], pre_t=pre_t,
-            scale=softmax_scale(c))
+            scale=softmax_scale(c), lengths=rows[None])
         return o[0].transpose(1, 0, 2)                     # [S, h, v]
 
     with jax.named_scope("mla_prefill"):
-        return jax.lax.map(one, (q_nope, q_pe, keys, prefix_len))
+        return jax.lax.map(one, (q_nope, q_pe, keys, prefix_len, lengths))
 
 
 def _absorb_query(q_nope, q_pe, lp):
@@ -260,8 +261,8 @@ def _prefill(params, tokens, lengths, stats, c: ModelConfig, prefix=None):
             pre = pool[li][prefix_pages].transpose(0, 1, 3, 2).reshape(
                 n, pre_t, -1)
             keys = jnp.concatenate([pre.astype(lat.dtype), lat], axis=1)
-        o = _attend_up_projected(q_nope, q_pe, keys, prefix_len, lp, c,
-                                 pre_t)
+        o = _attend_up_projected(q_nope, q_pe, keys, prefix_len, lengths, lp,
+                                 c, pre_t)
         h = x + jnp.einsum("bsk,kd->bsd", o.reshape(n, s, -1), lp["wo"])
         x, stats = _mlp_block(h, lp, c, li, valid, stats)
     return (rmsnorm(x, params["final_norm"], c.norm_eps),

@@ -353,9 +353,10 @@ def _qkv(u, lp, c: ModelConfig, hold=lambda a: a):
             v.reshape(lead + (c.n_kv_heads, c.head_dim)))
 
 
-def _attention_prefill(u, lp, c: ModelConfig, prefix, layer: int):
-    """u [n, S, d] -> (out [n, S, d], this chunk's k, v [n, S, hkv, hd]);
-    keys = the cached prefix pages of pool layer `layer` | the chunk."""
+def _attention_prefill(u, lp, c: ModelConfig, prefix, layer: int, lengths):
+    """u [n, S, d], right-padded to `lengths` -> (out [n, S, d], this
+    chunk's k, v [n, S, hkv, hd]); keys = the cached prefix pages of pool
+    layer `layer` | the chunk."""
     n, s, _ = u.shape
     with jax.named_scope("attention"):
         q, k, v = _qkv(u, lp, c)
@@ -374,7 +375,8 @@ def _attention_prefill(u, lp, c: ModelConfig, prefix, layer: int):
             keys, values = behind(pool_k, keys), behind(pool_v, values)
         o = prefill_attention(
             q.transpose(0, 2, 1, 3), keys, values, prefix_len, pre_t=pre_t,
-            scale=c.head_dim ** -0.5, name="gqa_prefill_attention")
+            scale=c.head_dim ** -0.5, name="gqa_prefill_attention",
+            lengths=lengths)
         o = o.transpose(0, 2, 1, 3).reshape(n, s, -1).astype(u.dtype)
         return jnp.einsum("nsq,qd->nsd", o, lp["wo"]), k, v
 
@@ -456,7 +458,7 @@ def _prefill(params, tokens, lengths, stats, c: ModelConfig, prefix=None,
             states.append(state)
             windows.append(window)
         elif kind == "*":
-            out, k, v = _attention_prefill(u, lp, c, prefix, at)
+            out, k, v = _attention_prefill(u, lp, c, prefix, at, lengths)
             ks.append(k)
             vs.append(v)
         elif kind == "-":

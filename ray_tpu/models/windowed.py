@@ -179,13 +179,14 @@ def _prefill_name(kind: str, n: int, s: int, pre_t: int) -> str:
 
 
 def _attention_prefill(u, lp, c: ModelConfig, kind: str, turn, prefix,
-                       layer: int):
-    """u [n, S, d] -> (out [n, S, d], this chunk's rotated k and v [n, S,
-    hkv, hd]); keys = the cached prefix pages of layer `layer` of the
-    kind's pools | the chunk. prefix: None, or (pool_k, pool_v, pages
-    [n, Pp], prefix_len [n]): an "F" layer's pages lead (positions 0..),
-    a "W" layer's are the LAST Pp pages before the chunk, the table
-    right-aligned (scratch before a shorter prefix)."""
+                       layer: int, lengths):
+    """u [n, S, d], right-padded to `lengths` -> (out [n, S, d], this
+    chunk's rotated k and v [n, S, hkv, hd]); keys = the cached prefix
+    pages of layer `layer` of the kind's pools | the chunk. prefix: None,
+    or (pool_k, pool_v, pages [n, Pp], prefix_len [n]): an "F" layer's
+    pages lead (positions 0..), a "W" layer's are the LAST Pp pages before
+    the chunk, the table right-aligned (scratch before a shorter
+    prefix)."""
     n, s, _ = u.shape
     with jax.named_scope("attention"):
         q, k, v, gate = _qkvg(u, lp, c, kind)
@@ -208,7 +209,7 @@ def _attention_prefill(u, lp, c: ModelConfig, kind: str, turn, prefix,
         o = prefill_attention(
             q.transpose(0, 2, 1, 3), keys, values, prefix_len, pre_t=pre_t,
             scale=c.head_dim ** -0.5, name=_prefill_name(kind, n, s, pre_t),
-            window=c.window if kind == "W" else 0)
+            window=c.window if kind == "W" else 0, lengths=lengths)
         return _out(o.transpose(0, 2, 1, 3), gate, lp, u.dtype), k, v
 
 
@@ -279,7 +280,7 @@ def _prefill(params, tokens, lengths, stats, c: ModelConfig, prefix=None,
                        prefix_len)
         out, k, v = _attention_prefill(
             u, lp, c, kind, lambda t, kind=kind: _turn(t, rotary[kind]),
-            of_kind, at)
+            of_kind, at, lengths)
         if page and kind == "W":    # a layer at a time: never the stack
             k, v = pages_of(k), pages_of(v)
         kept[kind][0].append(k)

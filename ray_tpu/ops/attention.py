@@ -429,13 +429,17 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
 # ------------------------------------------- serving prefill (forward only)
 
 
-def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths
+def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths,
+                    real_ref,  # [n * heads] query blocks that hold a token
                     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     scale: float, bq: int, bk: int, nk: int, pre_t: int,
                     heads: int, window: int = 0):
     """Keys are [pre_t cached-prefix positions | the chunk]: prefix key j
     counts where j < plen of the request, chunk key c where c <= the query
-    row. Blocks with nothing to count are predicated out.
+    row. Blocks with nothing to count are predicated out, and so is every
+    key block of a query block that lies past its request's last real row:
+    nothing accumulates there, and what `_finalize` writes of it is zeros
+    (the index maps of `_prefill_flash` fetch nothing for such a cell).
 
     With a `window` a query sees only the last `window` keys, and the
     cached prefix is RIGHT-aligned (request i's last plen positions before
@@ -469,7 +473,7 @@ def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths
             jnp.maximum(k0, pre_t) - pre_t <= qi * bq + bq - 1)
         visit = has_prefix | has_chunk
 
-    @pl.when(visit)
+    @pl.when(visit & (qi < real_ref[pl.program_id(0)]))
     def _compute():
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
@@ -509,7 +513,48 @@ def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths
 
 def _window_first_block(qi, *, bq: int, bk: int, pre_t: int, window: int):
     """The first key block query block `qi` of a windowed call can see."""
-    return jnp.maximum(pre_t + qi * bq - window + 1, 0) // bk
+    return jax.lax.div(jnp.maximum(pre_t + qi * bq - window + 1, 0), bk)
+
+
+def _prefill_index_maps(*, h: int, group: int, bq: int, bk: int, nk: int,
+                        pre_t: int, window: int):
+    """(q's index map, K's and V's) over `_prefill_flash`'s grid (row b = a
+    request's head, query block i, key step j), beside the two prefetched
+    scalars. A cell that counts nothing fetches nothing: its index is that
+    of the block the cell before it left resident, or of the next one that
+    counts, so the pipeline issues no copy for it."""
+    def last(i):        # the key block that holds query block i's last row
+        return jax.lax.div(pre_t + i * bq + bq - 1, bk)
+
+    if window:
+        def kv_block(b, i, j, plen_ref):
+            return jnp.minimum(_window_first_block(
+                i, bq=bq, bk=bk, pre_t=pre_t, window=window) + j, last(i))
+    else:
+        def kv_block(b, i, j, plen_ref):
+            if pre_t >= bk:
+                # whole blocks of prefix: past those the request has, on
+                # to the chunk's first block
+                held = jax.lax.div(
+                    jnp.minimum(plen_ref[jax.lax.div(b, h)], pre_t) + bk - 1,
+                    bk)
+                j = jnp.where((j >= held) & (j < pre_t // bk), pre_t // bk, j)
+            return jnp.minimum(j, last(i))  # past the diagonal: its block
+
+    def q_block(b, i, real_ref):
+        # past the request's last real row: its last real block, resident
+        return jnp.minimum(i, jnp.maximum(real_ref[b] - 1, 0))
+
+    def q_map(b, i, j, plen_ref, real_ref):
+        return b, q_block(b, i, real_ref), 0
+
+    def kv_map(b, i, j, plen_ref, real_ref):
+        # ... and of that block's key blocks the last, resident too
+        j = jnp.where(i < real_ref[b], j, nk - 1)
+        return jax.lax.div(b, group), kv_block(b, q_block(b, i, real_ref), j,
+                                               plen_ref), 0
+
+    return q_map, kv_map
 
 
 # Query rows and key rows a grid cell: min(1024, S) and min(1024, keys).
@@ -536,10 +581,12 @@ _PREFILL_BK = 1024
 
 @functools.partial(jax.jit, static_argnames=("pre_t", "scale", "name", "bq",
                                              "bk", "window", "interpret"))
-def _prefill_flash(q, k, v, prefix_len, *, pre_t: int, scale: float,
-                   name: str, bq: int, bk: int, interpret: bool,
+def _prefill_flash(q, k, v, prefix_len, lengths=None, *, pre_t: int,
+                   scale: float, name: str, bq: int, bk: int, interpret: bool,
                    window: int = 0):
     n, h, s, dq = q.shape
+    if lengths is None:     # every row is real
+        lengths = jnp.full((n,), s, jnp.int32)
     hkv, t, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hkv    # query heads that read one K/V head: the index
     #                     maps hand a grid cell its head's K and V, so a
@@ -552,41 +599,32 @@ def _prefill_flash(q, k, v, prefix_len, *, pre_t: int, scale: float,
         k = jnp.pad(k, [(0, 0), (0, 0), (0, t_pad - t), (0, 0)])
         v = jnp.pad(v, [(0, 0), (0, 0), (0, t_pad - t), (0, 0)])
     nk = t_pad // bk
-
-    def kv_block(i, j):
-        return j
-
     if window:
-        first = functools.partial(_window_first_block, bq=bq, bk=bk,
-                                  pre_t=pre_t, window=window)
-
-        def last(i):    # the block that holds query block i's last row
-            return (pre_t + i * bq + bq - 1) // bk
-
         # key blocks the widest query block's bq + window - 1 indices
         # straddle
-        nk = max(last(i) - max(pre_t + i * bq - window + 1, 0) // bk + 1
+        nk = max((pre_t + i * bq + bq - 1) // bk
+                 - max(pre_t + i * bq - window + 1, 0) // bk + 1
                  for i in range(s_pad // bq))
-
-        def kv_block(i, j):
-            return jnp.minimum(first(i) + j, last(i))
-
+    # The query blocks of each grid row (a request's head) that hold a
+    # token, reckoned here once: the index maps run a grid step each, on
+    # the scalar core, and a division there is time the step waits for.
+    real = jnp.repeat(-(-lengths // bq), h).astype(jnp.int32)
+    q_map, kv_map = _prefill_index_maps(h=h, group=group, bq=bq, bk=bk,
+                                        nk=nk, pre_t=pre_t, window=window)
     kernel = functools.partial(_prefill_kernel, scale=scale, bq=bq, bk=bk,
                                nk=nk, pre_t=pre_t, heads=h, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(n * h, s_pad // bq, nk),
             in_specs=[
-                pl.BlockSpec((1, bq, dq), lambda b, i, j, pl_: (b, i, 0)),
-                pl.BlockSpec((1, bk, dq), lambda b, i, j, pl_: (
-                    b // group, kv_block(i, j), 0)),
-                pl.BlockSpec((1, bk, dv), lambda b, i, j, pl_: (
-                    b // group, kv_block(i, j), 0)),
+                pl.BlockSpec((1, bq, dq), q_map),
+                pl.BlockSpec((1, bk, dq), kv_map),
+                pl.BlockSpec((1, bk, dv), kv_map),
             ],
             out_specs=pl.BlockSpec((1, bq, dv),
-                                   lambda b, i, j, pl_: (b, i, 0)),
+                                   lambda b, i, j, *_: (b, i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),   # running max
                 pltpu.VMEM((bq, 128), jnp.float32),   # running sum
@@ -598,7 +636,7 @@ def _prefill_flash(q, k, v, prefix_len, *, pre_t: int, scale: float,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(prefix_len, q.reshape(n * h, s_pad, dq),
+    )(prefix_len, real, q.reshape(n * h, s_pad, dq),
       k.reshape(n * hkv, t_pad, dq), v.reshape(n * hkv, t_pad, dv))
     return out.reshape(n, h, s_pad, dv)[:, :, :s]
 
@@ -610,8 +648,17 @@ def window_block(window: int) -> int:
     return max(128, min(_PREFILL_BQ, 1 << (max(window, 1) - 1).bit_length()))
 
 
+def prefill_blocks(lengths, s: int, window: int = 0) -> tuple[int, int]:
+    """The query blocks a head of one `prefill_attention` call of
+    [len(lengths), s] rows has in its grid, and those of them that hold a
+    token, which are the ones run; `lengths` on the host (numpy)."""
+    bq = min(window_block(window) if window else _PREFILL_BQ, s)
+    return len(lengths) * -(-s // bq), int((-(-lengths // bq)).sum())
+
+
 def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
-                      name: str, impl: str = "auto", window: int = 0):
+                      name: str, impl: str = "auto", window: int = 0,
+                      lengths=None):
     """The serving prefill programs' attention, forward only, no score
     tensor in HBM. q [n, h, S, dq]; k [n, hkv, pre_t + S, dq], v [n, hkv,
     pre_t + S, dv], h a multiple of hkv: the first pre_t keys are a cached
@@ -627,6 +674,14 @@ def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
     a key's index is its position up to a constant. Key blocks wholly
     before a query block's window are not visited.
 
+    `lengths` [n]: the real rows of each right-padded request (None: every
+    row is real). A whole query block (`_PREFILL_BQ` rows, `window_block`
+    with a window) past a request's last real row is not run: the kernel
+    computes nothing and fetches nothing there and its rows come out as
+    zeros; a padded row of a block that holds a token comes out as
+    whatever it attends to, as every padded row does without `lengths` and
+    in the reference.
+
     `name` is the kernel's in a device trace. impl: "auto" (the kernel on
     the TPU, the jnp reference elsewhere), "pallas", "interpret" (the
     kernel's interpreter), "reference"."""
@@ -637,8 +692,8 @@ def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
                                            scale=scale, window=window)
     bq, bk = ((window_block(window),) * 2 if window
               else (_PREFILL_BQ, _PREFILL_BK))
-    return _prefill_flash(q, k, v, prefix_len, pre_t=pre_t, scale=scale,
-                          name=name, bq=bq, bk=bk, window=window,
+    return _prefill_flash(q, k, v, prefix_len, lengths, pre_t=pre_t,
+                          scale=scale, name=name, bq=bq, bk=bk, window=window,
                           interpret=(impl == "interpret"))
 
 

@@ -165,14 +165,14 @@ def paged_latent_decode_reference(q_lat, pool, lengths, page_tables, *,
 
 
 def mla_prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
-                          interpret: bool | None = None):
+                          interpret: bool | None = None, lengths=None):
     """ops/attention.prefill_attention at the up-projected form's widths:
     every head has a K and V of its own (q, k [n, h, ., 192], v [n, h, .,
     128] at DeepSeek-V2's). interpret=None: the kernel's interpreter off
-    the chip."""
+    the chip. `lengths` [n]: the real rows of each request, as there."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     return prefill_attention(
         q, k, v, prefix_len, pre_t=pre_t, scale=scale,
-        name="mla_prefill_attention",
+        name="mla_prefill_attention", lengths=lengths,
         impl="interpret" if interpret else "pallas")

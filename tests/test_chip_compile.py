@@ -628,3 +628,54 @@ def test_walked_prefill_reads_its_weights_where_they_lie(topo, monkeypatch,
     assert len(re.findall(r" conditional\(", text)) == 1
     assert len(re.findall(r" while\(", text)) == 4
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("program", ["prefill_batch",
+                                     "prefill_with_prefix_batch"])
+def test_laguna_prefill_programs_keep_the_parents_control_flow(
+        topo, monkeypatch, program):
+    """Two 2048-row prompts at the laguna_s_2_1 mixed-length cell's
+    geometry (3 of its layers: full attention over the dense MLP, two
+    window layers over its share of 32 experts): both attention kinds go
+    through the kernel, which takes the chunk's `lengths` as an operand
+    (PR 55) and skips the query blocks past a request's end inside its
+    grid. The program around it is the parent's: no `while` and no
+    `conditional` beyond those PR 53's text held (three loops an expert
+    layer), lowered or compiled; a walk over tiles or a branch on the
+    lengths (ROADMAP S13 (1)) would show here as it did in `setup_s`."""
+    from ray_tpu.models import configs, init_params, windowed
+    from ray_tpu.models.experts import stats_zero
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, s, pre_f, pre_w, n_pages, win_pages = 2, 2048, 16, 4, 2100, 145
+    one = SingleDeviceSharding(topo.devices[0])
+    c = configs.laguna_s_2_1(n_layers=3, attn_pattern="FWW", vocab=12544,
+                             moe_experts=32)
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    args = (on(jax.eval_shape(lambda: init_params(c, jax.random.PRNGKey(0)))),
+            sds((n, s)), sds((n,)))
+    if program == "prefill_with_prefix_batch":
+        args += (*on(windowed.page_pools(c, n_pages, PAGE)),
+                 *on(windowed.window_pools(c, win_pages, PAGE)),
+                 (sds((n, pre_f)), sds((n, pre_w))), sds((n,)))
+    lowered = jax.jit(partial(getattr(windowed, program), config=c,
+                              page=PAGE)).lower(
+        *args, on(jax.eval_shape(lambda: stats_zero(c))))
+    text = lowered.compile().as_text()
+    pre_t = PAGE * pre_w if program == "prefill_with_prefix_batch" else 0
+    assert text.count("tpu_custom_call") == 3
+    assert "gqa_prefill_attention" in text
+    assert f"swa_prefill_n{n}_s{s}_t{pre_t}" in text
+    source = lowered.as_text()
+    assert (source.count("stablehlo.while"), source.count("stablehlo.case"),
+            source.count("stablehlo.if")) == (6, 0, 0)
+    assert len(re.findall(r" while\(", text)) == 6
+    assert len(re.findall(r" conditional\(", text)) == 0
+

@@ -90,7 +90,8 @@ def test_absorbed_form_equals_up_projected_form():
                     c.rope_scaling)
     q_nope, q_pe, lat = ds._mla_project(x, lp, c, sin[None], cos[None])
     up = ds._attend_up_projected(q_nope, q_pe, lat,
-                                 jnp.zeros((2,), jnp.int32), lp, c, 0)
+                                 jnp.zeros((2,), jnp.int32),
+                                 jnp.full((2,), 24, jnp.int32), lp, c, 0)
     np.testing.assert_allclose(
         up, ds.attend_absorbed_dense(q_nope, q_pe, lat, lp, c), atol=TOL)
 

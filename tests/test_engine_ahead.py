@@ -324,7 +324,13 @@ def test_a_first_token_that_ends_its_request_is_returned():
 # was taken again at PR 46: the token's K and V go to the attention call
 # (off the chip its XLA insert) where 2 * slots * layers column updates
 # stood; its two prefill programs and the six programs of the other kinds
-# passed as they were.
+# passed as they were. The latent kind's two prefill programs were taken
+# again at PR 55: off the chip they run the prefill kernel's interpreter
+# (`mla_prefill_attention`), not the jnp reference, so their text holds the
+# kernel, which gained a second prefetched scalar (the query blocks that
+# hold a token, from `lengths`) and its clamped index maps; the per-head
+# and hybrid kinds take the reference, which reads no `lengths`, and no
+# decode program moved.
 PROGRAMS = {
     "per_head": {
         "decode_paged":
@@ -338,9 +344,9 @@ PROGRAMS = {
         "decode_paged":
             "979f1dae2be5a2245df52197728d3a37a961658f4f1a2892746b7885cd4bf4a4",
         "prefill_batch":
-            "e3a3298cb174d097bf93da4de2632023ed9497544b436f686f87565de692b430",
+            "fcc1e0c06700a4bd81fc23078a6d6628e58f51a1c14e8f2bd02306f8e145170f",
         "prefill_with_prefix_batch":
-            "1b8927245278c049f665d7f51944ad00c33722acf5650cec15fb8eb8602e3950",
+            "26ca89a5ce1a3357c98fa695b74b140af8f5d1d4ce3ec7f6f2f3c979957b2433",
     },
     "hybrid": {
         "decode_paged":

@@ -194,8 +194,10 @@ def test_kv_stats_loses_the_two_speculation_counters_alone():
     st = _engine("tiny").kv_stats()
     assert PARENT_KV_STATS - set(st) == {"spec_drafted", "spec_accepted"}
     # ... and since PR 51 counts its admissions three ways
+    # ... and since PR 55 its prefill kernels' query blocks two ways
     assert set(st) - PARENT_KV_STATS == {
-        "admissions", "admissions_unfenced", "admissions_under_flight"}
+        "admissions", "admissions_unfenced", "admissions_under_flight",
+        "prefill_attn_blocks", "prefill_attn_blocks_run"}
     # what perfbench/harness/serve_cell._engine_counters reads
     assert st["preemptions"] == 0 and st["prefix_hits"] == 0
 

@@ -172,3 +172,40 @@ def test_kv_stats_counts_the_rows_bucketed_and_the_rows_run():
     assert run < bucketed
     assert engine._rows_run(np.asarray([300, 100, S, 0]), S) == tiles(
         300, 100, S)
+
+
+def test_kv_stats_counts_the_attention_blocks_and_those_run(monkeypatch):
+    """The same script for the attention kernel's query blocks, which are
+    `ops/attention._PREFILL_BQ` rows (here a tile's 256, so that a bucket
+    holds several): every layer's grid has bucket / 256 a request, and runs
+    those that hold a token."""
+    from ray_tpu.ops import attention
+    monkeypatch.setattr(attention, "_PREFILL_BQ", T)
+    c = MODELS["dense"]
+    eng = InferenceEngine(c, EngineConfig(
+        max_slots=4, max_len=3 * S, page_size=PAGE, eos_token=-1,
+        prompt_buckets=(T // 4, S, 2 * S)), params=_params("dense"))
+    rng = np.random.RandomState(1)
+    blocks = run = 0
+    # (prompts admitted in one step, their batch's [n, bucket], the blocks
+    # of it that hold a token)
+    for ns, n, bucket, real in [
+            ((40,), 1, T // 4, 1),          # one short block
+            ((200,), 1, S, 1), ((T,), 1, S, 1), ((T + 1,), 1, S, 2),
+            ((S + 3,), 1, 2 * S, 3), ((2 * S,), 1, 2 * S, 4),
+            # three of one bucket: a batch of four, the fourth of no token
+            ((300, 100, S), 4, S, 2 + 1 + 2)]:
+        for m in ns:
+            eng.add_request([int(t) for t in rng.randint(0, 256, m)], 2, 0.0)
+        while eng.has_work():
+            eng.step()
+        blocks += c.n_layers * n * -(-bucket // T)
+        run += c.n_layers * real
+        stats = eng.kv_stats()
+        assert (stats["prefill_attn_blocks"],
+                stats["prefill_attn_blocks_run"]) == (blocks, run)
+    assert run < blocks
+    assert attention.prefill_blocks(np.asarray([300, 100, S, 0]), S) == (8, 5)
+    # a window layer's blocks are its window's, within [128, 1024]
+    assert attention.prefill_blocks(np.asarray([300, 100, S, 0]), S,
+                                    window=100) == (16, 3 + 1 + 4)

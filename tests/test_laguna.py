@@ -21,9 +21,11 @@ from ray_tpu.llm.engine import PrefillEngine
 from ray_tpu.models import configs, experts, forward, init_params, windowed
 from ray_tpu.ops import layers
 from ray_tpu.ops.attention import (prefill_attention,
-                                   prefill_attention_reference)
+                                   prefill_attention_reference, window_block)
 from ray_tpu.ops.paged_attention import (paged_decode_attention,
                                          paged_decode_attention_reference)
+
+from tests.test_ops import poison_past_lengths
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-5      # float32 on both sides; sums in another order
@@ -187,13 +189,22 @@ def test_published_sizes():
 
 
 @pytest.mark.parametrize("h,hkv", [(9, 1), (6, 1), (18, 2)])
-@pytest.mark.parametrize("s,pre_t,window,plens", [
-    (256, 0, 100, (0, 0)),          # no prefix; window under a block
-    (384, 128, 130, (128, 40)),     # a right-aligned prefix, one shorter
-    (200, 256, 512, (256, 0)),      # rows padded; window over the chunk
+@pytest.mark.parametrize("s,pre_t,window,plens,lengths,poison", [
+    (256, 0, 100, (0, 0), None, False),     # no prefix; window under a block
+    (384, 128, 130, (128, 40), None, False),    # a right-aligned prefix,
+    #                                             one shorter
+    (200, 256, 512, (256, 0), None, False),     # rows padded; window over
+    #                                             the chunk
+    # `lengths`: a query block (`window_block` rows: 128, 256, 128, 128)
+    # past a request's last real row is not run
+    (512, 0, 100, (0, 0), (128, 0), False),     # a block's edge; no rows
+    (768, 128, 130, (128, 40), (257, 255), False),  # past an edge, before
+    (512, 256, 100, (256, 100), (129, 300), False),  # ragged over a prefix
+    (384, 128, 100, (128, 40), (200, 60), True),    # NaN past them
 ])
 def test_window_prefill_kernel_is_the_masked_reference(h, hkv, s, pre_t,
-                                                       window, plens):
+                                                       window, plens,
+                                                       lengths, poison):
     rng = np.random.RandomState(s + h)
     q = jnp.asarray(rng.randn(2, h, s, 32), jnp.float32)
     k = jnp.asarray(rng.randn(2, hkv, pre_t + s, 32), jnp.float32)
@@ -204,6 +215,19 @@ def test_window_prefill_kernel_is_the_masked_reference(h, hkv, s, pre_t,
     got = prefill_attention(q, k, v, plen, name="swa_prefill_test",
                             impl="interpret", **kw)
     np.testing.assert_allclose(got, want, atol=2e-5)
+    if lengths is not None:
+        blk = window_block(window)
+        ends = [-(-m // blk) * blk for m in lengths]
+        ragged = prefill_attention(
+            *(poison_past_lengths(q, k, v, pre_t, lengths, ends) if poison
+              else (q, k, v)), plen, name="swa_prefill_test",
+            impl="interpret", lengths=jnp.asarray(lengths, jnp.int32), **kw)
+        for i, (m, end) in enumerate(zip(lengths, ends)):
+            # real rows: the very cells that ran without `lengths`; the
+            # rows of a block that holds none: zeros
+            np.testing.assert_array_equal(ragged[i, :, :m], got[i, :, :m])
+            assert not np.asarray(ragged[i, :, end:]).any()
+        assert any(end < s for end in ends)
     # the oracle itself, by hand: row 150 of request 1 sees `window` keys
     # ending at itself and none of the prefix padding
     first = max(pre_t + 150 - window + 1, pre_t - plens[1])
@@ -211,6 +235,39 @@ def test_window_prefill_kernel_is_the_masked_reference(h, hkv, s, pre_t,
     sc = (q[1, 0, 150] @ k[1, 0, keys].T) * 32 ** -0.5
     np.testing.assert_allclose(want[1, 0, 150],
                                jax.nn.softmax(sc) @ v[1, 0, keys], atol=2e-5)
+
+
+def test_kv_stats_counts_the_attention_blocks_and_those_that_hold_a_token():
+    """A scripted run, every dispatch known. SHARE's layers are "FWWWF": a
+    window layer's query blocks are `window_block(20)` = 128 rows, a full
+    layer's the whole 256-row bucket (`_PREFILL_BQ` is 1024)."""
+    eng = _engine(max_slots=4, max_len=320, prompt_buckets=(32, 256))
+    blocks = run = 0
+
+    def prompts(*ns):
+        for n in ns:
+            eng.add_request(_ids(n, n), 2, 0.0)
+        _run(eng)
+
+    # (prompts of one step, the requests of its dispatch's batch, a full
+    # layer's blocks that hold a token, a window layer's)
+    for ns, batch, full, window in [
+            ((20,), 1, 1, 1),           # the 32-row bucket: one block each
+            ((100,), 1, 1, 1),          # 256 rows: the second 128 hold none
+            ((128,), 1, 1, 1), ((129,), 1, 1, 2), ((256,), 1, 1, 2),
+            # three in one step: a batch of four, the fourth of no token
+            ((100, 200, 50), 4, 3, 4)]:
+        prompts(*ns)
+        wide = 2 if max(ns) > 32 else 1     # window blocks a request
+        blocks += batch * (2 * 1 + 3 * wide)
+        run += 2 * full + 3 * window
+        st = eng.kv_stats()
+        assert (st["prefill_attn_blocks"],
+                st["prefill_attn_blocks_run"]) == (blocks, run)
+    assert run < blocks
+    # `lengths` is an operand: a program a batch's shape, as at the parent
+    assert sorted(eng._prefill_batches) == [(1, 32), (1, 256), (4, 256)]
+    assert not eng._prefill_pre
 
 
 @pytest.mark.parametrize("group", [9, 6])
