@@ -111,9 +111,12 @@ def _paged(kind):
     return build
 
 
-def _latent(kind):
+def _latent(kind, pool_dtype=jnp.bfloat16, pages_per_seq=68):
     """DeepSeek-V2's widths: 128 heads over a 512 + 64 latent, a 192-wide
-    q.k and a 128-wide p.v; the longdoc cell's pool and chunk."""
+    q.k and a 128-wide p.v; the longdoc cell's pool and chunk. The decode
+    kernel's block of pages follows its shapes (`_block_pages`): 68 pages
+    a slot are eight blocks of 8 and a ragged ninth; a float32 pool halves
+    the block under the same bytes; a table of 4 pages holds a block of 4."""
     from ray_tpu.ops import latent_attention as la
 
     def build(topo):
@@ -126,8 +129,9 @@ def _latent(kind):
             return (lambda q, pool, ln, tb: la.paged_latent_decode_attention(
                 q, pool, ln, tb, layer=3, rank=512, scale=0.1,
                 interpret=False)), (
-                sds((8, 128, 576)), sds((8, 704, 576, 128)),
-                sds((8,), jnp.int32), sds((8, 68), jnp.int32))
+                sds((8, 128, 576), pool_dtype),
+                sds((8, 704, 576, 128), pool_dtype),
+                sds((8,), jnp.int32), sds((8, pages_per_seq), jnp.int32))
         return (lambda q, k, v, pl: la.mla_prefill_attention(
             q, k, v, pl, pre_t=4096, scale=0.1, interpret=False)), (
             sds((1, 128, 4096, 192)), sds((1, 128, 8192, 192)),
@@ -258,6 +262,8 @@ CASES = {
     "paged_decode": _paged("decode"),
     "paged_decode_insert": _paged("decode_insert"),
     "latent_decode": _latent("decode"),
+    "latent_decode_float32_pool": _latent("decode", pool_dtype=jnp.float32),
+    "latent_decode_table_under_a_block": _latent("decode", pages_per_seq=4),
     "mla_prefill_over_prefix": _latent("prefill"),
     "gqa_prefill_qwen2_7b_1x1024": _gqa_prefill(28, 4, 1, 1024, 0),
     "gqa_prefill_qwen2_7b_8x1024_over_prefix": _gqa_prefill(28, 4, 8, 1024,

@@ -176,17 +176,71 @@ def test_yarn_frequencies_and_scale_by_hand():
     np.testing.assert_allclose(ds.softmax_scale(plain), 192 ** -0.5)
 
 
-def test_latent_decode_kernel_matches_jnp():
-    L, N, W, page, B, h, rank = 2, 9, 24, 16, 3, 4, 16
-    pool = jax.random.normal(jax.random.PRNGKey(0), (L, N, W, page))
-    q = jax.random.normal(jax.random.PRNGKey(1), (B, h, W))
-    lengths = jnp.array([5, 33, 64], jnp.int32)
-    tables = jnp.array([[1, 0, 0, 0], [2, 3, 4, 0], [5, 6, 7, 8]], jnp.int32)
+# One turn of the decode kernel attends a block of G pages; G = 4 here
+# (`_BLOCK_BYTES` set to two blocks of four toy pages), page = 16.
+_BLOCK_CASES = {
+    # pages a slot: 1, 3, 4 = G, whose end is a page's and the block's
+    "below_and_equal_to_a_block": ([5, 33, 64], 4),
+    # 5 = G + 1 (a last block of one page), 5 ending on a page, 4 + a row
+    "a_block_and_a_page": ([65, 80, 70], 5),
+    # 10, 10, 8 pages: three turns with a ragged last one, an end on a
+    # page inside a block, an end on a block's edge
+    "several_blocks_a_ragged_last": ([147, 160, 128], 11),
+    "an_empty_slot_between_two_live": ([70, 0, 100], 7),
+    "empty_slots_first_and_last": ([0, 90, 0], 7),
+    "every_slot_empty": ([0, 0, 0], 4),
+    # the table names fewer pages than a block holds: G = 2
+    "a_table_narrower_than_a_block": ([20, 32, 1], 2),
+    # a length past the table's end attends what the table names
+    "a_length_past_the_table": ([200, 17, 96], 6),
+}
+
+
+@pytest.mark.parametrize("case", list(_BLOCK_CASES))
+def test_latent_decode_kernel_matches_jnp(case, monkeypatch):
+    """Every page no slot holds (the scratch page 0 and three more) and
+    every page of the other layer is NaN in the kernel's pool: a page
+    fetched and not masked, or not fetched and attended, makes the output
+    NaN (0 * NaN in p . c_kv), whatever the mask did to its score."""
+    lens, P = _BLOCK_CASES[case]
+    L, W, page, h, rank = 2, 24, 16, 4, 16
+    monkeypatch.setattr(la, "_BLOCK_BYTES", 2 * 4 * W * page * 4)
+    assert la._block_pages(W, page, 4, P) == min(4, P)
+    held = [min(-(-n // page), P) for n in lens]
+    N = 1 + sum(held) + 3
+    ids = np.random.RandomState(3).permutation(np.arange(1, N))
+    tables = np.zeros((len(lens), P), np.int32)
+    for b, n in enumerate(held):
+        tables[b, :n], ids = ids[:n], ids[n:]
+    unheld = jnp.asarray(np.concatenate([[0], ids]))
+    clean = jax.random.normal(jax.random.PRNGKey(0), (L, N, W, page))
+    clean = clean.at[:, unheld].set(0.0)
+    pool = clean.at[:, unheld].set(jnp.nan).at[0].set(jnp.nan)  # layer 1 runs
+    q = jax.random.normal(jax.random.PRNGKey(1), (len(lens), h, W))
+    lengths, tables = jnp.array(lens, jnp.int32), jnp.asarray(tables)
     kw = dict(layer=1, rank=rank, scale=0.3)
-    np.testing.assert_allclose(
-        la.paged_latent_decode_attention(q, pool, lengths, tables, **kw),
-        la.paged_latent_decode_reference(q, pool, lengths, tables, **kw),
-        atol=TOL)
+    got = np.asarray(
+        la.paged_latent_decode_attention(q, pool, lengths, tables, **kw))
+    want = np.asarray(
+        la.paged_latent_decode_reference(q, clean, lengths, tables, **kw))
+    live = np.array(lens) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=TOL)
+    assert not got[~live].any()             # an empty slot reads zeros
+
+
+@pytest.mark.parametrize("width,page,itemsize,pages_per_seq,want", [
+    (576, 128, 2, 68, 8),     # the longdoc cell: DeepSeek-V2 in bfloat16
+    (576, 128, 4, 68, 4),     # a float32 pool: half the pages, same bytes
+    (576, 128, 2, 4, 4),      # a page bucket narrower than a block
+    (576, 128, 2, 1, 1),
+    (24, 16, 4, 11, 11),      # toy pages: the table is the bound
+    (4096, 512, 2, 68, 1),    # a page over the budget still goes alone
+])
+def test_the_decode_block_follows_the_shapes(width, page, itemsize,
+                                             pages_per_seq, want):
+    """G is a function of what the kernel is handed, under one budget."""
+    assert la._block_pages(width, page, itemsize, pages_per_seq) == want
+    assert 2 * want * width * page * itemsize <= la._BLOCK_BYTES or want == 1
 
 
 @pytest.mark.parametrize("n,s,pre_t,plen", [

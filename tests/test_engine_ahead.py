@@ -330,7 +330,11 @@ def test_a_first_token_that_ends_its_request_is_returned():
 # kernel, which gained a second prefetched scalar (the query blocks that
 # hold a token, from `lengths`) and its clamped index maps; the per-head
 # and hybrid kinds take the reference, which reads no `lengths`, and no
-# decode program moved.
+# decode program moved. The latent kind's `decode_paged` was taken again at
+# PR 57: off the chip it holds the latent decode kernel's interpreter
+# (`paged_latent_decode`), whose loop now attends a block of pages a turn;
+# its two prefill programs and every program of the other kinds passed as
+# they were.
 PROGRAMS = {
     "per_head": {
         "decode_paged":
@@ -342,7 +346,7 @@ PROGRAMS = {
     },
     "latent": {
         "decode_paged":
-            "979f1dae2be5a2245df52197728d3a37a961658f4f1a2892746b7885cd4bf4a4",
+            "326baf3d544d1d6c2fd46d9b61d6af78249b7198cafc39a6f68a04a16b6b1aad",
         "prefill_batch":
             "fcc1e0c06700a4bd81fc23078a6d6628e58f51a1c14e8f2bd02306f8e145170f",
         "prefill_with_prefix_batch":
