@@ -1585,7 +1585,6 @@ class InferenceEngine:
         t_ns = time.perf_counter_ns()
         admitted: dict[int, int] = {}
         pending: list[tuple] = []  # (slot, req, last-logits row) to sample
-        rows_run_before = self.prefill_rows_run
         e = self.e
         page = e.page_size
         # The slots free on the host's view, a decode step in flight or
@@ -1747,7 +1746,14 @@ class InferenceEngine:
                         srcs[j] = p["src_row"]
             if self.win_span:
                 tabs, pres = (tabs, win_tabs), (pres, win_pres)
-            with diagnostics.span("ray_tpu.engine.admit.prefill"):
+            with diagnostics.span("ray_tpu.engine.admit.prefill") as gsp:
+                if gsp.on:
+                    # what the dispatch works on: the new tokens and the
+                    # cached-prefix tokens of each real request (the
+                    # batch's padding is none) in a bucket of S rows
+                    gsp.set(bucket=bucket,
+                            tokens=tuple(p["ns"] for p in group),
+                            prefix=tuple(p["hit"] * page for p in group))
                 self._prefill_group(group, logits_of, toks, lens, tabs,
                                     pres, plens, srcs, dsts)
 
@@ -1834,8 +1840,7 @@ class InferenceEngine:
             self.admissions_under_flight += under_flight
         if sp.on:
             sp.set(rows=sum(p["bucket"] for p in planned),
-                   rows_run=self.prefill_rows_run - rows_run_before,
-                   fenced=int(fenced), under_flight=int(under_flight))
+                   fenced=int(fenced))
         return admitted
 
     def _first_token_now(self, slot: int, req: Request) -> bool:
@@ -2305,7 +2310,12 @@ class InferenceEngine:
             if ft is not None for _s, r in ft.owners}
         self.decode_steps += 1
         with diagnostics.span("ray_tpu.engine.decode", step=self.decode_steps,
-                              ahead=int(prev is not None)):
+                              ahead=int(prev is not None)) as sp:
+            if sp.on:
+                # the work: the `lengths` operand of the active slots, in
+                # slot order (the kernels attend one key more a slot, the
+                # token this step writes)
+                sp.set(lengths=tuple(lengths[active].tolist()))
             tables = self._build_tables(active)
             p_bucket = tables.shape[1]
             if self.win_span:

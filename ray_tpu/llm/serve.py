@@ -106,6 +106,8 @@ class _LLMServerImpl:
 
     def _loop(self):
         idle = None   # the `ray_tpu.pump.idle` span of a quiet stretch
+        streamed: set[int] = set()   # while spans are recorded: the live
+        #                              requests a turn has handed a token of
         while not self._stop:
             if not self.engine.has_work():
                 if idle is None:
@@ -128,7 +130,16 @@ class _LLMServerImpl:
                 time.sleep(0.1)
                 continue
             done = []
-            with self._lock, diagnostics.span("ray_tpu.pump.fanout"):
+            with self._lock, diagnostics.span("ray_tpu.pump.fanout") as sp:
+                if sp.on:
+                    # what the turn delivers: the tokens step() returned
+                    # (one a request), and those of them that are the first
+                    # their request's subscriber sees (one that was
+                    # streaming when recording began counts once more)
+                    firsts = (emitted or {}).keys() - streamed
+                    sp.set(tokens=len(emitted or ()), firsts=len(firsts))
+                    streamed |= firsts
+                    streamed -= streamed & self.engine.finished.keys()
                 # Per-token fanout to streaming subscribers.
                 for rid, tok in (emitted or {}).items():
                     sub = self._token_subs.get(rid)

@@ -188,7 +188,8 @@ def test_a_request_of_one_token_or_at_max_len_or_guided_stays_fenced(kind):
                     if r.name == "ray_tpu.engine.admit"]
     finally:
         diagnostics.spans_off()
-    assert admit.attrs["fenced"] == 1 and admit.attrs["under_flight"] == 0
+    assert admit.attrs["fenced"] == 1      # nothing in the air: the
+    #                                        counter below says so
     assert len(req.generated) == 6
     assert _stats(eng, *ADMISSIONS) == (4, 1, 0)    # the first chunk's alone
 
@@ -283,8 +284,9 @@ def test_the_counters_and_the_spans_say_how_an_admission_went():
     n, unfenced, under = np.subtract(_stats(eng, *ADMISSIONS), base)
     assert dropped == 0 and len(admits) == n == 5   # the last in two chunks
     assert sum(1 - r.attrs["fenced"] for r in admits) == unfenced == 4
-    assert sum(r.attrs["under_flight"] for r in admits) == under == 4
-    assert [r.attrs["under_flight"] for r in admits][0] == 0
+    # all but the first, which found nothing in the air (`kv_stats()`
+    # counts it; the span dropped `under_flight` with PR 58)
+    assert under == 4
     # the decode step after an admission under a step in flight led it
     st = eng.kv_stats()
     assert st["decode_steps_ahead"] >= st["decode_steps"] - 3

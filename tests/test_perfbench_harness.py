@@ -1,7 +1,7 @@
 """Tier-1's hold on the code that decides a cell's `correct`: the
 benchmark's look-ups (perfbench/tests/test_lookups.py's cases, collected
 here because tier-1 collects `tests/` alone; perfbench/tests/
-test_program_spans.py's beside them), the `deepseek_v2-serve-longdoc`
+test_program_spans.py's and test_ring_steps.py's beside them), the `deepseek_v2-serve-longdoc`
 and `nemotron3_nano_30b-serve-reasoning` cells end to end at their rehearsal
 sizes, their references handed a fault, and the latent kernel's and the
 state update kernel's cost functions against counts made by hand."""
@@ -16,6 +16,7 @@ import pytest
 
 from perfbench.tests.test_lookups import *  # noqa: F401,F403 — its cases
 from perfbench.tests.test_program_spans import *  # noqa: F401,F403 — too
+from perfbench.tests.test_ring_steps import *  # noqa: F401,F403 — too
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "deepseek_v2-serve-longdoc"
