@@ -368,27 +368,130 @@ def exit_step(params, c: ModelConfig, t, rows, state) -> tuple:
                 still * (1.0 - lam), cdf, chosen | now)
 
 
+def _chip_index(mesh, axes):
+    """Inside a shard_map: this chip's row-major index over `axes`."""
+    index = 0
+    for a in axes:
+        index = index * mesh.shape[a] + jax.lax.axis_index(a)
+    return index
+
+
+def _embed(table, tokens, mesh):
+    """tokens [b, s] -> their rows of `table`, [b, s, d] in the activation
+    layout, under a mesh of several devices.
+
+    The declared table is [vocab, embed] -> P("tp", "fsdp") and the batch is
+    sharded over fsdp too, so whatever is left to GSPMD moves the matrix.
+    `_head_shard_axes` decides on the table's transposed shape (the table
+    IS the head's transpose, declared so): where one axis of several chips
+    shards both and the sizes divide, `_embed_rows` moves the tokens and
+    the activations and leaves the table and its gradient where they lie.
+    Otherwise (no such axis: `forward()` under a tp mesh; sizes that do
+    not divide; a sequence axis of several chips, which the lookup's
+    layout does not know) the FALLBACK is the one-hot product (the
+    iota-embed trick): the SPMD partitioner handles a [b,s,v] x [v,d]
+    contraction over the tp-sharded vocab axis cleanly (masked matmul +
+    psum), where the equivalent gather forced "Involuntary full
+    rematerialization" (spmd_partitioner.cc:652) of the embedding
+    activation in fwd AND bwd. It gathers the whole table over fsdp and
+    all-reduces its whole gradient; the explicit constraint pins the result
+    to the activation layout (batch over the data axes, embed replicated)
+    so the bwd table grad partitions as a plain matmul too."""
+    from ray_tpu.parallel.sharding import (ShardingRules,
+                                           activation_batch_sharded)
+    seq_axis = ShardingRules.default().rules["seq"]
+    axes = None
+    if mesh.shape.get(seq_axis, 1) == 1:
+        axes = _head_shard_axes(mesh, table.shape[::-1], tokens.shape[0])
+    with jax.named_scope("embed_lookup"):
+        if axes is not None:
+            return _embed_rows(table, tokens, mesh, axes)
+        onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=table.dtype)
+        x = jnp.einsum("bsv,vd->bsd", onehot, table)
+        return activation_batch_sharded(x, mesh)
+
+
+def _embed_rows(table, tokens, mesh, axes):
+    """The lookup where the table's slice lies: [b, s, d], bit for bit the
+    rows `jnp.take(table, tokens, axis=0)` reads.
+
+    Inside a shard_map a chip holds the DECLARED [V/tp, d/g] slice (`g` the
+    axis that shards the model dimension and the batch, fsdp) and its
+    [b/(dp*g), s] tokens. Forward: the group's token ids are gathered over
+    `g` (bytes), the chip reads ITS d/g columns of every token of the group
+    from its own slice (under tp an id outside the chip's vocabulary range
+    reads zero and one psum over tp adds the ranges up), and ONE all-to-all
+    over `g` (split the batch, concatenate the model dimension) gives the
+    batch layout. Backward, the transpose: the cotangent goes back through
+    one all-to-all to [b*g, s, d/g] and the chip scatter-adds every token
+    of its group into its own slice of the table's gradient: a COMPLETE
+    slice, no reduction over `g` at all, nothing over tp (a chip owns its
+    vocabulary range), one psum over dp where the table is replicated. The
+    sum is accumulated in fp32 and rounded once, as the matrix unit
+    accumulated the transposed one-hot product: a batch repeats tokens."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel.sharding import data_axes, shard_map_compat
+    g, v_axes, dp_axes = axes
+    tp_axes = tuple(a for a in v_axes if a != g)    # vocabulary only
+    batch_axes = data_axes(mesh)
+    x_spec, t_spec = P(batch_axes, None, None), P(batch_axes, None)
+    table_spec = P(tp_axes or None, g)              # the declared layout
+    n_g = mesh.shape[g]
+    rows = table.shape[0] // math.prod(mesh.shape[a] for a in tp_axes)
+    cols = table.shape[1] // n_g
+
+    def local_ids(tokens):
+        """The group's ids as rows of this chip's slice; `rows` (past the
+        slice's end) where another chip's vocabulary range holds the id."""
+        ids = jax.lax.all_gather(tokens, g, axis=0, tiled=True)
+        ids = ids - _chip_index(mesh, tp_axes) * rows
+        return jnp.where((ids >= 0) & (ids < rows), ids, rows)
+
+    def fwd_body(table, tokens):
+        x = jnp.take(table, local_ids(tokens), axis=0, mode="fill",
+                     fill_value=0)
+        if tp_axes:
+            x = jax.lax.psum(x, tp_axes)
+        # [chip of the group, b, s, d/g] -> [b, s, slice of d, d/g]
+        x = jax.lax.all_to_all(x.reshape(n_g, -1, *x.shape[1:]), g, 0, 2)
+        return x.reshape(*x.shape[:2], -1)
+
+    def bwd_body(tokens, ct):
+        # the forward's exchange back; written as ONE tiled all-to-all that
+        # splits the minor dimension it costs the chip's compiler 5 s
+        ct = jax.lax.all_to_all(ct.reshape(*ct.shape[:2], n_g, cols), g, 2, 0)
+        dtable = jnp.zeros((rows, cols), jnp.float32).at[
+            local_ids(tokens)].add(
+                ct.reshape(-1, *ct.shape[2:]).astype(jnp.float32),
+                mode="drop")
+        if dp_axes:         # the table is replicated over them
+            dtable = jax.lax.psum(dtable, dp_axes)
+        return dtable.astype(table.dtype)
+
+    @jax.custom_vjp
+    def lookup(table, tokens):
+        return shard_map_compat(fwd_body, mesh, (table_spec, t_spec),
+                                x_spec)(table, tokens)
+
+    def fwd(table, tokens):
+        return lookup(table, tokens), tokens
+
+    def bwd(tokens, ct):
+        return shard_map_compat(bwd_body, mesh, (t_spec, x_spec),
+                                table_spec)(tokens, ct), None
+
+    lookup.defvjp(fwd, bwd)
+    return lookup(table, tokens)
+
+
 def hidden_states(params, tokens, config: ModelConfig, mesh=None):
     """tokens [batch, seq] -> final-norm hidden states [batch, seq, d]: of
     a looped stack (`loops` > 1) the closed state of each position's exit
     pass."""
     c = config
     if mesh is not None and mesh.devices.size > 1:
-        # One-hot matmul lookup instead of gather (the iota-embed trick):
-        # the SPMD partitioner handles a [b,s,v] x [v,d] contraction over
-        # the tp-sharded vocab axis cleanly (masked matmul + psum), where
-        # the equivalent gather forced "Involuntary full rematerialization"
-        # (spmd_partitioner.cc:652) of the embedding activation in fwd AND
-        # bwd — the table's embed axis is fsdp-sharded on a transposed
-        # device order the partitioner cannot leave cheaply. The explicit
-        # constraint pins the result to the activation layout (batch over
-        # the data axes, embed replicated) so the bwd table grad
-        # partitions as a plain matmul too.
-        from ray_tpu.parallel.sharding import activation_batch_sharded
-        table = params["embed"]
-        onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=table.dtype)
-        x = jnp.einsum("bsv,vd->bsd", onehot, table)
-        x = activation_batch_sharded(x, mesh)
+        x = _embed(params["embed"], tokens, mesh)
     else:
         x = jnp.take(params["embed"], tokens, axis=0)
     positions = jnp.arange(tokens.shape[1])
@@ -464,7 +567,9 @@ LOSS_CHUNK_MIN_BYTES = 1 << 30
 
 def _head_shard_axes(mesh, head_shape, batch: int):
     """(gather axis, vocabulary axes, batch-only axes) when the chunked
-    head can run vocabulary-parallel on this mesh, else None.
+    head can run vocabulary-parallel on this mesh, and the lookup in the
+    table's slices (`_embed`, with the table's transposed shape), else
+    None.
 
     Read off the declared table (parallel/sharding.py): the head is
     [embed, vocab] -> P("fsdp", "tp"), the batch P(("dp", "fsdp")). The
@@ -539,10 +644,7 @@ def _xent_vocab_parallel(x, head, targets, mesh, axes, chunk: int):
         exact row log-sum-exp, and where each target falls in the slice."""
         logits = jnp.einsum("bsd,dv->bsv", xa, w,
                             preferred_element_type=jnp.float32)
-        lo = 0
-        for a in v_axes:
-            lo = lo * mesh.shape[a] + jax.lax.axis_index(a)
-        col = ta - lo * w.shape[1]
+        col = ta - _chip_index(mesh, v_axes) * w.shape[1]
         m = jax.lax.pmax(jnp.max(logits, axis=-1), v_axes)
         se = jax.lax.psum(
             jnp.sum(jnp.exp(logits - m[..., None]), axis=-1), v_axes)
@@ -630,18 +732,23 @@ def loss_fn(params, batch, config: ModelConfig, mesh=None,
     the head+softmax runs in rematerialized sequence chunks: peak logits
     memory is b*loss_chunk*vocab and the backward recomputes each chunk.
 
-    Where the head's layout is decided: the DECLARED layout is
-    parallel/sharding.py's table (`lm_head` [embed, vocab] -> P("fsdp",
-    "tp"), a tied table its transpose), and the optimizer state, the
-    checkpoints and the serving engine all read that one table. Under an
-    fsdp axis of more than one chip the chunked head does not leave the
-    product to GSPMD, which can only serve a batch and a model dimension
-    sharded over the same axis by gathering the whole matrix in every
-    chunk: `_xent_vocab_parallel` turns the chip's slice into a
-    vocabulary slice once a step and moves the tokens instead. `mesh=None`,
-    one device, an fsdp axis of one, sizes that do not divide (then the
-    head FALLS BACK to the GSPMD product; nothing is padded) and a logits
-    tensor under LOSS_CHUNK_MIN_BYTES keep the plain `_xent` program.
+    Where the layout of the two vocabulary-wide matrices is decided: the
+    DECLARED layout is parallel/sharding.py's table (`lm_head` [embed,
+    vocab] -> P("fsdp", "tp"), `embed` and a tied table its transpose),
+    and the optimizer state, the checkpoints and the serving engine all
+    read that one table. Under an fsdp axis of more than one chip neither
+    product is left to GSPMD, which can only serve a batch and a model
+    dimension sharded over the same axis by gathering the whole matrix
+    (the head's in every chunk) and reducing its whole gradient: the
+    lookup reads the tokens of the group from the chip's own slice and
+    exchanges activations (`hidden_states` -> `_embed_rows`), and the
+    chunked head turns the chip's slice into a vocabulary slice once a
+    step and moves the tokens (`_xent_vocab_parallel`). One function
+    decides for both, `_head_shard_axes`. `mesh=None`, one device, an
+    fsdp axis of one and sizes that do not divide (then both FALL BACK to
+    the GSPMD products; nothing is padded) keep `jnp.take` or the one-hot
+    product, and the plain `_xent` program, which a logits tensor under
+    LOSS_CHUNK_MIN_BYTES keeps too.
     """
     if config.loops > 1:
         raise ValueError(

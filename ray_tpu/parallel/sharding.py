@@ -6,21 +6,25 @@ to mesh axes with a rules table, and let GSPMD insert collectives. FSDP is
 just "embed→fsdp on params + gather before use"; TP is "mlp/heads→tp";
 sequence parallelism is "seq→sp".
 
-One product is NOT left to GSPMD: the loss head. The table below is where
-its layout is DECLARED (`lm_head` [embed, vocab] → P("fsdp", "tp"), a tied
-table its transpose), and the optimizer state, the checkpoints, graphcheck
-and the serving engine all read that one declaration. But the batch is
-sharded over fsdp too, so `bsd,dv->bsv` wants every token or the whole
-matrix on a chip, and "gather before use" moves the matrix — the largest
-in the model, once a rematerialised loss chunk, and its fp32 gradient
-back. `models/transformer.loss_fn` reads `ShardingRules.default()` and
+Two products are NOT left to GSPMD: the loss head and the embedding
+lookup. The table below is where their layout is DECLARED (`lm_head`
+[embed, vocab] → P("fsdp", "tp"), `embed` and a tied table its transpose),
+and the optimizer state, the checkpoints, graphcheck and the serving
+engine all read that one declaration. But the batch is sharded over fsdp
+too, so `bsd,dv->bsv` wants every token or the whole matrix on a chip, and
+"gather before use" moves the matrix — the largest in the model, once a
+rematerialised loss chunk, and its fp32 gradient back; the lookup as a
+one-hot product gathers the table and all-reduces its gradient the same
+way. `models/transformer.loss_fn` reads `ShardingRules.default()` and
 `data_axes(mesh)` and, where one axis shards both, turns the chip's
 [d/fsdp, V] slice into a [d, V/fsdp] one by one all-to-all a step and
-moves the TOKENS between chips instead (`_xent_vocab_parallel`). The
-declared layout stays as it is so that nothing else follows: a layout
-declared by vocabulary would save that all-to-all (0.2 GB out of a chip
-at Qwen2-7B's widths) and would refuse every vocabulary that fsdp × tp
-does not divide, at `device_put`, where the table knows no shapes.
+moves the TOKENS between chips instead (`_xent_vocab_parallel`);
+`hidden_states` reads the group's tokens from the chip's own [V, d/fsdp]
+slice and exchanges the rows (`_embed_rows`). The declared layout stays
+as it is so that nothing else follows: a layout declared by vocabulary
+would save the head's all-to-all (0.2 GB out of a chip at Qwen2-7B's
+widths) and would refuse every vocabulary that fsdp × tp does not divide,
+at `device_put`, where the table knows no shapes.
 """
 
 from __future__ import annotations
@@ -162,9 +166,11 @@ def activation_batch_sharded(x, mesh: Mesh):
     """Constrain a [batch, ...] activation to the canonical layout: batch
     over the data axes, everything else replicated. Used at layout seams
     where the partitioner would otherwise propagate a PARAM sharding into
-    the activation (the embedding lookup: its natural output inherits the
+    the activation: the embedding lookup's FALLBACK, the one-hot product
+    (`models/transformer._embed`), whose natural output inherits the
     table's embed sharding on a transposed device order, which XLA can
-    only leave via involuntary full rematerialization)."""
+    only leave via involuntary full rematerialization. The lookup in the
+    table's slices (`_embed_rows`) states its output's layout itself."""
     axes = data_axes(mesh)
     spec = P(axes if axes else None, *([None] * (x.ndim - 1)))
     return with_sharding(x, mesh, spec)

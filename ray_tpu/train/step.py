@@ -168,12 +168,22 @@ def __graphcheck__(gc):
     def build_lm(mesh):
         # The decoder's own loss at Qwen2's ratios cut small, but with a
         # logits tensor over models/transformer.LOSS_CHUNK_MIN_BYTES, so
-        # the chunked head is in the graph. Its collective counts are the
-        # fingerprint of the vocabulary-parallel head: tokens gathered and
-        # dx reduce-scattered per chunk, the head's slice exchanged by one
-        # all-to-all each way, and no gather or reduction of the
-        # [d, vocab] matrix in any loop body
-        # (tests/test_train_loss_sharding.py reads the shapes).
+        # the chunked head is in the graph. Its fingerprint is that of the
+        # vocabulary-parallel head and of the lookup in the table's slices
+        # (`_embed_rows`). The compiled step holds 20 all-gathers (a
+        # layer's seven weights in the forward and in the backward scan's
+        # body 14, the tokens, targets and cotangent of a loss chunk 2 + 3,
+        # the lookup's token ids 1: bytes, where the whole table was), 6
+        # all-reduces (max, sum and target logit of a chunk 2 + 2, a
+        # layer's gradients in the backward scan's body 1 (ROADMAP S11
+        # (2)), the final norm's and the loss 1), 1 reduce-scatter (a
+        # chunk's dx) and 4 all-to-alls (the head's slice each way, the
+        # lookup's rows and their cotangent); no gather or reduction
+        # carries the vocabulary (tests/test_train_loss_sharding.py reads
+        # the shapes). The fingerprint's counter does not see a collective
+        # of a tuple type and records 20 / 3 / 1, the same as with the
+        # one-hot product: what a silent fall-back to that product moves
+        # is `flops` (46.5 -> 115.7 G) and `bytes` (338 -> 867 M).
         from ray_tpu.models import (ModelConfig, init_params, loss_fn,
                                     param_logical_axes)
         cfg = ModelConfig(vocab=65536, d_model=128, n_layers=2, n_heads=4,
