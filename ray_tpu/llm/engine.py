@@ -672,7 +672,8 @@ def prefill_with_prefix_batch(params, tokens, lengths, pool_k, pool_v,
 
 def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
     """insert_pages for a whole admission burst in one dispatch.
-    ks/vs [L, n, S, hkv, hd], L the pools' cache layers; page_ids
+    ks/vs [L, n, S, hkv, hd] (vs of its own width where the pools'
+    differ), L the pools' cache layers; page_ids
     [n, n_tab] (0 = scratch, where duplicate writes may race — scratch
     holds garbage by contract); lengths [n]."""
     L, n, S, hkv, hd = ks.shape
@@ -688,7 +689,7 @@ def insert_pages_batch(pool_k, pool_v, ks, vs, page_ids, lengths):
     ks = jnp.where(mask, ks, 0).transpose(0, 3, 1, 2, 4).reshape(
         L, hkv, n * n_tab, page, hd).swapaxes(3, 4)
     vs = jnp.where(mask, vs, 0).transpose(0, 3, 1, 2, 4).reshape(
-        L, hkv, n * n_tab, page, hd).swapaxes(3, 4)
+        L, hkv, n * n_tab, page, vs.shape[4]).swapaxes(3, 4)
     flat = page_ids.reshape(-1)
     pool_k = pool_k.at[:, :, flat].set(ks.astype(pool_k.dtype))
     pool_v = pool_v.at[:, :, flat].set(vs.astype(pool_v.dtype))
@@ -879,7 +880,12 @@ def _refuse(c: ModelConfig, what: str):
             f"{c.attn_gate!r})" if "K" in c.layer_pattern else ""),
         "windowed": f"ModelConfig.attn_pattern={c.attn_pattern!r} keeps its "
                     f"window layers' K and V in a second pool of "
-                    f"ModelConfig.window={c.window} positions a sequence",
+                    f"ModelConfig.window={c.window} positions a sequence" + (
+            f", the kinds' pools of other shapes (window_kv_heads="
+            f"{c.window_kv_heads} beside n_kv_heads={c.n_kv_heads}, "
+            f"v_head_dim={c.v_head_dim} beside a head of {c.head_dim}, "
+            f"attn_sink={c.attn_sink!r})"
+            if c.window_kv_heads or c.v_head_dim or c.attn_sink else ""),
     }[c.kv_cache]
     raise ValueError(
         f"{keeps} (ModelConfig.kv_cache == \"{c.kv_cache}\"), which does "
@@ -1940,13 +1946,20 @@ class InferenceEngine:
     def kv_stats(self) -> dict:
         """Pool/HBM accounting for tests, the dashboard, and the bench."""
         pools = [p for p in (self.cache_k, self.cache_v) if p is not None]
+        page_bytes = sum(p.nbytes // self.num_pages for p in pools)
         return {
             "layout": "paged", "num_pages": self.num_pages,
             # layers of K and V (or of latents) a token keeps in the page
             # pools, and the bytes one page takes of them: pages x
             # page_bytes = bytes, for any model
             "cache_layers": pools[0].shape[0],
-            "page_bytes": sum(p.nbytes // self.num_pages for p in pools),
+            "page_bytes": page_bytes,
+            # the same by kind of pool, K + V over the kind's layers (the
+            # growing pools'; the window pools', 0 without them): K and V,
+            # and the kinds, may differ in heads and width
+            "page_bytes_full": page_bytes,
+            "page_bytes_window": sum(p.nbytes // self.num_window_pages
+                                     for p in self.win_pools),
             "free_pages": len(self.free_pages),
             "cached_pages": len(self.cached_lru),
             "pages_in_use": self.num_pages - 1 - len(self.free_pages)

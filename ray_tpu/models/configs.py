@@ -267,6 +267,54 @@ def tiny_laguna(**kw) -> ModelConfig:
         moe_grouped="tiles")
 
 
+def mimo_v2_pattern(n_layers: int) -> str:
+    """MiMo-V2.5's `hybrid_layer_pattern` (0 full, 1 window) as letters: a
+    full layer, five window layers, the first period one window layer
+    short (F W W W W, then F W W W W W ...), and the last layer full."""
+    return "".join("F" if l == 0 or l % 6 == 5 else "W"
+                   for l in range(n_layers))
+
+
+def mimo_v2_5(**kw) -> ModelConfig:
+    """Xiaomi MiMo-V2.5's language model at its published sizes: 48 layers,
+    9 full attention (4 K/V heads, rotary base 1e7) and 39 a window of 128
+    (8 K/V heads, base 1e4, a learned sink a head), 64 query heads in
+    both, q and k 192 wide of which the first 64 rotate, v 128 wide and
+    scaled by 0.707; layer 0 a dense MLP of 16384, then 256 routed experts
+    of 2048 (8 a token, sigmoid scores, a bias in the choice, weights
+    renormalised, none shared). Every expert held: a serving replica names
+    its share through `moe_experts`, `moe_router_experts` and
+    `moe_held_group`."""
+    return _preset(
+        kw, vocab=152576, d_model=4096, n_layers=48, n_heads=64,
+        n_kv_heads=4, head_size=192, v_head_dim=128, d_ff=16384,
+        rope_theta=1e7, norm_eps=1e-5, dtype="bfloat16",
+        tie_embeddings=False, attn_pattern=mimo_v2_pattern(48), window=128,
+        window_heads=64, window_kv_heads=8, window_rope_theta=10000.0,
+        rotary_fraction=0.334, window_rotary_fraction=0.334, attn_sink="W",
+        value_scale=0.707, first_k_dense=1, moe_experts=256,
+        moe_router_experts=256, moe_top_k=8, moe_d_ff=2048,
+        moe_score="sigmoid", moe_router_bias=True, moe_norm_topk=True,
+        moe_grouped="tiles")
+
+
+def tiny_mimo_v2(**kw) -> ModelConfig:
+    """CPU-test scale of mimo_v2_5's structure: 7 layers (F W W W W F W),
+    8 query heads on 2 ("F") and 4 ("W") K/V heads, q and k 24 wide (the
+    first 8 rotate), v 16 wide and scaled, a window of 16 with a sink a
+    head, one dense layer, then 8 routed experts (3 a token) with a bias
+    in the choice."""
+    return _preset(
+        kw, vocab=256, d_model=64, n_layers=7, n_heads=8, n_kv_heads=2,
+        head_size=24, v_head_dim=16, d_ff=96, rope_theta=1e7, norm_eps=1e-5,
+        tie_embeddings=False, attn_pattern=mimo_v2_pattern(7), window=16,
+        window_heads=8, window_kv_heads=4, window_rope_theta=10000.0,
+        rotary_fraction=0.334, window_rotary_fraction=0.334, attn_sink="W",
+        value_scale=0.707, first_k_dense=1, moe_experts=8,
+        moe_router_experts=8, moe_top_k=3, moe_d_ff=32, moe_score="sigmoid",
+        moe_router_bias=True, moe_norm_topk=True, moe_grouped="tiles")
+
+
 def ouro_2_6b(**kw) -> ModelConfig:
     """ByteDance Ouro-2.6B at its published sizes: 48 layers run 4 times
     over one set of weights (192 cache layers), 16 heads of 128 on 16 K/V

@@ -118,8 +118,9 @@ class ModelConfig:
     # context, "W" over the last `window` positions (query i sees keys j
     # with i - window < j <= i). The kinds differ in more than the mask:
     # `n_heads`, `rope_theta`, `rope_scaling` and `rotary_fraction` are the
-    # "F" layers', the `window_*` fields the "W" layers'; K/V heads and
-    # the head size are shared. "" = every layer alike (the modules above).
+    # "F" layers', the `window_*` fields the "W" layers'; the head size
+    # (q's and k's) and the page are shared. "" = every layer alike (the
+    # modules above).
     attn_pattern: str = ""
     window: int = 0
     window_heads: int = 0           # query heads of a "W" layer
@@ -132,6 +133,17 @@ class ModelConfig:
     # "per_element": the same with one scalar a head AND channel, W_gate
     # [d, heads * head size] (models/nemotron_h.py). "" = no gate.
     attn_gate: str = ""
+    # What a pattern's kinds need NOT share (models/windowed.py; each
+    # default reads "as the fields above say"): the "W" layers' K/V heads
+    # (0 = `n_kv_heads`, the "F" layers'); the kinds, of "F" and "W", whose
+    # softmax has a learned sink, one logit a query head that joins every
+    # row's denominator and gives no value ("" = none); a factor on v
+    # before it is cached; whether a sigmoid router's choice adds a learned
+    # bias. The V width is `v_head_dim` above (0 = the head size).
+    window_kv_heads: int = 0
+    attn_sink: str = ""
+    value_scale: float = 1.0
+    moe_router_bias: bool = False
     # --- a looped stack (loops > 1): this file and llm/engine.py ---
     # The `n_layers` layers run `loops` times over ONE set of weights, pass
     # t's layer l with K and V of its own (cache layer t * n_layers + l);

@@ -433,7 +433,7 @@ def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths,
                     real_ref,  # [n * heads] query blocks that hold a token
                     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     scale: float, bq: int, bk: int, nk: int, pre_t: int,
-                    heads: int, window: int = 0):
+                    heads: int, window: int = 0, sink_ref=None):
     """Keys are [pre_t cached-prefix positions | the chunk]: prefix key j
     counts where j < plen of the request, chunk key c where c <= the query
     row. Blocks with nothing to count are predicated out, and so is every
@@ -447,7 +447,12 @@ def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths,
     constant: query row r (index pre_t + r) sees the indices in (pre_t + r
     - window, pre_t + r] that are at least pre_t - plen. A grid cell then
     walks only the `nk` key blocks its query block can see, from
-    `_window_first_block` on, not every block."""
+    `_window_first_block` on, not every block.
+
+    `sink_ref` (`_prefill_sink_kernel`): the head's learned sink, a logit
+    that joins every row's denominator and gives no value. It is the
+    online softmax's starting state: running max the sink, sum 1,
+    accumulator 0."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     plen = plen_ref[jax.lax.div(pl.program_id(0), heads)]
     if window:
@@ -458,8 +463,12 @@ def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths,
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink_ref is None:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+        else:
+            m_scr[...] = jnp.broadcast_to(sink_ref[0][:1], m_scr.shape)
+            l_scr[...] = jnp.ones_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     if window:
@@ -509,6 +518,14 @@ def _prefill_kernel(plen_ref,  # scalar prefetch (SMEM): [n] prefix lengths,
     def _finalize():
         o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(
             o_ref.dtype)
+
+
+def _prefill_sink_kernel(plen_ref, real_ref, q_ref, k_ref, v_ref, sink_ref,
+                         *refs, **statics):
+    """`_prefill_kernel` with one more input, the heads' sinks: a grid
+    row's [8, 128] float32 block holds its head's sink in every cell."""
+    _prefill_kernel(plen_ref, real_ref, q_ref, k_ref, v_ref, *refs,
+                    sink_ref=sink_ref, **statics)
 
 
 def _window_first_block(qi, *, bq: int, bk: int, pre_t: int, window: int):
@@ -581,9 +598,9 @@ _PREFILL_BK = 1024
 
 @functools.partial(jax.jit, static_argnames=("pre_t", "scale", "name", "bq",
                                              "bk", "window", "interpret"))
-def _prefill_flash(q, k, v, prefix_len, lengths=None, *, pre_t: int,
-                   scale: float, name: str, bq: int, bk: int, interpret: bool,
-                   window: int = 0):
+def _prefill_flash(q, k, v, prefix_len, lengths=None, sink=None, *,
+                   pre_t: int, scale: float, name: str, bq: int, bk: int,
+                   interpret: bool, window: int = 0):
     n, h, s, dq = q.shape
     if lengths is None:     # every row is real
         lengths = jnp.full((n,), s, jnp.int32)
@@ -613,16 +630,23 @@ def _prefill_flash(q, k, v, prefix_len, lengths=None, *, pre_t: int,
                                         nk=nk, pre_t=pre_t, window=window)
     kernel = functools.partial(_prefill_kernel, scale=scale, bq=bq, bk=bk,
                                nk=nk, pre_t=pre_t, heads=h, window=window)
+    operands = (q.reshape(n * h, s_pad, dq), k.reshape(n * hkv, t_pad, dq),
+                v.reshape(n * hkv, t_pad, dv))
+    in_specs = [pl.BlockSpec((1, bq, dq), q_map),
+                pl.BlockSpec((1, bk, dq), kv_map),
+                pl.BlockSpec((1, bk, dv), kv_map)]
+    if sink is not None:    # [h] float32 -> a tile a grid row
+        kernel = functools.partial(_prefill_sink_kernel, **kernel.keywords)
+        operands += (jnp.broadcast_to(jnp.tile(sink.astype(
+            jnp.float32), n)[:, None, None], (n * h, 8, 128)),)
+        in_specs.append(pl.BlockSpec((1, 8, 128),
+                                     lambda b, i, j, *_: (b, 0, 0)))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n * h, s_pad // bq, nk),
-            in_specs=[
-                pl.BlockSpec((1, bq, dq), q_map),
-                pl.BlockSpec((1, bk, dq), kv_map),
-                pl.BlockSpec((1, bk, dv), kv_map),
-            ],
+            in_specs=in_specs,
             out_specs=pl.BlockSpec((1, bq, dv),
                                    lambda b, i, j, *_: (b, i, 0)),
             scratch_shapes=[
@@ -636,8 +660,7 @@ def _prefill_flash(q, k, v, prefix_len, lengths=None, *, pre_t: int,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(prefix_len, real, q.reshape(n * h, s_pad, dq),
-      k.reshape(n * hkv, t_pad, dq), v.reshape(n * hkv, t_pad, dv))
+    )(prefix_len, real, *operands)
     return out.reshape(n, h, s_pad, dv)[:, :, :s]
 
 
@@ -658,7 +681,7 @@ def prefill_blocks(lengths, s: int, window: int = 0) -> tuple[int, int]:
 
 def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
                       name: str, impl: str = "auto", window: int = 0,
-                      lengths=None):
+                      lengths=None, sink=None):
     """The serving prefill programs' attention, forward only, no score
     tensor in HBM. q [n, h, S, dq]; k [n, hkv, pre_t + S, dq], v [n, hkv,
     pre_t + S, dv], h a multiple of hkv: the first pre_t keys are a cached
@@ -682,6 +705,11 @@ def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
     whatever it attends to, as every padded row does without `lengths` and
     in the reference.
 
+    `sink` [h] float32: a learned logit a query head that joins the
+    denominator of every row of that head and gives no value (p_ij =
+    exp(s_ij - m_i) / (exp(sink - m_i) + sum_j exp(s_ij - m_i)), m_i the
+    larger of the sink and the row's largest score).
+
     `name` is the kernel's in a device trace. impl: "auto" (the kernel on
     the TPU, the jnp reference elsewhere), "pallas", "interpret" (the
     kernel's interpreter), "reference"."""
@@ -689,16 +717,17 @@ def prefill_attention(q, k, v, prefix_len, *, pre_t: int, scale: float,
         impl = "pallas" if _on_tpu() else "reference"
     if impl == "reference":
         return prefill_attention_reference(q, k, v, prefix_len, pre_t=pre_t,
-                                           scale=scale, window=window)
+                                           scale=scale, window=window,
+                                           sink=sink)
     bq, bk = ((window_block(window),) * 2 if window
               else (_PREFILL_BQ, _PREFILL_BK))
-    return _prefill_flash(q, k, v, prefix_len, lengths, pre_t=pre_t,
+    return _prefill_flash(q, k, v, prefix_len, lengths, sink, pre_t=pre_t,
                           scale=scale, name=name, bq=bq, bk=bk, window=window,
                           interpret=(impl == "interpret"))
 
 
 def prefill_attention_reference(q, k, v, prefix_len, *, pre_t: int,
-                                scale: float, window: int = 0):
+                                scale: float, window: int = 0, sink=None):
     """The same function with the whole score tensor: the tests' oracle,
     and what the prefill programs run where no chip is."""
     n, h, s, _ = q.shape
@@ -712,6 +741,12 @@ def prefill_attention_reference(q, k, v, prefix_len, *, pre_t: int,
     else:
         ok = jnp.where(cols < pre_t, cols < prefix_len[:, None, None],
                        cols - pre_t <= rows)                 # [n, s, t]
-    p = jax.nn.softmax(jnp.where(ok[:, None, None], sc, -jnp.inf), axis=-1)
+    sc = jnp.where(ok[:, None, None], sc, -jnp.inf)
+    if sink is None:
+        p = jax.nn.softmax(sc, axis=-1)
+    else:       # one more column a row, which gives no value
+        col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            1, hkv, h // hkv, 1, 1), sc.shape[:-1] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([sc, col], -1), axis=-1)[..., :-1]
     out = jnp.einsum("ngrqk,ngkd->ngrqd", p, v.astype(jnp.float32))
     return out.reshape(n, h, s, -1).astype(q.dtype)

@@ -17,6 +17,9 @@ page DMA).
 Layouts:
   q            [B, n_heads, head_dim]
   pool_k, pool_v   [n_layers, n_kv_heads, num_pages, head_dim, page_size]
+      (`paged_decode_attention` alone: pool_v's width may be another than
+      pool_k's, keys of q's width and values of their own, which is the
+      output's then)
       the engine's STACKED pools, with the `layer` to read: the kernels
       index `pool.at[layer, :, page_id]` themselves. A caller that hands
       over `pool[layer]` makes XLA slice 1/L of the pool out and re-tile
@@ -67,7 +70,7 @@ def _mosaic_tiles(page: int, hd: int) -> bool:
 
 def paged_decode_attention(q, pool_k, pool_v, lengths, page_tables, *,
                            layer: int, interpret: bool | None = None,
-                           lows=None, name: str | None = None):
+                           lows=None, name: str | None = None, sink=None):
     """Flash decode over layer `layer` of the stacked paged pools; see
     module docstring for layouts.
 
@@ -75,7 +78,9 @@ def paged_decode_attention(q, pool_k, pool_v, lengths, page_tables, *,
     p < lengths only, positions counted from the first token of the
     table's first page, which need not be the sequence's first: the
     caller hands over the window's pages alone, and the kernel starts
-    there. `name`: the call's name in a device trace.
+    there. `name`: the call's name in a device trace. `sink` [h] float32
+    (with `lows`): a learned logit a query head that joins its softmax's
+    denominator and gives no value.
 
     interpret=None auto-selects: the Mosaic lowering needs a real TPU
     backend; everywhere else (CPU tests, multichip dryrun) the kernel
@@ -86,42 +91,44 @@ def paged_decode_attention(q, pool_k, pool_v, lengths, page_tables, *,
     import os
     if os.environ.get("RAY_TPU_PAGED_ATTN_IMPL") == "xla":
         return _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables,
-                                    lows, layer)
+                                    lows, layer, sink)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     page, hd = pool_k.shape[4], pool_k.shape[3]
-    if not interpret and not _mosaic_tiles(page, hd):
+    if not interpret and not (_mosaic_tiles(page, hd)
+                              and _mosaic_tiles(page, pool_v.shape[3])):
         return _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables,
-                                    lows, layer)
+                                    lows, layer, sink)
     return _paged_decode_dma(q, pool_k, pool_v, lengths, page_tables, layer,
-                             lows, interpret=interpret, name=name)
+                             lows, sink, interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("layer",))
-def _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables, lows=None, *,
-                      layer: int):
+def _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables, lows=None,
+                      sink=None, *, layer: int):
     return paged_decode_attention_reference(
-        q, pool_k[layer], pool_v[layer], lengths, page_tables, lows)
+        q, pool_k[layer], pool_v[layer], lengths, page_tables, lows, sink)
 
 
 def _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables, lows,
-                         layer):
+                         layer, sink=None):
     """The XLA gather formulation at `layer`, an int or a traced scalar (a
     looped stack's cache layer, inside its loop over passes)."""
     if isinstance(layer, int):
         return _paged_decode_xla(q, pool_k, pool_v, lengths, page_tables,
-                                 lows, layer=layer)
+                                 lows, sink, layer=layer)
     return paged_decode_attention_reference(
         q, *(jax.lax.dynamic_index_in_dim(p, layer, keepdims=False)
-             for p in (pool_k, pool_v)), lengths, page_tables, lows)
+             for p in (pool_k, pool_v)), lengths, page_tables, lows, sink)
 
 
 def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
                 q_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, m_ref, l_ref, acc_ref, sem, *, page: int,
                 scale: float, pages_per_seq: int, n_q: int = 1,
-                lows_ref=None):
-    """k_hbm / v_hbm are the stacked pools [L, hkv, N, hd, page], read at
+                lows_ref=None, sink_ref=None):
+    """k_hbm / v_hbm are the stacked pools [L, hkv, N, hd | dv, page] (V's
+    width its own: `vbuf`, the accumulator and the output have it), read at
     layer `layer_ref[0]`: a prefetched scalar, not a static, so that the
     L calls of a decode program share ONE traced, lowered and compiled
     kernel (a static layer made twelve of each on qwen2_7b: 0.5 s more
@@ -140,7 +147,11 @@ def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     head-group axis with the query index MINOR ([hkv, g*n_q, hd], layout
     [g, n_q]); query j sits at absolute position lengths-1+j, so its
     causal limit is lengths+j. The flash accumulators simply widen by n_q
-    rows."""
+    rows.
+
+    `sink_ref` [hkv * g, 128] float32 (`_dma_sink_kernel`), a query head's
+    learned sink in every lane of its row: the flash update's starting
+    state is then running max the sink, sum 1, accumulator 0."""
     b = pl.program_id(0)
     layer = layer_ref[0]
     length = lengths_ref[b]
@@ -160,8 +171,12 @@ def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
         pltpu.make_async_copy(
             v_hbm.at[layer, :, 0], vbuf.at[slot], sem.at[slot, 1]).wait()
 
-    m_ref[...] = jnp.full_like(m_ref, _NEG)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if sink_ref is None:
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+    else:
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(npg > 0)
@@ -207,7 +222,7 @@ def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
         pv = jax.lax.dot_general(
             p_exp.reshape(hkv, g, page), v,
             (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [hkv, g, hd]
+            preferred_element_type=jnp.float32)        # [hkv, g, dv]
         acc_ref[...] = acc_ref[...] * alpha[:, None].reshape(
             hkv, g, 1) + pv
         m_ref[...] = m_new
@@ -227,14 +242,23 @@ def _dma_window_kernel(layer_ref, lengths_ref, tables_ref, lows_ref, *refs,
                 **statics)
 
 
+def _dma_sink_kernel(layer_ref, lengths_ref, tables_ref, lows_ref, q_ref,
+                     k_hbm, v_hbm, sink_ref, *refs, **statics):
+    """`_dma_window_kernel` with one more input, the heads' sinks."""
+    _dma_kernel(layer_ref, lengths_ref, tables_ref, q_ref, k_hbm, v_hbm,
+                *refs, lows_ref=lows_ref, sink_ref=sink_ref, **statics)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "name"))
 def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, layer,
-                      lows=None, *, interpret: bool = False,
+                      lows=None, sink=None, *, interpret: bool = False,
                       name: str | None = None):
     """With `lows` (a window layer's pages): one more prefetched scalar a
-    slot, below which nothing counts."""
+    slot, below which nothing counts; with a `sink` besides, one more
+    input, resident over the grid."""
     B, h, hd = q.shape
     _, hkv, N, _, page = k_pages.shape
+    dv = v_pages.shape[3]
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     P = page_tables.shape[1]
@@ -250,33 +274,41 @@ def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, layer,
     def slot(b, *_scalars):
         return (b, 0, 0, 0)
 
+    operands = (q4, k_pages, v_pages)
+    in_specs = [
+        pl.BlockSpec((1, hkv, g, hd), slot),
+        pl.BlockSpec(memory_space=pl.ANY),   # k_pages in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # v_pages in HBM
+    ]
+    if sink is not None:    # [h] float32 -> a row a head, every lane
+        assert lows is not None, "a sink comes with a window's `lows`"
+        kernel = functools.partial(_dma_sink_kernel, **kernel.keywords)
+        operands += (jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None], (h, 128)),)
+        in_specs.append(pl.BlockSpec((h, 128), lambda b, *_scalars: (0, 0)))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, hkv, g, hd), slot),
-                pl.BlockSpec(memory_space=pl.ANY),   # k_pages in HBM
-                pl.BlockSpec(memory_space=pl.ANY),   # v_pages in HBM
-            ],
-            out_specs=pl.BlockSpec((1, hkv, g, hd), slot),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, hkv, g, dv), slot),
             scratch_shapes=[
                 pltpu.VMEM((2, hkv, hd, page), k_pages.dtype),  # kbuf
-                pltpu.VMEM((2, hkv, hd, page), v_pages.dtype),  # vbuf
+                pltpu.VMEM((2, hkv, dv, page), v_pages.dtype),  # vbuf
                 pltpu.VMEM((hkv * g, 128), jnp.float32),        # m
                 pltpu.VMEM((hkv * g, 128), jnp.float32),        # l
-                pltpu.VMEM((hkv, g, hd), jnp.float32),          # acc
+                pltpu.VMEM((hkv, g, dv), jnp.float32),          # acc
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, hkv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, hkv, g, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
-    )(*scalars, q4, k_pages, v_pages)
-    return out.reshape(B, h, hd)
+    )(*scalars, *operands)
+    return out.reshape(B, h, dv)
 
 
 def _fused_kernel(lengths_ref, tables_ref,  # scalar prefetch (SMEM)
@@ -589,8 +621,9 @@ def _fused_insert_call(q, k_pages, v_pages, knew, vnew, lengths,
 
 
 def paged_decode_attention_reference(q, k_pages, v_pages, lengths,
-                                     page_tables, lows=None):
-    """Dense reference for tests: gather pages, mask, softmax."""
+                                     page_tables, lows=None, sink=None):
+    """Dense reference for tests: gather pages, mask, softmax (with a
+    `sink` [h]: over one more column a head, which gives no value)."""
     B, h, hd = q.shape
     hkv, N, _, page = k_pages.shape
     g = h // hkv
@@ -602,7 +635,7 @@ def paged_decode_attention_reference(q, k_pages, v_pages, lengths,
     ck = jnp.moveaxis(ck, 0, 1).transpose(0, 1, 2, 4, 3).reshape(
         B, hkv, T, hd)
     cv = jnp.moveaxis(cv, 0, 1).transpose(0, 1, 2, 4, 3).reshape(
-        B, hkv, T, hd)
+        B, hkv, T, -1)
     q4 = q.reshape(B, hkv, g, hd).astype(jnp.float32)
     s = jnp.einsum("bkgd,bktd->bkgt", q4, ck.astype(jnp.float32))
     s = s / np.sqrt(hd)
@@ -610,7 +643,15 @@ def paged_decode_attention_reference(q, k_pages, v_pages, lengths,
     if lows is not None:
         mask &= jnp.arange(T)[None, None, None] >= lows[:, None, None, None]
     s = jnp.where(mask, s, -jnp.inf)
+    if sink is not None:
+        col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            1, hkv, g, 1), (B, hkv, g, 1))
+        s = jnp.concatenate([s, col], -1)
+        mask = jnp.concatenate([jnp.broadcast_to(mask, (B, 1, 1, T)),
+                                jnp.zeros((B, 1, 1, 1), bool)], -1)
     # a slot of length 0 attends nothing: a row of 0, as the kernels give
     pr = jnp.where(mask, jax.nn.softmax(s, axis=-1), 0.0)
+    if sink is not None:
+        pr = pr[..., :T]
     out = jnp.einsum("bkgt,bktd->bkgd", pr, cv.astype(jnp.float32))
-    return out.reshape(B, h, hd).astype(q.dtype)
+    return out.reshape(B, h, -1).astype(q.dtype)

@@ -10,6 +10,7 @@ smoke runs (28 query heads, 4 KV heads, head_dim 128). Nothing executes:
 a pass here says nothing about results or times.
 """
 
+import math
 import os
 import re
 from functools import partial
@@ -233,6 +234,49 @@ def _window(kind, h):
     return build
 
 
+def _mimo(kind):
+    """mimo_v2_5's attention at the long-turn cell's shapes: 64 query heads
+    in both kinds, keys 192 wide and values 128 (a K page [hkv, 192, 128],
+    a V page [hkv, 128, 128], the output 128 wide); window layers on 8 K/V
+    heads (8 queries a head) over the 2 pages a 128-token window spans,
+    with the heads' sinks; full layers on 4 (16 queries a head) over a
+    16512-token table. Prefill: an 8192-row chunk over the one held page
+    before it, and two 4096-row prompts."""
+    def build(topo):
+        from ray_tpu.ops import paged_attention as pa
+        from ray_tpu.ops.attention import prefill_attention
+        one = SingleDeviceSharding(topo.devices[0])
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        ints, sink = sds((32,), jnp.int32), sds((64,), jnp.float32)
+        if kind == "sinkwin_decode":
+            return (lambda q, pk, pv, ln, tb, lo, sk:
+                    pa.paged_decode_attention(
+                        q, pk, pv, ln, tb, layer=3, lows=lo, sink=sk,
+                        name="sinkwin_paged_decode", interpret=False)), (
+                sds((32, 64, 192)), sds((9, 8, 104, 192, PAGE)),
+                sds((9, 8, 104, 128, PAGE)), ints, sds((32, 2), jnp.int32),
+                ints, sink)
+        if kind == "splitkv_decode":
+            return (lambda q, pk, pv, ln, tb: pa.paged_decode_attention(
+                q, pk, pv, ln, tb, layer=1, name="splitkv_paged_decode",
+                interpret=False)), (
+                sds((32, 64, 192)), sds((2, 4, 4400, 192, PAGE)),
+                sds((2, 4, 4400, 128, PAGE)), ints,
+                sds((32, 129), jnp.int32))
+        n, s, pre_t = kind
+        return (lambda q, k, v, pl, ln, sk: prefill_attention(
+            q, k, v, pl, pre_t=pre_t, scale=192 ** -0.5, window=128,
+            lengths=ln, sink=sk, name=f"sinkwin_prefill_n{n}_s{s}_t{pre_t}",
+            impl="pallas")), (
+            sds((n, 64, s, 192)), sds((n, 8, pre_t + s, 192)),
+            sds((n, 8, pre_t + s, 128)), sds((n,), jnp.int32),
+            sds((n,), jnp.int32), sink)
+    return build
+
+
 def _looped_decode(topo):
     """ouro_2_6b's paged decode call: 16 query heads on 16 K/V heads (a
     GROUP OF ONE: a q block of [16, 1, 128], one row a head in both
@@ -283,6 +327,10 @@ CASES = {
     "swa_paged_decode_6_to_1": _window("decode", 48),
     "gqa_prefill_laguna_full_1x8192_over_prefix": _gqa_prefill(48, 8, 1, 8192,
                                                                8192),
+    "sinkwin_prefill_mimo_1x8192_over_held_page": _mimo((1, 8192, 128)),
+    "sinkwin_prefill_mimo_2x4096": _mimo((2, 4096, 0)),
+    "sinkwin_paged_decode_mimo_8_to_1": _mimo("sinkwin_decode"),
+    "splitkv_paged_decode_mimo_16_to_1": _mimo("splitkv_decode"),
     "looped_paged_decode_ouro_group_of_one": _looped_decode,
     "gqa_prefill_ouro_2_6b_1x512": _gqa_prefill(16, 16, 1, 512, 0),
     "gqa_prefill_ouro_2_6b_1x256_over_prefix": _gqa_prefill(16, 16, 1, 256,
@@ -769,3 +817,106 @@ def test_solar_decode_program_compiles_with_its_pools_in_place(
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) >= 4
     assert "kda_state_update" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
+# ---- MiMo-V2.5 (models/windowed.py), at mimo_v2_5's widths ----
+
+
+def _mimo_share(layers, one):
+    """(config, parameter shapes, `on`, `sds`) of the first `layers` layers
+    of mimo_v2_5's cell: published widths, 16 experts held; `on(tree)` and
+    `sds(shape, dtype)` put shapes on device `one`."""
+    from ray_tpu.models import configs, init_params
+    c = configs.mimo_v2_5(
+        n_layers=layers, attn_pattern=configs.mimo_v2_pattern(layers),
+        vocab=19072, moe_experts=16)
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    return c, on(jax.eval_shape(
+        lambda: init_params(c, jax.random.PRNGKey(0)))), on, sds
+
+
+def test_mimo_decode_paged_leaves_its_four_pools_where_they_lie(
+        topo, monkeypatch):
+    """The whole `windowed.decode_paged` program at the mimo_v2_5 long-turn
+    cell's geometry (3 of its layers: full attention over the dense MLP,
+    two sink-window layers over its share of 16 experts; 32 slots, 3700
+    full pages under the 129-page table, 80 window pages), the four pools
+    donated: K pools 192 wide and V pools 128, 4 K/V heads in the full
+    kind's and 8 in the window kind's. Each layer's call is the Pallas
+    kernel under its own name; every pool comes back aliased to its
+    operand; the optimized HLO holds no `copy`, `scatter` or slice whose
+    result is a pool or one layer of it, and the temporaries hold no
+    pool."""
+    from ray_tpu.models import windowed
+    from ray_tpu.models.experts import stats_zero
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    c, params, on, sds = _mimo_share(3, one)
+    slots, n_pages, win_pages = 32, 3700, 80
+    pools = (*on(windowed.page_pools(c, n_pages, PAGE)),
+             *on(windowed.window_pools(c, win_pages, PAGE)))
+    assert [p.shape for p in pools] == [
+        (1, 4, n_pages, 192, PAGE), (1, 4, n_pages, 128, PAGE),
+        (2, 8, win_pages, 192, PAGE), (2, 8, win_pages, 128, PAGE)]
+    compiled = jax.jit(partial(windowed.decode_paged, config=c),
+                       donate_argnums=(1, 2, 3, 4)).lower(
+        params, *pools, sds((slots,)), sds((slots,)),
+        sds((slots,), jnp.bool_),
+        (sds((slots, 129)), sds((slots, 2)), sds((slots,))),
+        on(jax.eval_shape(lambda: stats_zero(c)))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "splitkv_paged_decode" in text and "sinkwin_paged_decode" in text
+    assert "swa_paged_decode" not in text
+    for p in pools:
+        L, hkv, n, w, _ = p.shape
+        pool_sized = re.compile(
+            r"= bf16\[(?:%d|1),%d,%d,%d,%d\]\S* "
+            r"(copy|scatter|slice|dynamic-slice)[-(]" % (L, hkv, n, w, PAGE))
+        assert pool_sized.findall(text) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(math.prod(p.shape) * 2 for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("program", ["prefill_batch",
+                                     "prefill_with_prefix_batch"])
+def test_mimo_chunk_programs_hoist_no_layers_weights(topo, monkeypatch,
+                                                     program):
+    """One 8192-row chunk at the mimo_v2_5 cell's geometry (5 of its
+    layers: F W W W W). `prefill_batch` takes no pool, so the compiler is
+    blind to what the pools hold of the chip and its temporaries are what
+    the schedule it likes best asks for: with the per-layer fence of
+    `windowed._prefill` 1.04 GiB here (1.21 over a prefix), without it the
+    re-laid-out copies of every layer's wq, wk and wv live at once from
+    the program's start (2.17 GiB at these 5 layers, 4.37 against 1.36 at
+    the cell's 11). Both kinds' kernels are in it under their names."""
+    from ray_tpu.models import windowed
+    from ray_tpu.models.experts import stats_zero
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    c, params, on, sds = _mimo_share(5, one)
+    args = (params, sds((1, 8192)), sds((1,)))
+    pre_t = 0
+    if program == "prefill_with_prefix_batch":
+        args += (*on(windowed.page_pools(c, 3700, PAGE)),
+                 *on(windowed.window_pools(c, 80, PAGE)),
+                 (sds((1, 64)), sds((1, 1))), sds((1,)))
+        pre_t = PAGE
+    compiled = jax.jit(partial(getattr(windowed, program), config=c,
+                               page=PAGE)).lower(
+        *args, on(jax.eval_shape(lambda: stats_zero(c)))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    assert "gqa_prefill_attention" in text
+    assert f"sinkwin_prefill_n1_s8192_t{pre_t}" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30

@@ -196,9 +196,11 @@ def test_kv_stats_loses_the_two_speculation_counters_alone():
     # ... and since PR 51 counts its admissions three ways
     # ... and since PR 55 its prefill kernels' query blocks two ways
     # ... and since PR 56 the bytes of a state row
+    # ... and since PR 60 the bytes of a page by kind of pool
     assert set(st) - PARENT_KV_STATS == {
         "admissions", "admissions_unfenced", "admissions_under_flight",
-        "prefill_attn_blocks", "prefill_attn_blocks_run", "row_bytes"}
+        "prefill_attn_blocks", "prefill_attn_blocks_run", "row_bytes",
+        "page_bytes_full", "page_bytes_window"}
     # what perfbench/harness/serve_cell._engine_counters reads
     assert st["preemptions"] == 0 and st["prefix_hits"] == 0
 
