@@ -3,10 +3,24 @@
 The decode hot loop attends one query token per slot against that slot's
 paged KV history. XLA lowers the naive formulation (gather pages into a
 contiguous [B, T] cache, then attend) at ~10% of HBM bandwidth — the page
-gather dominated the whole decode step. This kernel instead walks each
-slot's page table and DMAs exactly the pages it owns through a two-deep
-manual pipeline, flash-accumulating on the fly, so per-step traffic is
-the true KV working set.
+gather dominated the whole decode step. These kernels instead walk each
+slot's page table and DMA exactly the pages it owns, flash-accumulating
+on the fly, so per-step traffic is the true KV working set.
+
+Two kernels. `paged_decode_attention` (`_dma_kernel`: the hybrid and the
+window modules' decode, which write the token's K and V themselves) reads
+only, a BLOCK of G pages a turn of its loop: while a block is attended
+the G copies of the next one (the next slot's first, at a slot's end) are
+all in flight, side by side in the other half of a two-block buffer, and
+a turn is one score product, one softmax update and one p . v over
+G * page positions. G follows the shapes the call is handed, by
+`latent_attention._block_pages` over K and V of a page together: 8 at two
+K/V heads of 128, 16 at one, 2 at eight, never more than a table is wide.
+`paged_decode_insert_attention` (`_fused_kernel`: every per-head decode
+step) also writes the token's K and V, and still walks a page a turn
+through a two-deep pipeline (page i+1 in flight while page i is in the
+flash update); `_block_copies` and `_block_update` are written to be
+called from there next (ROADMAP S15).
 
 Parity: the role of vLLM's paged attention CUDA kernel inside the
 reference's LLM serving stack (`python/ray/llm/_internal/serve/deployments/
@@ -49,6 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.latent_attention import _block_pages
 
 _NEG = -0.7 * float(np.finfo(np.float32).max)
 
@@ -99,8 +115,14 @@ def paged_decode_attention(q, pool_k, pool_v, lengths, page_tables, *,
                               and _mosaic_tiles(page, pool_v.shape[3])):
         return _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables,
                                     lows, layer, sink)
+    # G, the pages a turn of the kernel attends: K and V of a page together
+    # are what the latent kernel's one pool is to its rule
+    block = _block_pages(
+        pool_k.shape[1] * (hd + pool_v.shape[3]), page,
+        pool_k.dtype.itemsize, page_tables.shape[1])
     return _paged_decode_dma(q, pool_k, pool_v, lengths, page_tables, layer,
-                             lows, sink, interpret=interpret, name=name)
+                             lows, sink, block=block, interpret=interpret,
+                             name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("layer",))
@@ -122,10 +144,60 @@ def _paged_decode_gather(q, pool_k, pool_v, lengths, page_tables, lows,
              for p in (pool_k, pool_v)), lengths, page_tables, lows, sink)
 
 
+def _block_copies(tables_ref, k_hbm, v_hbm, kbuf, vbuf, sem, *, layer, slot,
+                  first, count, half, page: int, wait: bool = False):
+    """Start, or wait for, the copies of `count` pages of `slot` (its
+    table's entries `first`, `first` + 1, ...) into block `half` of `kbuf`
+    and `vbuf` [2, hkv, hd | dv, G * page]: page j of them lands in lanes
+    [j * page, (j + 1) * page), by a copy of its own on the block's two
+    semaphores (`sem` [2, 2]: block, K | V). The trip count is `count`: no
+    table entry past it is read, and nothing is unrolled."""
+    def one(j, _):
+        pid = tables_ref[slot, first + j]
+        at = pl.ds(pl.multiple_of(j * page, page), page)
+        for which, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            copy = pltpu.make_async_copy(
+                hbm.at[layer, :, pid], buf.at[half, :, :, at],
+                sem.at[half, which])
+            copy.wait() if wait else copy.start()
+        return 0
+    jax.lax.fori_loop(0, count, one, 0)
+
+
+def _block_update(q, k, v, m_ref, l_ref, acc_ref, *, start, limit, low=None,
+                  scale: float):
+    """One running-softmax update over a block of keys: q [hkv, g, hd], k
+    [hkv, hd, T] and v [hkv, dv, T] float32, T the block's G * page
+    positions `start`, `start` + 1, ...; those outside [`low`, `limit`)
+    score `_NEG`, so their p is exactly 0 once the running maximum is a
+    real score's (or a sink's). m_ref / l_ref [hkv * g, 128], acc_ref
+    [hkv, g, dv]."""
+    hkv, g, _ = q.shape
+    s = jax.lax.dot_general(
+        q, k, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale       # [hkv, g, T]
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=2)
+    s = jnp.where(pos < limit, s, _NEG)
+    if low is not None:         # a window layer: nothing before `low`
+        s = jnp.where(pos >= low, s, _NEG)
+    m_old = m_ref[...]                                    # [hkv*g, 128]
+    s2 = s.reshape(hkv * g, s.shape[2])
+    m_cur = jnp.max(s2, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_old, jnp.broadcast_to(m_cur, m_old.shape))
+    alpha = jnp.exp(m_old[:, :1] - m_new[:, :1])
+    p_exp = jnp.exp(s2 - m_new[:, :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p_exp, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p_exp.reshape(s.shape), v, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)               # [hkv, g, dv]
+    acc_ref[...] = acc_ref[...] * alpha[:, None].reshape(hkv, g, 1) + pv
+    m_ref[...] = m_new
+
+
 def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
                 q_ref, k_hbm, v_hbm, o_ref,
-                kbuf, vbuf, m_ref, l_ref, acc_ref, sem, *, page: int,
-                scale: float, pages_per_seq: int, n_q: int = 1,
+                kbuf, vbuf, m_ref, l_ref, acc_ref, sem, first_ref, *,
+                page: int, scale: float, pages_per_seq: int, block: int,
                 lows_ref=None, sink_ref=None):
     """k_hbm / v_hbm are the stacked pools [L, hkv, N, hd | dv, page] (V's
     width its own: `vbuf`, the accumulator and the output have it), read at
@@ -135,41 +207,70 @@ def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     of tracing and lowering whenever a decode program is looked up, 7 s
     of the chat cell's warm-up on the chip machine; PERF.md section 6).
 
-    One grid step per slot; the slot's pages stream HBM->VMEM through
-    a two-deep manual DMA pipeline (page i+1 in flight while page i is in
-    the flash update). One grid step per slot keeps grid overhead off the
-    hot path — a BlockSpec-per-page variant spends more time stepping the
+    One grid step per slot, one turn of its loop per BLOCK of `block`
+    pages (`_block_copies`, `_block_update`): one [hkv, g, hd] .
+    [hkv, hd, block * page] score product, one running-softmax update, one
+    p . v. A page a turn paid the turn's dependency chain (wait, scores,
+    maximum, rescale, p . v, each behind the one before) once a page: 0.2
+    to 0.35 us over the page's bytes whether it held 128 KB or 512 (PERF.md
+    section 5, PR 61). One grid step per slot keeps grid overhead off the
+    hot path: a BlockSpec-per-page variant spends more time stepping the
     grid than computing (measured ~0.8ms per layer call vs ~0.2ms for
     this shape).
 
-    n_q > 1 (no caller passes it: the decode programs ask one query a
-    slot): the q block carries n_q query tokens per slot folded into the
-    head-group axis with the query index MINOR ([hkv, g*n_q, hd], layout
-    [g, n_q]); query j sits at absolute position lengths-1+j, so its
-    causal limit is lengths+j. The flash accumulators simply widen by n_q
-    rows.
+    `kbuf` / `vbuf` are two blocks. While block i is attended every copy
+    of block i + 1 is in flight, and a slot's last turn starts the NEXT
+    slot's first block (the grid is sequential: `first_ref` carries the
+    half that block lands in), so only the call's first block is waited
+    for with nothing to compute: what a window layer's call gains, whose
+    tables hold 2 to 5 pages a slot.
+
+    The ragged end: a slot's last block fetches the pages the slot holds
+    and no other. The rest of that block's buffer is attended all the
+    same, at a score of `_NEG`, and 0 times what lies there is 0 because
+    nothing non-finite can lie there: both blocks are zeroed on the first
+    grid step, and every copy since brought a page that a slot of this
+    call holds, the same pages whose own tail past `length` has always
+    been attended at p = 0.
 
     `sink_ref` [hkv * g, 128] float32 (`_dma_sink_kernel`), a query head's
     learned sink in every lane of its row: the flash update's starting
-    state is then running max the sink, sum 1, accumulator 0."""
-    b = pl.program_id(0)
+    state is then running max the sink, sum 1, accumulator 0, and the
+    maximum is finite whether or not a block holds a real position."""
+    b, nb = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
-    length = lengths_ref[b]
-    npg = jnp.minimum(
-        jax.lax.div(length + (n_q - 1) + page - 1, page), pages_per_seq)
+    span = block * page
+    # a length past the table's end attends what the table names
+    length = jnp.minimum(lengths_ref[b], pages_per_seq * page)
 
-    def start_copy(i, slot):
-        pid = tables_ref[b, i]
-        pltpu.make_async_copy(
-            k_hbm.at[layer, :, pid], kbuf.at[slot], sem.at[slot, 0]).start()
-        pltpu.make_async_copy(
-            v_hbm.at[layer, :, pid], vbuf.at[slot], sem.at[slot, 1]).start()
+    def pages_of(slot):
+        return jnp.minimum(
+            jax.lax.div(lengths_ref[slot] + page - 1, page), pages_per_seq)
 
-    def wait_copy(slot):
-        pltpu.make_async_copy(
-            k_hbm.at[layer, :, 0], kbuf.at[slot], sem.at[slot, 0]).wait()
-        pltpu.make_async_copy(
-            v_hbm.at[layer, :, 0], vbuf.at[slot], sem.at[slot, 1]).wait()
+    def copies(slot, i, half, wait=False):
+        """Block i of `slot`: the pages the slot holds there."""
+        _block_copies(
+            tables_ref, k_hbm, v_hbm, kbuf, vbuf, sem, layer=layer,
+            slot=slot, first=i * block, half=half, page=page, wait=wait,
+            count=jnp.minimum(pages_of(slot) - i * block, block))
+
+    npg = pages_of(b)
+    nblk = jax.lax.div(npg + block - 1, block)
+
+    @pl.when(b == 0)
+    def _clean():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        first_ref[0] = 0
+
+    first = first_ref[0]          # the half this slot's block 0 lands in
+
+    # the slot before started this slot's first block in its last turn,
+    # unless it had no turn (or there is no slot before)
+    @pl.when((npg > 0)
+             & ((b == 0) | (pages_of(jnp.maximum(b - 1, 0)) == 0)))
+    def _first():
+        copies(b, 0, first)
 
     if sink_ref is None:
         m_ref[...] = jnp.full_like(m_ref, _NEG)
@@ -178,57 +279,30 @@ def _dma_kernel(layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
         m_ref[...] = sink_ref[...]
         l_ref[...] = jnp.ones_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(npg > 0)
-    def _first():
-        start_copy(0, 0)
-
     q = q_ref[0].astype(jnp.float32)                   # [hkv, g, hd]
-    hkv, g, hd = q.shape
+    hkv, g, _ = q.shape
 
     def body(i, _):
-        slot = jax.lax.rem(i, 2)
+        half = jax.lax.rem(first + i, 2)
 
-        @pl.when(i + 1 < npg)
+        # the copies of this slot's next block or, in its last turn, of
+        # the next slot's first (none where that slot holds no page)
+        last = i + 1 == nblk
+
+        @pl.when(~last | (b + 1 < nb))
         def _prefetch():
-            start_copy(i + 1, 1 - slot)
+            copies(jnp.where(last, jnp.minimum(b + 1, nb - 1), b),
+                   jnp.where(last, 0, i + 1), 1 - half)
 
-        wait_copy(slot)
-        k = kbuf[slot].astype(jnp.float32)             # [hkv, hd, page]
-        v = vbuf[slot].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [hkv, g, page]
-        pos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, dimension=2)
-        if n_q == 1:
-            limit = length
-        else:
-            # row r of the folded axis is query j = r % n_q
-            limit = length + jax.lax.rem(
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=1),
-                n_q)
-        s = jnp.where(pos < limit, s, _NEG)
-        if lows_ref is not None:    # a window layer: nothing before `lows`
-            s = jnp.where(pos >= lows_ref[b], s, _NEG)
-        m_old = m_ref[...]                             # [hkv*g, 128]
-        s2 = s.reshape(hkv * g, page)
-        m_cur = jnp.max(s2, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_old, jnp.broadcast_to(m_cur, m_old.shape))
-        alpha = jnp.exp(m_old[:, :1] - m_new[:, :1])
-        p_exp = jnp.exp(s2 - m_new[:, :1])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(
-            p_exp, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_exp.reshape(hkv, g, page), v,
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [hkv, g, dv]
-        acc_ref[...] = acc_ref[...] * alpha[:, None].reshape(
-            hkv, g, 1) + pv
-        m_ref[...] = m_new
+        copies(b, i, half, wait=True)
+        _block_update(
+            q, kbuf[half].astype(jnp.float32), vbuf[half].astype(jnp.float32),
+            m_ref, l_ref, acc_ref, start=i * span, limit=length,
+            low=None if lows_ref is None else lows_ref[b], scale=scale)
         return 0
 
-    jax.lax.fori_loop(0, npg, body, 0)
+    jax.lax.fori_loop(0, nblk, body, 0)
+    first_ref[0] = jax.lax.rem(first + nblk, 2)
     l = l_ref[...][:, :1]
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc_ref[...] / l.reshape(hkv, g, 1)).astype(o_ref.dtype)
@@ -249,13 +323,13 @@ def _dma_sink_kernel(layer_ref, lengths_ref, tables_ref, lows_ref, q_ref,
                 *refs, lows_ref=lows_ref, sink_ref=sink_ref, **statics)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "name"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "name"))
 def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, layer,
-                      lows=None, sink=None, *, interpret: bool = False,
-                      name: str | None = None):
+                      lows=None, sink=None, *, block: int,
+                      interpret: bool = False, name: str | None = None):
     """With `lows` (a window layer's pages): one more prefetched scalar a
     slot, below which nothing counts; with a `sink` besides, one more
-    input, resident over the grid."""
+    input, resident over the grid. `block`: the pages a turn attends."""
     B, h, hd = q.shape
     _, hkv, N, _, page = k_pages.shape
     dv = v_pages.shape[3]
@@ -269,7 +343,7 @@ def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, layer,
         scalars += (lows,)
     kernel = functools.partial(
         _dma_kernel if lows is None else _dma_window_kernel, page=page,
-        scale=scale, pages_per_seq=P)
+        scale=scale, pages_per_seq=P, block=block)
 
     def slot(b, *_scalars):
         return (b, 0, 0, 0)
@@ -294,12 +368,13 @@ def _paged_decode_dma(q, k_pages, v_pages, lengths, page_tables, layer,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, hkv, g, dv), slot),
             scratch_shapes=[
-                pltpu.VMEM((2, hkv, hd, page), k_pages.dtype),  # kbuf
-                pltpu.VMEM((2, hkv, dv, page), v_pages.dtype),  # vbuf
+                pltpu.VMEM((2, hkv, hd, block * page), k_pages.dtype),
+                pltpu.VMEM((2, hkv, dv, block * page), v_pages.dtype),
                 pltpu.VMEM((hkv * g, 128), jnp.float32),        # m
                 pltpu.VMEM((hkv * g, 128), jnp.float32),        # l
                 pltpu.VMEM((hkv, g, dv), jnp.float32),          # acc
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2, 2)),         # block, K | V
+                pltpu.SMEM((1,), jnp.int32),     # first block's half
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, hkv, g, dv), q.dtype),

@@ -347,6 +347,48 @@ def test_kernel_compiles_for_v5e(topo, case):
         "kernel (an XLA fallback took its place)")
 
 
+# The read-only paged decode kernel at the three hybrid cells' shapes
+# (`models/nemotron_h._attention_decode`'s call): slots, query heads, K/V
+# heads, the widest decode table, the cell's pages, and G, the pages a turn
+# attends by `latent_attention._block_pages` over K and V of a page
+# together (128, 64 and 512 KB).
+HYBRID_DECODE = {
+    "nemotron3_nano_30b": (64, 32, 2, 29, 2048, 8),
+    "solar_open2_250b": (128, 64, 8, 73, 5120, 2),
+    "jamba2_3b": (16, 20, 1, 131, 2400, 16),
+}
+
+
+@pytest.mark.parametrize("cell", list(HYBRID_DECODE))
+def test_hybrid_paged_decode_compiles_at_the_rules_block(topo, monkeypatch,
+                                                         cell):
+    """Mosaic takes `_dma_kernel` at the G the rule gives these shapes: the
+    two blocks of G pages, their float32 upcasts and the scores of a turn
+    fit its scoped VMEM (a kernel that asks too much is refused here, which
+    the interpreter cannot see)."""
+    from ray_tpu.ops import paged_attention as pa
+    slots, h, hkv, p_seq, n_pages, want = HYBRID_DECODE[cell]
+    one = SingleDeviceSharding(topo.devices[0])
+    dma, blocks = pa._paged_decode_dma, []
+
+    def spy(*a, block, **kw):
+        blocks.append(block)
+        return dma(*a, block=block, **kw)
+
+    monkeypatch.setattr(pa, "_paged_decode_dma", spy)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    pool = sds((2, hkv, n_pages, HD, PAGE))
+    text = jax.jit(lambda *a: pa.paged_decode_attention(
+        *a, layer=1, interpret=False)).lower(
+        sds((slots, h, HD)), pool, pool, sds((slots,), jnp.int32),
+        sds((slots, p_seq), jnp.int32)).compile().as_text()
+    assert blocks == [want]
+    assert "tpu_custom_call" in text
+
+
 def _qwen2_7b(layers, one):
     """(config, parameter shapes on device `one`) of `layers` layers at
     qwen2_7b's published widths."""

@@ -334,11 +334,19 @@ def test_a_first_token_that_ends_its_request_is_returned():
 # PR 57: off the chip it holds the latent decode kernel's interpreter
 # (`paged_latent_decode`), whose loop now attends a block of pages a turn;
 # its two prefill programs and every program of the other kinds passed as
-# they were.
+# they were. The hybrid and the per-head kinds' `decode_paged` were taken
+# again at PR 61: off the chip both hold the interpreter of the read-only
+# paged decode kernel (`ops/paged_attention._dma_kernel`; the per-head kind
+# through `paged_decode_insert_attention`'s XLA insert, since the fused
+# kernel's write-back does not interpret), whose loop now attends a block
+# of pages a turn; on the chip the per-head kind runs `_fused_kernel`, whose
+# jaxpr inside `decode_paged` is the parent's to the character (PERF.md
+# section 6, PR 61). The latent kind's three programs and the other kinds'
+# four prefill programs passed as they were.
 PROGRAMS = {
     "per_head": {
         "decode_paged":
-            "ddfb2b7a0ca6ac68da44e5d92d38adb2588ff9dcd29ef545126b03b25cb9d7a0",
+            "b6982c390ecfd61a4254f132dc13d3eb9f96ada07a3bd242dcc68b4a1a75448b",
         "prefill_batch":
             "7fa5792158f9e2cbedd1d91dcd51ffbd100fb5da31623fc8a8c611748f9a3d42",
         "prefill_with_prefix_batch":
@@ -354,7 +362,7 @@ PROGRAMS = {
     },
     "hybrid": {
         "decode_paged":
-            "54eb863618eac5b3d2e03811ee494061e7b2743d5f54004c13466aefaf02d72b",
+            "d28eaf0194fa4cf7a425405c34ea8ef8018003033eab43c2e46899131c70ddc7",
         "prefill_batch":
             "b7a9b155bff79759c0263a7097fcc6270b9f73287177e60f59bf0678cff00264",
         "prefill_with_prefix_batch":
@@ -365,8 +373,12 @@ PROGRAMS = {
 # fingerprints.json, as sorted JSON. Taken again at PR 48 for the two
 # entries that left with their programs (the window loop's and the
 # speculative verify's); the five that stay are the parent's to the byte.
+# Taken again at PR 61 for `llm.decode_paged@1dev` alone (flops 196300 ->
+# 210500, bytes 315500 -> 347500): the graph is the per-head program as the
+# CPU lowers it, with the read-only paged kernel's interpreter inside,
+# whose turn is a block of pages now; the four others are the parent's.
 FINGERPRINTS = (
-    "925a231d70e327a227844935f6c2c937af5fd64582399000559363fd80d6e824")
+    "3593251875236f5daa6801e4d7a14d6604207c84bd8d4f77385eae8dd506e182")
 
 
 # The two per-head prefill programs at a shape of more than one tile, [2,
